@@ -1,10 +1,8 @@
-# TPU-native training image.
+# Training image: the pinned stack of requirements.txt on a slim Python base.
 #
 # Reference analogue: Dockerfile:1-23 builds on a CUDA 10.2 / cuDNN 7 base
-# because the accelerator stack lives in the container.  On Cloud TPU the
-# accelerator runtime (libtpu) is provided via the TPU VM, so a slim Python
-# base suffices; swap the jax pin for the TPU wheel when building for a TPU
-# VM (see comment below).
+# because the accelerator stack lives in the container.  Here it is a pip
+# pin like the rest: requirements.txt names jax, jaxlib and libtpu together.
 FROM python:3.12-slim
 
 RUN apt-get update \
@@ -14,9 +12,6 @@ RUN apt-get update \
 WORKDIR /workspace
 
 COPY requirements.txt requirements-dev.txt ./
-# CPU wheels by default (CI / laptop). On a TPU VM instead run:
-#   pip install 'jax[tpu]==0.9.0' \
-#     -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
 RUN pip install --no-cache-dir -r requirements.txt
 
 COPY . .
@@ -25,8 +20,10 @@ COPY . .
 RUN useradd -m trainer && chown -R trainer /workspace
 USER trainer
 
-# 8-virtual-device CPU mesh by default so the SPMD paths run anywhere;
-# harmless on a real TPU VM (TPU devices take precedence).
+# The default command is the CPU rehearsal, and says so: the CPU backend on
+# an 8-virtual-device mesh.  On a TPU host start the container with
+# JAX_PLATFORMS unset (e.g. `-e JAX_PLATFORMS=`) and run chip_smoke.py first.
+ENV JAX_PLATFORMS=cpu
 ENV XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 CMD ["sh", "src/tpu_jax/run_tpu.sh", "--synthetic-data"]

@@ -1,90 +1,34 @@
-"""Version tolerance for the narrow jax API surface that moved between
-releases.
+"""The jax-pin seam: the private or backend-dependent jax surfaces this
+package leans on, written for the one installation there is
+(``requirements.txt``: jax/jaxlib 0.9.0, libtpu 0.0.34).
 
-The framework targets the pinned ``requirements.txt`` jax, but the repo
-must also import (and its CPU tests must run) on the adjacent releases CI
-images carry.  Exactly three things have moved:
+Everything public and stable (``jax.shard_map``, ``jax.lax.axis_size``,
+``pltpu.CompilerParams``) is imported from jax where it is used.  What
+lives here is what a jax upgrade must re-check in one place:
 
-- ``shard_map``: top-level ``jax.shard_map`` in newer releases, under
-  ``jax.experimental.shard_map`` before that;
-- its replication-check kwarg: ``check_vma`` today, ``check_rep`` in
-  older releases (same meaning — the wrapper translates);
-- ``jax.lax.axis_size``: absent in older releases, where the idiom is
-  ``psum(1, axis)`` (folded to the static size on a constant operand);
-- the Pallas TPU compiler-params dataclass: ``pltpu.CompilerParams``
-  today, ``pltpu.TPUCompilerParams`` in older releases (same fields).
-
-Beyond those renames, this module also guards the *observability-only*
-API surface (device/executable memory stats, cost analysis, the
-monitoring listener, ``jax.live_arrays``): telemetry reads that degrade
-to "no data" instead of breaking training when a jax release moves them.
-
-Import them from here; everything else in the codebase uses stable API.
+- the persistent compile cache's write bar for donated executables
+  (a private config ``State``);
+- the compile-observability reads (``obs/compilation.py``,
+  ``obs/resource.py``): executable cost/memory analysis, the private
+  monitoring listener the persistent cache reports hits through, and
+  device memory stats.  These differ by BACKEND on this jax — the CPU
+  backend reports no device memory stats, a deserialized executable may
+  refuse an analysis — so they return ``None`` for "no data" instead of
+  raising into the train path.
 """
 
 from __future__ import annotations
 
-import inspect
+from contextlib import nullcontext
 
-try:  # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_HAS_CHECK_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
-
-def shard_map(*args, **kwargs):
-    """``jax.shard_map`` with the ``check_vma``→``check_rep`` kwarg rename
-    papered over (callers use the current name)."""
-    if not _HAS_CHECK_VMA and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(*args, **kwargs)
-
-import jax as _jax
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` with the pre-export fallback (``psum(1, ·)``
-    over a constant folds to the static mapped-axis size)."""
-    fn = getattr(_jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return _jax.lax.psum(1, axis_name)
-
-
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def _missing_compiler_params(*_a, **_k):
-    raise ImportError(
-        "this jax release exposes neither pltpu.CompilerParams nor "
-        "pltpu.TPUCompilerParams — the Pallas kernels need one of them; "
-        "install a requirements.txt-adjacent jax"
-    )
-
-
-# Resolved lazily-failing rather than raising at import: only the Pallas
-# kernel call sites need it, and the rest of the package must stay
-# importable on such a jax.
-CompilerParams = getattr(
-    _pltpu,
-    "CompilerParams",
-    getattr(_pltpu, "TPUCompilerParams", _missing_compiler_params),
+import jax
+from jax._src import monitoring
+from jax._src.config import (
+    persistent_cache_min_compile_time_secs as _min_compile_secs,
 )
 
 
-from contextlib import nullcontext as _nullcontext
-
-try:  # thread-scoped config State, context-manager-able on this jax
-    from jax._src.config import (
-        persistent_cache_min_compile_time_secs as _min_compile_secs,
-    )
-except ImportError:  # pragma: no cover - future jax moved/renamed it
-    _min_compile_secs = None
-
-
-def donated_cache_write_barred():
+def donated_cache_write_barred(platform: str):
     """Context under which freshly-compiled executables are NEVER written to
     the persistent on-disk cache (the min-compile-time write threshold is
     raised past any real compile; the threshold is read at write time, so a
@@ -96,132 +40,81 @@ def donated_cache_write_barred():
     re-running the donated scanned runners segfaults or silently corrupts
     the carried train state (reproduced while developing
     tests/test_overlap.py; cold-cache and cache-off runs are correct, as
-    are non-donated programs).  The donated hot-path runners therefore
-    compile under this context: their executables exist only in process
-    memory, so no process can ever deserialize one — donation's HBM saving
-    is kept, the cache keeps serving the expensive non-donated programs
-    (eval runners, serve buckets), and only the donated runners pay a
-    per-process compile.  If the config State ever moves in a future jax,
-    this degrades to a no-op — caching donated programs again — so revisit
-    the underlying bug before upgrading past it.
+    are non-donated programs).  ``platform`` is the platform the executable
+    is compiled FOR (its mesh's devices, not the default backend).  The
+    fault was never seen on ``"tpu"`` — there the donated train programs
+    are cached like any other (``chip_smoke.py`` run twice checks it: warm
+    cache hits, bit-identical losses) — so the bar stays up everywhere
+    else: the donated runners' executables exist only in process memory,
+    and no process can ever deserialize one.
     """
-    if _min_compile_secs is None:  # pragma: no cover - future jax
-        return _nullcontext()
+    if platform == "tpu":
+        return nullcontext()
     return _min_compile_secs(1e18)
 
 
 # ---------------------------------------------------------------- compiler
 #
-# The compile-observability hook (obs/compilation.py) leans on four jax
-# surfaces that have each moved (or may move) between releases: the AOT
-# executable's cost/memory analyses, the internal monitoring listener the
-# persistent compile cache reports hits through, and jax.live_arrays.
-# Every accessor below degrades to None/False — compile telemetry must
-# never be the reason a run fails to import or train.
+# The compile-observability hook (obs/compilation.py) reads the AOT
+# executable's cost/memory analyses and the internal monitoring stream the
+# persistent compile cache reports hits on.  The analyses return None for
+# "this executable reports nothing" — compile telemetry must never be the
+# reason a run fails to train.
 
 
 def executable_cost_analysis(compiled) -> dict | None:
-    """``Compiled.cost_analysis()`` normalized to ONE flat dict (newer jax
-    returns the dict directly, older returns a one-element list of dicts);
-    ``None`` when the API is absent, raises, or reports nothing."""
-    fn = getattr(compiled, "cost_analysis", None)
-    if fn is None:
-        return None
+    """``Compiled.cost_analysis()`` (one flat dict on this jax); ``None``
+    when the executable raises or reports nothing."""
     try:
-        out = fn()
+        out = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(out, (list, tuple)):
-        out = out[0] if out else None
-    return out if isinstance(out, dict) and out else None
+    return out or None
 
 
 def executable_memory_analysis(compiled) -> dict | None:
     """``Compiled.memory_analysis()`` flattened to the byte counts the HBM
     ledger wants (``{argument,output,temp,generated_code,alias}_bytes``);
-    ``None`` when absent/raising — the CPU CI backend HAS these today, but
-    the hook must outlive a jax that drops them."""
-    fn = getattr(compiled, "memory_analysis", None)
-    if fn is None:
-        return None
+    ``None`` when the executable raises or reports nothing."""
     try:
-        stats = fn()
+        stats = compiled.memory_analysis()
     except Exception:
         return None
     if stats is None:
         return None
-    out = {}
-    for key, attr in (
-        ("argument_bytes", "argument_size_in_bytes"),
-        ("output_bytes", "output_size_in_bytes"),
-        ("temp_bytes", "temp_size_in_bytes"),
-        ("alias_bytes", "alias_size_in_bytes"),
-        ("generated_code_bytes", "generated_code_size_in_bytes"),
-    ):
-        v = getattr(stats, attr, None)
-        if isinstance(v, int):
-            out[key] = v
-    return out or None
+    return {
+        "argument_bytes": stats.argument_size_in_bytes,
+        "output_bytes": stats.output_size_in_bytes,
+        "temp_bytes": stats.temp_size_in_bytes,
+        "alias_bytes": stats.alias_size_in_bytes,
+        "generated_code_bytes": stats.generated_code_size_in_bytes,
+    }
 
 
-def register_monitoring_listener(callback) -> bool:
+def register_monitoring_listener(callback) -> None:
     """Attach ``callback(event, **metadata)`` to jax's internal monitoring
     stream (the persistent compile cache announces hits there as
-    ``/jax/compilation_cache/cache_hits``).  Private API — returns False
-    (and the caller reports cache state 'unknown') when it has moved."""
-    try:
-        from jax._src import monitoring
-
-        monitoring.register_event_listener(callback)
-        return True
-    except Exception:
-        return False
+    ``/jax/compilation_cache/cache_hits``).  Private API."""
+    monitoring.register_event_listener(callback)
 
 
 def compilation_cache_dir() -> str | None:
     """The configured persistent compile-cache directory, or None when
     caching is off (then a compile can be neither a hit nor a miss)."""
-    try:
-        return _jax.config.jax_compilation_cache_dir or None
-    except Exception:
-        return None
-
-
-def live_arrays() -> list | None:
-    """``jax.live_arrays()`` or None where absent — the HBM census input
-    (obs/resource.py).  Callers must still guard per-array attribute
-    reads: a donated array in the list may already be deleted."""
-    fn = getattr(_jax, "live_arrays", None)
-    if fn is None:
-        return None
-    try:
-        return fn()
-    except Exception:
-        return None
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def device_memory_stats(device) -> dict | None:
-    """``device.memory_stats()`` normalized across backends: a dict with
-    at least ``bytes_in_use`` on allocator-backed devices (TPU/GPU), and
-    ``None`` wherever the stats don't exist — the CPU CI backend returns
-    None or raises depending on the jax release, and older Device classes
-    lack the method entirely.  Callers treat None as "no HBM gauge here",
-    never as an error."""
+    """``device.memory_stats()``: a dict with at least ``bytes_in_use`` on
+    allocator-backed devices (TPU), ``None`` on the CPU backend.  Callers
+    treat None as "no HBM gauge here", never as an error."""
     if device is None:
         return None
-    fn = getattr(device, "memory_stats", None)
-    if fn is None:
-        return None
-    try:
-        stats = fn()
-    except Exception:
-        return None
-    return stats if isinstance(stats, dict) and stats else None
+    return device.memory_stats() or None
 
 
 __all__ = [
-    "shard_map", "axis_size", "CompilerParams", "donated_cache_write_barred",
-    "device_memory_stats", "executable_cost_analysis",
-    "executable_memory_analysis", "register_monitoring_listener",
-    "compilation_cache_dir", "live_arrays",
+    "donated_cache_write_barred", "device_memory_stats",
+    "executable_cost_analysis", "executable_memory_analysis",
+    "register_monitoring_listener", "compilation_cache_dir",
 ]

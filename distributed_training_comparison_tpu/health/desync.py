@@ -132,7 +132,7 @@ def make_partial_fingerprint_fn(mesh, param_shardings=None):
     equal stays exactly equal; ``check_partial_desync``'s column
     comparison needs only that.
     """
-    from .._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if param_shardings is None:

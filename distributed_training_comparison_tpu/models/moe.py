@@ -53,6 +53,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..obs.compilation import note_kernel_path
 from ..ops.vmem import fits_weight_budget, gmm_weight_bytes
 
 
@@ -203,6 +204,15 @@ class SwitchFFN(nn.Module):
                 if jax.default_backend() == "tpu" and gmm_fits
                 else "gather"
             )
+        # off-TPU the kernel only runs through the Pallas interpreter (an
+        # explicit 'gmm' in CPU tests); which path ran is on the compile
+        # event
+        interpret = jax.default_backend() != "tpu"
+        note_kernel_path(
+            "moe_ffn",
+            "composed" if dispatch != "gmm"
+            else "pallas-interpret" if interpret else "pallas",
+        )
         if dispatch == "gmm":
             from ..ops.moe_gmm import grouped_ffn
 
@@ -232,7 +242,7 @@ class SwitchFFN(nn.Module):
                 w_up.astype(self.dtype), b_up.astype(self.dtype),
                 w_down.astype(self.dtype), b_down.astype(self.dtype),
                 starts, cap,
-                interpret=jax.default_backend() != "tpu",
+                interpret=interpret,
             )
             y = ys.at[dest].get(
                 unique_indices=True, mode="promise_in_bounds"

@@ -40,6 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..obs.compilation import note_kernel_path
 from ..ops.vmem import fits_weight_budget, fused_block_weight_bytes
 from .norms import norm_policy
 
@@ -175,6 +176,15 @@ class ViTBlock(nn.Module):
         )
         if self.block_fusion == "force" and not use_fused:
             _warn_force_composed(declined[0])
+        # off-TPU the kernel only runs through the Pallas interpreter (CPU
+        # tests); which of the three ran is on the compile event
+        interpret = jax.default_backend() != "tpu"
+        if not declined:
+            note_kernel_path(
+                "vit_block",
+                "composed" if not use_fused
+                else "pallas-interpret" if interpret else "pallas",
+            )
         if use_fused:
             from ..ops.vit_block import fused_vit_block
 
@@ -195,7 +205,7 @@ class ViTBlock(nn.Module):
                 params,
                 heads=self.heads,
                 norm_f32=self.norm_dtype is not None,
-                interpret=jax.default_backend() != "tpu",
+                interpret=interpret,
             )
             return out, None
 

@@ -16,18 +16,21 @@ that gap:
   new signatures itself through the AOT path (``lower().compile()``,
   timed), and dispatches through the compiled executable from then on.
   Owning the compile is what makes the executable *inspectable*:
-  ``cost_analysis()`` / ``memory_analysis()`` (via ``_compat`` — absent
-  APIs degrade to "no data") yield the per-executable FLOPs and the
+  ``cost_analysis()`` / ``memory_analysis()`` (via ``_compat`` — an
+  executable that reports nothing degrades to "no data") yield the per-executable FLOPs and the
   argument/output/temp HBM footprint no post-hoc hook could recover.
   Any failure anywhere in the instrumented path falls back to the plain
   jitted call — compile telemetry must never take training down.
 - Every compile emits ONE registered ``compile`` bus event: a stable
   **fingerprint** (sha256 over name + abstract in-shapes/dtypes +
   sharding specs + mesh axes — identical across processes of one fleet),
-  compile wall time, persistent-cache ``hit``/``miss``/``off``/
-  ``unknown`` (a monitoring listener catches the cache's own hit
-  events), the cost/memory analysis, and the device kind/count the
-  ``run_report --compute`` MFU reconstruction needs.
+  compile wall time, persistent-cache ``hit``/``miss``/``off`` (a
+  monitoring listener catches the cache's own hit events), the
+  cost/memory analysis, the device kind/count the ``run_report
+  --compute`` MFU reconstruction needs, which path each kernel gate took
+  while the program traced (``kernel_paths``, see ``note_kernel_path``)
+  and how many compiled Pallas kernels the executable carries
+  (``tpu_custom_calls``).
 - ``compile/*`` metrics ride the existing registry (and therefore every
   ``metrics`` flush, the OpenMetrics exporter, and ``--alert`` rules):
   compile counts total and per family, a compile-time histogram,
@@ -97,13 +100,12 @@ def peak_flops_for(device_kind: str | None) -> float | None:
 # stream; one process-wide listener (installed lazily, never removed —
 # the API has no unregister contract) bumps a per-thread counter, and the
 # probe brackets a compile on its own thread: hits observed → "hit",
-# none but a cache dir configured → "miss", no dir → "off", listener
-# unavailable → "unknown".
+# none but a cache dir configured → "miss", no dir → "off".
 
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _probe_local = threading.local()
 _probe_lock = threading.Lock()
-_probe_state = "uninstalled"  # -> "ok" | "unavailable"
+_probe_installed = False
 
 
 def _on_monitoring_event(event, **_kw) -> None:
@@ -111,32 +113,42 @@ def _on_monitoring_event(event, **_kw) -> None:
         _probe_local.hits = getattr(_probe_local, "hits", 0) + 1
 
 
-def _ensure_probe() -> bool:
-    global _probe_state
+def _ensure_probe() -> None:
+    global _probe_installed
     with _probe_lock:
-        if _probe_state == "uninstalled":
-            _probe_state = (
-                "ok"
-                if register_monitoring_listener(_on_monitoring_event)
-                else "unavailable"
-            )
-        return _probe_state == "ok"
+        if not _probe_installed:
+            register_monitoring_listener(_on_monitoring_event)
+            _probe_installed = True
+
+
+def note_kernel_path(kernel: str, path: str) -> None:
+    """Record, at TRACE time, which path a kernel gate took (``"pallas"``,
+    ``"pallas-interpret"`` or ``"composed"``).  The gates in models/vit.py,
+    models/moe.py and ops/attention.py choose from the backend and their
+    own shape limits; the choice is a fact about the executable being
+    built, so an observed compile collects what was noted while it lowered
+    and puts it on its ``compile`` event (``kernel_paths``).  A no-op
+    outside an observed compile."""
+    notes = getattr(_probe_local, "kernel_paths", None)
+    if notes is not None:
+        notes[kernel] = path
 
 
 class _CacheProbe:
-    """Bracket one compile; classify its persistent-cache outcome."""
+    """Bracket one lower+compile: classify its persistent-cache outcome and
+    collect the kernel paths its trace noted."""
 
     def __enter__(self) -> "_CacheProbe":
-        self._ok = _ensure_probe()
+        _ensure_probe()
         self._before = getattr(_probe_local, "hits", 0)
+        self.kernel_paths: dict[str, str] = {}
+        _probe_local.kernel_paths = self.kernel_paths
         return self
 
     def __exit__(self, *exc) -> None:
-        pass
+        _probe_local.kernel_paths = None
 
     def outcome(self) -> str:
-        if not self._ok:
-            return "unknown"
         if getattr(_probe_local, "hits", 0) > self._before:
             return "hit"
         return "miss" if compilation_cache_dir() else "off"
@@ -282,7 +294,7 @@ class CompileMonitor:
             compile_s = time.perf_counter() - t0
         rec = self._record_compile(
             name, fingerprint_of(name, parts), compile_s,
-            compiled, probe.outcome(), sentinel,
+            compiled, probe.outcome(), sentinel, probe.kernel_paths,
         )
         return compiled, rec
 
@@ -329,13 +341,15 @@ class CompileMonitor:
     # ---------------------------------------------------------- internal
 
     def _record_compile(
-        self, name, fingerprint, compile_s, compiled, cache, sentinel
+        self, name, fingerprint, compile_s, compiled, cache, sentinel,
+        kernel_paths=None,
     ) -> ExecutableRecord:
         """Fold one observed compile into the ledger, the registry, and
         the bus.  Never raises (the caller is the training hot path)."""
         try:
             return self._record_compile_inner(
-                name, fingerprint, compile_s, compiled, cache, sentinel
+                name, fingerprint, compile_s, compiled, cache, sentinel,
+                kernel_paths,
             )
         except Exception:
             rec = ExecutableRecord(name, fingerprint)
@@ -343,7 +357,8 @@ class CompileMonitor:
             return rec
 
     def _record_compile_inner(
-        self, name, fingerprint, compile_s, compiled, cache, sentinel
+        self, name, fingerprint, compile_s, compiled, cache, sentinel,
+        kernel_paths,
     ) -> ExecutableRecord:
         self._taint.flag = True
         cost = executable_cost_analysis(compiled) if compiled is not None else None
@@ -416,6 +431,11 @@ class CompileMonitor:
             if rec.memory:
                 payload.update(rec.memory)
                 payload["peak_bytes"] = rec.peak_bytes
+            if kernel_paths:
+                payload["kernel_paths"] = dict(kernel_paths)
+            mosaic = _mosaic_kernel_count(compiled)
+            if mosaic is not None:
+                payload["tpu_custom_calls"] = mosaic
             self.bus.emit(COMPILE_KIND, **payload)
         return rec
 
@@ -423,6 +443,19 @@ class CompileMonitor:
         hist = rec._dispatch_hist
         if hist is not None:
             hist.record(seconds)
+
+
+def _mosaic_kernel_count(compiled) -> int | None:
+    """How many compiled Pallas (Mosaic) kernels the executable's HLO
+    carries — ``tpu_custom_call`` targets.  A kernel run through the
+    Pallas interpreter, or a gate that composed instead, leaves none, so
+    with ``kernel_paths`` this says whether the kernel a model chose is
+    really in the program.  None when the executable has no text (a
+    deserialized one)."""
+    try:
+        return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    except Exception:
+        return None
 
 
 def _device_identity(compiled=None) -> tuple[str | None, str | None, int | None]:
@@ -549,7 +582,7 @@ class _InstrumentedFunction:
         )
         rec = self._monitor._record_compile(
             self._name, fingerprint, compile_s, compiled, cache,
-            self._sentinel,
+            self._sentinel, probe.kernel_paths,
         )
         entry = (compiled, rec)
         self._cache[key] = entry

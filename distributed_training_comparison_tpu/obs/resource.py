@@ -12,14 +12,14 @@ same ``metrics`` events, the exporter, and the alert engine as everything
 else (registry gauges are not reset by a flush, so every flush event
 carries the latest sampled values regardless of the cadence)::
 
-    res/hbm_used_bytes · res/hbm_limit_bytes   (device.memory_stats(),
-        guarded through _compat — absent on backends that report none,
+    res/hbm_used_bytes · res/hbm_limit_bytes   (device.memory_stats()
+        through _compat — absent on backends that report none,
         e.g. the CPU CI backend)
     res/host_rss_bytes                          (/proc/self/statm)
     res/open_fds                                (/proc/self/fd)
     res/disk_free_bytes                         (statvfs of the ckpt root)
-    res/live_arrays · res/live_array_bytes      (jax.live_arrays() census,
-        guarded through _compat — with the per-executable analysis totals
+    res/live_arrays · res/live_array_bytes      (jax.live_arrays() census
+        — with the per-executable analysis totals
         of the compile ledger this answers "where did HBM go": arrays the
         program still holds vs what the executables themselves reserve)
 
@@ -35,7 +35,7 @@ import shutil
 import time
 from pathlib import Path
 
-from .._compat import device_memory_stats, live_arrays
+from .._compat import device_memory_stats
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
@@ -63,17 +63,16 @@ def disk_free_bytes(path: str | Path) -> int | None:
         return None
 
 
-def live_array_census() -> tuple[int, int] | None:
+def live_array_census() -> tuple[int, int]:
     """``(count, total_bytes)`` over ``jax.live_arrays()`` — the array
     side of the HBM ledger.  Donated buffers linger in the list as
     deleted arrays whose attribute reads raise; they hold no memory and
-    are skipped, not counted.  None when the API is absent."""
-    arrays = live_arrays()
-    if arrays is None:
-        return None
+    are skipped, not counted."""
+    import jax
+
     count = 0
     total = 0
-    for a in arrays:
+    for a in jax.live_arrays():
         try:
             nbytes = a.nbytes
         except Exception:  # deleted (donated) array — owns nothing
@@ -126,10 +125,9 @@ class ResourceSampler:
             free = disk_free_bytes(self.ckpt_root)
             if free is not None:
                 out["res/disk_free_bytes"] = float(free)
-        census = live_array_census()
-        if census is not None:
-            out["res/live_arrays"] = float(census[0])
-            out["res/live_array_bytes"] = float(census[1])
+        count, nbytes = live_array_census()
+        out["res/live_arrays"] = float(count)
+        out["res/live_array_bytes"] = float(nbytes)
         stats = device_memory_stats(self._resolve_device())
         if stats:
             used = stats.get("bytes_in_use")

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from ..obs.compilation import note_kernel_path
 
 _NEG_INF = -1e30  # finite "-inf": keeps fully-masked rows NaN-free
 
@@ -327,7 +327,7 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret):
             pltpu.VMEM((bq, 8), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(q3, k3, v3)
@@ -528,7 +528,7 @@ def _flash_bwd(q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpre
     nq, nk = sq // bq, skv // bk
     # bh and the own-block grid dims are independent; only the innermost
     # (streaming, accumulating) dim must execute in order
-    params = CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
@@ -742,6 +742,12 @@ def attention(
             if on_tpu and kernel_ok and q.shape[seq_ax] >= min_seq
             else "reference"
         )
+    if impl in ("pallas", "fused_small"):
+        note_kernel_path(
+            "attention", "pallas-interpret" if interpret else "pallas"
+        )
+    elif impl == "reference":
+        note_kernel_path("attention", "composed")
     if impl == "fused_small":
         from .attention_small import small_mha
 

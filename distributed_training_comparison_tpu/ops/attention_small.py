@@ -62,8 +62,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -192,7 +190,7 @@ def _call(kernel, n_out, q2, *rest, tb, s, h, d, scale, causal, interpret):
         out_specs=spec if n_out == 1 else [spec] * n_out,
         out_shape=shape if n_out == 1 else [shape] * n_out,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
     )(q2, *rest)

@@ -58,8 +58,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -204,7 +202,7 @@ def _row_grid_call(kernel, n_out, out_dtype, xs, dy, weights, starts,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, d), out_dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
     )(starts, *tensor_in)
@@ -263,7 +261,7 @@ def _dw_call(xs, dy, w1, b1, w2, starts, cap, block_rows, interpret):
             jax.ShapeDtypeStruct((ne, 1, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
     )(starts, xs, dy, w1, b1[:, None, :], w2)

@@ -73,8 +73,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 from .attention_small import head_bwd, head_fwd, pick_block_items
 
 _LN_EPS = 1e-6
@@ -269,7 +267,7 @@ def _block_call(x2, dy2, params, tb, s, h, d, scale, norm_f32, interpret):
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((n, dim), x2.dtype),
             interpret=interpret,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             ),
         )(x2, *p2)
@@ -285,7 +283,7 @@ def _block_call(x2, dy2, params, tb, s, h, d, scale, norm_f32, interpret):
         ],
         out_shape=[jax.ShapeDtypeStruct((n, dim), x2.dtype)] + grad_shapes,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
     )(x2, dy2, *p2)

@@ -50,11 +50,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .._compat import shard_map
 from .mesh import DATA_AXIS
 
 GRAD_COMMS_MODES = ("fp32", "fp16", "int8")

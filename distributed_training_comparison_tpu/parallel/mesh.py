@@ -19,6 +19,7 @@ activation handoff is one hop.
 
 from __future__ import annotations
 
+import logging
 from typing import Sequence
 
 import jax
@@ -29,6 +30,10 @@ from jax.sharding import Mesh
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 PIPE_AXIS = "pipe"
+
+# a child of the run logger (utils/logging.py): lines reach experiment.log
+# once a Trainer has set that up; the reshape fallback warns either way
+_log = logging.getLogger("dtc_tpu.mesh")
 
 
 def mesh_shape_for_backend(
@@ -104,10 +109,20 @@ def make_mesh(
     n_used = shape[0] * shape[1] * shape[2]
     if n_used != len(devices):
         devices = devices[:n_used]
+    kind = f"{len(devices)} {devices[0].device_kind} device(s)"
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except (ValueError, AssertionError):
+        _log.info(
+            "make_mesh: %s over %s, device array built by "
+            "mesh_utils.create_device_mesh", shape, kind,
+        )
+    except (ValueError, AssertionError) as e:
         # create_device_mesh can reject shapes that don't tile the physical
-        # topology (or CPU test meshes); a plain reshape is always valid.
+        # topology; a plain reshape is always valid, but its axis
+        # neighbours need not be interconnect neighbours — say so.
         dev_array = np.asarray(list(devices)).reshape(shape)
+        _log.warning(
+            "make_mesh: %s over %s, create_device_mesh refused (%s): "
+            "device array built by plain reshape", shape, kind, e,
+        )
     return Mesh(dev_array, (DATA_AXIS, MODEL_AXIS, PIPE_AXIS))

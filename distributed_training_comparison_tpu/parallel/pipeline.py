@@ -63,7 +63,8 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from .._compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, MODEL_AXIS, PIPE_AXIS

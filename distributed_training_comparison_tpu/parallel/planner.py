@@ -90,13 +90,14 @@ WIRE_BYTES_PER_S_BY_DEVICE_KIND = {
     "TPU v6 lite": 90e9,
     "TPU v6e": 90e9,
 }
-# unknown device kinds (the CPU CI container): a flat planning number so
-# the comms term still *ranks* layouts; absolute seconds are then labeled
-# fit_source="default" in the plan event
+# the CPU backend (tests, rehearsals) has no entry: a flat planning number
+# so the comms term still *ranks* layouts; absolute seconds are then
+# labeled fit_source="default" in the plan event.  A TPU kind with no
+# entry is an error (CostModel.fit), never this default.
 DEFAULT_WIRE_BYTES_PER_S = 10e9
 # peak-table fallback assumes this MFU when no dispatch sketches exist
 ASSUMED_MFU = 0.3
-# flat compute-throughput fallback for device kinds with no peak entry
+# flat compute-throughput fallback, CPU backend only (see above)
 DEFAULT_FLOPS_PER_S = 5e10
 # the HBM feasibility gate refuses candidates predicted past this share
 # of the device limit (headroom for allocator slack + staging buffers)
@@ -618,11 +619,25 @@ class CostModel:
         from ..obs.compilation import peak_flops_for
 
         kind = device_kind or (ledger.device_kind if ledger else None)
-        wire = DEFAULT_WIRE_BYTES_PER_S
-        for prefix, bw in WIRE_BYTES_PER_S_BY_DEVICE_KIND.items():
-            if kind and str(kind).startswith(prefix):
-                wire = bw
-                break
+        # every jax TPU device_kind starts "TPU"; pricing one the tables do
+        # not know at the CPU's made-up defaults would rank layouts for a
+        # chip nobody measured
+        on_tpu = bool(kind) and str(kind).startswith("TPU")
+        wire = next(
+            (
+                bw for prefix, bw in WIRE_BYTES_PER_S_BY_DEVICE_KIND.items()
+                if kind and str(kind).startswith(prefix)
+            ),
+            None,
+        )
+        if wire is None:
+            if on_tpu:
+                raise PlanError(
+                    f"device kind {kind!r} has no entry in "
+                    "WIRE_BYTES_PER_S_BY_DEVICE_KIND: add its interconnect "
+                    "bandwidth (with its source) before planning for it"
+                )
+            wire = DEFAULT_WIRE_BYTES_PER_S
         points = list(ledger.points) if ledger else []
         if len(points) >= 2:
             # least squares t = a·f + b, clamped non-negative: a is the
@@ -658,6 +673,13 @@ class CostModel:
             return cls(
                 secs_per_flop=1.0 / (peak * ASSUMED_MFU),
                 wire_bytes_per_s=wire, device_kind=kind, source="peak-table",
+            )
+        if on_tpu:
+            raise PlanError(
+                f"device kind {kind!r} has no entry in obs.compilation."
+                "PEAK_FLOPS_BY_DEVICE_KIND and the ledger holds no "
+                "dispatch sketch to fit: add its peak (with its source) "
+                "before planning for it"
             )
         return cls(
             secs_per_flop=1.0 / DEFAULT_FLOPS_PER_S,

@@ -34,7 +34,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from .._compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import _NEG_INF as _NEG_BIG, attention

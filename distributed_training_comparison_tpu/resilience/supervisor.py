@@ -302,6 +302,26 @@ def run_supervised(hparams, argv: Sequence[str] | None = None) -> dict:
     from .. import obs
     from .goodput import aggregate_goodput, collect_goodput_records, write_goodput
 
+    if (
+        int(getattr(hparams, "fleet_hosts", 0) or 0) > 1
+        and not int(getattr(hparams, "fleet_local_devices", 0) or 0)
+        and os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+        != "cpu"
+    ):
+        # The fleet's "hosts" are processes on THIS machine, and with
+        # --fleet-local-devices 0 they inherit its accelerators unchanged.
+        # One process owns a chip: found on a v5e, the second host dies on
+        # libtpu's multi-process lockfile while the first waits in the
+        # rendezvous, and the fleet hangs.  This supervisor stays off JAX
+        # (its children need the chips), so it judges from the environment,
+        # and refuses before spawning.
+        raise SystemExit(
+            f"--fleet-hosts {hparams.fleet_hosts} starts that many host "
+            "processes on this one machine, and they would all open the "
+            "same chips (one process owns a chip; hosts are not placed per "
+            "chip yet): pass --fleet-local-devices K to emulate the fleet "
+            "on K virtual CPU devices per host, or set JAX_PLATFORMS=cpu"
+        )
     argv = list(sys.argv[1:] if argv is None else argv)
     child_args = [a for a in argv if a != "--supervise"]
     for extra in ("--auto-resume", "--resilience"):

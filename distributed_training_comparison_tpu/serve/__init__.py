@@ -255,6 +255,23 @@ def serve_main(hparams) -> dict:
             "--serve is single-process: run it on one host (a multi-host "
             "launch would dispatch desynchronized bucket programs)"
         )
+    if (
+        str(getattr(hparams, "serve_transport", "thread")) == "process"
+        and jax.devices()[0].platform == "tpu"
+    ):
+        # One process owns a chip.  This process — the router — has opened
+        # the host's chips by now (entry.run initialized the backend), so a
+        # replica process cannot: found on a v5e, the worker dies on
+        # libtpu's multi-process lockfile and the fleet burns its restart
+        # budget before failing.  Per-chip placement of replica processes
+        # does not exist yet; refuse before spawning.
+        raise ValueError(
+            "--serve-transport process cannot run on a TPU host: this "
+            "router process holds the chip(s), so replica processes cannot "
+            "open them, and replicas are not placed per chip yet — use "
+            "--serve-transport thread (N engines in this process), or "
+            "JAX_PLATFORMS=cpu for the CPU process fleet"
+        )
     logger = setup_logger(None, is_main_process=is_main_process())
     # obs wiring happens BEFORE the engines exist so the warmup compiles
     # are observed: the bus buffers pre-bind emits and flushes them when

@@ -59,14 +59,20 @@ def _observed(jitted, monitor, name, sentinel=True):
     return monitor.instrument(jitted, name, sentinel=sentinel)
 
 
-def _donated_jit(fun, *, donate_argnums, monitor=None, name=None, **jit_kw):
-    """``jax.jit`` with buffer donation whose executables are never WRITTEN
-    to the persistent compile cache: donated executables deserialized from
-    the on-disk cache misbehave on this jax's CPU backend (segfaults /
-    silently corrupted carries — see ``_compat.donated_cache_write_barred``).
-    Barring the write means no process can ever load one.  The context
-    wraps every call (compilation happens at the first call per shape);
-    steady-state calls pay only a thread-local config flip.
+def _donated_jit(
+    fun, mesh: Mesh, *, donate_argnums, monitor=None, name=None, **jit_kw
+):
+    """``jax.jit`` with buffer donation.  Off the TPU its executables are
+    never WRITTEN to the persistent compile cache: donated executables
+    deserialized from the on-disk cache misbehave on this jax's CPU backend
+    (segfaults / silently corrupted carries — see
+    ``_compat.donated_cache_write_barred``), and barring the write means no
+    process can ever load one.  The bar is keyed on the platform of
+    ``mesh``'s devices — what the executable is compiled FOR — and is down
+    on ``"tpu"``, where the train programs are the costliest compiles of a
+    run and are cached like any other.  The context wraps every call
+    (compilation happens at the first call per shape); steady-state calls
+    pay only a thread-local config flip.
 
     The compile monitor wraps INSIDE this context, so an observed AOT
     compile of a donated runner happens under the same write bar as the
@@ -75,6 +81,7 @@ def _donated_jit(fun, *, donate_argnums, monitor=None, name=None, **jit_kw):
         jax.jit(fun, donate_argnums=donate_argnums, **jit_kw),
         monitor, name or getattr(fun, "__name__", "donated"),
     )
+    platform = mesh.devices.flat[0].platform
 
     def call(*args):
         # An input uint8 chunk can rarely alias any float output, so a
@@ -92,7 +99,7 @@ def _donated_jit(fun, *, donate_argnums, monitor=None, name=None, **jit_kw):
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
-            with donated_cache_write_barred():
+            with donated_cache_write_barred(platform):
                 return jitted(*args)
 
     return call
@@ -670,8 +677,7 @@ def make_chunk_runner(
 
     The streaming path can't pre-stage the whole split in HBM, but paying a
     dispatch + H2D round-trip per step leaves the chip idle between tiny
-    step programs (measured on the bench host: ~20× slower than the scanned
-    epoch).  Stacking K batches ``(K, B, ...)`` and scanning K steps per
+    step programs.  Stacking K batches ``(K, B, ...)`` and scanning K steps per
     dispatch amortizes that latency K× while keeping memory bounded.
 
     Per-step PRNG keys are folded from ``(epoch_key, start + k)`` — the
@@ -727,6 +733,7 @@ def make_chunk_runner(
         return _declare_state_layout(
             _donated_jit(
                 run,
+                mesh,
                 donate_argnums=(0, 1, 2),
                 monitor=monitor,
                 name="chunk_runner",
@@ -833,7 +840,7 @@ def make_device_chunk_runner(
     if donate:
         return _declare_state_layout(
             _donated_jit(
-                run, donate_argnums=(0,), monitor=monitor,
+                run, mesh, donate_argnums=(0,), monitor=monitor,
                 name=obs_name, out_shardings=(state_sh, repl),
             ),
             fwd_bwd, state_layout,
@@ -924,7 +931,7 @@ def make_epoch_runner(
     if donate:
         return _declare_state_layout(
             _donated_jit(
-                run, donate_argnums=(0,), monitor=monitor,
+                run, mesh, donate_argnums=(0,), monitor=monitor,
                 name="epoch_runner", out_shardings=(state_sh, repl),
             ),
             fwd_bwd, state_layout,

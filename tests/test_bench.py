@@ -39,27 +39,6 @@ def test_train_is_three_forwards():
     )
 
 
-def test_run_legs_retries_transient_failures(monkeypatch):
-    """A leg that fails once (the remote-compile service dropping a
-    connection) and succeeds on retry must record its numbers, not an
-    error."""
-    import bench
-
-    calls = {"n": 0}
-
-    def flaky_bench_native(*a, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("response body closed before all bytes were read")
-        return 2000.0
-
-    monkeypatch.setattr(bench, "bench_native", flaky_bench_native)
-    configs = [("leg", "resnet18", "bf16", 64, 32, "cifar", 128, 1, {})]
-    per_config, _ = bench.run_legs(None, configs, 1, 197e12)
-    assert per_config["leg"]["images_per_sec_per_chip"] == 2000.0
-    assert calls["n"] == 2
-
-
 def test_run_legs_isolates_leg_failures(monkeypatch):
     """One leg blowing up (the round-3 failure mode: a compile OOM) must
     record an error for that leg only — every other leg's numbers survive."""
@@ -186,6 +165,8 @@ def test_main_emits_one_budgeted_line_and_detail_file(monkeypatch, tmp_path, cap
         bench, "bench_flash_attention", lambda *a, **kw: {"configs": {}}
     )
     monkeypatch.chdir(tmp_path)
+    # the rehearsal sizing is honoured only under an EXPLICIT cpu platform
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     bench.main()
     out = capsys.readouterr().out.strip()
     assert "\n" not in out  # ONE line
@@ -214,3 +195,82 @@ def test_compact_line_degrades_instead_of_overflowing():
     parsed = json.loads(line)
     assert parsed["value"] == 34710.4
     assert "mfu" not in parsed["detail"]  # dropped to fit
+
+
+def _stub_measurements(monkeypatch, bench, native):
+    monkeypatch.setattr(bench, "bench_native", native)
+    monkeypatch.setattr(
+        bench, "bench_reference_style", lambda *a, **kw: 500.0
+    )
+    monkeypatch.setattr(
+        bench, "bench_flash_attention", lambda *a, **kw: {"configs": {}}
+    )
+
+
+def test_main_refuses_without_a_tpu_or_an_explicit_cpu(monkeypatch, tmp_path, capsys):
+    """No chip and no explicit JAX_PLATFORMS=cpu: the default mode must
+    refuse (non-zero, reason on stderr, NO result line) instead of silently
+    sizing down to whatever backend answered."""
+    import bench
+
+    _stub_measurements(monkeypatch, bench, lambda *a, **kw: 1000.0)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code not in (0, None)
+    assert "no TPU found" in str(ei.value.code)
+    assert capsys.readouterr().out.strip() == ""
+    assert not (tmp_path / "BENCH_DETAIL.json").exists()
+
+
+def test_main_exits_nonzero_when_a_leg_errored(monkeypatch, tmp_path, capsys):
+    """A failed leg still leaves the record (evidence over abort) — but the
+    exit code must say the run was not whole."""
+    import json
+
+    import bench
+
+    def boom(*a, **kw):
+        raise RuntimeError("Mosaic scoped vmem OOM (simulated)")
+
+    _stub_measurements(monkeypatch, bench, boom)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code not in (0, None)
+    assert "leg(s) failed" in str(ei.value.code)
+    parsed = json.loads(capsys.readouterr().out.strip())
+    assert parsed["value"] is None
+    assert set(parsed["detail"]["ips"].values()) == {"err"}
+
+
+@pytest.mark.parametrize(
+    "mode",
+    [
+        "bench_serve", "bench_serve_fleet", "bench_trace", "bench_resilience",
+        "bench_chaos", "bench_control", "bench_comms", "bench_parity",
+        "bench_relayout", "bench_plan", "bench_pipeline",
+    ],
+)
+def test_child_spawning_modes_refuse_without_explicit_cpu(monkeypatch, tmp_path, mode):
+    """A chip belongs to one process: every mode whose parent touches JAX
+    and then starts children that need a device must exit non-zero with a
+    reason BEFORE it spawns anything, unless the environment explicitly
+    asks for the CPU (where these CPU captures belong)."""
+    import subprocess
+
+    import bench
+
+    def no_spawn(*a, **kw):
+        raise AssertionError(f"{mode} spawned a child before refusing")
+
+    monkeypatch.setattr(subprocess, "run", no_spawn)
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit) as ei:
+        getattr(bench, mode)()
+    assert "refused" in str(ei.value.code)
+    assert list(tmp_path.iterdir()) == []  # no capture written

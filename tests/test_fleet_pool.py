@@ -545,6 +545,36 @@ def test_fleet_poll_secs_flag_validation():
     assert hp.fleet_hosts == 2 and hp.fleet_local_devices == 0
 
 
+def test_fleet_hosts_on_one_machine_refuse_to_share_its_chips(monkeypatch, tmp_path):
+    """The fleet's hosts are processes on this machine; unless they are
+    forced onto virtual CPU devices (--fleet-local-devices) or the
+    environment says JAX_PLATFORMS=cpu they would all open the same chips
+    (one process owns a chip: on a v5e the second host dies on libtpu's
+    lockfile and the fleet hangs).  The supervisor stays off JAX, so it
+    refuses from the environment alone, before it spawns anything."""
+    import subprocess
+
+    from distributed_training_comparison_tpu.resilience.supervisor import (
+        run_supervised,
+    )
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("spawned a host before refusing")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    argv = ["--supervise", "--fleet-hosts", "2", "--ckpt-path", str(tmp_path)]
+    hp = load_config("tpu", argv)
+    for platforms in (None, "tpu,cpu"):
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        with pytest.raises(SystemExit) as ei:
+            run_supervised(hp, argv)
+        assert "--fleet-local-devices" in str(ei.value.code)
+    assert list(tmp_path.iterdir()) == []  # nothing bound, nothing written
+
+
 # ------------------------------------------------ corrupt-shard quarantine
 
 

@@ -171,12 +171,14 @@ def test_donated_runner_consumes_input_state(mesh, tiny_data):
     assert not leaf_before.is_deleted()
 
 
-def test_donated_cache_write_bar_blocks_only_barred_compiles():
-    """Donated executables must never land in the persistent compile cache:
-    on this jax's CPU backend a warm process deserializing one segfaults or
-    silently corrupts the scanned carry (the bug _compat.
-    donated_cache_write_barred / step._donated_jit exist for).  Normal
-    programs keep caching — the guard must not disable the cache wholesale.
+def test_donated_cache_write_bar_blocks_only_barred_compiles(mesh):
+    """Donated executables compiled for the CPU must never land in the
+    persistent compile cache: on this jax's CPU backend a warm process
+    deserializing one segfaults or silently corrupts the scanned carry (the
+    bug _compat.donated_cache_write_barred / step._donated_jit exist for).
+    Normal programs keep caching — the guard must not disable the cache
+    wholesale.  (The bar is down for a TPU mesh: test_write_bar_is_keyed_
+    on_the_compiled_platform.)
 
     Observes the LIVE cache dir (conftest's — the cache singleton latches
     its directory at first use, so redirecting the config mid-process is a
@@ -204,7 +206,9 @@ def test_donated_cache_write_bar_blocks_only_barred_compiles():
 
     try:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        barred = _donated_jit(overlap_cache_probe_barred, donate_argnums=(0,))
+        barred = _donated_jit(
+            overlap_cache_probe_barred, mesh, donate_argnums=(0,)
+        )
         out = barred(jnp.ones((32, 32)), jnp.ones((4, 16)))
         jax.block_until_ready(out)
         assert named_entries("overlap_cache_probe_barred") == set()
@@ -217,6 +221,22 @@ def test_donated_cache_write_bar_blocks_only_barred_compiles():
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", min_secs
         )
+
+
+def test_write_bar_is_keyed_on_the_compiled_platform():
+    """The bar stays where its fault was reproduced (executables compiled
+    for the CPU backend) and is down for the TPU, where the donated train
+    programs — the costliest compiles of a run — must reach the cache."""
+    from distributed_training_comparison_tpu._compat import (
+        donated_cache_write_barred,
+    )
+
+    base = jax.config.jax_persistent_cache_min_compile_time_secs
+    with donated_cache_write_barred("cpu"):
+        assert jax.config.jax_persistent_cache_min_compile_time_secs > 1e9
+    with donated_cache_write_barred("tpu"):
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == base
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == base
 
 
 # ------------------------------------------------------- chunked_batches

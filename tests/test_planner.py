@@ -174,7 +174,16 @@ def test_cost_model_fallbacks():
     assert cm.secs_per_flop == pytest.approx(
         1.0 / (459e12 * planner.ASSUMED_MFU)
     )
-    assert CostModel.fit(None, device_kind="weird").source == "default"
+    # the flat defaults stay, labelled, for the CPU backend only
+    assert CostModel.fit(None, device_kind="cpu").source == "default"
+
+
+def test_cost_model_refuses_an_unknown_tpu_kind():
+    """A TPU the tables do not know is an error, not a silent default: a
+    made-up 10 GB/s / 50 GFLOP/s would rank layouts for a chip nobody
+    measured."""
+    with pytest.raises(planner.PlanError, match="TPU v99"):
+        CostModel.fit(None, device_kind="TPU v99")
 
 
 def test_fit_ledger_mesh_follows_the_chosen_executable_attempt():

@@ -843,3 +843,33 @@ def test_process_replica_kill_requeues_and_supervisor_restarts(tmp_path):
     lifecycle = [e["payload"] for e in evs if e["kind"] == "replica"]
     assert any(p.get("lifecycle") == "attempt_start" and p.get("attempt")
                for p in lifecycle), "supervisor restart never hit the bus"
+
+
+def test_process_transport_refuses_on_a_tpu_host(monkeypatch, tmp_path):
+    """One process owns a chip: by the time serve_main runs, the router
+    process has opened the host's chips, so replica processes cannot (on a
+    v5e the worker dies on libtpu's lockfile and the fleet burns its restart
+    budget).  Replicas are not placed per chip yet, so the process transport
+    must refuse — clearly, before it spawns — wherever the platform is tpu."""
+    import subprocess
+    import types
+
+    import jax
+
+    from distributed_training_comparison_tpu.config import load_config
+    from distributed_training_comparison_tpu.serve import serve_main
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("spawned a replica before refusing")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")]
+    )
+    hp = load_config(
+        "tpu",
+        ["--serve", "--serve-transport", "process", "--ckpt-path", str(tmp_path)],
+    )
+    with pytest.raises(ValueError, match="--serve-transport thread"):
+        serve_main(hp)
+    assert list(tmp_path.iterdir()) == []

@@ -1,0 +1,134 @@
+"""Ask the TPU's compiler, without a TPU, whether the kernels compile.
+
+The rest of ``tests/`` runs the Pallas kernels through the interpreter on
+the CPU — semantics only.  The interpreter cannot see what Mosaic refuses:
+a slice not aligned to the tiling, more scoped VMEM than a kernel may use, a
+kernel that cannot be lowered at all.  libtpu is installed here and compiles
+for a chip that is *described*, not attached
+(``jax.experimental.topologies``), so each kernel family of the main path is
+compiled for a v5e at the real shapes the zoo trains, from shapes alone,
+with ``interpret=False`` passed to the kernel directly (code that asks
+``jax.default_backend()`` still sees the CPU here).
+
+A compile that passes is not a chip run: it says nothing about results or
+times (``chip_smoke.py`` and ``tests_tpu/`` are the chip's side).  Skipped,
+not failed, where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_training_comparison_tpu.ops import flash_attention
+from distributed_training_comparison_tpu.ops.attention_small import small_mha
+from distributed_training_comparison_tpu.ops.moe_gmm import grouped_ffn
+from distributed_training_comparison_tpu.ops.vit_block import fused_vit_block
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip.  The persistent compile cache is off around
+    these compiles: an executable compiled for a described device is
+    written to the cache but cannot be read back without a chip, and the
+    next run would warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, chip, *shapes) -> str:
+    args = [
+        jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), a
+        )
+        for a in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _s(*shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _grad_of(fn, n_args):
+    """d(sum of outputs)/d(every argument) — the program a train step's
+    backward runs through the kernel's custom VJP."""
+    return jax.grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=tuple(range(n_args))
+    )
+
+
+# vit_long as chip_smoke trains it (batch 8, 4 heads, 4096 tokens, head dim
+# 128), and S=16384: past _FWD_RESIDENT_KV_LIMIT, the streamed forward
+FLASH_SHAPES = {"vit_long": (8, 4, 4096, 128), "s16384": (1, 4, 16384, 128)}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
+def test_flash_attention_compiles_for_v5e(chip, shape, backward, causal):
+    attn = lambda q, k, v: flash_attention(q, k, v, causal=causal)  # noqa: E731
+    fn = _grad_of(attn, 3) if backward else attn
+    text = _compiled_text(fn, chip, _s(*shape), _s(*shape), _s(*shape))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_vit_block_compiles_for_v5e(chip):
+    """vit_tiny at --patch-size 2: batch 256, 256 tokens, dim 192, 3 heads."""
+    dim, hidden = 192, 4 * 192
+    dense = lambda i, o: {"kernel": _s(i, o, dtype=jnp.float32),  # noqa: E731
+                          "bias": _s(o, dtype=jnp.float32)}
+    ln = {"scale": _s(dim, dtype=jnp.float32), "bias": _s(dim, dtype=jnp.float32)}
+    params = {
+        "ln_attn": ln, "q_proj": dense(dim, dim), "k_proj": dense(dim, dim),
+        "v_proj": dense(dim, dim), "proj": dense(dim, dim), "ln_mlp": ln,
+        "mlp_up": dense(dim, hidden), "mlp_down": dense(hidden, dim),
+    }
+    block = lambda x, p: fused_vit_block(x, p, heads=3)  # noqa: E731
+    text = _compiled_text(_grad_of(block, 2), chip, _s(256, 256, dim), params)
+    assert "tpu_custom_call" in text
+
+
+def test_grouped_ffn_compiles_for_v5e(chip):
+    """vit_moe's own expert layer: 256 x 64 tokens over 8 experts, d=192,
+    h=768, capacity 1.25 x n/E padded to the bf16 sublane tile."""
+    n, e, d, h = 256 * 64, 8, 192, 768
+    cap = -(-int(n * 1.25) // e)
+    cap = -(-cap // 16) * 16
+    ffn = lambda xs, w1, b1, w2, b2, starts: grouped_ffn(  # noqa: E731
+        xs, w1, b1, w2, b2, starts, cap
+    )
+    text = _compiled_text(
+        _grad_of(ffn, 5), chip, _s(n, d), _s(e, d, h), _s(e, h), _s(e, h, d),
+        _s(e, d), _s(e + 1, dtype=jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_small_mha_compiles_for_v5e(chip):
+    """The short-sequence kernel at vit_tiny's S=64 (its head_fwd/head_bwd
+    helpers are what the fused block kernel is built from)."""
+    shape = (256, 64, 3, 64)
+    text = _compiled_text(
+        _grad_of(small_mha, 3), chip, _s(*shape), _s(*shape), _s(*shape)
+    )
+    assert "tpu_custom_call" in text

@@ -4,6 +4,12 @@ Covers the semantics of the reference's ``src/single/utils.py`` symbols
 (fix_seed / AverageMeter / accuracy) under the JAX rebuild.
 """
 
+import contextlib
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -115,3 +121,64 @@ def test_collective_census_parser():
     cp_bytes = 2 * 2 * 8 * 2
     assert c["collective-permute"] == (1, cp_bytes, cp_bytes // 2)
     assert "add" not in c and len(c) == 3
+
+
+# ------------------------------------------------- compile-cache placement
+
+
+@contextlib.contextmanager
+def _restore_cache_dir():
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        yield
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_dir_from_the_environment_and_no_other(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: that
+    directory is configured, and the in-checkout default is neither
+    configured nor created."""
+    from distributed_training_comparison_tpu.utils import compile_cache
+
+    checkout = tmp_path / "checkout" / ".jax_cache"
+    monkeypatch.setattr(compile_cache, "_CHECKOUT_CACHE", checkout)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    with _restore_cache_dir():
+        compile_cache.enable_persistent_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "outside")
+    assert (tmp_path / "outside").is_dir()
+    assert not checkout.parent.exists()
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch, tmp_path):
+    """Unset, the cache lives at <checkout>/.jax_cache — the same path for
+    every call and every process, whatever its working directory (the path
+    is part of the cache's key: a directory that moves never hits)."""
+    from distributed_training_comparison_tpu.utils import compile_cache
+
+    repo = Path(__file__).resolve().parent.parent
+    want = str(repo / ".jax_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    with _restore_cache_dir():
+        for _ in range(2):
+            compile_cache.enable_persistent_compilation_cache()
+            assert jax.config.jax_compilation_cache_dir == want
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+    script = (
+        "import jax\n"
+        "from distributed_training_comparison_tpu.utils import "
+        "enable_persistent_compilation_cache as on\n"
+        "on(); print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = f"{repo}{os.pathsep}" + env.get("PYTHONPATH", "")
+    seen = {
+        subprocess.run(
+            [sys.executable, "-c", script], cwd=cwd, env=env,
+            capture_output=True, text=True, check=True,
+        ).stdout.strip()
+        for cwd in (str(tmp_path), str(repo))
+    }
+    assert seen == {want}
