@@ -213,49 +213,56 @@ class ViTBlock(nn.Module):
         xavier = nn.initializers.xavier_uniform()
         hd = dim // self.heads
 
-        h = norm(name="ln_attn")(x).astype(self.dtype)
-        # q/k/v as three separate projections, not one packed 3*dim Dense:
-        # unpacking a packed qkv (reshape+slice, or transpose) is a real
-        # relayout on TPU — measured 21% of per-block fwd+bwd time at CIFAR
-        # shapes. Separate projections also make tensor parallelism
-        # head-aligned for free (each output axis shards on whole heads
-        # when heads % model_parallel == 0, parallel/tp.py).
-        proj_qkv = partial(
-            nn.Dense, dim, dtype=self.dtype, kernel_init=xavier
-        )
-        q = proj_qkv(name="q_proj")(h).reshape(b, s, self.heads, hd)
-        k = proj_qkv(name="k_proj")(h).reshape(b, s, self.heads, hd)
-        v = proj_qkv(name="v_proj")(h).reshape(b, s, self.heads, hd)
-        o = attention(
-            q, k, v,
-            impl=self.attn_impl,
-            # (B, S, H, D): the short-sequence path runs transpose-free
-            layout="bshd",
-        )
-        o = o.reshape(b, s, dim)
-        x = x + nn.Dense(dim, dtype=self.dtype, kernel_init=xavier, name="proj")(o)
+        # the block's two halves, named for the device trace (flax names the
+        # modules inside them; the products of attention sit in no module)
+        with jax.named_scope("attn"):
+            h = norm(name="ln_attn")(x).astype(self.dtype)
+            # q/k/v as three separate projections, not one packed 3*dim
+            # Dense: unpacking a packed qkv (reshape+slice, or transpose) is
+            # a real relayout on TPU — measured 21% of per-block fwd+bwd
+            # time at CIFAR shapes. Separate projections also make tensor
+            # parallelism head-aligned for free (each output axis shards on
+            # whole heads when heads % model_parallel == 0, parallel/tp.py).
+            proj_qkv = partial(
+                nn.Dense, dim, dtype=self.dtype, kernel_init=xavier
+            )
+            q = proj_qkv(name="q_proj")(h).reshape(b, s, self.heads, hd)
+            k = proj_qkv(name="k_proj")(h).reshape(b, s, self.heads, hd)
+            v = proj_qkv(name="v_proj")(h).reshape(b, s, self.heads, hd)
+            o = attention(
+                q, k, v,
+                impl=self.attn_impl,
+                # (B, S, H, D): the short-sequence path runs transpose-free
+                layout="bshd",
+            )
+            o = o.reshape(b, s, dim)
+            x = x + nn.Dense(
+                dim, dtype=self.dtype, kernel_init=xavier, name="proj"
+            )(o)
 
-        h = norm(name="ln_mlp")(x).astype(self.dtype)
-        if self.num_experts:
-            from .moe import SwitchFFN
+        with jax.named_scope("mlp"):
+            h = norm(name="ln_mlp")(x).astype(self.dtype)
+            if self.num_experts:
+                from .moe import SwitchFFN
 
-            x = x + SwitchFFN(
-                dim=dim,
-                num_experts=self.num_experts,
-                mlp_ratio=self.mlp_ratio,
-                capacity_factor=self.capacity_factor,
-                dtype=self.dtype,
-                dispatch=self.moe_dispatch,
-                name="moe",
+                x = x + SwitchFFN(
+                    dim=dim,
+                    num_experts=self.num_experts,
+                    mlp_ratio=self.mlp_ratio,
+                    capacity_factor=self.capacity_factor,
+                    dtype=self.dtype,
+                    dispatch=self.moe_dispatch,
+                    name="moe",
+                )(h)
+                return x, None
+            h = nn.Dense(
+                self.mlp_ratio * dim, dtype=self.dtype, kernel_init=xavier,
+                name="mlp_up",
             )(h)
-            return x, None
-        h = nn.Dense(
-            self.mlp_ratio * dim, dtype=self.dtype, kernel_init=xavier, name="mlp_up"
-        )(h)
-        h = nn.gelu(h)
-        x = x + nn.Dense(
-            dim, dtype=self.dtype, kernel_init=xavier, name="mlp_down"
-        )(h)
+            h = nn.gelu(h)
+            x = x + nn.Dense(
+                dim, dtype=self.dtype, kernel_init=xavier, name="mlp_down"
+            )(h)
         return x, None
 
 

@@ -12,21 +12,23 @@ Spans nest strictly by construction: each is a context manager pushed and
 popped on a per-thread stack, so a thread's spans at depth d always lie
 inside its enclosing depth d-1 span — the invariant the export test pins.
 
-During a ``--profile-dir`` capture (``recorder.annotate = True``) every
-span also enters a ``jax.profiler.TraceAnnotation``, so the xplane's host
-timeline carries the same names and a device trace joins the host spans
-by step id (the trainer additionally wraps chunk dispatches in
-``StepTraceAnnotation``).  Outside a capture the cost of a span is two
-clock reads and one dict append.
+Every span also enters a ``jax.profiler.TraceAnnotation``, which is inert
+while no profiler session runs.  So the host lines of ANY profiler trace —
+a ``--profile-dir`` capture, or one an embedder starts around ``fit()`` —
+carry these names on the trace's own clock, beside the device's ops (during
+a ``--profile-dir`` capture the trainer additionally wraps chunk dispatches
+in ``StepTraceAnnotation``).  With no session the cost of a span is two
+clock reads, one dict append and the annotation's no-op enter and exit.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import threading
 import time
 import zlib
-from contextlib import contextmanager, nullcontext
+from contextlib import nullcontext
 from pathlib import Path
 
 TRACE_NAME = "trace.json"
@@ -50,7 +52,6 @@ class SpanRecorder:
     ) -> None:
         self.process_index = int(process_index)
         self.max_spans = int(max_spans)
-        self.annotate = False  # emit jax TraceAnnotations alongside
         self._lock = threading.Lock()
         self._spans: list[dict] = []
         self._dropped = 0
@@ -62,37 +63,16 @@ class SpanRecorder:
             stack = self._local.stack = []
         return stack
 
-    @contextmanager
     def span(self, name: str, **attrs):
-        stack = self._stack()
-        depth = len(stack)
-        stack.append(name)
-        thread = threading.current_thread()
-        ann = (
-            _trace_annotation(name) if self.annotate else nullcontext()
-        )
-        t0 = time.monotonic()
-        try:
-            with ann:
-                yield
-        finally:
-            t1 = time.monotonic()
-            stack.pop()
-            rec = {
-                "name": str(name),
-                "t0": t0,
-                "t1": t1,
-                "thread_id": thread.ident,
-                "thread_name": thread.name,
-                "depth": depth,
-            }
-            if attrs:
-                rec["args"] = attrs
-            with self._lock:
-                if len(self._spans) < self.max_spans:
-                    self._spans.append(rec)
-                else:
-                    self._dropped += 1
+        """Context manager recording one span on the calling thread."""
+        return _Span(self, name, attrs)
+
+    def _append(self, rec: dict) -> None:
+        with self._lock:
+            if len(self._spans) < self.max_spans:
+                self._spans.append(rec)
+            else:
+                self._dropped += 1
 
     def record(
         self, name: str, t0: float, t1: float, *, lane: str | None = None,
@@ -122,11 +102,7 @@ class SpanRecorder:
         }
         if attrs:
             rec["args"] = attrs
-        with self._lock:
-            if len(self._spans) < self.max_spans:
-                self._spans.append(rec)
-            else:
-                self._dropped += 1
+        self._append(rec)
 
     def spans(self) -> list[dict]:
         with self._lock:
@@ -138,14 +114,55 @@ class SpanRecorder:
             return self._dropped
 
 
-def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` if this jax exposes one."""
+class _Span:
+    """One open span: a plain class, not a generator, since spans wrap hot
+    host paths (every chunk dispatch) and a generator's frame costs more
+    than the span's own work."""
+
+    __slots__ = ("rec", "name", "attrs", "stack", "ann", "t0")
+
+    def __init__(self, rec: SpanRecorder, name: str, attrs: dict) -> None:
+        self.rec, self.name, self.attrs = rec, name, attrs
+
+    def __enter__(self) -> None:
+        self.stack = self.rec._stack()
+        self.stack.append(self.name)
+        self.ann = _trace_annotation(self.name)
+        self.t0 = time.monotonic()
+        self.ann.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self.ann.__exit__(*exc)
+        t1 = time.monotonic()
+        self.stack.pop()
+        thread = threading.current_thread()
+        rec = {
+            "name": str(self.name),
+            "t0": self.t0,
+            "t1": t1,
+            "thread_id": thread.ident,
+            "thread_name": thread.name,
+            "depth": len(self.stack),
+        }
+        if self.attrs:
+            rec["args"] = self.attrs
+        self.rec._append(rec)
+
+
+@functools.cache
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` if this jax exposes one, looked up
+    once: a span pays no import machinery."""
     try:
         import jax.profiler
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation
     except (ImportError, AttributeError):  # pragma: no cover - exotic jax
-        return nullcontext()
+        return lambda name: nullcontext()
+
+
+def _trace_annotation(name: str):
+    return _annotation_class()(name)
 
 
 def step_annotation(step: int | None = None):

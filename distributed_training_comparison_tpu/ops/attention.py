@@ -672,7 +672,17 @@ def flash_attention(
     return out
 
 
-def attention(
+def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, **options):
+    """:func:`_attention` (its keyword options are this function's) under
+    the scope ``attention``: whichever implementation runs — composed
+    einsums, a Pallas kernel, a sequence-parallel ring — its device ops
+    carry one name, so a trace times attention the same before and after a
+    kernel replaces the einsums."""
+    with jax.named_scope("attention"):
+        return _attention(q, k, v, **options)
+
+
+def _attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
     v: jnp.ndarray,

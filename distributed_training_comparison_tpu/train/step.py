@@ -29,7 +29,7 @@ TPU-native redesign:
 from __future__ import annotations
 
 import warnings
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable
 
 import jax
@@ -48,12 +48,33 @@ from .state import TrainState
 Metrics = dict[str, jnp.ndarray]
 
 
-def _observed(jitted, monitor, name, sentinel=True):
-    """Route a jitted runner through the compile monitor when one is
-    wired (obs/compilation.py): every distinct executable it builds then
-    emits a ``compile`` event with its HLO cost/memory analysis, and
-    dispatches are accounted per executable.  ``monitor=None`` (tests,
-    library embedders, ``--no-obs``) returns the function unchanged."""
+def _named(fun, name):
+    """``fun`` under the name its compiled program should carry: ``name``
+    without its ``@k...`` suffix.  ``jax.jit`` names the XLA module
+    ``jit_<__name__>``, and that is what a device trace shows on its
+    module line — a ``<lambda>`` or an inner ``run`` there says nothing."""
+    base = name.split("@", 1)[0]
+    if getattr(fun, "__name__", None) == base:
+        return fun
+
+    @wraps(fun)
+    def named(*args, **kwargs):
+        return fun(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = base
+    return named
+
+
+def observed_jit(fun, monitor, name, sentinel=True, **jit_kw):
+    """``jax.jit(fun)`` as the program ``name``, routed through the compile
+    monitor when one is wired (obs/compilation.py): every distinct
+    executable it builds then emits a ``compile`` event under ``name`` with
+    its HLO cost/memory analysis, and dispatches are accounted per
+    executable.  The XLA module is named ``jit_<name>`` (less an ``@k...``
+    suffix, which tells the monitor's families apart and is no part of a
+    function's name).  ``monitor=None`` (tests, library embedders,
+    ``--no-obs``) returns the plain jitted function."""
+    jitted = jax.jit(_named(fun, name), **jit_kw)
     if monitor is None:
         return jitted
     return monitor.instrument(jitted, name, sentinel=sentinel)
@@ -77,9 +98,9 @@ def _donated_jit(
     The compile monitor wraps INSIDE this context, so an observed AOT
     compile of a donated runner happens under the same write bar as the
     jit path it replaces."""
-    jitted = _observed(
-        jax.jit(fun, donate_argnums=donate_argnums, **jit_kw),
-        monitor, name or getattr(fun, "__name__", "donated"),
+    jitted = observed_jit(
+        fun, monitor, name or getattr(fun, "__name__", "donated"),
+        donate_argnums=donate_argnums, **jit_kw,
     )
     platform = mesh.devices.flat[0].platform
 
@@ -238,14 +259,17 @@ def _make_step_core(
     def forward_backward(
         params, apply_fn, batch_stats, images, labels, key, residual=None
     ):
-        if augment:
-            # draw_sharding pins the crop/flip draws replicated: without
-            # it GSPMD may partition the threefry generation differently
-            # per mesh shape, and the SAME (seed, epoch, step) would
-            # augment differently under DP than under DP×TP×PP
-            # (data/augment.py) — breaking cross-layout trajectory parity
-            images = random_crop_flip(images, key, draw_sharding=repl_sharding)
-        x = normalize_images(images, mean, std, dtype=compute_dtype)
+        with jax.named_scope("augment"):
+            if augment:
+                # draw_sharding pins the crop/flip draws replicated: without
+                # it GSPMD may partition the threefry generation differently
+                # per mesh shape, and the SAME (seed, epoch, step) would
+                # augment differently under DP than under DP×TP×PP
+                # (data/augment.py) — breaking cross-layout trajectory parity
+                images = random_crop_flip(
+                    images, key, draw_sharding=repl_sharding
+                )
+            x = normalize_images(images, mean, std, dtype=compute_dtype)
 
         if fwd_bwd is not None:
             if jax.tree_util.tree_leaves(batch_stats):
@@ -264,7 +288,8 @@ def _make_step_core(
                 )
             else:
                 loss, logits, grads = fwd_bwd(params, x, labels)
-            top1, _ = _topk_hits(logits, labels)
+            with jax.named_scope("loss"):
+                top1, _ = _topk_hits(logits, labels)
             return grads, batch_stats, loss, top1.sum(), {}, residual
 
         def loss_fn(p):
@@ -278,16 +303,23 @@ def _make_step_core(
                 # for every dense zoo model
                 mutable=["batch_stats", "losses", "moe_metrics"],
             )
-            aux = sum(
-                jnp.sum(leaf)
-                for leaf in jax.tree_util.tree_leaves(mutated.get("losses", {}))
-            )
-            return _cross_entropy(logits, labels).mean() + aux, (logits, mutated)
+            # inside the differentiated function JAX wraps the scope: the
+            # trace reads jvp(loss) forward, transpose(jvp(loss)) backward
+            with jax.named_scope("loss"):
+                aux = sum(
+                    jnp.sum(leaf)
+                    for leaf in jax.tree_util.tree_leaves(
+                        mutated.get("losses", {})
+                    )
+                )
+                loss = _cross_entropy(logits, labels).mean() + aux
+            return loss, (logits, mutated)
 
         (loss, (logits, mutated)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(params)
-        top1, _ = _topk_hits(logits, labels)
+        with jax.named_scope("loss"):
+            top1, _ = _topk_hits(logits, labels)
         # BN-free models mutate nothing; keep the (empty) stats tree stable
         new_stats = mutated.get("batch_stats", batch_stats)
         extras = _moe_health(mutated.get("moe_metrics", {}))
@@ -353,33 +385,41 @@ def _make_step_core(
 
         # compiled numerics guards: a non-finite step keeps the ENTIRE old
         # state (the skipped update costs one batch, never a poisoned run)
-        grad_norm = global_norm(grads)
-        finite = step_finite(loss, grad_norm)
-        if comms_active:
-            new_state = comms.apply_gradients(
-                state, grads=grads, batch_stats=new_stats
-            )
-        else:
-            new_state = state.apply_gradients(grads=grads, batch_stats=new_stats)
+        with jax.named_scope("guards"):
+            grad_norm = global_norm(grads)
+            finite = step_finite(loss, grad_norm)
+        with jax.named_scope("optimizer"):
+            if comms_active:
+                new_state = comms.apply_gradients(
+                    state, grads=grads, batch_stats=new_stats
+                )
+            else:
+                new_state = state.apply_gradients(
+                    grads=grads, batch_stats=new_stats
+                )
         if residual_through_fwd_bwd and new_residual is not None:
             # the schedule's own wire residual (comms.wire_inline left the
             # field alone); a skipped step still reverts it via select_tree
             new_state = new_state.replace(comms_residual=new_residual)
-        state = select_tree(finite, new_state, state)
-        metrics = {
-            "loss": loss,
-            "top1_count": top1_count,
-            "count": labels.size,
-            "grad_norm": grad_norm,
-            "skipped": 1.0 - finite.astype(jnp.float32),
-            **extras,
-        }
-        if comms_active and comms.compressing and state.comms_residual is not None:
-            # compression health: the error-feedback residual's global norm
-            # rides the stacked fetch like the guard metrics (zero extra
-            # host syncs); a residual norm growing without bound means the
-            # wire is too narrow for this gradient distribution
-            metrics["comms_err"] = global_norm(state.comms_residual)
+        with jax.named_scope("guards"):
+            state = select_tree(finite, new_state, state)
+            metrics = {
+                "loss": loss,
+                "top1_count": top1_count,
+                "count": labels.size,
+                "grad_norm": grad_norm,
+                "skipped": 1.0 - finite.astype(jnp.float32),
+                **extras,
+            }
+            if (
+                comms_active and comms.compressing
+                and state.comms_residual is not None
+            ):
+                # compression health: the error-feedback residual's global
+                # norm rides the stacked fetch like the guard metrics (zero
+                # extra host syncs); a residual norm growing without bound
+                # means the wire is too narrow for this gradient distribution
+                metrics["comms_err"] = global_norm(state.comms_residual)
         return state, metrics
 
     return core
@@ -426,13 +466,10 @@ def make_train_step(
     # tests that re-read their inputs after the call (the scanned runners
     # donate — they own the train loop's hot path; see make_epoch_runner).
     return _declare_state_layout(
-        _observed(
-            jax.jit(
-                core,
-                in_shardings=(state_sh, data_shard, data_shard, repl),
-                out_shardings=(state_sh, repl),
-            ),
-            monitor, "train_step",
+        observed_jit(
+            core, monitor, "train_step",
+            in_shardings=(state_sh, data_shard, data_shard, repl),
+            out_shardings=(state_sh, repl),
         ),
         fwd_bwd, state_layout,
     )
@@ -593,9 +630,8 @@ def make_eval_step(
     # sentinel=False: eval programs legitimately compile one executable
     # per split shape whenever a new split first evaluates — steady state
     # does not mean "no eval compiles", unlike the train/serve hot paths
-    return _observed(
-        jax.jit(core, out_shardings=repl), monitor, "eval_step",
-        sentinel=False,
+    return observed_jit(
+        core, monitor, "eval_step", sentinel=False, out_shardings=repl
     )
 
 
@@ -639,8 +675,8 @@ def make_eval_runner(
     # sentinel=False: one executable per split shape is the design (val
     # and test differ), and the test split's first compile may land long
     # after the trainer declared steady state
-    return _observed(
-        jax.jit(run, out_shardings=repl), monitor, name, sentinel=False
+    return observed_jit(
+        run, monitor, name, sentinel=False, out_shardings=repl
     )
 
 
@@ -743,9 +779,9 @@ def make_chunk_runner(
             fwd_bwd, state_layout,
         )
     return _declare_state_layout(
-        _observed(
-            jax.jit(run, in_shardings=in_sh, out_shardings=(state_sh, repl)),
-            monitor, "chunk_runner",
+        observed_jit(
+            run, monitor, "chunk_runner",
+            in_shardings=in_sh, out_shardings=(state_sh, repl),
         ),
         fwd_bwd, state_layout,
     )
@@ -846,8 +882,8 @@ def make_device_chunk_runner(
             fwd_bwd, state_layout,
         )
     return _declare_state_layout(
-        _observed(
-            jax.jit(run, out_shardings=(state_sh, repl)), monitor, obs_name
+        observed_jit(
+            run, monitor, obs_name, out_shardings=(state_sh, repl)
         ),
         fwd_bwd, state_layout,
     )
@@ -937,9 +973,8 @@ def make_epoch_runner(
             fwd_bwd, state_layout,
         )
     return _declare_state_layout(
-        _observed(
-            jax.jit(run, out_shardings=(state_sh, repl)), monitor,
-            "epoch_runner",
+        observed_jit(
+            run, monitor, "epoch_runner", out_shardings=(state_sh, repl)
         ),
         fwd_bwd, state_layout,
     )

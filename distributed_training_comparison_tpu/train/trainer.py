@@ -93,6 +93,7 @@ from .step import (
     make_device_replay_step,
     make_eval_runner,
     make_replay_step,
+    observed_jit,
 )
 
 
@@ -1328,9 +1329,9 @@ class Trainer:
         if self._snapshot_fn is None:
             # sentinel=False: the snapshot program compiles whenever the
             # FIRST throttled save happens — legitimately after warmup
-            self._snapshot_fn = self.compile_monitor.instrument(
-                jax.jit(lambda s: jax.tree_util.tree_map(jnp.copy, s)),
-                "state_snapshot", sentinel=False,
+            self._snapshot_fn = observed_jit(
+                lambda s: jax.tree_util.tree_map(jnp.copy, s),
+                self.compile_monitor, "state_snapshot", sentinel=False,
             )
         return self._snapshot_fn(state)
 
@@ -1422,11 +1423,10 @@ class Trainer:
             profiling = getattr(hp, "profile_dir", None) and epoch == profile_epoch
             if profiling:
                 jax.profiler.start_trace(hp.profile_dir)
-                # host spans double as device TraceAnnotations for this
-                # epoch, and chunk dispatches gain StepTraceAnnotations —
-                # the xplane capture joins the host timeline on step ids
+                # chunk dispatches gain StepTraceAnnotations for this
+                # epoch — the xplane capture joins the host spans (always
+                # TraceAnnotations, obs/spans.py) on step ids
                 self._profiling = True
-                self.tracer.annotate = True
             self.bus.emit("epoch_start", epoch=epoch)
             t0 = time.perf_counter()
             try:
@@ -1445,7 +1445,6 @@ class Trainer:
                 if profiling:
                     jax.profiler.stop_trace()
                     self._profiling = False
-                    self.tracer.annotate = False
                 next_epoch = self._apply_control_rollback(
                     epoch, time.perf_counter() - t0, ctl
                 )
@@ -1460,7 +1459,6 @@ class Trainer:
             if profiling:
                 jax.profiler.stop_trace()
                 self._profiling = False
-                self.tracer.annotate = False
                 self.logger.info(f"profiler trace written to {hp.profile_dir}")
             imgs = len(losses) * hp.batch_size
 
@@ -1861,9 +1859,9 @@ class Trainer:
         runs alongside it — it costs a host fetch of the local shards, so
         it is gated to the meshes that have the blind spot."""
         if self._fingerprint_fn is None:
-            self._fingerprint_fn = self.compile_monitor.instrument(
-                jax.jit(param_fingerprint), "param_fingerprint",
-                sentinel=False,
+            self._fingerprint_fn = observed_jit(
+                param_fingerprint, self.compile_monitor,
+                "param_fingerprint", sentinel=False,
             )
         report = check_desync(
             float(self._fingerprint_fn(self.state.params)), inject=inject

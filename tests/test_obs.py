@@ -378,10 +378,87 @@ def test_annotations_are_nullcontexts_outside_profiling():
     with obs.step_annotation(7):
         pass
     rec = SpanRecorder()
-    rec.annotate = True  # TraceAnnotation path, no active trace session
+    # every span enters a TraceAnnotation; no trace session is active
     with rec.span("annotated"):
         pass
     assert rec.spans()[0]["name"] == "annotated"
+
+
+def test_span_with_no_profiler_session_is_cheap():
+    """A span always enters ``jax.profiler.TraceAnnotation`` (so the host
+    lines of any profiler trace carry the spans), which must cost next to
+    nothing while no session runs.  Held against the span it replaced,
+    which entered a ``nullcontext`` outside a capture, timed here in the
+    same rounds — the machine is shared and its speed varies by session,
+    so the sharp limit is the ratio (0.8 as measured) and the
+    absolute one (ISSUE 24's 5 us, about 2 us as measured) is wide."""
+    from contextlib import contextmanager, nullcontext
+
+    @contextmanager
+    def span_as_it_was(rec, name):
+        stack = rec._stack()
+        depth = len(stack)
+        stack.append(name)
+        thread = threading.current_thread()
+        t0 = time.monotonic()
+        try:
+            with nullcontext():
+                yield
+        finally:
+            t1 = time.monotonic()
+            stack.pop()
+            rec._append({
+                "name": str(name), "t0": t0, "t1": t1,
+                "thread_id": thread.ident, "thread_name": thread.name,
+                "depth": depth,
+            })
+
+    def mean_seconds(span):
+        rec = SpanRecorder()
+        t0 = time.perf_counter()
+        for _ in range(10_000):
+            with span(rec, "dispatch"):
+                pass
+        mean = (time.perf_counter() - t0) / 10_000
+        assert len(rec.spans()) == 10_000
+        return mean
+
+    now = before = float("inf")
+    for _ in range(5):  # the best of five rounds each, interleaved
+        now = min(now, mean_seconds(SpanRecorder.span))
+        before = min(before, mean_seconds(span_as_it_was))
+    said = f"{now * 1e6:.2f} us a span, {before * 1e6:.2f} us as it was"
+    assert now < 1.25 * before, said
+    assert now < 20e-6, said
+
+
+def test_chrome_trace_export_is_what_it_was():
+    """Always annotating changed nothing a span records or exports: the
+    same fields, nesting depth and Chrome-trace events as before."""
+    rec = SpanRecorder(process_index=3)
+    with rec.span("epoch", epoch=2):
+        with rec.span("eval"):
+            pass
+    rec.record("busy", 1.0, 1.5, lane="stage0", stage=0)
+    inner, outer, lane = rec.spans()
+    assert set(inner) == {"name", "t0", "t1", "thread_id", "thread_name", "depth"}
+    assert set(outer) == set(inner) | {"args"}
+    assert (inner["name"], inner["depth"]) == ("eval", 1)
+    assert (outer["name"], outer["depth"], outer["args"]) == ("epoch", 0, {"epoch": 2})
+    assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
+    events = [e for e in obs.chrome_trace(rec.spans(), 3)["traceEvents"]
+              if e["ph"] == "X"]
+    by_name = {e["name"]: e for e in events}
+    assert by_name["busy"] == {
+        "ph": "X", "name": "busy", "pid": 3, "tid": lane["thread_id"],
+        "ts": 1e6, "dur": 5e5, "args": {"stage": 0},
+    }
+    assert by_name["eval"] == {
+        "ph": "X", "name": "eval", "pid": 3, "tid": inner["thread_id"],
+        "ts": round(inner["t0"] * 1e6, 3),
+        "dur": round((inner["t1"] - inner["t0"]) * 1e6, 3),
+    }
+    assert by_name["epoch"]["args"] == {"epoch": 2}
 
 
 # --------------------------------------------- checkpoint-writer satellite
