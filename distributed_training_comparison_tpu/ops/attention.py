@@ -85,7 +85,13 @@ def mha_reference(
     ``layout`` is the q/k/v axis order: ``"bhsd"`` (B, H, S, D) or
     ``"bshd"`` (B, S, H, D).  The ``bshd`` path contracts directly via
     einsum — no transposes, which on TPU are real relayout work (measured
-    17.5%% of ViT-Tiny step time before this path existed).
+    17.5%% of ViT-Tiny step time before this path existed — a claim, like
+    the "1.4×" below, from chip runs older than the growth PRs and from
+    autodiff's backward of this function; the dispatcher now differentiates
+    through ``_composed``, so the kernel issue measures them again).
+
+    Stays plain and autodiff-differentiable: every kernel test compares
+    against it.
 
     ``return_lse=True`` additionally returns the per-row log-sum-exp of the
     scaled scores, (B, H, S) fp32 — the statistic ring attention needs to
@@ -118,6 +124,89 @@ def mha_reference(
             lse = lse.transpose(0, 2, 1)  # (b, q, h) → contract (B, H, S)
         return out, lse
     return out
+
+
+# ------------------------------------------- composed path, its own VJP
+
+
+def _composed_eqs(layout):
+    """``(scores, out, to_kv)`` einsums: q·kᵀ and dO·vᵀ; p·v and ds·k;
+    dsᵀ·q and pᵀ·dO."""
+    if layout == "bshd":
+        return "bqhd,bkhd->bqhk", "bqhk,bkhd->bqhd", "bqhk,bqhd->bkhd"
+    return "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd", "bhqk,bhqd->bhkd"
+
+
+def _causal_mask(s, layout):
+    """:func:`mha_reference`'s causal mask for scores ``s``."""
+    sq, skv = s.shape[-3 if layout == "bshd" else -2], s.shape[-1]
+    mask = jnp.arange(sq)[:, None] + (skv - sq) >= jnp.arange(skv)[None, :]
+    return mask[:, None, :] if layout == "bshd" else mask
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _composed(q, k, v, causal, scale, layout):
+    """The dispatcher's composed branch.  Not differentiated it *is*
+    :func:`mha_reference`.  Differentiated, the program and not autodiff
+    chooses what crosses from forward to backward: the probabilities in the
+    dtype the p·v matmul consumes them in, and no float32 score-sized
+    tensor (autodiff keeps float32 ``s - max`` and every consumer in the
+    backward computes ``exp`` of it again)."""
+    return mha_reference(q, k, v, causal=causal, scale=scale, layout=layout)
+
+
+def _composed_fwd(q, k, v, causal, scale, layout):
+    # mha_reference's arithmetic, with p·v's operand named: the residual
+    score_eq, out_eq, _ = _composed_eqs(layout)
+
+    def scores(q, k):
+        s = jnp.einsum(score_eq, q, k, preferred_element_type=jnp.float32)
+        s = s * scale
+        return jnp.where(_causal_mask(s, layout), s, _NEG_INF) if causal else s
+
+    s = scores(q, k)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if layout == "bshd" and not causal and k.shape[1] % 128 == 0:
+        # The scores a second time, for exp: behind the barrier the compiler
+        # keeps the two products apart and fuses each with what consumes it
+        # (max; exp, sum, divide, convert), so the only score-sized tensor
+        # the forward writes is p.  One product is written in float32 for
+        # the softmax to read back: 8 bytes an element against 2·d FLOPs.
+        # Only where the TPU compiler does fuse them (compiled for a v5e:
+        # this layout, no mask, keys in whole 128-lane tiles).  On the chip
+        # forward+backward take 0.68-0.72 x the one-product form's time
+        # there, and 1.22-1.35 x where they are not fused (PERF.md §6,
+        # PR 25).
+        s = scores(*jax.lax.optimization_barrier((q, k)))
+    e = jnp.exp(s - m)
+    p = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
+    out = jnp.einsum(
+        out_eq, p, v, preferred_element_type=jnp.float32
+    ).astype(q.dtype)
+    return out, (q, k, v, p, out)
+
+
+def _composed_bwd(causal, scale, layout, res, do):
+    q, k, v, p, out = res
+    score_eq, out_eq, to_kv_eq = _composed_eqs(layout)
+    f32 = jnp.float32
+    # Σ_k dp∘p = Σ_d dO∘O, the flash backward's identity (``_flash_bwd``):
+    # softmax's row term needs no pass over a score-sized tensor
+    delta = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1, keepdims=True)
+    dp = jnp.einsum(score_eq, do, v, preferred_element_type=f32)
+    ds = p.astype(f32) * (dp - delta) * scale
+    if causal:
+        # ``where``'s rule: a masked score has no cotangent (p is 0 there
+        # already, but for a row that is masked whole)
+        ds = jnp.where(_causal_mask(ds, layout), ds, 0.0)
+    ds = ds.astype(q.dtype)
+    dq = jnp.einsum(out_eq, ds, k, preferred_element_type=f32)
+    dk = jnp.einsum(to_kv_eq, ds, q, preferred_element_type=f32)
+    dv = jnp.einsum(to_kv_eq, p, do, preferred_element_type=f32)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_composed.defvjp(_composed_fwd, _composed_bwd)
 
 
 # ---------------------------------------------------------- kernel helpers
@@ -694,9 +783,10 @@ def _attention(
     layout: str = "bhsd",
     interpret: bool = False,
 ):
-    """Dispatch: Pallas kernel on TPU for non-trivial sequences, jnp
-    reference elsewhere (CPU CI, tiny sequences where one fused XLA softmax
-    beats a kernel launch per (batch, head)).
+    """Dispatch: Pallas kernel on TPU for non-trivial sequences, composed
+    einsums elsewhere (CPU CI, tiny sequences where one fused XLA softmax
+    beats a kernel launch per (batch, head)): ``mha_reference``'s
+    arithmetic with a backward of its own (``_composed``).
 
     ``impl="ring[:axis]"`` / ``"ulysses[:axis]"`` dispatch to the
     sequence-parallel implementations (``parallel/ring.py``) over the named
@@ -733,6 +823,11 @@ def _attention(
         # the kernel only supports square causal attention; offset-causal
         # cross-attention stays on the reference path
         kernel_ok = not causal or q.shape[seq_ax] == k.shape[seq_ax]
+        # A claim older than the composed branch's own backward
+        # (``_composed``): the ratios below were taken against autodiff's
+        # backward of ``mha_reference``, in chip runs older than the growth
+        # PRs.  From 256 tokens up the composed side is faster now, so the
+        # crossovers are for the kernel issue to measure again (PERF.md §7).
         # Measured fwd+bwd crossover on a v5e chip (bf16, batched so total
         # tokens are constant), re-validated after the round-4 tiled
         # backward cut bwd time ~17%: at D=128 the kernel wins from S=512
@@ -791,8 +886,13 @@ def _attention(
             return to_bhsd(out[0]), out[1]
         return to_bhsd(out)
     if impl == "reference":
-        return mha_reference(
-            q, k, v, causal=causal, scale=scale, return_lse=return_lse,
-            layout=layout,
-        )
+        if return_lse:
+            # lse is an output with a cotangent of its own (ring attention
+            # differentiates through it): plain autodiff keeps this one
+            return mha_reference(
+                q, k, v, causal=causal, scale=scale, return_lse=True,
+                layout=layout,
+            )
+        scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+        return _composed(q, k, v, causal, scale, layout)
     raise ValueError(f"unknown attention impl {impl!r}")

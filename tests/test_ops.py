@@ -11,6 +11,10 @@ their design points in ``tests_tpu/``; measured v5e throughput lives in
 the README's flash-attention table (reproduced by ``python bench.py``).
 """
 
+import re
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -322,6 +326,213 @@ def test_flash_jit_and_grad_compile():
     g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     assert all(x.shape == y.shape for x, y in zip(g, (q, k, v)))
     assert all(bool(jnp.all(jnp.isfinite(x))) for x in g)
+
+
+# ------------------------------------------- composed branch, its own VJP
+#
+# ``attention(impl="reference")`` (what ``auto`` picks off the TPU and below
+# the kernels' windows) differentiates through a custom VJP that keeps the
+# probabilities in the compute dtype and no float32 score-sized tensor.
+# ``mha_reference`` stays on plain autodiff and is the contract.  One case
+# of the branch stays on plain autodiff too: ``return_lse=True`` (ring
+# attention's blocks), because ``lse`` is an output with a cotangent of its
+# own and the blocks it is asked for are a ring step's, not a model's.
+
+
+def _composed_case(layout, dtype, sq=256, skv=256, b=4, h=6, d=64, seed=11):
+    """Unit-scale float32 q, k, v and an output cotangent, their ``dtype``
+    casts, in ``layout``."""
+    shape = lambda s: (b, s, h, d) if layout == "bshd" else (b, h, s, d)  # noqa: E731
+    keys = jax.random.split(jax.random.key(seed), 4)
+    full = [
+        jax.random.normal(key, shape(s), jnp.float32)
+        for key, s in zip(keys, (sq, skv, skv, sq))
+    ]
+    return full, [x.astype(dtype) for x in full]
+
+
+def _grads(fn, q, k, v, do, **options):
+    def loss(q, k, v):
+        out = fn(q, k, v, **options)
+        return jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def _composed(q, k, v, **options):
+    return attention(q, k, v, impl="reference", **options)
+
+
+# beyond the square self-attention a ViT block asks for: an explicit scale,
+# offset-causal cross-attention, and more queries than keys, where causal
+# masks the first rows whole (their scores get no cotangent, as ``where``'s
+# rule gives).  ``square`` (256 keys) in ``bshd`` without a mask is where the
+# forward takes the score product twice; every other case takes it once
+COMPOSED_SHAPES = {
+    "square": dict(),
+    "scaled": dict(sq=64, skv=64, scale=0.3),
+    "more_keys": dict(sq=64, skv=96),
+    "more_queries": dict(sq=96, skv=64),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(COMPOSED_SHAPES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("layout", ["bshd", "bhsd"])
+def test_composed_gradients_match_reference(layout, causal, dtype, shape):
+    """Against ``jax.grad`` of ``mha_reference`` in float32 at ``highest``:
+    float32 to 1e-5 relative, bf16 no further off than 1.25 x what plain
+    bf16 autodiff of ``mha_reference`` is."""
+    sizes = dict(COMPOSED_SHAPES[shape])
+    options = dict(causal=causal, layout=layout)
+    if "scale" in sizes:
+        options["scale"] = sizes.pop("scale")
+    full, cast = _composed_case(layout, jnp.dtype(dtype), **sizes)
+    with jax.default_matmul_precision("highest"):
+        want = _grads(mha_reference, *full, **options)
+        got = _grads(_composed, *cast, **options)
+        plain = _grads(mha_reference, *cast, **options)
+    for g, p, w, c, name in zip(got, plain, want, cast, "qkv"):
+        assert g.dtype == c.dtype and g.shape == c.shape
+        if dtype == "float32":
+            assert _rel(g, w) < 1e-5, f"d{name}"
+        else:
+            assert _rel(g, w) <= 1.25 * _rel(p, w), f"d{name}"
+
+
+def _score_sized(avals, dtype, s=256):
+    return [
+        a for a in avals
+        if a.dtype == dtype and sum(n == s for n in a.shape) >= 2
+    ]
+
+
+@pytest.mark.parametrize(
+    "fn,float32,bfloat16",
+    [(_composed, 0, 1), (mha_reference, 1, 1)],
+    ids=["composed", "plain_autodiff"],
+)
+def test_composed_residuals_hold_no_float32_scores(fn, float32, bfloat16):
+    """What crosses from forward to backward at 2 x 256 x 6 x 64 in bf16:
+    the probabilities once, in bf16, and no float32 tensor with two
+    sequence axes.  Plain autodiff of ``mha_reference`` keeps both, which
+    is what the custom VJP is for (and shows the reading can tell)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    _, (q, k, v, _) = _composed_case("bshd", jnp.bfloat16, b=2)
+    avals = [
+        aval for aval, _ in saved_residuals(
+            lambda q, k, v: fn(q, k, v, layout="bshd"), q, k, v
+        )
+    ]
+    assert len(_score_sized(avals, jnp.float32)) == float32, avals
+    assert len(_score_sized(avals, jnp.bfloat16)) == bfloat16, avals
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("layout", ["bshd", "bhsd"])
+def test_composed_primal_is_the_reference(layout, causal):
+    """Not differentiated (validation, serving), the branch is
+    ``mha_reference`` bit for bit, jitted or not; differentiated, its
+    forward is the same arithmetic."""
+    _, (q, k, v, do) = _composed_case(layout, jnp.bfloat16, b=2)
+    options = dict(causal=causal, layout=layout)
+    want = mha_reference(q, k, v, **options)
+    assert bool(jnp.all(_composed(q, k, v, **options) == want))
+    jitted = jax.jit(lambda q, k, v: _composed(q, k, v, **options))
+    assert bool(jnp.all(
+        jitted(q, k, v) == jax.jit(
+            lambda q, k, v: mha_reference(q, k, v, **options)
+        )(q, k, v)
+    ))
+    out, _ = jax.vjp(lambda q, k, v: _composed(q, k, v, **options), q, k, v)
+    assert bool(jnp.all(out == want))
+
+
+def test_composed_return_lse_stays_on_plain_autodiff():
+    """The one case the custom VJP leaves out: with ``return_lse=True`` the
+    branch is ``mha_reference`` itself, and both outputs differentiate."""
+    full, _ = _composed_case("bhsd", jnp.float32, sq=64, skv=64, b=2)
+    q, k, v, do = full
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v, return_lse=True)
+            return jnp.sum(out * do) + jnp.sum(lse)
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(_composed), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert bool(jnp.all(g == w))
+
+
+def test_composed_backward_keeps_the_attention_scope():
+    """Under ``jax.grad`` the backward's four einsums carry
+    ``transpose(jvp(...))`` and the scope ``attention`` in their
+    ``op_name``, so the benchmark's ``attention_ms_per_step`` and
+    ``bwd_ms_per_step`` keep reading them (its own reader decides)."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmark"))
+    from harness import scopes
+
+    _, (q, k, v, do) = _composed_case("bshd", jnp.bfloat16, sq=64, skv=64, b=2)
+    grad = jax.jit(lambda q, k, v: _grads(attention, q, k, v, do, layout="bshd"))
+    text = grad.lower(q, k, v).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*dot_general)"', text))
+    backward = {
+        n.rsplit("/", 2)[-2] for n in names
+        if scopes.phase_of(n) == "backward" and scopes.under(n, "attention")
+    }
+    # dO·vᵀ, ds·k, and dsᵀ·q with pᵀ·dO (one equation, two einsums)
+    assert backward == {"bqhd,bkhd->bqhk", "bqhk,bkhd->bqhd", "bqhk,bqhd->bkhd"}
+    assert all("transpose(jvp(" in n for n in names
+               if scopes.phase_of(n) == "backward")
+    forward = [n for n in names if scopes.phase_of(n) == "forward"]
+    assert forward and all(scopes.under(n, "attention") for n in forward)
+
+
+def test_vit_train_step_still_reports_composed():
+    """The label does not move: a ViT train step's ``compile`` event says
+    ``{"attention": "composed"}`` and no other key (the benchmark's cell
+    compares that dict by equality)."""
+    from distributed_training_comparison_tpu import obs
+    from distributed_training_comparison_tpu.models.vit import ViT
+    from distributed_training_comparison_tpu.parallel import (
+        make_mesh,
+        replicated_sharding,
+    )
+    from distributed_training_comparison_tpu.train import (
+        configure_optimizers,
+        create_train_state,
+        make_train_step,
+    )
+    from test_train import HP
+
+    mesh = make_mesh(1, backend="ddp")
+    model = ViT(depth=2, dim=64, heads=2, num_classes=10, patch=8)
+    tx, _ = configure_optimizers(HP, steps_per_epoch=4)
+    state = jax.device_put(
+        create_train_state(model, jax.random.key(0), tx),
+        replicated_sharding(mesh),
+    )
+    bus = obs.configure(run_id=obs.new_run_id(), persist=True)
+    try:
+        monitor = obs.CompileMonitor(bus=bus, registry=obs.MetricRegistry())
+        step = make_train_step(mesh, monitor=monitor)
+        x = jnp.zeros((4, 32, 32, 3), jnp.uint8)
+        step(state, x, jnp.zeros((4,), jnp.int32), jax.random.key(1))
+        (event,) = [e for e in bus.ring_events() if e["kind"] == "compile"]
+    finally:
+        obs.reset()
+    assert event["payload"]["kernel_paths"] == {"attention": "composed"}
+    assert event["payload"].get("tpu_custom_calls", 0) == 0
 
 
 # ------------------------------------------------------- grouped MoE FFN

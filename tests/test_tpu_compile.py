@@ -19,12 +19,15 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distributed_training_comparison_tpu.ops import flash_attention
+from distributed_training_comparison_tpu.ops import attention, flash_attention
 from distributed_training_comparison_tpu.ops.attention_small import small_mha
 from distributed_training_comparison_tpu.ops.moe_gmm import grouped_ffn
 from distributed_training_comparison_tpu.ops.vit_block import fused_vit_block
@@ -132,3 +135,39 @@ def test_small_mha_compiles_for_v5e(chip):
         _grad_of(small_mha, 3), chip, _s(*shape), _s(*shape), _s(*shape)
     )
     assert "tpu_custom_call" in text
+
+
+def _written(text: str, dtype: str) -> list:
+    """Element counts of the ``dtype`` arrays that the entry computation's
+    instructions write (a fusion's outputs; what stays inside a fused
+    computation never reaches HBM)."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}")]
+    counts = []
+    for line in entry.splitlines()[1:]:
+        outputs = line.partition(" = ")[2]
+        outputs = outputs[: re.search(r"\s[\w\-]+\(", outputs + " x(").start()]
+        for dims in re.findall(rf"\b{dtype}\[([\d,]+)\]", outputs):
+            counts.append(math.prod(int(n) for n in dims.split(",")))
+    return counts
+
+
+def test_composed_attention_writes_no_float32_scores_for_v5e(chip):
+    """DeiT-S's cell (batch 256, 256 tokens, 6 heads of 64, ``bshd``): in
+    the composed branch's forward and backward the compiler writes no
+    float32 score-sized tensor — the forward's two score products each
+    fuse with what consumes them — and exactly two bf16 ones, ``p`` and
+    ``ds``.  A reading of the compiler's fusion choices, which the
+    phrasing in ``ops/attention.py _composed_fwd`` leans on: if a new
+    compiler splits them, this says so before a chip does."""
+    shape = (256, 256, 6, 64)
+    scores = 256 * 256 * 6 * 256
+    attn = lambda q, k, v: attention(  # noqa: E731
+        q, k, v, impl="reference", layout="bshd"
+    )
+    text = _compiled_text(
+        _grad_of(attn, 3), chip, _s(*shape), _s(*shape), _s(*shape)
+    )
+    assert "tpu_custom_call" not in text
+    assert scores not in _written(text, "f32")
+    assert _written(text, "bf16").count(scores) == 2
