@@ -97,8 +97,36 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         choices=[
             "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
             "vit_tiny", "vit_small", "vit_long", "vit_moe",
+            "lfm2_24b_a2b", "lfm2_tiny",
         ],
         help="Model zoo entry (live, unlike the reference's dead --model flag)",
+    )
+    parser.add_argument(
+        "--model-cut",
+        type=str,
+        default=None,
+        help="What this chip holds of a published token model "
+        "(lfm2_*, models/lfm2.py), as layers=N,dense=N,experts=N,"
+        "first_expert=N,vocab=N: layers kept (the leading dense ones, then "
+        "the layers that follow them), experts held in every expert layer "
+        "and the first one's index, vocabulary rows. No width is cut; the "
+        "router keeps every output. Keys left out keep the published value",
+    )
+    parser.add_argument(
+        "--seq-len",
+        type=int,
+        default=4096,
+        help="Tokens a sequence for a token model (one document a "
+        "sequence, no packing; --batch-size counts sequences)",
+    )
+    parser.add_argument(
+        "--optimizer",
+        type=str,
+        default="sgd",
+        choices=["sgd", "adamw"],
+        help="'sgd' = the paper's Nesterov SGD with coupled decay; 'adamw' "
+        "= AdamW (beta 0.9/0.95, eps 1e-8, decoupled --weight-decay on "
+        "matrices only), both under the StepLR schedule",
     )
     parser.add_argument("--lr", type=float, default=0.1)
     parser.add_argument("--weight-decay", type=float, default=0.0001)

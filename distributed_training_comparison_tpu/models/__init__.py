@@ -17,7 +17,8 @@ from .resnet import (
     ResNet101,
     ResNet152,
 )
-from .moe import SwitchFFN, resolve_dispatch
+from .lfm2 import LFM2, LFM2_24B_A2B_MODEL, LFM2_TINY_MODEL
+from .moe import SwitchFFN, TopKMoE, resolve_dispatch
 from .vit import ViT, ViTBlock, ViTLong, ViTMoE, ViTSmall, ViTTiny
 
 _ZOO = {
@@ -30,7 +31,15 @@ _ZOO = {
     "vit_small": ViTSmall,
     "vit_long": ViTLong,
     "vit_moe": ViTMoE,
+    "lfm2_24b_a2b": LFM2_24B_A2B_MODEL,
+    "lfm2_tiny": LFM2_TINY_MODEL,
 }
+
+
+def model_cli_options(name: str) -> tuple:
+    """The launcher flags (as ``hparams`` fields) a zoo entry takes beyond
+    the keywords every model gets: its constructor's ``cli_options``."""
+    return tuple(getattr(_ZOO.get(name.lower()), "cli_options", ()))
 
 
 def get_model(name: str, *, expert_parallel: bool = False, **kwargs):
@@ -70,6 +79,9 @@ __all__ = [
     "ViTLong",
     "ViTMoE",
     "SwitchFFN",
+    "TopKMoE",
+    "LFM2",
     "get_model",
+    "model_cli_options",
     "resolve_dispatch",
 ]

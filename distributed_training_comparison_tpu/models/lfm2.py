@@ -1,0 +1,316 @@
+"""LFM2-MoE: a token decoder of gated short convolutions, grouped-query
+attention and sigmoid-routed experts (Liquid AI; ``model_type`` ``lfm2_moe``).
+
+``LFM2_24B_A2B`` is the published ``config.json`` of ``LiquidAI/LFM2-24B-A2B``
+whole (https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json);
+no width is ever cut.  What one chip holds of it is a ``--model-cut``
+(``parse_cut``): how many layers, how many of the leading dense layers,
+which experts, how many rows of the vocabulary — one rank's share of an
+expert-parallel deployment, the layers left out lying on further chips as
+pipeline stages.  The router keeps its published width and top-k; the
+expert layer computes its own experts' part of the result
+(``models/moe.py TopKMoE``); the vocabulary slice is a smaller vocabulary.
+
+The equations (``eps`` = ``norm_eps``; no bias anywhere):
+
+- RMSNorm: ``y = x * rsqrt(mean(x^2) + eps) * g``, statistics in float32.
+- Layer: ``h = h + mixer(operator_norm(h))``; ``h = h + ffn(ffn_norm(h))``.
+  After the last layer ``norm_out``, then logits ``= h @ E^T`` with the
+  tied embedding ``E``.
+- Short convolution: ``(B, C, X) = split3(h W_in)``; ``u = B * X``; ``v_t =
+  sum_j w[:, j] * u_{t - (L-1) + j}`` per channel, causal, zeros before the
+  sequence (``L`` = ``conv_L_cache``); ``out = (C * v) W_out``.
+- Attention: query heads and fewer key-value heads of ``hidden / heads``;
+  per-head RMSNorm on q and k; RoPE in the rotate-half form; causal
+  softmax through ``ops/attention.py``'s dispatcher, each key-value head
+  repeated for the query heads it serves.
+- FFN: ``W_2(silu(W_1 x) * W_3 x)``; the leading ``num_dense_layers`` at
+  ``intermediate_size``, every later layer ``TopKMoE`` at
+  ``moe_intermediate_size``.
+
+Scopes a device trace shows: ``embed``, ``short_conv``, ``attn`` (with
+``attention`` inside it), ``mlp``, ``moe`` (with ``moe_gmm`` inside it),
+``lm_head`` — module names and ``jax.named_scope``s alike.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any
+
+import flax
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import attention
+from .moe import TopKMoE
+
+_PERIOD = ("full_attention", "conv", "conv", "conv")
+LFM2_24B_A2B = {
+    "conv_L_cache": 3,
+    "conv_bias": False,
+    "hidden_size": 2048,
+    "intermediate_size": 11776,
+    "layer_types": ["conv", "conv", *_PERIOD * 9, "full_attention", "conv"],
+    "max_position_embeddings": 128000,
+    "model_type": "lfm2_moe",
+    "moe_intermediate_size": 1536,
+    "norm_eps": 1e-05,
+    "norm_topk_prob": True,
+    "num_attention_heads": 32,
+    "num_dense_layers": 2,
+    "num_experts": 64,
+    "num_experts_per_tok": 4,
+    "num_hidden_layers": 40,
+    "num_key_value_heads": 8,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "routed_scaling_factor": 1,
+    "use_expert_bias": True,
+    "vocab_size": 65536,
+}
+# the same pattern at test widths (tests/, rehearsals): never a cell
+LFM2_TINY = {
+    **LFM2_24B_A2B,
+    "hidden_size": 64,
+    "intermediate_size": 160,
+    "layer_types": ["conv", "conv", *_PERIOD, "full_attention", "conv"],
+    "moe_intermediate_size": 48,
+    "num_attention_heads": 4,
+    "num_experts": 16,
+    "num_hidden_layers": 8,
+    "num_key_value_heads": 2,
+    "vocab_size": 512,
+}
+CUT_KEYS = ("layers", "dense", "experts", "first_expert", "vocab")
+
+
+def parse_cut(text: str | None) -> dict:
+    """``--model-cut layers=5,dense=1,experts=8,first_expert=0,vocab=8192``
+    as a dict; keys left out keep the published value."""
+    cut = {}
+    for part in filter(None, (text or "").split(",")):
+        key, _, value = part.partition("=")
+        if key not in CUT_KEYS or not value.isdigit():
+            raise ValueError(
+                f"--model-cut takes {'=N,'.join(CUT_KEYS)}=N; got {part!r}"
+            )
+        cut[key] = int(value)
+    return cut
+
+
+def cut_config(config: dict, cut: dict) -> dict:
+    """The share of ``config`` one chip holds: the first ``dense`` of the
+    leading dense layers, then the layers that follow the published dense
+    ones, ``layers`` in all; experts ``first_expert`` .. ``+ experts``; the
+    first ``vocab`` rows of the vocabulary."""
+    published_dense = config["num_dense_layers"]
+    dense = cut.get("dense", published_dense)
+    layers = cut.get("layers", config["num_hidden_layers"] - published_dense + dense)
+    types = config["layer_types"]
+    kept = list(types[:dense]) + list(
+        types[published_dense:published_dense + layers - dense]
+    )
+    held = cut.get("experts", config["num_experts"])
+    first = cut.get("first_expert", 0)
+    vocab = cut.get("vocab", config["vocab_size"])
+    if (
+        not 0 <= dense <= published_dense or len(kept) != layers
+        or not 0 < held <= config["num_experts"] - first
+        or not 0 < vocab <= config["vocab_size"]
+    ):
+        raise ValueError(f"--model-cut {cut} does not fit the published model")
+    return {
+        **config, "layer_types": kept, "num_hidden_layers": layers,
+        "num_dense_layers": dense, "num_experts_held": held,
+        "first_expert": first, "vocab_size": vocab,
+    }
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+def _dense(features, dtype, name):
+    return nn.Dense(
+        features, use_bias=False, dtype=dtype, param_dtype=jnp.float32,
+        kernel_init=nn.initializers.normal(stddev=0.02), name=name,
+    )
+
+
+def rope(x, theta: float):
+    """Rotary position embedding in the rotate-half form on ``(B, S, H,
+    D)``, positions ``0 .. S-1``, angles in float32."""
+    s, d = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution (five steps in the module docstring)."""
+
+    dim: int
+    kernel: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b_gate, c_gate, x = jnp.split(_dense(3 * self.dim, self.dtype, "in_proj")(h), 3, axis=-1)
+        w = self.param(
+            "conv_kernel", nn.initializers.normal(stddev=0.02),
+            (self.dim, self.kernel), jnp.float32,
+        ).astype(self.dtype)
+        u = b_gate * x
+        s, pad = u.shape[1], self.kernel - 1
+        u = jnp.pad(u, ((0, 0), (pad, 0), (0, 0)))
+        v = sum(w[:, j] * u[:, j:j + s] for j in range(self.kernel))
+        return _dense(self.dim, self.dtype, "out_proj")(c_gate * v)
+
+
+class GQAttention(nn.Module):
+    dim: int
+    heads: int
+    kv_heads: int
+    eps: float
+    theta: float
+    dtype: Any = jnp.float32
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, h):
+        b, s, _ = h.shape
+        hd = self.dim // self.heads
+        q = _dense(self.dim, self.dtype, "q_proj")(h).reshape(b, s, self.heads, hd)
+        k = _dense(self.kv_heads * hd, self.dtype, "k_proj")(h).reshape(b, s, self.kv_heads, hd)
+        v = _dense(self.kv_heads * hd, self.dtype, "v_proj")(h).reshape(b, s, self.kv_heads, hd)
+        q = rope(RMSNorm(self.eps, self.dtype, name="q_norm")(q), self.theta)
+        k = rope(RMSNorm(self.eps, self.dtype, name="k_norm")(k), self.theta)
+        # each key-value head serves heads // kv_heads consecutive queries
+        rep = self.heads // self.kv_heads
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        o = attention(q, k, v, causal=True, layout="bshd", impl=self.attn_impl)
+        return _dense(self.dim, self.dtype, "o_proj")(o.reshape(b, s, self.dim))
+
+
+class SwiGLU(nn.Module):
+    dim: int
+    hidden: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        gate = nn.silu(_dense(self.hidden, self.dtype, "w1")(x))
+        return _dense(self.dim, self.dtype, "w2")(gate * _dense(self.hidden, self.dtype, "w3")(x))
+
+
+class LFM2Layer(nn.Module):
+    config: Any  # the cut config, frozen
+    kind: str
+    dense: bool
+    dtype: Any = jnp.float32
+    moe_gmm: str = "auto"
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.config
+        x = RMSNorm(c["norm_eps"], self.dtype, name="operator_norm")(h)
+        if self.kind == "conv":
+            mixed = ShortConv(
+                c["hidden_size"], c["conv_L_cache"], self.dtype, name="short_conv"
+            )(x)
+        else:
+            mixed = GQAttention(
+                c["hidden_size"], c["num_attention_heads"],
+                c["num_key_value_heads"], c["norm_eps"],
+                float(c["rope_parameters"]["rope_theta"]), self.dtype,
+                self.attn_impl, name="attn",
+            )(x)
+        h = h + mixed
+        x = RMSNorm(c["norm_eps"], self.dtype, name="ffn_norm")(h)
+        if self.dense:
+            y = SwiGLU(c["hidden_size"], c["intermediate_size"], self.dtype, name="mlp")(x)
+        else:
+            y = TopKMoE(
+                c["hidden_size"], c["moe_intermediate_size"], c["num_experts"],
+                c["num_experts_per_tok"], c["num_experts_held"],
+                c["first_expert"], float(c["routed_scaling_factor"]),
+                c["norm_topk_prob"], c["use_expert_bias"], self.dtype,
+                self.moe_gmm, name="moe",
+            )(x)
+        return h + y
+
+
+def _frozen(config: dict):
+    """The config as a hashable module attribute (lists become tuples)."""
+    return flax.core.freeze({
+        k: tuple(v) if isinstance(v, list) else v for k, v in config.items()
+    })
+
+
+class LFM2(nn.Module):
+    """``tokens (B, S) int32 -> logits (B, S, vocab) float32``."""
+
+    config: Any
+    dtype: Any = jnp.float32
+    remat: bool = False
+    moe_gmm: str = "auto"
+    attn_impl: str = "auto"
+
+    task = "next_token"  # train/task.py: what this family trains on
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        c = self.config
+        embedding = self.param(
+            "embedding", nn.initializers.normal(stddev=0.02),
+            (c["vocab_size"], c["hidden_size"]), jnp.float32,
+        ).astype(self.dtype)
+        with jax.named_scope("embed"):
+            h = embedding[tokens]
+        # prevent_cse stays on: the layers are a Python loop, so forward and
+        # backward share one program body and XLA would merge the
+        # recomputation back into the forward pass
+        layer = nn.remat(LFM2Layer) if self.remat else LFM2Layer
+        for i, kind in enumerate(c["layer_types"]):
+            h = layer(
+                c, kind, i < c["num_dense_layers"], self.dtype, self.moe_gmm,
+                self.attn_impl, name=f"layers_{i}",
+            )(h)
+        h = RMSNorm(c["norm_eps"], self.dtype, name="norm_out")(h)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum(
+                "bsd,vd->bsv", h, embedding, preferred_element_type=jnp.float32
+            )
+
+
+def _build(config, *, dtype=jnp.float32, remat=False, model_cut=None,
+           moe_gmm="auto", **_image_options):
+    """A zoo constructor: takes the Trainer's model keywords and its own
+    ``cli_options``; the image families' (``stem``, ``norm_dtype``) do not
+    apply to a token decoder."""
+    cut = cut_config(config, parse_cut(model_cut))
+    return LFM2(_frozen(cut), dtype=dtype, remat=remat, moe_gmm=moe_gmm)
+
+
+def _zoo_entry(config):
+    build = functools.partial(_build, config)
+    build.cli_options = ("model_cut",)
+    return build
+
+
+LFM2_24B_A2B_MODEL = _zoo_entry(LFM2_24B_A2B)
+LFM2_TINY_MODEL = _zoo_entry(LFM2_TINY)

@@ -85,6 +85,24 @@ def resolve_dispatch(dispatch: str = "auto", *, expert_parallel: bool = False) -
     return "gather" if dispatch == "auto" else dispatch
 
 
+
+def sorted_slots(onehot: jnp.ndarray, counts: jnp.ndarray):
+    """Where each row goes when rows are sorted by expert, as a counting
+    sort: ``onehot`` is ``(rows, E)`` int32 with one 1 a row, ``counts`` its
+    column sums.  Returns ``(dest, starts)``: ``dest[r]`` is row ``r``'s
+    slot in expert order (a permutation of ``[0, rows)``; within an expert
+    the original order, as a stable sort gives), ``starts`` the ``(E + 1,)``
+    int32 group boundaries.  Shared by ``SwitchFFN``'s grouped-matmul
+    branch and ``TopKMoE``."""
+    e = onehot.shape[1]
+    pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
+    starts = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)]
+    )
+    dest = jnp.sum(starts[:e][None, :] * onehot, axis=1) + pos
+    return dest, starts
+
+
 class SwitchFFN(nn.Module):
     """Top-1 (Switch) MoE feed-forward: router → dispatch → per-expert
     MLP → gate-weighted combine.
@@ -224,11 +242,7 @@ class SwitchFFN(nn.Module):
             # bit-identical to the "gather" branch.  The gate multiply
             # happens in *unsorted* order (y is linear in ys), saving the
             # gate[order] gather too.
-            pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
-            starts = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)]
-            )
-            dest = jnp.sum(starts[:e][None, :] * onehot, axis=1) + pos
+            dest, starts = sorted_slots(onehot, counts)
             # dest is a permutation of [0, n): promising uniqueness and
             # bounds lets XLA emit a plain row scatter instead of the
             # sort-based fallback (measured ~10% of the vit_moe step as
@@ -293,4 +307,182 @@ class SwitchFFN(nn.Module):
             y = jnp.take(y_sorted, inv, axis=0)
         else:
             raise ValueError(f"unknown MoE dispatch {self.dispatch!r}")
+        return y.reshape(b, s, d).astype(self.dtype)
+
+
+# ------------------------------------------------------- top-k, no drops
+
+
+@jax.custom_vjp
+def _dispatch_rows(xt, dest, inv):
+    """``xs[s] = xt[inv[s] // k]``: every token's row copied to the slots
+    of its ``k`` (token, expert) pairs.  ``dest`` (pair -> slot) and ``inv``
+    (slot -> pair) are inverse permutations of ``[0, n * k)``, so the
+    backward is a gather too (``dest``), not autodiff's scatter-add with
+    ``k`` collisions a token."""
+    return xt[inv // (inv.shape[0] // xt.shape[0])]
+
+
+def _dispatch_rows_fwd(xt, dest, inv):
+    return _dispatch_rows(xt, dest, inv), (dest, xt.shape[0])
+
+
+def _dispatch_rows_bwd(res, g):
+    dest, n = res
+    return g[dest].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(a, idx, inv):
+    """``a[idx]`` for a permutation ``idx`` with inverse ``inv``: the
+    backward is the gather ``g[inv]``."""
+    return a[idx]
+
+
+def _permute_rows_fwd(a, idx, inv):
+    return a[idx], inv
+
+
+def _permute_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def route_topk(x, router_kernel, bias, k: int, scale: float = 1.0,
+               renormalise: bool = True):
+    """Sigmoid routing with a selection bias (DeepSeek-V3's auxiliary-loss-
+    free form, as LFM2-MoE configures it): ``s = sigmoid(x W_r)`` in
+    float32 at ``highest`` precision (a bf16 pass flips near-ties), ``sel =
+    top_k(s + b)`` — the bias enters the selection only — and weights ``s[sel]
+    / (sum s[sel] + 1e-6) * scale`` over all ``k`` selected.  Returns ``(sel
+    (n, k) int32, weights (n, k) float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, sel = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if renormalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return sel, w * scale
+
+
+# The selection bias is drawn once and moved by no rule.  At 0.002 it
+# changes the top-4 of about one token in fifteen; at 0.02 it decided which
+# experts are popular, and the rows this chip's experts receive varied by a
+# tenth from seed to seed (PERF.md, Findings, PR 27).
+EXPERT_BIAS_STD = 0.002
+
+
+class TopKMoE(nn.Module):
+    """Top-k sigmoid-routed SwiGLU experts, no capacity and no dropped
+    pair, for a layer that is told which experts it holds.
+
+    The router scores all ``num_experts``; this layer holds
+    ``num_experts_held`` of them from index ``first_expert`` (all of them
+    by default) and returns ``sum over selected e held here of w_e *
+    expert_e(x)`` — one rank's part of an expert-parallel layer's result,
+    the normaliser of ``w`` running over all ``k`` selected, held or not.
+    On one chip there is no exchange: what the absent experts would add is
+    left out (the eight shares add up to the uncut layer,
+    ``tests/test_lfm2.py``).
+
+    Dispatch: the ``n * k`` (token, expert) pairs are sorted by expert with
+    ``sorted_slots`` (pairs of experts held elsewhere sort behind the held
+    ones), rows are gathered into that order, three grouped matmuls
+    (``ops/moe_gmm.py grouped_matmul``) run over the held groups — their
+    work follows the rows that arrived — and the pairs' outputs are
+    gathered back and combined.  The buffer is ``n * k`` rows, the most
+    that can arrive, so nothing is ever dropped.
+
+    ``expert_bias`` is a float32 buffer in the ``batch_stats`` collection,
+    not a parameter: it enters the selection only, no rule updates it (the
+    config publishes none) and the optimizer never sees it.
+    """
+
+    dim: int
+    hidden: int
+    num_experts: int
+    top_k: int
+    num_experts_held: int = 0  # 0: all of them
+    first_expert: int = 0
+    scale: float = 1.0
+    renormalise: bool = True
+    use_bias: bool = True
+    dtype: Any = jnp.float32
+    gmm: str = "auto"
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        from ..ops.moe_gmm import grouped_matmul, resolve_gmm_impl
+
+        b, s, d = x.shape
+        n, k = b * s, self.top_k
+        held = self.num_experts_held or self.num_experts
+        init = nn.initializers.normal(stddev=0.02)
+        router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        bias = (
+            self.variable(
+                "batch_stats", "expert_bias",
+                lambda: EXPERT_BIAS_STD * jax.random.normal(
+                    self.make_rng("params"), (self.num_experts,), jnp.float32
+                ),
+            ).value
+            if self.use_bias else jnp.zeros((self.num_experts,), jnp.float32)
+        )
+        w1 = self.param("w1", init, (held, d, self.hidden), jnp.float32)
+        w3 = self.param("w3", init, (held, d, self.hidden), jnp.float32)
+        w2 = self.param("w2", init, (held, self.hidden, d), jnp.float32)
+
+        xt = x.reshape(n, d)
+        sel, weights = route_topk(
+            xt, router, bias, k, self.scale, self.renormalise
+        )
+        local = sel.reshape(n * k) - self.first_expert
+        here = (local >= 0) & (local < held)
+        # bucket ``held`` collects the pairs of experts held elsewhere
+        onehot = jax.nn.one_hot(
+            jnp.where(here, local, held), held + 1, dtype=jnp.int32
+        )
+        counts = jnp.sum(onehot, axis=0)
+        dest, _ = sorted_slots(onehot, counts)
+        inv = jnp.zeros_like(dest).at[dest].set(
+            jnp.arange(n * k, dtype=dest.dtype),
+            unique_indices=True, mode="promise_in_bounds",
+        )
+        group_sizes = counts[:held]
+        rows = jnp.sum(group_sizes).astype(jnp.float32)
+        self.sow("moe_metrics", "rows", rows)
+        self.sow(
+            "moe_metrics", "load_max_over_mean",
+            jnp.max(group_sizes).astype(jnp.float32)
+            / jnp.maximum(rows / held, 1.0),
+        )
+
+        impl = resolve_gmm_impl(self.gmm)
+        interpret = impl == "megablox" and jax.default_backend() != "tpu"
+        note_kernel_path("moe_gmm", impl + "-interpret" * interpret)
+        xs = _dispatch_rows(xt.astype(self.dtype), dest, inv)
+        with jax.named_scope("moe_gmm"):
+            gmm = lambda a, w: grouped_matmul(  # noqa: E731
+                a, w.astype(self.dtype), group_sizes,
+                impl=impl, interpret=interpret,
+            )
+            ys = gmm(nn.silu(gmm(xs, w1)) * gmm(xs, w3), w2)
+        here = here.reshape(n, k)
+        # a pair held elsewhere reads a row behind the groups: zero by
+        # ``grouped_matmul``'s contract, and selected away all the same
+        pair_out = jnp.where(
+            here[..., None], _permute_rows(ys, dest, inv).reshape(n, k, d), 0
+        )
+        pair_w = jnp.where(here, weights, 0.0)
+        y = jnp.einsum(
+            "nk,nkd->nd", pair_w.astype(self.dtype), pair_out,
+            preferred_element_type=jnp.float32,
+        )
         return y.reshape(b, s, d).astype(self.dtype)

@@ -346,3 +346,75 @@ def grouped_ffn(
         int(cap), block_rows, bool(interpret),
     )
     return ys[:n]
+
+
+# ------------------------------------------------- streamed grouped matmul
+
+# The kernel above keeps every expert's weights in VMEM (8 MiB for all of
+# them, ops/vmem.py).  An expert of a language model does not fit — one of
+# LFM2-24B-A2B's is 3 x 2048 x 1536, 18.9 MB in bf16 — so its weights stream
+# from HBM tile by tile.  Two implementations of the same mathematics, both
+# JAX's own; which one ``auto`` takes on a TPU was decided by timing them
+# inside the train step on the chip (PERF.md, Findings, PR 27).
+GMM_IMPLS = ("ragged_dot", "megablox")
+
+
+def _tile(dim: int, target: int) -> int:
+    """The largest multiple of 128 up to ``target`` that divides ``dim``;
+    ``dim`` itself where there is none (a tiny test width)."""
+    for t in range(min(target, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def resolve_gmm_impl(impl: str = "auto") -> str:
+    if impl == "auto":
+        return "megablox" if jax.default_backend() == "tpu" else "ragged_dot"
+    if impl not in GMM_IMPLS:
+        raise ValueError(f"unknown grouped matmul {impl!r}; one of {GMM_IMPLS}")
+    return impl
+
+
+def grouped_matmul(
+    xs: jnp.ndarray,
+    w: jnp.ndarray,
+    group_sizes: jnp.ndarray,
+    *,
+    impl: str = "ragged_dot",
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``ys[r] = xs[r] @ w[e]`` for the rows ``r`` of group ``e``.
+
+    ``xs`` is ``(m, k)`` with its rows sorted by expert, ``w`` is ``(E, k,
+    n)`` and ``group_sizes`` ``(E,)`` int32: expert ``e`` owns the
+    ``group_sizes[e]`` rows after those of the experts before it.  Rows
+    past ``sum(group_sizes)`` belong to no expert and come out zero; the
+    work done is in proportion to the rows that belong to one (``megablox``
+    runs a grid over the row tiles that hold such rows and no others).
+    Differentiable in ``xs`` and ``w``.  Returns ``(m, n)`` in ``xs``'s dtype.
+    """
+    group_sizes = group_sizes.astype(jnp.int32)
+    m, k = xs.shape
+    # What lies behind the groups is not the implementations' to define:
+    # megablox visits only row tiles that hold a group's rows and stores
+    # only those rows (the rest is uninitialised memory, in the output and,
+    # through its VJP, in the gradient with respect to ``xs``), and the
+    # chip's ``ragged_dot`` gave non-finite steps on such rows (my chip run,
+    # PR 27).  Selecting on both sides makes both exact zeros.
+    valid = (jnp.arange(m) < jnp.sum(group_sizes))[:, None]
+    xs = jnp.where(valid, xs, 0)
+    if impl == "ragged_dot":
+        out = jax.lax.ragged_dot(
+            xs, w, group_sizes, preferred_element_type=jnp.float32
+        ).astype(xs.dtype)
+    elif impl == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        tiling = (_tile(m, 512), _tile(k, 1024), _tile(w.shape[2], 1024))
+        out = megablox.gmm(
+            xs, w, group_sizes, xs.dtype, tiling, interpret=interpret
+        )
+    else:
+        raise ValueError(f"unknown grouped matmul {impl!r}; one of {GMM_IMPLS}")
+    return jnp.where(valid, out, 0)

@@ -38,6 +38,7 @@ makes failure a first-class, testable code path:
 
 from .ckpt_io import (
     atomic_write_bytes,
+    atomic_write_chunks,
     manifest_path,
     previous_path,
     read_and_hash,
@@ -77,6 +78,7 @@ from .supervisor import Supervisor
 
 __all__ = [
     "atomic_write_bytes",
+    "atomic_write_chunks",
     "manifest_path",
     "previous_path",
     "read_and_hash",

@@ -196,14 +196,44 @@ def atomic_write_bytes(path: str | Path, data: bytes, durable: bool = True) -> P
     return path
 
 
-def write_manifest(path: str | Path, data: bytes, meta: dict | None = None) -> Path:
+def atomic_write_chunks(
+    path: str | Path, chunks, durable: bool = True
+) -> tuple[Path, str, int]:
+    """``atomic_write_bytes`` for a payload that arrives in pieces (bytes
+    or C-contiguous byte buffers), hashed while it is written: returns
+    ``(path, sha256 hex, size)``.  A multi-gigabyte train state is written
+    from its arrays' own memory, never joined into one ``bytes``; writing
+    and hashing a large buffer both release the GIL, so a writer thread
+    here does not hold up the training thread."""
+    path = Path(path)
+    tmp = path.with_name(path.name + f".tmp.{os.getpid()}")
+    digest, size = hashlib.sha256(), 0
+    with open(tmp, "wb") as f:
+        for chunk in chunks:
+            f.write(chunk)
+            digest.update(chunk)
+            size += len(chunk)
+        if durable:
+            f.flush()
+            os.fsync(f.fileno())
+    os.replace(tmp, path)
+    if durable:
+        _fsync_dir(path.parent)
+    return path, digest.hexdigest(), size
+
+
+def write_manifest(
+    path: str | Path, data: bytes | None = None, meta: dict | None = None,
+    *, digest: str | None = None, size: int | None = None,
+) -> Path:
     """Write the sidecar integrity manifest for a payload already at
-    ``path`` whose bytes are ``data``.  Call AFTER the payload write: the
+    ``path`` whose bytes are ``data`` (or whose ``digest`` and ``size``
+    ``atomic_write_chunks`` returned).  Call AFTER the payload write: the
     crash window then holds a stale manifest (checksum mismatch → fallback),
     never a fresh manifest over torn bytes."""
     record = {
-        "sha256": hashlib.sha256(data).hexdigest(),
-        "bytes": len(data),
+        "sha256": digest or hashlib.sha256(data).hexdigest(),
+        "bytes": size if data is None else len(data),
         **(meta or {}),
     }
     return atomic_write_bytes(

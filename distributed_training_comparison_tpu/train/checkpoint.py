@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import logging
 import re
+import struct
 from pathlib import Path
 from typing import Any, Callable
 
@@ -29,6 +30,7 @@ from ..parallel.layouts import tree_from_canonical, tree_to_canonical
 from ..parallel.sharding import fetch_to_host
 from ..resilience.ckpt_io import (
     atomic_write_bytes,
+    atomic_write_chunks,
     previous_path,
     read_and_hash,
     read_manifest,
@@ -49,6 +51,50 @@ LAST_NAME = "last.ckpt"
 # head-major); those checkpoints are structurally and semantically
 # incompatible with the current trunk.
 CKPT_FMT = 3
+
+
+# arrays from this size up are written from their own memory (below it a
+# leaf goes through flax's packer; its ext and bin headers are the 32-bit
+# forms only above 64 KiB, which the streamed form writes by hand)
+_STREAM_MIN_BYTES = 1 << 20
+_EXT_NDARRAY = 1  # flax.serialization._MsgpackExtType.ndarray
+
+
+def msgpack_chunks(tree):
+    """The bytes ``flax.serialization.msgpack_serialize(tree)`` gives, as an
+    iterator of pieces: headers as small ``bytes``, every large array as a
+    view of its own memory.  ``msgpack_serialize`` copies each array four
+    times into one ``bytes`` with the GIL held throughout — 5 s a gigabyte
+    here, during which the training thread stands still (PERF.md, Findings,
+    PR 27); these pieces go to ``atomic_write_chunks``, whose writes and
+    hashing release it.  ``msgpack_restore`` reads the result back."""
+    import msgpack
+
+    if isinstance(tree, dict):
+        yield msgpack.Packer().pack_map_header(len(tree))
+        for key, value in sorted(tree.items()):  # flax's copy sorts keys
+            yield msgpack.packb(key)
+            yield from msgpack_chunks(value)
+        return
+    big = (
+        isinstance(tree, np.ndarray)
+        and _STREAM_MIN_BYTES <= tree.nbytes < serialization.MAX_CHUNK_SIZE
+        and not (tree.dtype.hasobject or tree.dtype.isalignedstruct)
+    )
+    if not big:  # scalars, strings, small arrays, arrays flax would split
+        yield serialization.msgpack_serialize(tree)
+        return
+    # ExtType(ndarray, packb((shape, dtype name, bytes), use_bin_type=True))
+    head = (
+        msgpack.Packer().pack_array_header(3)
+        + msgpack.packb(list(tree.shape)) + msgpack.packb(tree.dtype.name)
+        + b"\xc6" + struct.pack(">I", tree.nbytes)
+    )
+    yield (
+        b"\xc9" + struct.pack(">I", len(head) + tree.nbytes)
+        + struct.pack("b", _EXT_NDARRAY) + head
+    )
+    yield memoryview(np.ascontiguousarray(tree).reshape(-1).view(np.uint8))
 
 
 def _check_ckpt_fmt(raw: dict, params, path) -> None:
@@ -173,7 +219,7 @@ def save_checkpoint(
         "val_acc": float(val_acc),
     }
     path = version_dir / f"{BEST_PREFIX}epoch_{epoch}_acc_{val_acc:.4f}.ckpt"
-    atomic_write_bytes(path, serialization.msgpack_serialize(payload))
+    atomic_write_chunks(path, msgpack_chunks(payload))
     # drop superseded best files only AFTER the new one is durably in place
     # — a crash mid-save (fetch can take seconds) must never leave the
     # version dir with zero best checkpoints
@@ -438,12 +484,12 @@ def save_resume_state(
     path = Path(version_dir) / LAST_NAME
     if fault_hook is not None:
         fault_hook("pre", path)
-    data = serialization.msgpack_serialize(payload)
     rotate_previous(path)
-    atomic_write_bytes(path, data)
+    _, digest, size = atomic_write_chunks(path, msgpack_chunks(payload))
     write_manifest(
         path,
-        data,
+        digest=digest,
+        size=size,
         meta={
             "kind": "resume_state",
             "fmt": CKPT_FMT,

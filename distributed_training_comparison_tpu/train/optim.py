@@ -35,7 +35,12 @@ collectives; price it against the compile ledger before defaulting it.
 
 from __future__ import annotations
 
+import jax
 import optax
+
+# AdamW (Loshchilov & Hutter 2019) as language models are trained with it:
+# beta 0.9 / 0.95, eps 1e-8; --lr and --weight-decay are the launcher's
+ADAMW_B1, ADAMW_B2, ADAMW_EPS = 0.9, 0.95, 1e-8
 
 
 def step_lr_schedule(
@@ -53,7 +58,8 @@ def step_lr_schedule(
 def configure_optimizers(
     hparams, steps_per_epoch: int
 ) -> tuple[optax.GradientTransformation, optax.Schedule]:
-    """Build the torch-parity SGD+StepLR transform.
+    """Build the torch-parity SGD+StepLR transform, or ``--optimizer
+    adamw`` under the same schedule (constant with ``--lr-decay-gamma 1``).
 
     Returns ``(tx, schedule)``; the schedule is also returned standalone so
     the Trainer can log the current LR without peeking into opt_state
@@ -66,6 +72,18 @@ def configure_optimizers(
         hparams.lr_decay_gamma,
         steps_per_epoch,
     )
+    if getattr(hparams, "optimizer", "sgd") == "adamw":
+        # decoupled decay on matrices only (two axes or more: projections,
+        # expert stacks, the embedding), never on a norm's scale.  Still
+        # elementwise, so the sharded update's precondition above holds.
+        tx = optax.adamw(
+            schedule, b1=ADAMW_B1, b2=ADAMW_B2, eps=ADAMW_EPS,
+            weight_decay=hparams.weight_decay,
+            mask=lambda params: jax.tree_util.tree_map(
+                lambda p: p.ndim >= 2, params
+            ),
+        )
+        return tx, schedule
     tx = optax.chain(
         optax.add_decayed_weights(hparams.weight_decay),
         optax.sgd(learning_rate=schedule, momentum=0.9, nesterov=True),

@@ -16,6 +16,8 @@ import jax
 import optax
 from flax import core, struct
 
+from .task import IMAGE_CLASSIFICATION, Task, task_of
+
 
 class TrainState(struct.PyTreeNode):
     """Minimal SPMD train state (flax ``train_state.TrainState`` + BN stats).
@@ -38,6 +40,9 @@ class TrainState(struct.PyTreeNode):
     apply_fn: Callable = struct.field(pytree_node=False)
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
     comms_residual: Any = None
+    # what the model family trains on (train/task.py): static, like
+    # apply_fn, so every step and eval program built from a state finds it
+    task: Task = struct.field(pytree_node=False, default=IMAGE_CLASSIFICATION)
 
     def apply_gradients(self, *, grads, batch_stats) -> "TrainState":
         updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
@@ -61,7 +66,8 @@ def create_train_state(
     """
     import jax.numpy as jnp
 
-    variables = model.init(rng, jnp.zeros(input_shape, jnp.float32), train=False)
+    task = task_of(model)  # an image of ``input_shape``, or the task's own
+    variables = model.init(rng, task.init_input(input_shape), train=False)
     params = variables["params"]
     # models without BatchNorm have no batch_stats collection
     batch_stats = variables.get("batch_stats", {})
@@ -72,4 +78,5 @@ def create_train_state(
         opt_state=tx.init(params),
         apply_fn=model.apply,
         tx=tx,
+        task=task,
     )

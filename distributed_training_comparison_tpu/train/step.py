@@ -38,7 +38,6 @@ import optax
 from jax.sharding import Mesh
 
 from .._compat import donated_cache_write_barred
-from ..data.augment import normalize_images, random_crop_flip
 from ..data.cifar100 import CIFAR100_MEAN, CIFAR100_STD
 from ..data.sampler import epoch_permutation
 from ..health.guards import global_norm, select_tree, step_finite
@@ -169,7 +168,7 @@ def _moe_health(coll) -> Metrics:
     router collapses onto one expert).  Empty for dense models."""
     from jax.tree_util import tree_flatten_with_path
 
-    dropped, load_max = [], []
+    dropped, load_max, rows, imbalance = [], [], [], []
     for path, leaf in tree_flatten_with_path(coll)[0]:
         keys = {getattr(p, "key", getattr(p, "name", "")) for p in path}
         if "dropped_frac" in keys:
@@ -177,18 +176,19 @@ def _moe_health(coll) -> Metrics:
         elif "expert_load" in keys:
             # leaf: (..., depth, E) — max over experts, mean over layers
             load_max.append(jnp.mean(jnp.max(leaf, axis=-1)))
+        elif "rows" in keys:  # TopKMoE: pairs routed to the held experts
+            rows.append(jnp.sum(leaf))
+        elif "load_max_over_mean" in keys:
+            imbalance.append(jnp.mean(leaf))
     out: Metrics = {}
+    if rows:  # summed over the layers; the fullest expert's, their mean
+        out["moe_rows"] = jnp.sum(jnp.stack(rows))
+        out["moe_load_max_over_mean"] = jnp.mean(jnp.stack(imbalance))
     if dropped:
         out["moe_dropped_frac"] = jnp.mean(jnp.stack(dropped))
     if load_max:
         out["moe_load_max"] = jnp.mean(jnp.stack(load_max))
     return out
-
-
-def _topk_hits(logits: jnp.ndarray, labels: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    _, top5 = jax.lax.top_k(logits, 5)
-    hits = top5 == labels[:, None]
-    return hits[:, :1].any(-1), hits.any(-1)
 
 
 def _make_step_core(
@@ -203,6 +203,11 @@ def _make_step_core(
     repl_sharding=None,
 ) -> Callable[[TrainState, jnp.ndarray, jnp.ndarray, jax.Array], tuple[TrainState, Metrics]]:
     """The shared train core: augment → normalize → fwd/bwd → SGD update.
+
+    The state's ``task`` (``train/task.py``) is the model family's seam:
+    how a batch becomes the model's input (images: augment and normalise;
+    tokens: as they are) and how hits are counted.  The loss is the mean
+    over every label either way.
 
     Used by the per-step path (``make_train_step``), the scanned epoch path
     (``make_epoch_runner``) and the chunked streaming path
@@ -256,20 +261,12 @@ def _make_step_core(
     )
     compute_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
 
-    def forward_backward(
-        params, apply_fn, batch_stats, images, labels, key, residual=None
-    ):
-        with jax.named_scope("augment"):
-            if augment:
-                # draw_sharding pins the crop/flip draws replicated: without
-                # it GSPMD may partition the threefry generation differently
-                # per mesh shape, and the SAME (seed, epoch, step) would
-                # augment differently under DP than under DP×TP×PP
-                # (data/augment.py) — breaking cross-layout trajectory parity
-                images = random_crop_flip(
-                    images, key, draw_sharding=repl_sharding
-                )
-            x = normalize_images(images, mean, std, dtype=compute_dtype)
+    def forward_backward(state, batch_stats, images, labels, key, residual=None):
+        params, apply_fn, task = state.params, state.apply_fn, state.task
+        x = task.prepare(
+            images, key, augment=augment, mean=mean, std=std,
+            dtype=compute_dtype, draw_sharding=repl_sharding,
+        )
 
         if fwd_bwd is not None:
             if jax.tree_util.tree_leaves(batch_stats):
@@ -289,7 +286,7 @@ def _make_step_core(
             else:
                 loss, logits, grads = fwd_bwd(params, x, labels)
             with jax.named_scope("loss"):
-                top1, _ = _topk_hits(logits, labels)
+                top1, _ = task.hits(logits, labels)
             return grads, batch_stats, loss, top1.sum(), {}, residual
 
         def loss_fn(p):
@@ -319,7 +316,7 @@ def _make_step_core(
             loss_fn, has_aux=True
         )(params)
         with jax.named_scope("loss"):
-            top1, _ = _topk_hits(logits, labels)
+            top1, _ = task.hits(logits, labels)
         # BN-free models mutate nothing; keep the (empty) stats tree stable
         new_stats = mutated.get("batch_stats", batch_stats)
         extras = _moe_health(mutated.get("moe_metrics", {}))
@@ -330,8 +327,7 @@ def _make_step_core(
         if grad_accum <= 1:
             grads, new_stats, loss, top1_count, extras, new_residual = (
                 forward_backward(
-                    state.params, state.apply_fn, state.batch_stats,
-                    images, labels, key, res0,
+                    state, state.batch_stats, images, labels, key, res0,
                 )
             )
         else:
@@ -356,10 +352,7 @@ def _make_step_core(
                 grads_sum, batch_stats, res = carry
                 bx, by, k = inp
                 grads, new_stats, loss, top1_count, extras, res = (
-                    forward_backward(
-                        state.params, state.apply_fn, batch_stats, bx, by, k,
-                        res,
-                    )
+                    forward_backward(state, batch_stats, bx, by, k, res)
                 )
                 grads_sum = jax.tree_util.tree_map(jnp.add, grads_sum, grads)
                 return (grads_sum, new_stats, res), {
@@ -376,7 +369,8 @@ def _make_step_core(
             loss = stacked["loss"].mean()
             top1_count = stacked["top1"].sum()
             extras = {
-                k: stacked[k].mean() for k in stacked if k.startswith("moe_")
+                k: stacked[k].sum() if k == "moe_rows" else stacked[k].mean()
+                for k in stacked if k.startswith("moe_")
             }
 
         if fault_scale is not None:
@@ -582,7 +576,9 @@ def make_device_replay_step(
 
 def _make_eval_core(mesh: Mesh, precision: str, mean, std):
     """Per-batch eval metrics fn shared by the one-shot step and the scanned
-    runner (so the two can never diverge)."""
+    runner (so the two can never diverge).  ``weights`` masks whole
+    examples; an example of several labels (a token sequence) counts each
+    of them."""
     compute_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
     data_shard = batch_sharding(mesh)
 
@@ -592,19 +588,27 @@ def _make_eval_core(mesh: Mesh, precision: str, mean, std):
         images = jax.lax.with_sharding_constraint(images, data_shard)
         labels = jax.lax.with_sharding_constraint(labels, data_shard)
         weights = jax.lax.with_sharding_constraint(weights, data_shard)
-        x = normalize_images(images, mean, std, dtype=compute_dtype)
+        x = state.task.prepare(
+            images, None, augment=False, mean=mean, std=std,
+            dtype=compute_dtype,
+        )
         logits = state.apply_fn(
             {"params": state.params, "batch_stats": state.batch_stats},
             x,
             train=False,
         )
+        labels_each = labels.size // labels.shape[0]
+        count = weights.sum()
+        if labels_each > 1:
+            weights = jnp.expand_dims(weights, tuple(range(1, labels.ndim)))
+            count = count * labels_each
         per_example = _cross_entropy(logits, labels) * weights
-        top1, top5 = _topk_hits(logits, labels)
+        top1, top5 = state.task.hits(logits, labels)
         return {
             "loss_sum": per_example.sum(),
             "top1_count": (top1 * weights).sum(),
             "top5_count": (top5 * weights).sum(),
-            "count": weights.sum(),
+            "count": count,
         }
 
     return core

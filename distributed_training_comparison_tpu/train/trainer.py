@@ -54,11 +54,10 @@ from ..data import (
     HostLoader,
     PrefetchLoader,
     chunked_batches,
-    get_datasets,
 )
 from ..data.cifar100 import CIFAR100_MEAN, CIFAR100_STD, IMAGENET_MEAN, IMAGENET_STD
 from ..health import HealthConfig, Watchdog, check_desync, param_fingerprint, write_health
-from ..models import get_model
+from ..models import get_model, model_cli_options
 from ..parallel import is_main_process, make_mesh, state_shardings
 from ..parallel import comms as comms_mod
 from ..parallel import layouts as layouts_mod
@@ -87,6 +86,7 @@ from . import checkpoint as ckpt
 from .async_ckpt import AsyncCheckpointer
 from .optim import configure_optimizers
 from .state import create_train_state
+from .task import task_of
 from .step import (
     make_chunk_runner,
     make_device_chunk_runner,
@@ -109,6 +109,26 @@ def _pad_batches(images: np.ndarray, labels: np.ndarray, batch_size: int):
     if pad:
         weights[-pad:] = 0.0
     return images, labels, weights
+
+
+class _WriterSnapshot:
+    """What the epoch boundary hands the checkpoint writer: a device
+    snapshot that the writer fetches once.  The first job that runs moves
+    it to the host and drops the device copy — serialising and writing a
+    multi-gigabyte state takes the writer half a minute, and a device copy
+    held that long meets the next save's; the jobs that share it (best and
+    last of one epoch) read the host copy.  A job superseded before it ran
+    drops its reference with it.  (Fetching at once on a thread of its own
+    was tried: the transfer then runs beside the next epoch's dispatch, and
+    the boundary grew from 241 to 291 ms; PERF.md, Findings, PR 27.)"""
+
+    def __init__(self, state):
+        self._state, self._fetched = state, False
+
+    def on_host(self):  # the one writer thread runs its jobs in turn
+        if not self._fetched:
+            self._state, self._fetched = fetch_to_host(self._state), True
+        return self._state
 
 
 class Trainer:
@@ -263,15 +283,26 @@ class Trainer:
                     )
                 fusion = "off"
             model_kw["block_fusion"] = fusion
+        # the launcher flags a zoo entry registers for itself (a token
+        # model's cut): taken by field, not by name
+        for opt in model_cli_options(hparams.model):
+            model_kw[opt] = getattr(hparams, opt)
         self.model = model if model is not None else get_model(
             hparams.model, expert_parallel=expert_parallel, **model_kw
         )
+        # what the family trains on (train/task.py): its splits, the array
+        # it is initialised on; the step and eval programs find it on the
+        # train state
+        self.task = task_of(self.model)
 
         # --- data.  'device' mode: split is HBM-resident and replicated;
         # per-batch sharding happens inside the compiled epoch.  'host'
         # mode: train batches stream from a per-host-sharded numpy loader
         # (val/test stay device-resident — they are small either way).
-        trn, val, tst = get_datasets(hparams)
+        trn, val, tst = self.task.datasets(hparams, self.model)
+        # labels an example carries: 1 an image, the sequence length a
+        # token sequence (the hit counts are per label)
+        self._labels_per_example = int(np.prod(trn.labels.shape[1:]))
         if len(trn) < hparams.batch_size or len(val) == 0:
             raise ValueError(
                 f"dataset too small after split: {len(trn)} train / {len(val)} "
@@ -1318,13 +1349,19 @@ class Trainer:
             return None
         return tqdm(iterable, desc=desc, leave=False)
 
-    def _snapshot_state(self, state):
+    def _snapshot_state(self, state, whole: bool = True):
         """Device-side copy of ``state`` (same shardings, async dispatch).
 
         The write-behind checkpointer fetches from this snapshot while the
         next epoch's donated dispatch reuses the live state's buffers.  Cost:
         one HBM→HBM state copy on epochs that actually save — versus the
         pre-donation design's copy on EVERY dispatch.
+
+        The copy runs as two executions of one program: what a best-only
+        save writes (params, statistics), then the rest.  ``whole=False``
+        stops after the first and hands back a state without optimizer
+        state.  Both compile at the first whole save (epoch 0), so a later
+        best-only epoch compiles nothing.
         """
         if self._snapshot_fn is None:
             # sentinel=False: the snapshot program compiles whenever the
@@ -1333,7 +1370,14 @@ class Trainer:
                 lambda s: jax.tree_util.tree_map(jnp.copy, s),
                 self.compile_monitor, "state_snapshot", sentinel=False,
             )
-        return self._snapshot_fn(state)
+        params, batch_stats = self._snapshot_fn(
+            (state.params, state.batch_stats)
+        )
+        rest = state.replace(params=None, batch_stats=None)
+        rest = self._snapshot_fn(rest) if whole else rest.replace(
+            step=None, opt_state=None, comms_residual=None
+        )
+        return rest.replace(params=params, batch_stats=batch_stats)
 
     def _note_pipeline_obs(self, t0: float, t1: float) -> None:
         """Per-dispatch pipeline observability (pipeline runs only): one
@@ -1461,6 +1505,7 @@ class Trainer:
                 self._profiling = False
                 self.logger.info(f"profiler trace written to {hp.profile_dir}")
             imgs = len(losses) * hp.batch_size
+            labels_seen = imgs * self._labels_per_example
 
             # failure detection + recovery, BEFORE this epoch validates or
             # checkpoints (a bad epoch must neither save its state nor be
@@ -1517,7 +1562,7 @@ class Trainer:
             lr_now = float(self.lr_schedule(epoch * self.steps_per_epoch))
             self.logger.info(
                 f"[{hp.backend.upper()} Version {self.version} Epoch {epoch}] "
-                f"train loss: {meter.avg:.4f}, train acc: {100.0 * top1 / imgs:.2f}%, "
+                f"train loss: {meter.avg:.4f}, train acc: {100.0 * top1 / labels_seen:.2f}%, "
                 f"val loss: {val['val_loss']:.4f}, val acc: {val['val_acc']:.2f}%, "
                 f"lr: {lr_now:.4f}, {imgs / epoch_time:.0f} img/s"
             )
@@ -1632,19 +1677,28 @@ class Trainer:
                 # dispatched async; a computation, so under multi-host it
                 # runs on EVERY process), never a reference donation would
                 # invalidate mid-fetch.
+                # A best-only save writes params and statistics, so only
+                # they are copied: at a language model's size the optimizer
+                # state's copy beside the next epoch's step is what would
+                # bound the batch.
                 with self.goodput.phase("ckpt"), self.tracer.span(
                     "ckpt_snapshot", epoch=epoch
                 ):
-                    state_ref = self._snapshot_state(state_ref)
+                    state_ref = self._snapshot_state(
+                        state_ref, whole=want_last
+                    )
             if self.is_main:
                 # write-behind: the worker thread fetches + serializes while
                 # the next epoch computes (from the snapshot/host copy above
-                # — never the live state the donated dispatch will reuse)
+                # — never the live state the donated dispatch will reuse).
+                # The first job to run moves the snapshot to the host
+                # and lets the device copy go (``_WriterSnapshot``).
+                state_ref = _WriterSnapshot(state_ref)
                 if want_best:
                     self.ckpt_writer.submit(
                         lambda s=state_ref, e=epoch, b=self.best_acc: (
                             ckpt.save_checkpoint(
-                                vdir, s, e, b,
+                                vdir, s.on_host(), e, b,
                                 state_layout=self._state_layout,
                             )
                         ),
@@ -1660,7 +1714,7 @@ class Trainer:
                     self.ckpt_writer.submit(
                         lambda s=state_ref, e=epoch, b=self.best_acc, h=hook: (
                             ckpt.save_resume_state(
-                                vdir, s, e, b,
+                                vdir, s.on_host(), e, b,
                                 fault_hook=h,
                                 meta=self._ckpt_meta(),
                                 state_layout=self._state_layout,
@@ -3144,6 +3198,16 @@ class Trainer:
             for k in fetched[0]
             if k.startswith("moe_")
         }
+        if "moe_rows" in fetched[0]:
+            # top-k expert layers (models/moe.py TopKMoE): pairs routed to
+            # the experts held here, summed over the epoch's steps and
+            # layers, and how far the fullest expert is above the mean
+            self.metrics.counter("moe/rows").inc(
+                int(sum(np.asarray(m["moe_rows"]).sum() for m in fetched))
+            )
+            self.metrics.gauge("moe/load_max_over_mean").set(
+                self._moe_health["moe_load_max_over_mean"]
+            )
         # the per-step signals land in the metric sketches here — one
         # vectorized pass over the stacked arrays, no per-step Python loop;
         # non-finite samples count into the sketch's side counter, so a

@@ -114,3 +114,42 @@ def test_host_data_mode_end_to_end(tmp_path):
     assert (vdir / "last.ckpt").exists()  # epoch 1 hits save-last-every=2
     assert list(vdir.glob("best_model_*.ckpt"))
     assert results["test_loss"] > 0
+
+
+def test_streamed_serialisation_is_flax_msgpack_byte_for_byte(tmp_path):
+    """``checkpoint.msgpack_chunks`` yields exactly the bytes
+    ``flax.serialization.msgpack_serialize`` builds — large arrays as views
+    of their own memory — and ``atomic_write_chunks`` hashes what it writes."""
+    import hashlib
+
+    import jax.numpy as jnp
+    import numpy as np
+    from flax import serialization
+
+    from distributed_training_comparison_tpu.resilience import atomic_write_chunks
+    from distributed_training_comparison_tpu.train.checkpoint import msgpack_chunks
+
+    rng = np.random.default_rng(0)
+    tree = {
+        "fmt": 3, "epoch": 7, "best_acc": 43.78, "name": "last",
+        "state": {
+            "step": np.asarray(36, np.int32),
+            "params": {
+                "big": rng.standard_normal((300, 1024)).astype(np.float32),
+                "half": rng.standard_normal((1024, 600)).astype(jnp.bfloat16),
+                "strided": rng.standard_normal((2048, 512)).astype(np.float32).T,
+                "small": rng.standard_normal((64,)).astype(np.float32),
+            },
+            "opt_state": {"0": {"count": np.asarray(36, np.int32), "mu": {}}},
+            "batch_stats": {},
+        },
+    }
+    want = serialization.msgpack_serialize(tree)
+    pieces = list(msgpack_chunks(tree))
+    assert b"".join(bytes(p) for p in pieces) == want
+    assert sum(isinstance(p, memoryview) for p in pieces) == 3  # no copies
+    path, digest, size = atomic_write_chunks(tmp_path / "x.ckpt", msgpack_chunks(tree))
+    assert path.read_bytes() == want and size == len(want)
+    assert digest == hashlib.sha256(want).hexdigest()
+    back = serialization.msgpack_restore(path.read_bytes())
+    np.testing.assert_array_equal(back["state"]["params"]["half"], tree["state"]["params"]["half"])

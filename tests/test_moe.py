@@ -432,3 +432,139 @@ def test_auto_gmm_gate_respects_vmem_budget():
     big = gmm_weight_bytes(64, 1024, 4096, jnp.bfloat16)
     assert big > WEIGHT_BUDGET_BYTES
     assert not fits_weight_budget(big)
+
+
+# ------------------------------------------------ top-k, no drops (TopKMoE)
+
+from distributed_training_comparison_tpu.models.moe import (  # noqa: E402
+    TopKMoE, route_topk,
+)
+from distributed_training_comparison_tpu.ops.moe_gmm import (  # noqa: E402
+    grouped_matmul,
+)
+
+from lfm2_reference import reference as lfm2_ref  # noqa: E402
+
+TOPK = dict(dim=32, hidden=48, num_experts=16, top_k=4)
+
+
+def _topk_layer(x, **held):
+    layer = TopKMoE(**TOPK, **held)
+    return layer, layer.init(jax.random.key(0), x)
+
+
+def _share(variables, first, held):
+    """One rank's slice of the whole layer's variables."""
+    p = dict(variables["params"])
+    for k in ("w1", "w2", "w3"):
+        p[k] = p[k][first:first + held]
+    return {"params": p, "batch_stats": variables["batch_stats"]}
+
+
+def _ref_moe(variables, x, first=0):
+    arch = {**lfm2_ref.ARCH, "first_expert": first}
+    return lfm2_ref.moe(
+        x, variables["params"], variables["batch_stats"]["expert_bias"], arch
+    )
+
+
+def test_selection_bias_changes_the_selection_and_not_the_weights():
+    x = jax.random.normal(jax.random.key(1), (64, 32))
+    router = 0.5 * jax.random.normal(jax.random.key(2), (32, 16))
+    zero = jnp.zeros((16,))
+    push = zero.at[3].set(10.0)  # expert 3 is now always selected
+    sel0, w0 = route_topk(x, router, zero, 4)
+    sel1, w1 = route_topk(x, router, push, 4)
+    assert not (np.asarray(sel0) == 3).any(axis=-1).all()
+    assert (np.asarray(sel1) == 3).any(axis=-1).all()
+    scores = jax.nn.sigmoid(x @ router)
+    for sel, w in ((sel0, w0), (sel1, w1)):
+        s = jnp.take_along_axis(scores, sel, axis=-1)
+        np.testing.assert_allclose(
+            w, s / (s.sum(-1, keepdims=True) + 1e-6), rtol=1e-5
+        )  # the bias is in no weight
+    np.testing.assert_allclose(np.asarray(w1).sum(-1), 1.0, rtol=1e-5)
+
+
+def test_topk_layer_matches_the_plain_reference():
+    x = jax.random.normal(jax.random.key(3), (2, 24, 32))
+    layer, variables = _topk_layer(x)
+    got = layer.apply(variables, x)
+    np.testing.assert_allclose(got, _ref_moe(variables, x), rtol=2e-4, atol=2e-6)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four ranks holding experts 0-3 ... 12-15 of the 16, summed, give
+    what the layer holding all of them gives (guide §4)."""
+    x = jax.random.normal(jax.random.key(4), (2, 24, 32))
+    whole, variables = _topk_layer(x)
+    want = whole.apply(variables, x)
+    total = 0.0
+    for first in range(0, 16, 4):
+        share = TopKMoE(**TOPK, num_experts_held=4, first_expert=first)
+        part = share.apply(_share(variables, first, 4), x)
+        np.testing.assert_allclose(
+            part, _ref_moe(_share(variables, first, 4), x, first),
+            rtol=2e-4, atol=2e-6,
+        )
+        total = total + part
+    np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-6)
+    np.testing.assert_allclose(want, _ref_moe(variables, x), rtol=2e-4, atol=2e-6)
+
+
+def test_no_pair_is_dropped_when_every_token_goes_to_one_held_expert():
+    """Expert 1 (held) is in every token's selection and the other three
+    selected are held elsewhere: one group of ``n`` rows, sixteen times the
+    mean load, every one computed."""
+    x = jax.random.normal(jax.random.key(5), (2, 40, 32))
+    share = TopKMoE(**TOPK, num_experts_held=4, first_expert=0)
+    _, variables = _topk_layer(x)
+    bias = jnp.zeros((16,)).at[1].set(10.0).at[jnp.array([9, 10, 11])].set(5.0)
+    variables = _share(
+        {**variables, "batch_stats": {"expert_bias": bias}}, 0, 4
+    )
+    got, sown = share.apply(variables, x, mutable=["moe_metrics"])
+    assert float(sown["moe_metrics"]["rows"][0]) == 80.0
+    assert float(sown["moe_metrics"]["load_max_over_mean"][0]) == 4.0
+    want = _ref_moe(variables, x)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-6)
+    assert float(jnp.abs(want).min(axis=-1).max()) > 0  # nothing zeroed out
+
+
+def test_expert_bias_gets_no_gradient_and_is_no_parameter():
+    x = jax.random.normal(jax.random.key(6), (1, 16, 32))
+    layer, variables = _topk_layer(x)
+    assert set(variables["params"]) == {"router", "w1", "w2", "w3"}
+    g = jax.grad(lambda v: layer.apply(v, x).sum())(variables)
+    assert float(jnp.abs(g["batch_stats"]["expert_bias"]).max()) == 0.0
+    assert float(jnp.abs(g["params"]["router"]).max()) > 0.0
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "megablox"])
+def test_grouped_matmul_matches_a_per_expert_loop(impl):
+    """Rows sorted by expert, ragged groups (one empty), rows behind the
+    groups zero; values and both gradients against a Python loop.  The
+    Pallas kernel runs interpreted here."""
+    m, k, n = 256, 128, 256
+    sizes = jnp.array([40, 0, 100, 30], jnp.int32)
+    xs = jax.random.normal(jax.random.key(7), (m, k))
+    w = jax.random.normal(jax.random.key(8), (4, k, n)) / np.sqrt(k)
+
+    def loop(xs, w):
+        out, start = jnp.zeros((m, n)), 0
+        for e, size in enumerate(np.asarray(sizes)):
+            rows = slice(start, start + int(size))
+            out = out.at[rows].set(xs[rows] @ w[e])
+            start += int(size)
+        return out
+
+    run = lambda xs, w: grouped_matmul(  # noqa: E731
+        xs, w, sizes, impl=impl, interpret=impl == "megablox"
+    )
+    np.testing.assert_allclose(run(xs, w), loop(xs, w), rtol=1e-4, atol=1e-5)
+    assert float(jnp.abs(run(xs, w)[170:]).max()) == 0.0
+    cot = jax.random.normal(jax.random.key(9), (m, n))
+    got = jax.grad(lambda a, b: (run(a, b) * cot).sum(), (0, 1))(xs, w)
+    want = jax.grad(lambda a, b: (loop(a, b) * cot).sum(), (0, 1))(xs, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)

@@ -83,3 +83,61 @@ def test_weight_decay_applies_to_all_params():
         np.testing.assert_allclose(
             np.asarray(leaf), -HP.lr * 1.9 * HP.weight_decay, rtol=1e-5
         )
+
+
+def test_adamw_step_matches_optax_and_the_plain_reference():
+    """``--optimizer adamw``: one step from a fresh state against
+    ``optax.adamw`` spelled out and against the plain reference's own
+    formula (``benchmark/reference/lfm2_24b_a2b_ep8.py step``'s): beta 0.9
+    / 0.95, eps 1e-8, decoupled decay on matrices only — a norm's scale is
+    not decayed — under a constant learning rate."""
+    from distributed_training_comparison_tpu.train import optim
+
+    class AdamHP(HP):
+        optimizer = "adamw"
+        lr = 3e-4
+        weight_decay = 0.1
+        lr_decay_gamma = 1.0
+
+    params = {
+        "kernel": jax.random.normal(jax.random.key(0), (8, 4)),
+        "experts": jax.random.normal(jax.random.key(1), (2, 8, 4)),
+        "scale": jnp.ones((4,)) * 1.5,
+    }
+    grads = jax.tree_util.tree_map(
+        lambda p: jax.random.normal(jax.random.key(2), p.shape) * 1e-3, params
+    )
+    tx, schedule = configure_optimizers(AdamHP, steps_per_epoch=10)
+    assert float(schedule(0)) == float(schedule(1000)) == pytest.approx(3e-4)
+    updates, _ = tx.update(grads, tx.init(params), params)
+    got = optax.apply_updates(params, updates)
+
+    plain = optax.adamw(
+        3e-4, b1=optim.ADAMW_B1, b2=optim.ADAMW_B2, eps=optim.ADAMW_EPS,
+        weight_decay=0.1,
+        mask={"kernel": True, "experts": True, "scale": False},
+    )
+    updates, _ = plain.update(grads, plain.init(params), params)
+    want = optax.apply_updates(params, updates)
+
+    def by_hand(p, g):  # the first step: m_hat = g, v_hat = g^2
+        decay = 0.1 * p if p.ndim >= 2 else 0.0
+        return p - 3e-4 * (g / (jnp.sqrt(g * g) + 1e-8) + decay)
+
+    for name in params:
+        np.testing.assert_allclose(got[name], want[name], rtol=1e-6)
+        np.testing.assert_allclose(
+            got[name], by_hand(params[name], grads[name]), rtol=1e-5
+        )
+    # the scale moved by the Adam step alone: lr, whatever its size
+    np.testing.assert_allclose(
+        np.abs(np.asarray(got["scale"] - params["scale"])), 3e-4, rtol=1e-3
+    )
+
+
+def test_sgd_is_still_the_default_optimizer():
+    tx, _ = configure_optimizers(HP, steps_per_epoch=10)
+    params = {"w": jnp.ones((3,))}
+    updates, _ = tx.update({"w": jnp.ones((3,))}, tx.init(params), params)
+    # torch SGD, nesterov, coupled decay: -(lr) * (d + 0.9 d), d = g + wd p
+    np.testing.assert_allclose(updates["w"], -0.1 * 1.9 * (1 + 1e-4), rtol=1e-6)

@@ -273,7 +273,12 @@ def test_trainer_program_is_named(tiny_trainer, name):
         t.bus.unsubscribe(seen.append)
     # the monitor's wrapper keeps the jitted function it observes
     assert _module_name(fn._fn.lower(*args)) == f"jit_{name}"
-    assert [e["payload"]["name"] for e in seen if e["kind"] == "compile"] == [name]
+    # the snapshot is two executions of its one program (what a best-only
+    # save writes, then the rest), compiled together at the first whole save
+    times = 2 if name == "state_snapshot" else 1
+    assert [
+        e["payload"]["name"] for e in seen if e["kind"] == "compile"
+    ] == [name] * times
 
 
 # -------------------------------------------------- names compute nothing

@@ -29,7 +29,10 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_training_comparison_tpu.ops import attention, flash_attention
 from distributed_training_comparison_tpu.ops.attention_small import small_mha
-from distributed_training_comparison_tpu.ops.moe_gmm import grouped_ffn
+from distributed_training_comparison_tpu.ops.moe_gmm import (
+    grouped_ffn,
+    grouped_matmul,
+)
 from distributed_training_comparison_tpu.ops.vit_block import fused_vit_block
 
 BF16 = jnp.bfloat16
@@ -82,7 +85,12 @@ def _grad_of(fn, n_args):
 
 # vit_long as chip_smoke trains it (batch 8, 4 heads, 4096 tokens, head dim
 # 128), and S=16384: past _FWD_RESIDENT_KV_LIMIT, the streamed forward
-FLASH_SHAPES = {"vit_long": (8, 4, 4096, 128), "s16384": (1, 4, 16384, 128)}
+# lfm2: the token cell's attention layer (4 sequences, 32 heads after the
+# key-value heads are repeated, 4,096 tokens, head size 64, padded to 128)
+FLASH_SHAPES = {
+    "vit_long": (8, 4, 4096, 128), "s16384": (1, 4, 16384, 128),
+    "lfm2": (4, 32, 4096, 64),
+}
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
@@ -125,6 +133,28 @@ def test_grouped_ffn_compiles_for_v5e(chip):
         _s(e, d), _s(e + 1, dtype=jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_streamed_grouped_matmul_compiles_for_v5e(chip):
+    """LFM2-24B-A2B's expert layer on one chip's share: the 65,536-row
+    dispatch buffer (16,384 tokens x top-4) over 8 held experts of 2048 x
+    1536 whose weights stream from HBM (JAX's megablox kernels at this
+    repo's tiling), the SwiGLU's three products, forward and backward."""
+    m, e, d, f = 4 * 4096 * 4, 8, 2048, 1536
+
+    def experts(xs, w1, w3, w2, sizes):
+        gmm = lambda a, w: grouped_matmul(a, w, sizes, impl="megablox")  # noqa: E731
+        return gmm(jax.nn.silu(gmm(xs, w1)) * gmm(xs, w3), w2)
+
+    grad = jax.grad(
+        lambda *a: experts(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3)
+    )
+    text = _compiled_text(
+        grad, chip, _s(m, d), _s(e, d, f), _s(e, d, f), _s(e, f, d),
+        _s(e, dtype=jnp.int32),
+    )
+    # 2 forward (the last one feeds only the sum, which grad drops), 3 dx, 3 dW
+    assert text.count("tpu_custom_call") >= 8
 
 
 def test_small_mha_compiles_for_v5e(chip):

@@ -200,3 +200,25 @@ def test_batch_not_divisible_raises(tmp_path):
     hp.batch_size = 60  # not divisible by 8-device data axis
     with pytest.raises(ValueError, match="not divisible"):
         Trainer(hp, model=TinyNet(num_classes=100))
+
+
+def test_best_only_snapshot_copies_only_what_the_save_writes(run_dir):
+    """A best-only save writes params and statistics, so its device
+    snapshot holds no optimizer state; a whole one holds everything; both
+    are copies, never the live (donated) buffers."""
+    import jax
+
+    _, _, _, trainer = run_dir
+    state = trainer.state
+    best = trainer._snapshot_state(state, whole=False)
+    whole = trainer._snapshot_state(state)
+    assert best.opt_state is None and best.step is None
+    assert jax.tree_util.tree_structure(whole) == jax.tree_util.tree_structure(state)
+    for snap in (best, whole):
+        for a, b in zip(jax.tree_util.tree_leaves(snap.params),
+                        jax.tree_util.tree_leaves(state.params)):
+            assert a is not b
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree_util.tree_leaves(whole.opt_state),
+                    jax.tree_util.tree_leaves(state.opt_state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
