@@ -1,4 +1,13 @@
-"""Switch-style mixture-of-experts FFN with expert parallelism.
+"""Mixture-of-experts feed-forward layers: ``SwitchFFN`` (top-1, a capacity
+and drops, expert parallelism as a sharding) and ``TopKMoE`` (top-k sigmoid
+routing for a rank that is told which experts it holds, no capacity and no
+drop: its dispatch works on a bounded *held prefix* of the expert-sorted
+pairs, sized from the share of experts held, with the plain every-expert-
+on-every-token sum behind it for the call in which more arrive — see its
+docstring).  What
+follows is ``SwitchFFN``'s design.
+
+Switch-style mixture-of-experts FFN with expert parallelism.
 
 Beyond parity: the reference has no MoE (its only model is a CNN,
 ``src/single/net.py``).  This layer completes the parallelism matrix —
@@ -46,14 +55,16 @@ TPU-native design:
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..obs.compilation import note_kernel_path
+from ..ops.moe_gmm import grouped_matmul, grouped_matmul_t, resolve_gmm_impl
 from ..ops.vmem import fits_weight_budget, gmm_weight_bytes
 
 
@@ -313,44 +324,243 @@ class SwitchFFN(nn.Module):
 # ------------------------------------------------------- top-k, no drops
 
 
-@jax.custom_vjp
-def _dispatch_rows(xt, dest, inv):
-    """``xs[s] = xt[inv[s] // k]``: every token's row copied to the slots
-    of its ``k`` (token, expert) pairs.  ``dest`` (pair -> slot) and ``inv``
-    (slot -> pair) are inverse permutations of ``[0, n * k)``, so the
-    backward is a gather too (``dest``), not autodiff's scatter-add with
-    ``k`` collisions a token."""
-    return xt[inv // (inv.shape[0] // xt.shape[0])]
+# The selection bias is drawn once and moved by no rule.  At 0.002 it
+# changes the top-4 of about one token in fifteen; at 0.02 it decided which
+# experts are popular, and the rows this chip's experts receive varied by a
+# tenth from seed to seed (PERF.md, Findings, PR 27).
+EXPERT_BIAS_STD = 0.002
+
+# The held prefix is twice the rows that even routing sends to the experts
+# held here: even routing fills half of it, and the fullest layer calls seen
+# where a rank holds an eighth of the experts (epoch 0 of lfm2_ep8_seq4k_job:
+# 10-12 k rows of an even 8 k; PERF.md, Findings, PR 27) fit with room.  More
+# than that takes the fallback, which drops nothing either.
+SLACK = 2
+
+# Tokens a group of the grouped matmul that sums a token's rows: one lane tile
+# of one-hot columns, so a group's product is 128 x (its rows) x d on the MXU.
+SUM_TOKENS = 128
 
 
-def _dispatch_rows_fwd(xt, dest, inv):
-    return _dispatch_rows(xt, dest, inv), (dest, xt.shape[0])
+class _Experts(NamedTuple):
+    """What is static about one call of the expert part."""
+
+    prefix: int  # rows of the held prefix, ``C``
+    top_k: int
+    impl: str
+    interpret: bool
 
 
-def _dispatch_rows_bwd(res, g):
-    dest, n = res
-    return g[dest].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+def held_prefix_rows(pairs: int, held: int, num_experts: int) -> int:
+    """``C``: the rows of the held prefix for ``pairs`` (token, expert)
+    pairs in a layer that holds ``held`` of ``num_experts`` — ``SLACK``
+    times its even share in whole lane tiles (megablox's row tile divides
+    it), and never more than the pairs there are."""
+    return min(pairs, -(-SLACK * pairs * held // (num_experts * 128)) * 128)
 
 
-_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+def _token_order(st, plan):
+    """The second permutation of the prefix's slots: the live ones (those of
+    pairs held here, ``[0, rows)``) in the order of their pairs ``(token,
+    j)``, compacted, so that a token's held pairs are at most ``k``
+    neighbours; the slots behind them stay where they are.  Returns ``(q2s,
+    token)`` for the positions ``q`` of that order: the slot each reads
+    and its token (-1 where no pair lives)."""
+    slot = jnp.arange(st.prefix, dtype=jnp.int32)
+    pair = plan["inv"][: st.prefix]
+    live = slot < plan["rows"]
+    s2q = jnp.where(live, plan["before"][pair], slot)
+    q2s = jnp.zeros_like(slot).at[s2q].set(
+        slot, unique_indices=True, mode="promise_in_bounds"
+    )
+    return q2s, jnp.where(live, pair[q2s] // st.top_k, -1)
 
 
-@jax.custom_vjp
-def _permute_rows(a, idx, inv):
-    """``a[idx]`` for a permutation ``idx`` with inverse ``inv``: the
-    backward is the gather ``g[inv]``."""
-    return a[idx]
+def _token_sums(a, weight, st, plan):
+    """``out[t] = sum of weight[s] * a[s]`` over the live slots ``s`` of
+    token ``t``'s pairs (zero where it has none), accumulated in float32
+    and returned in ``a``'s dtype: ``a`` is ``(slots, d)`` in slot order,
+    ``weight`` ``(slots,)`` or None.  One gather of its rows into token
+    order; there the positions of ``SUM_TOKENS`` tokens are one group of
+    neighbouring rows, and a grouped matmul with the rows contracted away
+    — each row against its token's one-hot column times its weight — sums
+    them: no ``(n, k, d)`` tensor, no scatter-add, and ``n`` rows out."""
+    k = st.top_k
+    q2s, token = _token_order(st, plan)
+    n = plan["before"].shape[0] // k
+    column = (token % SUM_TOKENS)[:, None] == jnp.arange(SUM_TOKENS)
+    lhs = jnp.where(
+        column & (token >= 0)[:, None],
+        1 if weight is None else weight[q2s][:, None], 0,
+    ).astype(a.dtype)
+    # held pairs before each block of tokens, and after the last
+    edges = jnp.append(plan["before"][:: k * SUM_TOKENS], plan["rows"])
+    out = grouped_matmul_t(
+        lhs, a[q2s], jnp.diff(edges), impl=st.impl, interpret=st.interpret
+    )
+    return out.reshape(-1, a.shape[1])[:n]
 
 
-def _permute_rows_fwd(a, idx, inv):
-    return a[idx], inv
+def _gmm(st, plan):
+    """``grouped_matmul`` over the plan's groups."""
+    return functools.partial(
+        grouped_matmul, group_sizes=plan["group_sizes"], impl=st.impl,
+        interpret=st.interpret,
+    )
 
 
-def _permute_rows_bwd(inv, g):
-    return g[inv], None, None
+def _pass_fwd(st, xt, weights, w1, w3, w2, plan):
+    """The expert part on the held prefix: dispatch in, the SwiGLU's three
+    grouped matmuls, combine out.  Returns ``(y, residuals)``."""
+    pair, gmm = plan["inv"][: st.prefix], _gmm(st, plan)
+    # slots behind the live ones read some token's row: ``grouped_matmul``
+    # zeroes them on both sides
+    xs = xt[pair // st.top_k]
+    with jax.named_scope("moe_gmm"):
+        h1, h3 = gmm(xs, w1), gmm(xs, w3)
+        ys = gmm(nn.silu(h1) * h3, w2)
+    w_slot = weights.reshape(-1)[pair].astype(xt.dtype)
+    return _token_sums(ys, w_slot, st, plan), (xs, h1, h3, ys)
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+def _pass_bwd(st, res, g, xt, weights, w1, w3, w2, plan):
+    """The mirror image: the cotangent reaches the slots by one gather of
+    their rows from its ``n``, and leaves them for ``xt`` by the same
+    token sums."""
+    xs, h1, h3, ys = res
+    pair, gmm = plan["inv"][: st.prefix], _gmm(st, plan)
+    g_slot = g[pair // st.top_k]
+    w_slot = weights.reshape(-1)[pair].astype(xt.dtype)
+    g_w = jnp.sum(
+        g_slot.astype(jnp.float32) * ys.astype(jnp.float32), axis=1
+    )
+    # zero behind the live slots, as ``ys`` is there
+    g_weights = jnp.zeros(weights.size, weights.dtype).at[pair].set(
+        g_w.astype(weights.dtype),
+        unique_indices=True, mode="promise_in_bounds",
+    ).reshape(weights.shape)
+    g_ys = (
+        g_slot.astype(jnp.float32) * w_slot.astype(jnp.float32)[:, None]
+    ).astype(ys.dtype)
+    with jax.named_scope("moe_gmm"):
+        # the forward products of these two are dead code: each grouped
+        # matmul's VJP reads its operands only
+        _, down = jax.vjp(lambda a, b, w: gmm(nn.silu(a) * b, w), h1, h3, w2)
+        g_h1, g_h3, g_w2 = down(g_ys)
+        _, up = jax.vjp(lambda a, u, v: (gmm(a, u), gmm(a, v)), xs, w1, w3)
+        g_xs, g_w1, g_w3 = up((g_h1, g_h3))
+    return _token_sums(g_xs, None, st, plan), g_weights, g_w1, g_w3, g_w2
+
+
+def _dispatch_plan(local, held):
+    """The index vectors of one dispatch.  ``local`` is ``(pairs,)``: each
+    (token, expert) pair's expert, counted from the first one held here;
+    outside ``[0, held)`` it is held elsewhere."""
+    here = (local >= 0) & (local < held)
+    # bucket ``held`` collects the pairs of experts held elsewhere
+    onehot = jax.nn.one_hot(
+        jnp.where(here, local, held), held + 1, dtype=jnp.int32
+    )
+    counts = jnp.sum(onehot, axis=0)
+    dest, _ = sorted_slots(onehot, counts)
+    inv = jnp.zeros_like(dest).at[dest].set(
+        jnp.arange(local.shape[0], dtype=dest.dtype),
+        unique_indices=True, mode="promise_in_bounds",
+    )
+    return {
+        "local": local, "inv": inv, "group_sizes": counts[:held],
+        "rows": jnp.sum(counts[:held]),
+        # held pairs before each pair, in the order of the pairs: where a
+        # held pair stands once the held ones are compacted
+        "before": jnp.cumsum(here, dtype=jnp.int32) - here,
+    }
+
+
+def _every_expert_on_every_token(xt, weights, w1, w3, w2, local):
+    """The same sum the plain way, for the call in which more pairs arrive
+    than the prefix holds: each held expert on all ``n`` tokens, its output
+    weighted by the pair that selected it (no pair, for most tokens: zero)
+    and accumulated in float32.  No gather, no sorted order, a cost that
+    does not depend on the routing — ``held / k`` times the products the
+    pairs need — and one expert's activations at a time."""
+    local = local.reshape(weights.shape)
+    pair_w = weights.astype(xt.dtype).astype(jnp.float32)
+    dot = lambda a, b: jnp.dot(  # noqa: E731
+        a, b, preferred_element_type=jnp.float32
+    ).astype(a.dtype)
+
+    @jax.checkpoint
+    def add_expert(acc, expert):
+        e, u, v, w = expert
+        out = dot(nn.silu(dot(xt, u)) * dot(xt, v), w)
+        mine = jnp.sum(jnp.where(local == e, pair_w, 0.0), axis=1)
+        return acc + mine[:, None] * out.astype(jnp.float32), None
+
+    acc, _ = jax.lax.scan(
+        add_expert, jnp.zeros(xt.shape, jnp.float32),
+        (jnp.arange(w2.shape[0]), w1, w3, w2),
+    )
+    return acc.astype(xt.dtype)
+
+
+def _fits(st, plan):
+    """Where more pairs arrived than the prefix holds, the prefix is given
+    no rows (it comes out zero) and the fallback does the work; returns
+    ``(the prefix's plan, whether to fall back as a trip count)``."""
+    if st.prefix == plan["local"].shape[0]:  # the prefix is the buffer
+        return plan, None
+    fits = plan["rows"] <= st.prefix
+    plan = {
+        **plan, "rows": jnp.where(fits, plan["rows"], 0),
+        "group_sizes": jnp.where(fits, plan["group_sizes"], 0),
+    }
+    return plan, jnp.where(fits, 0, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(st, xt, weights, w1, w3, w2, plan):
+    """``y[t] = sum over token t's pairs held here of weight * expert(xt[t])``
+    on the held prefix of the expert-sorted slots, and by
+    ``_every_expert_on_every_token`` where more pairs arrive than it holds:
+    nothing is dropped, and no tensor has more rows than the prefix.  The
+    VJP is its own because the fallback is a loop that runs once or, in the
+    common case, not at all — a ``lax.cond`` here cost the train program
+    2.5 GB of temporaries (PERF.md, Findings, PR 28) — and so that the
+    prefix saves prefix-sized residuals while the fallback saves nothing
+    and runs its forward again inside its backward: the rare path pays."""
+    return _experts_fwd(st, xt, weights, w1, w3, w2, plan)[0]
+
+
+def _experts_fwd(st, xt, weights, w1, w3, w2, plan):
+    plan, falls_back = _fits(st, plan)
+    y, res = _pass_fwd(st, xt, weights, w1, w3, w2, plan)
+    if falls_back is not None:
+        y = jax.lax.fori_loop(
+            0, falls_back,
+            lambda _, y: _every_expert_on_every_token(
+                xt, weights, w1, w3, w2, plan["local"]
+            ),
+            y,
+        )
+    return y, (res, xt, weights, w1, w3, w2, plan, falls_back)
+
+
+def _experts_bwd(st, saved, g):
+    res, xt, weights, w1, w3, w2, plan, falls_back = saved
+    grads = _pass_bwd(st, res, g, xt, weights, w1, w3, w2, plan)
+    if falls_back is not None:
+        plain = functools.partial(
+            _every_expert_on_every_token, local=plan["local"]
+        )
+        grads = jax.lax.fori_loop(
+            0, falls_back,
+            lambda _, grads: jax.vjp(plain, xt, weights, w1, w3, w2)[1](g),
+            grads,
+        )
+    return (*grads, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def route_topk(x, router_kernel, bias, k: int, scale: float = 1.0,
@@ -372,13 +582,6 @@ def route_topk(x, router_kernel, bias, k: int, scale: float = 1.0,
     return sel, w * scale
 
 
-# The selection bias is drawn once and moved by no rule.  At 0.002 it
-# changes the top-4 of about one token in fifteen; at 0.02 it decided which
-# experts are popular, and the rows this chip's experts receive varied by a
-# tenth from seed to seed (PERF.md, Findings, PR 27).
-EXPERT_BIAS_STD = 0.002
-
-
 class TopKMoE(nn.Module):
     """Top-k sigmoid-routed SwiGLU experts, no capacity and no dropped
     pair, for a layer that is told which experts it holds.
@@ -393,12 +596,27 @@ class TopKMoE(nn.Module):
     ``tests/test_lfm2.py``).
 
     Dispatch: the ``n * k`` (token, expert) pairs are sorted by expert with
-    ``sorted_slots`` (pairs of experts held elsewhere sort behind the held
-    ones), rows are gathered into that order, three grouped matmuls
-    (``ops/moe_gmm.py grouped_matmul``) run over the held groups — their
-    work follows the rows that arrived — and the pairs' outputs are
-    gathered back and combined.  The buffer is ``n * k`` rows, the most
-    that can arrive, so nothing is ever dropped.
+    ``sorted_slots``, the pairs of experts held elsewhere behind the held
+    ones, so the slots any expert reads are ``[0, rows)``.  Everything that
+    moves rows works on a static *held prefix* of ``C = SLACK * n * k * held
+    / num_experts`` slots (``held_prefix_rows``: twice what even routing
+    sends here; ``n * k`` itself where every expert is held), not on the
+    ``n * k`` that could arrive: one gather brings the prefix's token rows
+    in, three grouped matmuls (``ops/moe_gmm.py grouped_matmul``) run over
+    the held groups — their work follows the rows that arrived — and the
+    outputs are gathered into the order of their pairs ``(token, j)``, where
+    a token's are neighbours, and summed per token by a grouped matmul with
+    the rows contracted away (``grouped_matmul_t``): each row against its
+    token's one-hot column times its weight, float32 accumulation, no ``(n,
+    k, d)`` tensor.  The backward is the mirror image, by hand (``_experts``).
+
+    Nothing is ever dropped: a call in which more than ``C`` pairs arrive
+    gives the prefix no rows and takes the same sum the plain way, every
+    held expert on every token, masked (``_every_expert_on_every_token``:
+    no gather, a cost that does not depend on the routing) — inside a loop
+    that runs once or, in the common case, not at all
+    (``moe_metrics/full_buffer`` says which a call took).  The size is read
+    from ``held / num_experts``, which the layer is told; no flag decides it.
 
     ``expert_bias`` is a float32 buffer in the ``batch_stats`` collection,
     not a parameter: it enters the selection only, no rule updates it (the
@@ -419,8 +637,6 @@ class TopKMoE(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        from ..ops.moe_gmm import grouped_matmul, resolve_gmm_impl
-
         b, s, d = x.shape
         n, k = b * s, self.top_k
         held = self.num_experts_held or self.num_experts
@@ -443,46 +659,26 @@ class TopKMoE(nn.Module):
         sel, weights = route_topk(
             xt, router, bias, k, self.scale, self.renormalise
         )
-        local = sel.reshape(n * k) - self.first_expert
-        here = (local >= 0) & (local < held)
-        # bucket ``held`` collects the pairs of experts held elsewhere
-        onehot = jax.nn.one_hot(
-            jnp.where(here, local, held), held + 1, dtype=jnp.int32
-        )
-        counts = jnp.sum(onehot, axis=0)
-        dest, _ = sorted_slots(onehot, counts)
-        inv = jnp.zeros_like(dest).at[dest].set(
-            jnp.arange(n * k, dtype=dest.dtype),
-            unique_indices=True, mode="promise_in_bounds",
-        )
-        group_sizes = counts[:held]
-        rows = jnp.sum(group_sizes).astype(jnp.float32)
+        plan = _dispatch_plan(sel.reshape(n * k) - self.first_expert, held)
+        rows = plan["rows"].astype(jnp.float32)
+        prefix = held_prefix_rows(n * k, held, self.num_experts)
         self.sow("moe_metrics", "rows", rows)
         self.sow(
             "moe_metrics", "load_max_over_mean",
-            jnp.max(group_sizes).astype(jnp.float32)
+            jnp.max(plan["group_sizes"]).astype(jnp.float32)
             / jnp.maximum(rows / held, 1.0),
+        )
+        self.sow(
+            "moe_metrics", "full_buffer", (rows > prefix).astype(jnp.float32)
         )
 
         impl = resolve_gmm_impl(self.gmm)
         interpret = impl == "megablox" and jax.default_backend() != "tpu"
         note_kernel_path("moe_gmm", impl + "-interpret" * interpret)
-        xs = _dispatch_rows(xt.astype(self.dtype), dest, inv)
         with jax.named_scope("moe_gmm"):
-            gmm = lambda a, w: grouped_matmul(  # noqa: E731
-                a, w.astype(self.dtype), group_sizes,
-                impl=impl, interpret=interpret,
-            )
-            ys = gmm(nn.silu(gmm(xs, w1)) * gmm(xs, w3), w2)
-        here = here.reshape(n, k)
-        # a pair held elsewhere reads a row behind the groups: zero by
-        # ``grouped_matmul``'s contract, and selected away all the same
-        pair_out = jnp.where(
-            here[..., None], _permute_rows(ys, dest, inv).reshape(n, k, d), 0
+            w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
+        y = _experts(
+            _Experts(prefix, k, impl, interpret),
+            xt.astype(self.dtype), weights, w1, w3, w2, plan,
         )
-        pair_w = jnp.where(here, weights, 0.0)
-        y = jnp.einsum(
-            "nk,nkd->nd", pair_w.astype(self.dtype), pair_out,
-            preferred_element_type=jnp.float32,
-        )
-        return y.reshape(b, s, d).astype(self.dtype)
+        return y.reshape(b, s, d)

@@ -418,3 +418,38 @@ def grouped_matmul(
     else:
         raise ValueError(f"unknown grouped matmul {impl!r}; one of {GMM_IMPLS}")
     return jnp.where(valid, out, 0)
+
+
+def grouped_matmul_t(
+    lhs: jnp.ndarray,
+    rhs: jnp.ndarray,
+    group_sizes: jnp.ndarray,
+    *,
+    impl: str = "ragged_dot",
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``out[g] = lhs[rows of g].T @ rhs[rows of g]``: the grouped matmul
+    with the rows contracted away (the form a grouped matmul's weight
+    gradient has).  ``lhs`` is ``(m, k)``, ``rhs`` ``(m, n)``, both with
+    their rows sorted by group; group ``g`` owns the ``group_sizes[g]`` rows
+    after those of the groups before it, rows past ``sum(group_sizes)`` are
+    not read, and a group without rows comes out zero.  Products accumulate
+    in float32.  Returns ``(G, k, n)`` in ``rhs``'s dtype; not
+    differentiable (its callers have VJPs of their own)."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if impl == "ragged_dot":
+        contract_rows = jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(([0], [0]), ([], [])),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+        )
+        return jax.lax.ragged_dot_general(
+            lhs, rhs, group_sizes, contract_rows,
+            preferred_element_type=jnp.float32,
+        ).astype(rhs.dtype)
+    if impl != "megablox":
+        raise ValueError(f"unknown grouped matmul {impl!r}; one of {GMM_IMPLS}")
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    m, k = lhs.shape
+    tiling = (_tile(m, 512), _tile(k, 1024), _tile(rhs.shape[1], 1024))
+    return tgmm(lhs.T, rhs, group_sizes, rhs.dtype, tiling, interpret=interpret)

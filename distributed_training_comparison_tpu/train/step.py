@@ -168,7 +168,7 @@ def _moe_health(coll) -> Metrics:
     router collapses onto one expert).  Empty for dense models."""
     from jax.tree_util import tree_flatten_with_path
 
-    dropped, load_max, rows, imbalance = [], [], [], []
+    dropped, load_max, rows, imbalance, full_buffer = [], [], [], [], []
     for path, leaf in tree_flatten_with_path(coll)[0]:
         keys = {getattr(p, "key", getattr(p, "name", "")) for p in path}
         if "dropped_frac" in keys:
@@ -180,10 +180,13 @@ def _moe_health(coll) -> Metrics:
             rows.append(jnp.sum(leaf))
         elif "load_max_over_mean" in keys:
             imbalance.append(jnp.mean(leaf))
+        elif "full_buffer" in keys:  # 1.0 where the held prefix overflowed
+            full_buffer.append(jnp.mean(leaf))
     out: Metrics = {}
     if rows:  # summed over the layers; the fullest expert's, their mean
         out["moe_rows"] = jnp.sum(jnp.stack(rows))
         out["moe_load_max_over_mean"] = jnp.mean(jnp.stack(imbalance))
+        out["moe_full_buffer_share"] = jnp.mean(jnp.stack(full_buffer))
     if dropped:
         out["moe_dropped_frac"] = jnp.mean(jnp.stack(dropped))
     if load_max:

@@ -3201,12 +3201,16 @@ class Trainer:
         if "moe_rows" in fetched[0]:
             # top-k expert layers (models/moe.py TopKMoE): pairs routed to
             # the experts held here, summed over the epoch's steps and
-            # layers, and how far the fullest expert is above the mean
+            # layers, how far the fullest expert is above the mean, and the
+            # share of layer calls whose rows overflowed the held prefix
             self.metrics.counter("moe/rows").inc(
                 int(sum(np.asarray(m["moe_rows"]).sum() for m in fetched))
             )
             self.metrics.gauge("moe/load_max_over_mean").set(
                 self._moe_health["moe_load_max_over_mean"]
+            )
+            self.metrics.gauge("moe/full_buffer_share").set(
+                self._moe_health["moe_full_buffer_share"]
             )
         # the per-step signals land in the metric sketches here — one
         # vectorized pass over the stacked arrays, no per-step Python loop;

@@ -437,7 +437,7 @@ def test_auto_gmm_gate_respects_vmem_budget():
 # ------------------------------------------------ top-k, no drops (TopKMoE)
 
 from distributed_training_comparison_tpu.models.moe import (  # noqa: E402
-    TopKMoE, route_topk,
+    TopKMoE, held_prefix_rows, route_topk,
 )
 from distributed_training_comparison_tpu.ops.moe_gmm import (  # noqa: E402
     grouped_matmul,
@@ -512,23 +512,139 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(want, _ref_moe(variables, x), rtol=2e-4, atol=2e-6)
 
 
+# Biases that decide which of the two sizes a share holding experts 0-3 of
+# the 16 takes at 80 tokens (320 pairs, a held prefix of 256): expert 1 in
+# every selection and the other three selected held elsewhere (80 rows, the
+# prefix), or all four selected among the four held (320 rows, the buffer).
+ONE_HELD = jnp.zeros((16,)).at[1].set(10.0).at[jnp.array([9, 10, 11])].set(5.0)
+ALL_HELD = jnp.zeros((16,)).at[jnp.arange(4)].set(10.0)
+SIZES = {"prefix": (ONE_HELD, 80.0, 0.0), "fallback": (ALL_HELD, 320.0, 1.0)}
+
+
+def _held_share(x, bias):
+    """A share holding experts 0-3 with its selection bias replaced."""
+    share = TopKMoE(**TOPK, num_experts_held=4, first_expert=0)
+    _, variables = _topk_layer(x)
+    return share, _share(
+        {**variables, "batch_stats": {"expert_bias": bias}}, 0, 4
+    )
+
+
 def test_no_pair_is_dropped_when_every_token_goes_to_one_held_expert():
     """Expert 1 (held) is in every token's selection and the other three
     selected are held elsewhere: one group of ``n`` rows, sixteen times the
-    mean load, every one computed."""
+    mean load, every one computed — and inside the held prefix."""
     x = jax.random.normal(jax.random.key(5), (2, 40, 32))
-    share = TopKMoE(**TOPK, num_experts_held=4, first_expert=0)
-    _, variables = _topk_layer(x)
-    bias = jnp.zeros((16,)).at[1].set(10.0).at[jnp.array([9, 10, 11])].set(5.0)
-    variables = _share(
-        {**variables, "batch_stats": {"expert_bias": bias}}, 0, 4
-    )
+    assert held_prefix_rows(320, 4, 16) == 256
+    share, variables = _held_share(x, ONE_HELD)
     got, sown = share.apply(variables, x, mutable=["moe_metrics"])
     assert float(sown["moe_metrics"]["rows"][0]) == 80.0
     assert float(sown["moe_metrics"]["load_max_over_mean"][0]) == 4.0
+    assert float(sown["moe_metrics"]["full_buffer"][0]) == 0.0
     want = _ref_moe(variables, x)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-6)
     assert float(jnp.abs(want).min(axis=-1).max()) > 0  # nothing zeroed out
+
+
+def test_no_pair_is_dropped_when_more_arrive_than_the_held_prefix_takes():
+    """All four selected experts of every token are among the four held:
+    ``n * k`` = 320 rows against a prefix of 256, so the layer takes the
+    whole buffer, says so, and computes every pair."""
+    x = jax.random.normal(jax.random.key(5), (2, 40, 32))
+    share, variables = _held_share(x, ALL_HELD)
+    got, sown = share.apply(variables, x, mutable=["moe_metrics"])
+    assert float(sown["moe_metrics"]["rows"][0]) == 320.0
+    assert float(sown["moe_metrics"]["full_buffer"][0]) == 1.0
+    np.testing.assert_allclose(
+        got, _ref_moe(variables, x), rtol=2e-4, atol=2e-6
+    )
+
+
+@pytest.mark.parametrize("leaf", ["x", "router", "w1", "w2", "w3"])
+@pytest.mark.parametrize("size", SIZES)
+def test_topk_gradients_match_autodiff_of_the_reference(size, leaf):
+    """The expert part's own VJP, on the held prefix and on the whole
+    buffer, against autodiff of the plain reference."""
+    bias, rows, full = SIZES[size]
+    x = jax.random.normal(jax.random.key(11), (2, 40, 32))
+    share, variables = _held_share(x, bias)
+    cot = jax.random.normal(jax.random.key(12), x.shape)
+    _, sown = share.apply(variables, x, mutable=["moe_metrics"])
+    assert float(sown["moe_metrics"]["rows"][0]) == rows
+    assert float(sown["moe_metrics"]["full_buffer"][0]) == full
+
+    def loss(fn):
+        def of(params, x):
+            return (fn({**variables, "params": params}, x) * cot).sum()
+        return jax.grad(of, (0, 1))(variables["params"], x)
+
+    got_p, got_x = loss(lambda v, x: share.apply(v, x))
+    want_p, want_x = loss(_ref_moe)
+    got, want = {**got_p, "x": got_x}[leaf], {**want_p, "x": want_x}[leaf]
+    assert float(jnp.abs(want).max()) > 0
+    np.testing.assert_allclose(
+        got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max())
+    )
+
+
+@pytest.mark.parametrize("size", ["prefix", "fallback"])
+def test_tokens_with_no_one_and_k_held_pairs_in_one_batch(size):
+    """The neighbour sum's three edges: tokens whose selection holds none,
+    one and all ``k`` of the experts held here, interleaved in one batch —
+    values and the gradient with respect to ``x``.  120 tokens are 480
+    pairs against a held prefix of 256: 200 rows arrive (prefix) or, with
+    the one-pair tokens sent to all four held experts as well, 320
+    (fallback)."""
+    n = 120
+    kinds = np.arange(n) % 3  # 0: none held, 1: one, 2: all four
+    if size == "fallback":
+        kinds = np.where(kinds == 1, 2, kinds)
+    router = jnp.zeros((32, 16))
+    # the first three coordinates of a token decide its selection
+    router = router.at[0, 8:12].set(20.0)
+    router = router.at[1, 1].set(20.0).at[1, 9:12].set(10.0)
+    router = router.at[2, 0:4].set(20.0)
+    x = 0.1 * jax.random.normal(jax.random.key(13), (1, n, 32))
+    x = x.at[0, :, :3].set(jax.nn.one_hot(kinds, 3))
+    share, variables = _held_share(x, jnp.zeros((16,)))
+    variables = {**variables, "params": {**variables["params"], "router": router}}
+    got, sown = share.apply(variables, x, mutable=["moe_metrics"])
+    rows = float((kinds == 1).sum() + 4 * (kinds == 2).sum())
+    assert float(sown["moe_metrics"]["rows"][0]) == rows
+    assert float(sown["moe_metrics"]["full_buffer"][0]) == (size == "fallback")
+    want = _ref_moe(variables, x)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-6)
+    assert float(jnp.abs(got[0, kinds == 0]).max()) == 0.0
+    assert float(jnp.abs(got[0, kinds == 2]).min(axis=-1).max()) > 0
+    g = lambda fn: jax.grad(lambda x: fn(variables, x).sum())(x)  # noqa: E731
+    np.testing.assert_allclose(
+        g(lambda v, x: share.apply(v, x)), g(_ref_moe), rtol=2e-3, atol=2e-6
+    )
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_a_layer_that_holds_every_expert_builds_no_loop():
+    """``held == num_experts``: the held prefix is the buffer, so neither
+    the forward nor the backward holds a loop or a branch; a share has the
+    fallback's loop in each."""
+    x = jax.random.normal(jax.random.key(14), (2, 24, 32))
+    whole, variables = _topk_layer(x)
+    grad = lambda layer: jax.grad(  # noqa: E731
+        lambda v, x: layer.apply(v, x).sum(), (0, 1)
+    )
+    flow = lambda jaxpr: [  # noqa: E731
+        p for p in _primitives(jaxpr.jaxpr) if p in ("while", "cond")
+    ]
+    assert flow(jax.make_jaxpr(grad(whole))(variables, x)) == []
+    share = TopKMoE(**TOPK, num_experts_held=4, first_expert=0)
+    held = jax.make_jaxpr(grad(share))(_share(variables, 0, 4), x)
+    assert flow(held) == ["while", "while"]
 
 
 def test_expert_bias_gets_no_gradient_and_is_no_parameter():

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from distributed_training_comparison_tpu.models.moe import TopKMoE
 from distributed_training_comparison_tpu.ops import attention, flash_attention
 from distributed_training_comparison_tpu.ops.attention_small import small_mha
 from distributed_training_comparison_tpu.ops.moe_gmm import (
@@ -155,6 +156,40 @@ def test_streamed_grouped_matmul_compiles_for_v5e(chip):
     )
     # 2 forward (the last one feeds only the sum, which grad drops), 3 dx, 3 dW
     assert text.count("tpu_custom_call") >= 8
+
+
+def test_topk_moe_moves_no_worst_case_buffer_for_v5e(chip, monkeypatch):
+    """The token cell's expert layer as its chip sees it — 16,384 tokens of
+    2,048, experts of 1,536, 8 of 64 held, top-4, bf16, megablox — compiles
+    under ``jax.grad``, and no tensor in the program has the ``n * k`` =
+    65,536 rows of the worst-case dispatch buffer: the common path works on
+    the 16,384-row held prefix and the fallback on the 16,384 tokens.
+    ``auto`` asks ``jax.default_backend()``, which sees the CPU here: the
+    test answers for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = TopKMoE(2048, 1536, 64, 4, 8, 0, dtype=BF16)
+    x = _s(4, 4096, 2048)
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros(x.shape, x.dtype))
+    )
+
+    def loss(params, stats, x):
+        y = layer.apply({"params": params, "batch_stats": stats}, x)
+        return y.astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.grad(loss, (0, 2)), chip,
+        variables["params"], variables["batch_stats"], x,
+    )
+    rows = {
+        int(r) for r in re.findall(r"\b(?:bf16|f32)\[(\d+),(?:2048|1536)\]", text)
+    }
+    assert 16384 in rows and max(rows) == 16384, sorted(rows)
+    # 3 products forward (the forward's token sum feeds only the loss's sum,
+    # which grad drops, and its fallback with it); 3 dx, 3 dW and the token
+    # sum for dx backward.  The fallback is a loop and holds no kernel
+    assert text.count("tpu_custom_call") == 10
+    assert " while(" in text and " conditional(" not in text
 
 
 def test_small_mha_compiles_for_v5e(chip):
