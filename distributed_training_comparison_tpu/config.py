@@ -265,7 +265,7 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         "view, deleting the per-step relayout from the hot path "
         "(checkpoints stay canonical/contiguous on disk either way). "
         "--no-pipeline-resident-layout keeps the legacy per-step "
-        "relayout — the bench baseline (bench.py --relayout)",
+        "relayout (same trajectory: tests/test_layouts.py)",
     )
     parser.add_argument(
         "--precision",
@@ -749,7 +749,7 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         type=int,
         default=0,
         help="Devices per fleet host, used to pick the widest legal world "
-        "size AND (CPU emulation: tests/bench) forced into each child via "
+        "size AND (CPU emulation: tests) forced into each child via "
         "XLA_FLAGS. 0 = inherit the environment (real TPU hosts)",
     )
     parser.add_argument(
@@ -1081,8 +1081,8 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         "boundary — the same poll site as mid-epoch preemption, so "
         "time-to-mitigation is bounded by one chunk, not one epoch; "
         "'epoch' keeps the legacy policy-*.req channel applied at the "
-        "next epoch boundary (the PR-12 behavior, kept as the bench "
-        "baseline). Every application emits a 'control' event carrying "
+        "next epoch boundary (the PR-12 behavior; tests/test_control.py "
+        "runs both). Every application emits a 'control' event carrying "
         "decide->apply latency; see run_report --policy",
     )
     parser.add_argument(

@@ -14,8 +14,8 @@ Detection is layered by cost of the response:
 Rollbacks are budgeted (``max_rollbacks``): a fault that deterministically
 re-fires on replay (diverged hyperparameters, a persistently corrupt shard)
 must abort loudly, not loop.  Every event is appended to the run dir's
-``health.jsonl`` and aggregated into the summary that ``HEALTH.json`` /
-``bench.py --health`` / the goodput records carry.
+``health.jsonl`` and aggregated into the summary that ``--health-json``'s
+report and the goodput records carry.
 """
 
 from __future__ import annotations
@@ -277,8 +277,8 @@ class Watchdog:
 
 
 def write_health(path: str | Path, summary: dict) -> Path:
-    """Write a HEALTH.json report (trainer ``--health-json`` / bench leg).
-    Same report-file shape as GOODPUT.json, so it shares the writer."""
+    """Write the trainer's ``--health-json`` report.  Same report-file
+    shape as the supervisor's ``--goodput-json``, so it shares the writer."""
     from ..resilience.goodput import write_goodput
 
     return write_goodput(path, summary)

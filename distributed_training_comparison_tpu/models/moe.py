@@ -35,18 +35,18 @@ TPU-native design:
 - **Router in fp32** (standard practice — routing decisions are
   precision-sensitive; bf16 logits flip argmaxes), experts in the model's
   compute dtype.
-- **Cost model, measured honestly** (committed bench legs
-  ``vit_moe_bf16_bs256`` (auto → gmm) / ``vit_moe_gather_bf16_bs256`` /
-  ``vit_moe_onehot_bf16_bs256`` / ``vit_moe_dense_twin_bf16_bs256``,
-  ``bench.py``): three dispatch implementations with bit-equal routing.
-  The GShard-style one-hot matmuls are O(n·E·cap·d) and dominate at
-  CIFAR dims (v5e, depth-8/dim-192, bs256: 6.5k img/s vs the 35.2k
-  dense twin); the sort/gather dispatch moves O(n·d) data instead and
-  reaches 9.8k img/s; the fused Pallas grouped matmul removes the
-  capacity-buffer traffic on top and reaches 15.3k (+56%; committed
-  bench legs carry the round's exact numbers).  The remaining gap to
-  dense is the token permutation in and out of sorted order (~40
-  cycles/row in XLA's row gather at d=192) — amortizing at LLM-scale d.
+- **Cost model**: three dispatch implementations with bit-equal routing
+  (``tests/test_moe.py``), chosen by ``--moe-dispatch`` through
+  ``resolve_dispatch``.  The GShard-style one-hot matmuls (``onehot``,
+  the tests' reference) are O(n·E·cap·d) and dominate at CIFAR dims; the
+  sort/gather dispatch (``gather``, the one that shards under expert
+  parallelism) moves O(n·d) data instead; the fused Pallas grouped
+  matmul (``gmm``, ``auto`` on a TPU) removes the capacity-buffer
+  traffic on top.  What is left against a dense twin is the token
+  permutation in and out of sorted order.  No benchmark cell runs
+  ``SwitchFFN`` (``vit_moe`` is not a published architecture), so none
+  of this carries a chip number; ``TopKMoE`` below has the cell
+  ``lfm2_ep8_seq4k_job`` (``PERF.md`` §5).
 - The Switch **load-balance auxiliary loss** ``E · Σ_e f_e·P_e`` is sown
   into a ``"losses"`` flax collection; the train step sums the collection
   into the objective (``train/step.py``).  ``sow`` is a no-op when the
@@ -75,7 +75,7 @@ def resolve_dispatch(dispatch: str = "auto", *, expert_parallel: bool = False) -
     mesh axis) rules the Pallas grouped-matmul kernel out: GSPMD cannot
     partition a ``pallas_call``, so only the XLA ``"gather"`` formulation
     shards.  This used to be Trainer-private knowledge — every other
-    caller (bench harnesses, ``__graft_entry__.py``, the serve engine)
+    caller (``__graft_entry__.py``, the serve engine)
     had to hand-pin ``'gather'`` or hand GSPMD an unpartitionable kernel
     (ADVICE r5 #1).  ``models.get_model(..., expert_parallel=True)``
     routes through here, so the fallback now lives next to the dispatch

@@ -87,7 +87,8 @@ KNOWN_KINDS = {
     # rule, triggering alert, action, cooldown/budget state, dry-run flag
     # — whether the action ran, deferred, or was suppressed
     "policy",
-    # chaos gauntlet (resilience/faults scenario catalog + bench --chaos):
+    # chaos gauntlet (resilience/faults scenario catalog, run by
+    # tools/chaos_matrix.py):
     # one event per named scenario with its outcome counts
     "chaos",
     # auto-parallel planner (parallel/planner): one event per planning

@@ -84,8 +84,9 @@ PEAK_FLOPS_BY_DEVICE_KIND = {
 
 
 def peak_flops_for(device_kind: str | None) -> float | None:
-    """Peak per-chip FLOP/s for a ``device_kind`` string (prefix match,
-    like bench.py's table), or None when the kind is unknown."""
+    """Peak per-chip FLOP/s for a ``device_kind`` string (prefix match),
+    or None when the kind is unknown (``benchmark/harness/peaks.json``,
+    the benchmark's own table, makes an unknown kind an error)."""
     if not device_kind:
         return None
     for prefix, peak in PEAK_FLOPS_BY_DEVICE_KIND.items():

@@ -2,12 +2,12 @@
 
 Beyond parity (the reference has no MoE at all; ``models/moe.py`` situates
 the layer against SURVEY.md §2.2).  This kernel is the TPU answer to the
-dispatch cost the committed bench measured for the XLA formulations: at
-CIFAR dims (n=16384 tokens, d=192, E=8) the sort/gather dispatch spends
-**58% of device time in gather/scatter fusions** and only 15% in the
-expert matmuls themselves (``tools/op_profile.py`` on ``vit_moe_bf16_bs256``
-— the capacity-buffer scatter ``(E·cap, d)``, the gather back, and the
-``(E, cap, hidden)`` activation round-trips through HBM).
+dispatch cost of the XLA formulations: at CIFAR dims (n=16384 tokens,
+d=192, E=8) a chip profile older than this round's benchmark showed the
+sort/gather dispatch spending most of its device time in gather/scatter
+fusions and a small part in the expert matmuls themselves — the
+capacity-buffer scatter ``(E·cap, d)``, the gather back, and the
+``(E, cap, hidden)`` activation round-trips through HBM.
 
 The megablocks-style fix (Gale et al., MegaBlocks; the jax ``gmm`` kernels
 in maxtext follow the same shape): keep tokens in *sorted order* and run a

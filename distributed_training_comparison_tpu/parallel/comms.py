@@ -41,7 +41,7 @@ legs the ZeRO decomposition introduces.  A formulation that compresses the
 below provides that primitive (a ``shard_map`` all-reduce whose wire dtype
 really is fp16/int8, with int8 accumulating in int32 under a shared
 ``pmax`` scale) for runners that do (the ``fwd_bwd`` hook, pipeline
-schedules), and the bench leg prices both against the compile ledger.
+schedules); ``tests/test_comms.py`` prices both against the compile ledger.
 """
 
 from __future__ import annotations
@@ -151,7 +151,7 @@ def zero_opt_shardings(mesh: Mesh, opt_state, base_shardings=None):
 def opt_state_bytes(opt_state, shardings=None) -> tuple[int, int]:
     """``(total_bytes, per_device_bytes)`` of an optimizer-state pytree —
     the host-side arithmetic behind the ``comms/opt_state_bytes*`` gauges
-    and the bench leg's expected-savings column.  ``shardings`` must be a
+    and their expected saving under ``--shard-optim``.  ``shardings`` must be a
     matching tree of ``NamedSharding``s (the mesh on each one supplies
     the axis sizes the division needs — a bare ``PartitionSpec`` carries
     no mesh and would silently count as replicated); ``None`` =

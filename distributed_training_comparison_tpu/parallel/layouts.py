@@ -227,7 +227,7 @@ def layout_for(
     Chunked only for the interleaved schedule with real virtual stages
     (``v > 1``) on a real pipe axis; everything else — single device,
     GPipe, plain 1F1B, and ``resident=False`` (the legacy per-step
-    relayout, kept as the bench baseline) — carries the contiguous
+    relayout, ``--no-pipeline-resident-layout``) — carries the contiguous
     stack.
     """
     if (

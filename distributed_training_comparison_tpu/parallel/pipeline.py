@@ -42,7 +42,7 @@ Schedules:
   virtual stages — per-tick work shrinks ``v×`` while the warmup/cooldown
   tick count grows sub-``v×``, so the bubble fraction at fixed M drops
   from ``(2P-2)/(M+2P-2)`` toward ``((v+1)P-2)/(vM+(v+1)P-2)`` (the
-  schedule arithmetic ``schedule_meta`` records and the bench measures).
+  schedule arithmetic ``schedule_meta`` records).
   The stash stays O(P·v) microbatch *inputs* of chunks ``1/v`` the size —
   the same O(P) activation memory as plain 1F1B.
 
@@ -50,9 +50,7 @@ SPMD shape: every stage runs the same unrolled program; per-stage behavior
 (which unit, valid or garbage) is selected by traced ``axis_index``
 arithmetic.  The one genuinely per-device branch is the loss head: only
 the LAST stage ever needs it, and it runs under ``lax.cond`` so non-last
-stages skip the compute entirely (it used to run — and be discarded — on
-every stage every forward tick; the flops delta shows in the
-compile-event ledger / BENCH_PIPELINE.json).
+stages skip the compute entirely.
 """
 
 from __future__ import annotations
@@ -92,7 +90,7 @@ def schedule_meta(
 ) -> dict:
     """The static tick arithmetic of a schedule — one source of truth for
     the bubble fraction the obs plane reports (per-stage span lanes,
-    ``run_report``'s bubble table, BENCH_PIPELINE.json).
+    ``run_report``'s bubble table; pinned by ``tests/test_pipeline.py``).
 
     ``useful_ticks`` counts ticks where a device performs valid unit work;
     every other tick is warmup/cooldown — computed (and on real silicon,
@@ -479,8 +477,8 @@ def vit_stage_fn(
 #
 # Total ticks T = M·v + N + P - 2 (v = 1 recovers M + 2P - 2); per-tick
 # chunk work is 1/v of the plain-1F1B slab, so the bubble *time* shrinks
-# ~v× at fixed M — the step-time win schedule_meta quantifies and
-# BENCH_PIPELINE.json measures.
+# ~v× at fixed M — the step-time win schedule_meta quantifies (no chip
+# measurement of it exists: there is no pipeline cell, ROADMAP.md D3).
 
 
 def _interleaved_1f1b(
@@ -496,7 +494,6 @@ def _interleaved_1f1b(
     data_axis: str | None,
     virtual: int,
     grad_comms: str = "fp32",
-    head_all_stages: bool = False,
 ):
     """The interleaved-1F1B schedule body; call inside ``shard_map``.
 
@@ -624,22 +621,9 @@ def _interleaved_1f1b(
             head_pred = jnp.logical_and(
                 valid_f, jnp.logical_and(is_last, i_f == v - 1)
             )
-            if head_all_stages:
-                # the pre-fix formulation, kept ONLY as the pricing
-                # baseline for the compile-ledger flops delta (bench.py
-                # --pipeline); masked, so numerics are identical
-                mb_loss, dh, head_dy, mb_logits = run_head(y, lbl_i)
-                keep = lambda z: jnp.where(  # noqa: E731
-                    head_pred, z, jnp.zeros_like(z)
-                )
-                mb_loss = keep(mb_loss)
-                dh = jax.tree_util.tree_map(keep, dh)
-                head_dy = keep(head_dy)
-                mb_logits = keep(mb_logits)
-            else:
-                mb_loss, dh, head_dy, mb_logits = jax.lax.cond(
-                    head_pred, run_head, zero_head, y, lbl_i
-                )
+            mb_loss, dh, head_dy, mb_logits = jax.lax.cond(
+                head_pred, run_head, zero_head, y, lbl_i
+            )
             loss = loss + mb_loss
             g_head = jax.tree_util.tree_map(jnp.add, g_head, dh)
             prev = jax.lax.dynamic_index_in_dim(
@@ -837,7 +821,6 @@ def make_interleaved_fwd_bwd(
     data_axis: str | None = DATA_AXIS,
     tp_axis: str | None = None,
     grad_comms: str = "fp32",
-    head_all_stages: bool = False,
     state_layout=None,
 ):
     """Build the (interleaved-)1F1B forward+backward for a zoo ViT.
@@ -973,7 +956,7 @@ def make_interleaved_fwd_bwd(
             out = _interleaved_1f1b(
                 stage, scaled_head_loss, chunk_params, hp, mbx, lbx, res,
                 axis_name=pipe_axis, data_axis=data_axis, virtual=v,
-                grad_comms=grad_comms, head_all_stages=head_all_stages,
+                grad_comms=grad_comms,
             )
             loss_v, g_chunks, g_head, dtok, logits, new_res = out
             if res is not None:

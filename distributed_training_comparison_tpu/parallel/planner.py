@@ -50,9 +50,10 @@ Predictions are planning numbers, not measurements: on captures with no
 usable ledger the absolute seconds come from documented per-device-kind
 planning constants, and the CPU CI container (host==device) can never
 show a wire saving.  What binds is (a) the *relative* ranking under one
-fit and (b) the committed prediction-vs-measured table
-(``BENCH_PLAN.json``, ``run_report --plan``) that makes any
-mis-prediction inspectable.
+fit and (b) the prediction-vs-measured table ``run_report --plan``
+renders from a run's own ``plan`` and ``compile`` events, which makes any
+mis-prediction inspectable (``tests/test_planner.py`` pins the ranking
+on synthetic ledgers; no chip run has fit the model yet — ROADMAP.md D6).
 """
 
 from __future__ import annotations
@@ -109,8 +110,8 @@ PLAN_EVENT_CANDIDATES = 12
 # the runtime carries the trunk stack RESIDENT in the schedule's native
 # layout (parallel/layouts.py), so interleaved v>1 candidates pay no
 # per-step chunk relayout — predict() prices the term and zeroes it when
-# this is active.  --no-pipeline-resident-layout (the bench baseline)
-# flips it back per run; plan_layout reads the hparams flag.
+# this is active.  --no-pipeline-resident-layout (the legacy per-step
+# relayout) flips it back per run; plan_layout reads the hparams flag.
 SCHEDULE_NATIVE_STATE_LAYOUT = True
 
 
@@ -193,7 +194,8 @@ def _vit_spec(name, depth, dim, heads, *, mlp_ratio=4, patch=4,
               num_experts=0, image_size=32) -> ModelSpec:
     tokens = (image_size // patch) ** 2
     # dense layers dominate: per block 12·d² MACs/token + attention's
-    # 2·S·d; patch embed + head (mirrors bench.py's analytic estimator)
+    # 2·S·d; patch embed + head (tests/test_planner.py holds it to
+    # benchmark/harness/flops.py on the DeiT-S configuration)
     macs_per_token = depth * ((4 + 2 * mlp_ratio) * dim * dim + 2 * tokens * dim)
     fwd = 2.0 * (tokens * (macs_per_token + patch * patch * 3 * dim) + dim * 100)
     block_params = (4 + 2 * mlp_ratio) * dim * dim
@@ -208,11 +210,13 @@ def _vit_spec(name, depth, dim, heads, *, mlp_ratio=4, patch=4,
     )
 
 
-# per-image forward GFLOPs of the ResNet zoo at 32px CIFAR stem (analytic,
-# matches bench.py's conv-MAC walk) — scaled by (image_size/32)² below
+# per-image forward GFLOPs of the ResNet zoo at 32px CIFAR stem (the
+# conv-MAC walk of benchmark/harness/flops.py, a multiply-accumulate as two
+# operations like _vit_spec; tests/test_planner.py holds resnet18 to it) —
+# scaled by (image_size/32)² below
 _RESNET_FWD_GFLOPS_32PX = {
-    "resnet18": 0.56, "resnet34": 1.16, "resnet50": 1.31,
-    "resnet101": 2.52, "resnet152": 3.73,
+    "resnet18": 1.11, "resnet34": 2.32, "resnet50": 2.60,
+    "resnet101": 5.02, "resnet152": 7.44,
 }
 _RESNET_PARAMS = {
     "resnet18": 11.2e6, "resnet34": 21.3e6, "resnet50": 23.6e6,
@@ -231,8 +235,8 @@ def model_spec(hparams, model=None) -> ModelSpec:
     if model is not None and all(
         hasattr(model, a) for a in ("depth", "dim", "heads")
     ):
-        # a caller-built model may not match the --model flag (tests,
-        # bench nets): its own dims — and name — win
+        # a caller-built model may not match the --model flag (tests'
+        # nets): its own dims — and name — win
         return _vit_spec(
             name if name.startswith("vit") else type(model).__name__,
             int(model.depth), int(model.dim), int(model.heads),
@@ -471,7 +475,7 @@ class LedgerFit:
 
 
 _K_SUFFIX = re.compile(r"@k(\d+)$")
-_TRAIN_EXEC_PREFIXES = ("device_chunk_runner", "chunk_runner", "epoch_runner")
+_TRAIN_EXEC_PREFIXES = ("device_chunk_runner", "chunk_runner")
 
 
 def _payload(ev: dict) -> dict:

@@ -9,7 +9,7 @@
 
 Entry points: ``--parity-check N`` (+ ``--parity-tol``) on any run,
 ``tools/run_report.py --parity`` to render/gate the emitted ``parity``
-event, ``bench.py --parity`` for the committed layout sweep.
+event; ``tests/test_parity.py`` sweeps the layouts.
 """
 
 from .diff import (

@@ -146,8 +146,8 @@ def host_step_key(data_key, epoch: int, step: int):
 
 def device_step_keys(data_key, epoch: int, steps: int):
     """Device mode: ``split(fold_in(fold_in(data_key, epoch), 1), steps)``
-    — the epoch runner's key table (``make_epoch_runner`` /
-    ``make_device_chunk_runner``)."""
+    — the epoch's key table, one key a step, out of which every
+    ``make_device_chunk_runner`` dispatch slices its rows."""
     epoch_key = jax.random.fold_in(data_key, epoch)
     return jax.random.split(jax.random.fold_in(epoch_key, 1), steps)
 

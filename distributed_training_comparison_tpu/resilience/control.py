@@ -20,8 +20,8 @@ generalizes that one-shot preemption drain into a **control barrier**:
   ``EXIT_PREEMPTED``, fast-forward resume;
 - every application emits one registered ``control`` event carrying the
   decide→apply timestamps (``t_decide``/``t_apply``/``ttm_s``) and the
-  step distance, so ``run_report --policy`` and BENCH_CONTROL.json can
-  render time-to-mitigation per decision.
+  step distance, so ``run_report --policy`` can render
+  time-to-mitigation per decision.
 
 One-shot across restarts: a ``drain`` request asks for *an attempt
 boundary* — if the supervisor restarted the run before the trainer
@@ -278,7 +278,8 @@ def unapplied_actions(events) -> list[dict]:
     lost.  Scope: act-mode ``completed`` decisions for the trainer-side
     control actions (``rollback``/``abort_with_evidence``); drain-class
     decisions complete supervisor-side (the marker/replan IS the fleet
-    mitigation) and are gated by the chaos/bench expectations instead.
+    mitigation) and are gated by the chaos matrix's expectations
+    (``tools/chaos_matrix.py``) instead.
     """
     gated = {"rollback", "abort_with_evidence"}
     completed: dict = {}

@@ -38,8 +38,8 @@ import jax
 
 def forced_host_device_env(n: int, base: dict | None = None) -> dict:
     """Subprocess environment forcing ``n`` virtual CPU devices — the one
-    recipe behind every elastic-on-CPU child (tests, ``bench.py
-    --resilience``): replace any existing
+    recipe behind every elastic-on-CPU child (tests,
+    ``tools/chaos_matrix.py``): replace any existing
     ``--xla_force_host_platform_device_count`` in ``XLA_FLAGS`` and pin
     the CPU backend (``JAX_PLATFORMS=cpu``).  Returns a COPY of
     ``base`` (default ``os.environ``) — never mutates the caller's env,

@@ -408,6 +408,12 @@ EMU_SLOW_DISPATCH_ENV = "DTC_EMU_SLOW_DISPATCH_S"
 # scoreboard's columns compare like with like.
 _STRAGGLER_ALERT = "step/dispatch_s:p95>30:for=2"
 _STRAGGLER_POLICY = f"{_STRAGGLER_ALERT} -> drain_host:cooldown=120"
+# the window the straggler chain is given: host 1 reports its slowed sketch
+# from rank 0's first checkpoint on, and two flushed windows, a supervisor
+# poll and the drain take about a second — the ten epochs of a matrix run
+# take less, so rank 0 holds at epoch 1's boundary (behind its checkpoint:
+# the stall never fires again on resume)
+_STRAGGLER_WINDOW = "stall@epoch=1:secs=15"
 _SPIKE_ALERT = "train/loss:p95>50:for=1"
 _SPIKE_POLICY = f"{_SPIKE_ALERT} -> rollback:cooldown=300"
 _SKIP_ALERT = "train/skipped_steps:n>0:for=1"
@@ -418,15 +424,15 @@ _REWARM_POLICY = f"{_SENTINEL_ALERT} -> rewarm_serve:cooldown=5"
 
 # Named scenarios composing preempt x straggler-stall x corrupt-shard
 # (nan_grad) x host-flap x mid-epoch control, each run end-to-end under
-# the fleet supervisor with the policy engine active (bench.py --chaos
-# -> CHAOS.json).  Every scenario recovers via policy/supervisor actions
+# the fleet supervisor with the policy engine active
+# (``tools/chaos_matrix.py``).  Every scenario recovers via policy/supervisor actions
 # alone — no scenario writes an operator marker file.  Re-admission of a
 # killed host goes through the SCHEDULER's interface: either the legacy
 # driver writing ``host-1.up`` directly (``kill_and_readmit_host1``) or,
 # in ``probe_readmission``, a :class:`SchedulerProbe` ready-file the
 # driver creates and ``--fleet-probe`` turns into the marker.
 #
-# Field contract (consumed by ``bench.py --chaos`` and linted by tests):
+# Field contract (consumed by ``tools/chaos_matrix.py``, linted by tests):
 #   fault_plan   --fault-plan spec for the training child (or None)
 #   alerts       --alert specs handed to the supervisor
 #   policies     --policy specs binding those alerts to actions
@@ -445,7 +451,7 @@ CHAOS_SCENARIOS: dict[str, dict] = {
     "straggler_drain": {
         "desc": "persistent straggler on host 1 -> dispatch alert -> "
                 "policy drain_host -> world shrinks -> run completes",
-        "fault_plan": None,
+        "fault_plan": _STRAGGLER_WINDOW,
         "alerts": (_STRAGGLER_ALERT,),
         "policies": (_STRAGGLER_POLICY,),
         "policy_mode": "act",
@@ -462,7 +468,7 @@ CHAOS_SCENARIOS: dict[str, dict] = {
     "straggler_dryrun": {
         "desc": "same straggler, --policy-mode dry-run: the decision is "
                 "logged, NO drain happens, the world never shrinks",
-        "fault_plan": None,
+        "fault_plan": _STRAGGLER_WINDOW,
         "alerts": (_STRAGGLER_ALERT,),
         "policies": (_STRAGGLER_POLICY,),
         "policy_mode": "dry-run",
@@ -630,7 +636,7 @@ CHAOS_SCENARIOS: dict[str, dict] = {
         "policy_mode": "act",
         "driver": "probe_readmit_host1",
         "env": {},
-        # {root} is substituted by bench.py with the scenario's ckpt
+        # {root} is substituted by the matrix with the scenario's ckpt
         # root; {host} survives for the probe's own substitution
         "extra_args": ("--fleet-probe", "file:{root}/probe-ready-{host}"),
         "expect": {
@@ -643,7 +649,7 @@ CHAOS_SCENARIOS: dict[str, dict] = {
                 "recompile storm trips the sentinel alert -> policy "
                 "rewarm_serve re-warms the replica fleet -> p99 recovers "
                 "after the flash",
-        # the serve session (session: "serve"): bench.py --chaos runs the
+        # the serve session (session: "serve"): the matrix runs the
         # real --serve entry instead of the training fleet worker.  Warm
         # buckets 1,2 only; the flash's queue depth reaches bucket 8 —
         # a mid-serving compile cliff, exactly the storm rewarm_serve
@@ -679,7 +685,7 @@ CHAOS_SCENARIOS: dict[str, dict] = {
                 "warm-started incarnation",
         # process transport (serve/fleet/): each replica is a real OS
         # process behind the socket transport, so the kill is a true
-        # worker death — the chaos driver (bench.py) watches the
+        # worker death — the chaos driver (the matrix) watches the
         # handshake files and SIGKILLs replica 0 once the fleet is
         # ready and load is flowing.  The autoscaler rides along
         # (--serve-scale-target) so the scenario also proves scaling

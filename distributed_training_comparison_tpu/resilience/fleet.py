@@ -231,7 +231,7 @@ class FleetSupervisor(Supervisor):
             lambda c, e: subprocess.Popen(list(c), env=e)
         )
         # the rendezvous address handed to every rank.  The loopback
-        # default serves the single-machine case (tests, bench, one-box
+        # default serves the single-machine case (tests, one-box
         # fleets); a multi-machine ``spawn`` implementation must pass the
         # supervisor's REACHABLE address here, or rank>0's --dist-url
         # resolves to its own loopback and the fleet never rendezvouses.
@@ -595,7 +595,7 @@ class FleetSupervisor(Supervisor):
         if self.local_devices > 0:
             from .elastic import forced_host_device_env
 
-            # the CPU-emulation knob (tests, bench): force each child's
+            # the CPU-emulation knob (tests, chaos matrix): force each child's
             # virtual device count; a real TPU fleet inherits its env
             return forced_host_device_env(self.local_devices, base=base)
         return dict(base) if base is not None else None
@@ -780,7 +780,7 @@ class FleetSupervisor(Supervisor):
 
 def fleet_env_knobs(hparams) -> dict:
     """The FleetSupervisor constructor kwargs derived from hparams — one
-    place, shared by ``run_supervised`` and ``bench.py``."""
+    place, for ``run_supervised``."""
     return {
         "hosts": int(getattr(hparams, "fleet_hosts", 0) or 0),
         "batch_size": int(getattr(hparams, "batch_size", 0) or 0),

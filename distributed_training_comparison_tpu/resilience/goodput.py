@@ -19,8 +19,8 @@ actually training.  The meter splits a process's lifetime into phases —
   must not inflate goodput,
 
 plus untracked remainder.  Each training attempt appends one record to the
-run dir's ``goodput.jsonl``; the supervisor (or ``bench.py --resilience``)
-aggregates records + its own restart downtime into ``GOODPUT.json`` —
+run dir's ``goodput.jsonl``; the supervisor aggregates records + its own
+restart downtime into the file ``--goodput-json`` names —
 goodput = productive seconds / (wall seconds across attempts + downtime).
 Attempt records may also carry a ``ckpt_writer`` gauge (the async writer
 thread's busy seconds/fraction, ``train/async_ckpt.py``) — visible when

@@ -20,7 +20,6 @@ from .step import (
     make_train_step,
     make_eval_step,
     make_eval_runner,
-    make_epoch_runner,
     make_chunk_runner,
     make_device_chunk_runner,
 )
@@ -48,7 +47,6 @@ __all__ = [
     "make_device_chunk_runner",
     "make_eval_step",
     "make_eval_runner",
-    "make_epoch_runner",
     "AsyncCheckpointer",
     "agreed_version_dir",
     "find_valid_resume",

@@ -1,4 +1,4 @@
-"""Compiled train/eval steps and the scanned epoch runner.
+"""Compiled train/eval steps and the scanned chunk runners.
 
 Parity: reference ``_train_epoch`` / ``validate`` / ``test`` hot loops
 (``src/single/trainer.py:122-228``) — forward, CrossEntropy, backward, SGD
@@ -18,12 +18,12 @@ TPU-native redesign:
 - AMP (``autocast`` + ``GradScaler``, ``src/single/trainer.py:134-140``)
   becomes a bf16 activation policy; params/grads/optimizer state stay fp32,
   and bf16's fp32-sized exponent needs no loss scaling.
-- ``make_epoch_runner`` runs a whole epoch as one ``lax.scan`` over a
-  device-resident dataset: shuffle (device-side permutation), gather,
-  augment, step — zero host round-trips per step.  Per-step losses come back
-  as one stacked array per epoch, so the reference's every-``eval_step``
-  log lines can be reconstructed exactly without its per-step
-  ``loss.item()`` device sync (``src/single/trainer.py:147-153``).
+- ``make_device_chunk_runner`` runs ``chunk_steps`` steps of an epoch (by
+  default all) as one ``lax.scan`` over a device-resident dataset: shuffle
+  (device-side permutation), gather, augment, step — zero host round-trips
+  per step.  Per-step losses come back as one stacked array per dispatch,
+  so the reference's every-``eval_step`` log lines can be reconstructed
+  without its per-step ``loss.item()`` sync (``src/single/trainer.py:147-153``).
 """
 
 from __future__ import annotations
@@ -135,7 +135,7 @@ def _declare_state_layout(runner, fwd_bwd, state_layout):
     layouts.py``): the state, the shardings, and the schedule's
     ``fwd_bwd`` must all have been built for the SAME resident layout.
     This cross-checks the declared layout against the schedule's and tags
-    the runner for introspection (parity/bench read it back).
+    the runner for introspection (the parity rail reads it back).
     """
     declared = getattr(fwd_bwd, "state_layout", None)
     if (
@@ -212,9 +212,9 @@ def _make_step_core(
     tokens: as they are) and how hits are counted.  The loss is the mean
     over every label either way.
 
-    Used by the per-step path (``make_train_step``), the scanned epoch path
-    (``make_epoch_runner``) and the chunked streaming path
-    (``make_chunk_runner``) so they can never diverge.
+    Used by the per-step path (``make_train_step``), the device-resident
+    scanned path (``make_device_chunk_runner``) and the chunked streaming
+    path (``make_chunk_runner``) so they can never diverge.
 
     ``grad_accum > 1`` splits the batch into that many sequential
     micro-batches, averages their gradients, and applies ONE optimizer
@@ -461,7 +461,8 @@ def make_train_step(
 
     # No buffer donation here: this per-step path serves benchmarks and
     # tests that re-read their inputs after the call (the scanned runners
-    # donate — they own the train loop's hot path; see make_epoch_runner).
+    # donate — they own the train loop's hot path; see
+    # make_device_chunk_runner).
     return _declare_state_layout(
         observed_jit(
             core, monitor, "train_step",
@@ -813,26 +814,43 @@ def make_device_chunk_runner(
     state_layout=None,
 ) -> Callable[..., tuple[TrainState, Metrics]]:
     """``chunk_steps`` steps of a device-resident epoch as ONE scanned
-    dispatch — the chunked form of ``make_epoch_runner``.
+    dispatch; ``chunk_steps = steps`` with ``start = 0`` is the whole epoch
+    as a single program.
+
+    Inputs are the device-resident split (uint8 images + labels), the root
+    PRNG key, the epoch number and the chunk's first step (both traced, so
+    every epoch and every full-size chunk reuse one executable).  Per-epoch
+    shuffling is a device-side permutation folded from (key, epoch);
+    ``drop_last=True`` semantics match the reference's train loader
+    (``src/single/dataset.py:97``).
 
     Bit-identity contract (the same one the host chunk runner documents):
-    the permutation and the per-step keys are recomputed exactly as the
-    monolithic epoch runner derives them — ``epoch_permutation(key, epoch,
-    n)`` and ``split(fold_in(fold_in(key, epoch), 1), steps)`` — and the
-    chunk dynamic-slices rows ``[start, start + K)`` out of both, so the
-    loss/param trajectory is bit-identical to the monolithic program for ANY
-    chunk size.  What chunking buys is a host touch point every K steps: the
-    health watchdog and the preemption poll gain chunk-boundary granularity
-    in device data mode, where the epoch used to be one uninterruptible
-    program.  The permutation recompute per chunk is O(n log n) device work
-    — noise next to K training steps for any practical K.
+    every chunk derives the epoch's whole tables — the permutation
+    ``epoch_permutation(key, epoch, n)`` cut to whole batches and the key
+    table ``split(fold_in(fold_in(key, epoch), 1), steps)``, one key per
+    step of the epoch — and dynamic-slices rows ``[start, start + K)`` out
+    of both, so the loss/param trajectory is bit-identical for ANY chunk
+    size (``tests/test_overlap.py`` pins it against ``K = steps``).  What
+    chunking buys is a host touch point every K steps: the health watchdog
+    and the preemption poll gain chunk-boundary granularity in device data
+    mode, where a whole-epoch chunk is one uninterruptible program.  The
+    permutation recompute per chunk is O(n log n) device work — noise next
+    to K training steps for any practical K.
 
     ``start`` is traced, so every full-size chunk shares one executable (at
     most two per run: the full chunk and the remainder).  Callers must keep
     ``start + chunk_steps <= steps`` — ``dynamic_slice`` clamps an
     out-of-range start instead of failing, which would silently replay
-    batches.  ``donate=True`` donates only the state (the split arrays are
-    the epoch-persistent dataset).
+    batches.  ``donate=True`` donates only the state: the output state
+    aliases it, so the runner keeps no second copy in HBM (the trainer hands
+    the async checkpoint writer an explicit device-side snapshot instead —
+    see ``Trainer.fit``).  The split arrays are the epoch-persistent dataset
+    and are never donated; the eval runners likewise keep donation off.
+
+    ``fault_injection=True`` appends a traced ``(scale, start, stop)``
+    step-fault argument (``resilience/faults.py`` step faults; indices are
+    steps within the epoch); the default runner's signature and executable
+    are unchanged.
     """
     data_shard = batch_sharding(mesh)
     repl = replicated_sharding(mesh)
@@ -891,97 +909,6 @@ def make_device_chunk_runner(
     return _declare_state_layout(
         observed_jit(
             run, monitor, obs_name, out_shardings=(state_sh, repl)
-        ),
-        fwd_bwd, state_layout,
-    )
-
-
-def make_epoch_runner(
-    mesh: Mesh,
-    batch_size: int,
-    *,
-    precision: str = "fp32",
-    augment: bool = True,
-    mean=CIFAR100_MEAN,
-    std=CIFAR100_STD,
-    state_sharding=None,
-    grad_accum: int = 1,
-    fwd_bwd=None,
-    comms=None,
-    fault_injection: bool = False,
-    donate: bool = True,
-    monitor=None,
-    state_layout=None,
-) -> Callable[[TrainState, jnp.ndarray, jnp.ndarray, jax.Array, jnp.ndarray], tuple[TrainState, Metrics]]:
-    """One whole epoch as a single compiled ``lax.scan``.
-
-    Inputs are the device-resident split (uint8 images + labels), the root
-    PRNG key, and the epoch number (traced, so every epoch reuses one
-    executable).  Per-epoch shuffling is a device-side permutation folded
-    from (key, epoch); ``drop_last=True`` semantics match the reference's
-    train loader (``src/single/dataset.py:97``).
-
-    ``donate=True`` (default) donates the input state: the output state
-    aliases it, eliminating the one extra state copy of HBM the runner used
-    to keep for the async checkpointer's benefit (the trainer now hands the
-    writer an explicit device-side snapshot instead — see ``Trainer.fit``).
-    The split arrays are NOT donated: they are the persistent dataset,
-    reused every epoch.  The eval runners likewise keep donation off — their
-    inputs (state, the padded val/test split) are all reused across calls.
-
-    ``fault_injection=True`` appends a traced ``(scale, start, stop)``
-    step-fault argument (``resilience/faults.py`` step faults); the default
-    runner's signature and executable are unchanged.
-    """
-    data_shard = batch_sharding(mesh)
-    accum_shard = batch_sharding(mesh, axis=1)  # micro-batch layout (a, b/a, ...)
-    repl = replicated_sharding(mesh)
-    state_sh = state_sharding if state_sharding is not None else repl
-    core = _make_step_core(
-        precision, augment, mean, std, grad_accum, accum_shard, fwd_bwd,
-        comms, repl,
-    )
-
-    def _run(state: TrainState, images, labels, key: jax.Array, epoch, fault):
-        n = images.shape[0]
-        steps = n // batch_size
-        epoch_key = jax.random.fold_in(key, epoch)
-        perm = epoch_permutation(key, epoch, n)[: steps * batch_size]
-        perm = perm.reshape(steps, batch_size)
-        step_keys = jax.random.split(jax.random.fold_in(epoch_key, 1), steps)
-
-        def body(state, inp):
-            idx, step_key, i = inp
-            bx = jax.lax.with_sharding_constraint(images[idx], data_shard)
-            by = jax.lax.with_sharding_constraint(labels[idx], data_shard)
-            if fault is None:
-                return core(state, bx, by, step_key)
-            return core(state, bx, by, step_key, _step_fault_scale(i, fault))
-
-        state, stacked = jax.lax.scan(
-            body, state, (perm, step_keys, jnp.arange(steps))
-        )
-        return state, stacked  # stacked["loss"]: (steps,) per-step losses
-
-    if fault_injection:
-        run = lambda state, images, labels, key, epoch, fault: (  # noqa: E731
-            _run(state, images, labels, key, epoch, fault)
-        )
-    else:
-        run = lambda state, images, labels, key, epoch: (  # noqa: E731
-            _run(state, images, labels, key, epoch, None)
-        )
-    if donate:
-        return _declare_state_layout(
-            _donated_jit(
-                run, mesh, donate_argnums=(0,), monitor=monitor,
-                name="epoch_runner", out_shardings=(state_sh, repl),
-            ),
-            fwd_bwd, state_layout,
-        )
-    return _declare_state_layout(
-        observed_jit(
-            run, monitor, "epoch_runner", out_shardings=(state_sh, repl)
         ),
         fwd_bwd, state_layout,
     )

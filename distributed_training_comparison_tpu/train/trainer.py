@@ -480,7 +480,7 @@ class Trainer:
             # is re-laid ONCE below (state_from_canonical) and every
             # reader — eval, checkpoints, parity, the planner — goes
             # through this one seam.  --no-pipeline-resident-layout keeps
-            # the legacy per-step relayout (the bench baseline).
+            # the legacy per-step relayout (ROADMAP.md D3).
             self._state_layout = layouts_mod.layout_for(
                 schedule, virtual=virtual, pipe=pipe_size,
                 pipe_axis=pipe_axis, tp_axis=tp_axis,
@@ -3048,9 +3048,9 @@ class Trainer:
         """Chunked scanned epoch over the HBM-resident split.
 
         ``--device-chunk-steps`` steps per dispatch (default: the whole
-        epoch — exactly the old monolithic program).  Each chunk recomputes
-        the epoch permutation and the per-step key split the monolithic
-        runner derives and slices its ``[start, start+K)`` rows, so the
+        epoch as one program).  Each chunk recomputes the epoch's whole
+        permutation and per-step key table (``make_device_chunk_runner``)
+        and slices its ``[start, start+K)`` rows, so the
         trajectory is bit-identical for ANY chunk size; what smaller chunks
         buy is a host touch point mid-epoch — the preemption poll (and an
         injected ``preempt@epoch=K:step=S``) drains at the next chunk
@@ -3091,7 +3091,7 @@ class Trainer:
             )
             # a --profile-dir capture gets one StepTraceAnnotation per
             # chunk dispatch: the xplane gains step boundaries, so device
-            # time joins the host spans (and op_profile output) by step id
+            # time joins the host spans by step id
             ann = (
                 obs.step_annotation(epoch * steps + done)
                 if self._profiling

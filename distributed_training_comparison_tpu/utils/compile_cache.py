@@ -7,8 +7,8 @@ whole-epoch scanned programs are the costliest part of a cold start.
 Two layers live here:
 
 - :func:`enable_persistent_compilation_cache` — jax's own on-disk HLO
-  cache, enabled by every entry point (CLI ``entry.run``, ``bench.py``,
-  ``chip_smoke.py``, the test workers).  ``JAX_COMPILATION_CACHE_DIR``
+  cache, enabled by every entry point (CLI ``entry.run``,
+  ``benchmark/run.py``, ``chip_smoke.py``, the test workers).  ``JAX_COMPILATION_CACHE_DIR``
   places it from outside; unset, it lives at one fixed path inside the
   checkout (``.jax_cache/``, git-ignored).  The path is part of the
   cache's key, so it is never a temporary or per-process name.  It caches

@@ -24,6 +24,7 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -35,6 +36,44 @@ from distributed_training_comparison_tpu.utils import (  # noqa: E402
 # dominated by CPU compiles of the zoo models; with the cache warm a repeat
 # run skips nearly all of them.
 enable_persistent_compilation_cache()
+
+
+def whole_epoch_runner(mesh, batch_size, n, **kw):
+    """A device-resident epoch of ``n`` examples as ONE dispatch of the
+    runner the product calls: ``make_device_chunk_runner`` with ``K =
+    steps``, called with ``start = 0``.  Signature of the result:
+    ``(state, images, labels, key, epoch[, fault])``."""
+    from distributed_training_comparison_tpu.train import (
+        make_device_chunk_runner,
+    )
+
+    runner = make_device_chunk_runner(
+        mesh, batch_size, chunk_steps=n // batch_size, **kw
+    )
+
+    def run(state, images, labels, key, epoch, *fault):
+        return runner(state, images, labels, key, epoch, jnp.asarray(0), *fault)
+
+    return run
+
+
+def assert_trees_within_ulp(actual, desired, ulp):
+    """Every leaf pair within ``ulp`` of ``parity/diff.py``'s scale-aware
+    ulp distance (max |a - b| in float32 ulps at the leaf's largest
+    magnitude): the metric built for comparing one trajectory across
+    device counts, where the reduction order differs and elementwise
+    ``allclose`` mistakes noise-floor elements for divergence."""
+    from distributed_training_comparison_tpu.parity.diff import ulp_distance
+
+    flat_a = jax.tree_util.tree_leaves_with_path(actual)
+    flat_d = jax.tree_util.tree_leaves(desired)
+    assert len(flat_a) == len(flat_d)
+    dist = {
+        jax.tree_util.keystr(path): ulp_distance(a, d)
+        for (path, a), d in zip(flat_a, flat_d)
+    }
+    worst = [k for k, v in dist.items() if v is None or not v <= ulp]
+    assert not worst, {k: dist[k] for k in worst}
 
 
 @pytest.fixture(scope="session", autouse=True)

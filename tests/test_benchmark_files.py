@@ -6,6 +6,9 @@ reference without its three functions, a configuration that drifts from
 the published one unsaid — is checked here too."""
 
 import json
+import os
+import subprocess
+import sys
 from pathlib import Path
 
 import pytest
@@ -55,6 +58,38 @@ def test_every_metric_finds_its_reader_and_its_cells(metric):
     assert callable(load(BENCH / folder / f"{metric['name']}.py").read)
     cells = {w["name"] for w in SPEC["workloads"]}
     assert set(metric.get("workloads", cells)) <= cells
+
+
+def test_cifar_resnet18_flop_count_matches_the_published_macs():
+    # 0.557 GMACs for CIFAR ResNet-18 at 32x32 -> 1.111 GFLOP forward
+    group = held("resnet18_cifar100")["flops"]
+    assert flops.resnet_forward_flops(group) == pytest.approx(1.111e9, rel=0.01)
+
+
+def test_train_is_three_forwards():
+    group = held("resnet18_cifar100")["flops"]
+    assert flops.train_flops_per_image(group) == pytest.approx(
+        3 * flops.resnet_forward_flops(group), rel=1e-9
+    )
+
+
+@pytest.mark.parametrize(
+    "cell", [w["name"] for w in SPEC["workloads"]] + ["no_such_cell"]
+)
+def test_benchmark_refuses_without_a_chip(cell, tmp_path):
+    """A measurement path that finds no chip fails: under an explicit
+    ``JAX_PLATFORMS=cpu`` the instrument exits 2 with the reason on stderr
+    and not one byte on stdout (the driver parses stdout), in every cell
+    and for a cell that does not exist."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", HOME=str(tmp_path))
+    proc = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload", cell,
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 2, proc.stderr[-2000:]
+    assert proc.stdout == ""
+    assert "benchmark: " in proc.stderr
 
 
 def test_token_configuration_holds_the_published_config_and_names_its_cut():

@@ -55,9 +55,9 @@ from distributed_training_comparison_tpu.train import (
     create_train_state,
     make_chunk_runner,
     make_device_chunk_runner,
-    make_epoch_runner,
 )
 
+from conftest import whole_epoch_runner
 from test_train import HP, TinyNet
 
 
@@ -227,8 +227,8 @@ def _run_epochs(mesh, data, comms, epochs=2, runner_kind="epoch"):
     state, sh = _prepared(mesh, comms)
     losses = []
     if runner_kind == "epoch":
-        runner = make_epoch_runner(
-            mesh, bs, state_sharding=sh, comms=comms, donate=False
+        runner = whole_epoch_runner(
+            mesh, bs, len(x), state_sharding=sh, comms=comms, donate=False
         )
         for e in range(epochs):
             state, stacked = runner(state, x, y, key, jnp.asarray(e))
@@ -269,7 +269,7 @@ def _run_epochs(mesh, data, comms, epochs=2, runner_kind="epoch"):
 )
 def test_sharded_update_matches_unsharded(mesh, tiny_data, runner_kind):
     """--shard-optim is the same arithmetic at a different layout: every
-    runner variant (monolithic epoch, device-chunked, host-chunked,
+    runner variant (whole-epoch chunk, device-chunked, host-chunked,
     donated) must land on the baseline's params to float reassociation."""
     base_l, base_p, _ = _run_epochs(mesh, tiny_data, None, runner_kind=runner_kind)
     comms = Comms(mesh, shard_optim=True)
@@ -342,8 +342,8 @@ def test_nonfinite_step_keeps_state_and_residual(mesh, tiny_data):
     x, y = tiny_data
     comms = Comms(mesh, shard_optim=True, grad_comms="int8")
     state, sh = _prepared(mesh, comms)
-    runner = make_epoch_runner(
-        mesh, 32, state_sharding=sh, comms=comms,
+    runner = whole_epoch_runner(
+        mesh, 32, len(x), state_sharding=sh, comms=comms,
         fault_injection=True, donate=False,
     )
     before = jax.device_get(state.params)
@@ -376,8 +376,8 @@ def test_benign_path_fingerprint_unchanged(mesh, tiny_data):
     assert not inactive.active
     monitor = CompileMonitor(registry=MetricRegistry())
     for comms in (None, inactive):
-        runner = make_epoch_runner(
-            mesh, 32, comms=comms, donate=False, monitor=monitor
+        runner = whole_epoch_runner(
+            mesh, 32, len(x), comms=comms, donate=False, monitor=monitor
         )
         runner(_fresh_state(mesh), x, y, jax.random.key(7), jnp.asarray(0))
     ledger = monitor.ledger()
@@ -568,33 +568,6 @@ def test_compute_summary_folds_compute_drain():
         1e12 * 10 / span / (275e12 * 4), rel=1e-6
     )
     assert "drain folded" in run_report.format_compute(comp)
-
-
-# ------------------------------------------------- satellite: bench leg
-
-
-@pytest.mark.slow
-@pytest.mark.perf
-def test_bench_comms_ledger(tmp_path):
-    """The --comms bench leg end to end (two legs only — the committed
-    BENCH_COMMS.json runs all five): the compile-event ledger must show
-    the opt-state footprint sharding 1/N, and the capture must
-    self-validate."""
-    sys.path.insert(0, str(Path(__file__).parent.parent))
-    import bench
-
-    record = bench.bench_comms(
-        out_path=str(tmp_path / "BENCH_COMMS.json"),
-        legs=("base", "shard_optim"),
-    )
-    assert record["events_check_rc"] == 0
-    ledger = record["ledger"]
-    assert ledger["opt_state_shard_ratio"] <= 0.5  # ~1/N on a 4-way axis
-    assert ledger["measured_saving_bytes"] > 0
-    assert (
-        ledger["update_bytes_shard_optim"] < ledger["update_bytes_base"]
-    )
-    assert record["loss_vs_base"]["shard_optim"] < 1e-4
 
 
 # ----------------------------------------------------------- config flags

@@ -41,6 +41,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "tools"))
 import goodput_report  # noqa: E402
 import run_report  # noqa: E402
 
+from conftest import assert_trees_within_ulp
 from distributed_training_comparison_tpu import obs
 from distributed_training_comparison_tpu.config import load_config
 from distributed_training_comparison_tpu.data.loader import (
@@ -977,10 +978,12 @@ def test_e2e_fleet_kill_shrink_readmit_reexpand(tmp_path):
         assert raw["epoch"] == 9  # all 10 epochs completed
         return raw["state"]["params"]
 
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-6
-        ),
-        final_params(root),
-        final_params(clean_root),
+    # 70 steps at world sizes 2 -> 1 -> 2 against 70 steps on 8 devices:
+    # the same trajectory under another reduction order.  Uninterrupted
+    # runs of this job at 1, 2, 4 and 8 devices end 1,900-7,400 scale-aware
+    # ulps apart (the per-step 2^6-2^8 of --parity-tol's help, compounded);
+    # a run that lost one epoch ends 340,000 or more away.  2^15 lies
+    # between: it passes reordering and fails a lost or repeated chunk.
+    assert_trees_within_ulp(
+        final_params(root), final_params(clean_root), ulp=1 << 15
     )

@@ -49,10 +49,10 @@ from distributed_training_comparison_tpu.train import (
     Trainer,
     configure_optimizers,
     create_train_state,
-    make_epoch_runner,
     make_train_step,
 )
 
+from conftest import whole_epoch_runner
 from test_train import HP, TinyNet
 
 BASE_ARGS = [
@@ -160,8 +160,8 @@ def test_guarded_epoch_skips_nonfinite_and_freezes_state(mesh, tiny_data):
     x, y = tiny_data
     # donate=False: this test deliberately re-reads the INPUT state after
     # the call to prove the guard froze it (the trainer's hot path donates)
-    runner = make_epoch_runner(
-        mesh, batch_size=64, fault_injection=True, donate=False
+    runner = whole_epoch_runner(
+        mesh, 64, len(x), fault_injection=True, donate=False
     )
     state = _fresh_state(mesh)
     key = jax.random.key(3)
@@ -194,9 +194,9 @@ def test_fault_scale_injection_is_windowed_and_benign_at_one(mesh, tiny_data):
     state = _fresh_state(mesh)
     key = jax.random.key(3)
     # donate=False: one state feeds three runner calls side by side
-    plain = make_epoch_runner(mesh, batch_size=64, donate=False)
-    faulted = make_epoch_runner(
-        mesh, batch_size=64, fault_injection=True, donate=False
+    plain = whole_epoch_runner(mesh, 64, len(x), donate=False)
+    faulted = whole_epoch_runner(
+        mesh, 64, len(x), fault_injection=True, donate=False
     )
     _, s_plain = plain(state, x, y, key, jnp.asarray(0))
     _, s_benign = faulted(state, x, y, key, jnp.asarray(0), (1.0, 0, 0))
@@ -780,28 +780,3 @@ def test_health_report_tool_summarizes_events(tmp_path):
     path.write_text("\n".join(json.dumps(e) for e in events) + "\n{torn")
     table = health_report.format_table([("run", health_report.load_report(path))])
     assert "rollbk" in table and "run" in table
-
-
-@pytest.mark.health
-@pytest.mark.slow
-def test_bench_health_leg_writes_report(tmp_path):
-    """bench.py --health end-to-end (tiny model, small sizing): HEALTH.json
-    carries the skip/rollback counts and the goodput split including the
-    rollback waste."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).parent.parent))
-    import bench
-
-    out = tmp_path / "HEALTH.json"
-    record = bench.bench_health(
-        out_path=str(out),
-        trainer_model=TinyNet(num_classes=100),
-        extra_argv=("--limit-examples", "640", "--epoch", "4"),
-    )
-    assert out.exists()
-    assert record["rollbacks"] == 2 and record["skipped_steps"] == 3
-    assert record["goodput"]["rollback_s"] > 0
-    assert record["goodput"]["goodput_frac"] > 0
-    assert record["events_check_rc"] == 0  # the capture self-validated

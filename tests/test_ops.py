@@ -7,8 +7,8 @@ causal block-skipping) run in CI on the CPU mesh.  Comparisons run under
 matmul precision is bf16-like, which would drown the parity signal.
 
 On real TPU hardware the same checks hold at bf16 tolerance and run at
-their design points in ``tests_tpu/``; measured v5e throughput lives in
-the README's flash-attention table (reproduced by ``python bench.py``).
+their design points in ``tests_tpu/``; the kernel's chip time is read in
+the cell ``lfm2_ep8_seq4k_job`` (``attention_roofline_pct``, ``PERF.md``).
 """
 
 import re

@@ -41,7 +41,6 @@ from distributed_training_comparison_tpu.train import (
     create_train_state,
     make_chunk_runner,
     make_device_chunk_runner,
-    make_epoch_runner,
 )
 from distributed_training_comparison_tpu.utils import StepTimeMeter
 
@@ -68,79 +67,75 @@ def _fresh_state(mesh):
 # -------------------------------------------- device-mode chunked runner
 
 
-def test_device_chunk_runner_bit_identical_to_monolithic(mesh, tiny_data):
-    """The chunked device runner must reproduce the monolithic epoch
-    runner's trajectory EXACTLY for any chunk size (the permutation and the
-    per-step key split are recomputed and sliced, never re-derived)."""
-    x, y = tiny_data
+def _run_chunked(mesh, data, chunk, epochs, fault=None):
+    """``epochs`` epochs in dispatches of ``chunk`` steps (and one of the
+    remainder): stacked losses and the final params."""
+    x, y = data
     bs = 32
     steps = len(x) // bs  # 8
     key = jax.random.key(7)
-
-    def run_monolithic():
-        runner = make_epoch_runner(mesh, bs)
-        state = _fresh_state(mesh)
-        losses = []
-        for e in range(2):
-            state, stacked = runner(state, x, y, key, jnp.asarray(e))
-            losses.append(np.asarray(stacked["loss"]))
-        return np.concatenate(losses), jax.device_get(state.params)
-
-    def run_chunked(chunk):
-        runner = make_device_chunk_runner(mesh, bs, chunk)
-        rem = steps % chunk
-        rem_runner = (
-            make_device_chunk_runner(mesh, bs, rem) if rem else None
-        )
-        state = _fresh_state(mesh)
-        losses = []
-        for e in range(2):
-            start = 0
-            while start < steps:
-                take = min(chunk, steps - start)
-                r = runner if take == chunk else rem_runner
-                state, stacked = r(
-                    state, x, y, key, jnp.asarray(e), jnp.asarray(start)
-                )
-                losses.append(np.asarray(stacked["loss"]))
-                start += take
-        return np.concatenate(losses), jax.device_get(state.params)
-
-    ref_losses, ref_params = run_monolithic()
-    assert len(ref_losses) == 2 * steps
-    for chunk in (1, 3, 8):
-        losses, params = run_chunked(chunk)
-        np.testing.assert_array_equal(losses, ref_losses)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_array_equal(a, b), params, ref_params
-        )
-
-
-def test_device_chunk_runner_fault_indices_are_epoch_global(mesh, tiny_data):
-    """The traced step-fault window indexes steps WITHIN the epoch, exactly
-    like the monolithic fault runner — a fault on steps [2, 5) must hit the
-    same batches regardless of how the epoch is chunked."""
-    x, y = tiny_data
-    bs, steps = 32, 8
-    key = jax.random.key(7)
-    fault = (64.0, 2, 5)
-
-    runner = make_epoch_runner(mesh, bs, fault_injection=True)
-    state, stacked = runner(
-        _fresh_state(mesh), x, y, key, jnp.asarray(0), fault
-    )
-    ref = np.asarray(stacked["loss"])
-
-    crunner = make_device_chunk_runner(mesh, bs, 3, fault_injection=True)
-    rrunner = make_device_chunk_runner(mesh, bs, 2, fault_injection=True)
+    kw = {"fault_injection": fault is not None}
+    extra = () if fault is None else (fault,)
+    runners = {
+        k: make_device_chunk_runner(mesh, bs, k, **kw)
+        for k in {chunk, steps % chunk} - {0}
+    }
     state = _fresh_state(mesh)
     losses = []
-    for start, r in ((0, crunner), (3, crunner), (6, rrunner)):
-        state, stacked = r(
-            state, x, y, key, jnp.asarray(0), jnp.asarray(start), fault
-        )
-        losses.append(np.asarray(stacked["loss"]))
-    np.testing.assert_array_equal(np.concatenate(losses), ref)
+    for e in range(epochs):
+        for start in range(0, steps, chunk):
+            take = min(chunk, steps - start)
+            state, stacked = runners[take](
+                state, x, y, key, jnp.asarray(e), jnp.asarray(start), *extra
+            )
+            losses.append(np.asarray(stacked["loss"]))
+    return np.concatenate(losses), jax.device_get(state.params)
+
+
+@pytest.fixture(scope="module")
+def whole_epoch(mesh, tiny_data):
+    """Two epochs at ``K = steps``: one dispatch an epoch, what ``Trainer``
+    runs by default."""
+    return _run_chunked(mesh, tiny_data, 8, epochs=2)
+
+
+FAULT = (64.0, 2, 5)  # (scale, start, stop): steps [2, 5) of the epoch
+
+
+@pytest.fixture(scope="module")
+def whole_epoch_faulted(mesh, tiny_data):
+    return _run_chunked(mesh, tiny_data, 8, epochs=1, fault=FAULT)[0]
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 5])
+def test_any_chunking_gives_the_whole_epoch_trajectory(
+    mesh, tiny_data, whole_epoch, chunk
+):
+    """Any chunk size gives the trajectory of ``K = steps``, bit for bit:
+    every chunk derives the epoch's whole permutation and key table and
+    slices its rows, never re-derives them from its own position."""
+    ref_losses, ref_params = whole_epoch
+    assert len(ref_losses) == 16
+    losses, params = _run_chunked(mesh, tiny_data, chunk, epochs=2)
+    np.testing.assert_array_equal(losses, ref_losses)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(a, b), params, ref_params
+    )
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 4])
+def test_fault_window_hits_the_same_batches_under_any_chunking(
+    mesh, tiny_data, whole_epoch, whole_epoch_faulted, chunk
+):
+    """The traced step-fault window indexes steps WITHIN the epoch — a
+    fault on steps [2, 5) must hit the same batches however the epoch is
+    chunked (inside one chunk, across two, one step a dispatch)."""
+    # the window is felt: its first step's loss is the plain one scaled
+    assert whole_epoch_faulted[2] == pytest.approx(
+        FAULT[0] * whole_epoch[0][2], rel=1e-4
+    )
+    losses, _ = _run_chunked(mesh, tiny_data, chunk, epochs=1, fault=FAULT)
+    np.testing.assert_array_equal(losses, whole_epoch_faulted)
 
 
 def test_donated_runner_consumes_input_state(mesh, tiny_data):

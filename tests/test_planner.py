@@ -4,8 +4,7 @@ Covers: the cost-model fit from synthetic compile/dispatch events, the
 feasibility filter against hand-constructed layouts (refusals carrying
 ``elastic.divisibility_help``-style numbers), plan == hand-flags
 trajectory parity through the real Trainer, resize→replan under the
-fleet supervisor (scripted FakeProc children — the real-subprocess
-flavor lives in ``bench.py --plan``), the ``replan`` policy action
+fleet supervisor (scripted FakeProc children), the ``replan`` policy action
 (act / dry-run / unavailable), the ``run_report --plan`` stream gate,
 and the two satellite knobs (``--device-prefetch auto``,
 ``--ckpt-comms-residual``).
@@ -34,8 +33,11 @@ from distributed_training_comparison_tpu.parallel.planner import (
     plan_layout,
 )
 
-sys.path.insert(0, str(Path(__file__).parent.parent / "tools"))
+REPO = Path(__file__).parent.parent
+sys.path.insert(0, str(REPO / "tools"))
+sys.path.insert(0, str(REPO / "benchmark"))
 import run_report  # noqa: E402
+from harness import flops  # noqa: E402
 
 
 def _hp(**kw):
@@ -46,6 +48,30 @@ def _hp(**kw):
     )
     base.update(kw)
     return argparse.Namespace(**base)
+
+
+# ------------------------------------------------- the planner's FLOP table
+
+
+@pytest.mark.parametrize(
+    "config,argv",
+    [
+        ("resnet18_cifar100", ["--model", "resnet18"]),
+        ("vit_small_cifar100_p2", ["--model", "vit_small", "--patch-size", "2"]),
+    ],
+)
+def test_planner_flops_agree_with_the_benchmarks_count(config, argv):
+    """The planner's hand-entered ResNet table (``_RESNET_FWD_GFLOPS_32PX``)
+    and its ViT formula against ``benchmark/harness/flops.py`` on the image
+    configurations' own ``flops`` groups, within 1 %: two counts of one
+    forward pass with no common source left to keep them together."""
+    group = json.loads(
+        (REPO / "benchmark" / "configs" / f"{config}.json").read_text()
+    )["flops"]
+    spec = model_spec(load_config("tpu", ["--synthetic-data", *argv]))
+    assert spec.fwd_flops_per_image == pytest.approx(
+        flops.train_flops_per_image(group) / 3, rel=0.01
+    )
 
 
 # ------------------------------------------------------------ feasibility

@@ -49,12 +49,12 @@ from distributed_training_comparison_tpu.train import (
     find_valid_resume,
     find_version_dir,
     load_resume_state,
-    make_epoch_runner,
     save_resume_state,
 )
 from distributed_training_comparison_tpu.train import checkpoint as ckpt_mod
 from distributed_training_comparison_tpu.parallel import make_mesh, replicated_sharding
 
+from conftest import assert_trees_within_ulp, whole_epoch_runner
 from test_train import HP, TinyNet
 
 WORKER = Path(__file__).parent / "resil_worker.py"
@@ -475,7 +475,7 @@ def test_elastic_restore_across_device_counts_in_process(tmp_path):
         jnp.asarray(np.random.default_rng(1).integers(0, 10, size=(64,)).astype(np.int32)),
     )
     mesh8 = make_mesh(backend="ddp")
-    runner8 = make_epoch_runner(mesh8, batch_size=32)
+    runner8 = whole_epoch_runner(mesh8, 32, len(x))
     state = _tiny_state(mesh8)
     key = jax.random.key(3)
     state, _ = runner8(state, x, y, key, jnp.asarray(0))
@@ -489,17 +489,16 @@ def test_elastic_restore_across_device_counts_in_process(tmp_path):
     assert next_epoch == 1 and int(restored.step) == 2
 
     state8, s8 = runner8(state, x, y, key, jnp.asarray(1))
-    runner4 = make_epoch_runner(mesh4, batch_size=32)
+    runner4 = whole_epoch_runner(mesh4, 32, len(x))
     state4, s4 = runner4(restored, x, y, key, jnp.asarray(1))
-    np.testing.assert_allclose(
-        np.asarray(s4["loss"]), np.asarray(s8["loss"]), rtol=1e-5, atol=1e-6
-    )
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-        ),
-        jax.device_get(state4.params),
-        jax.device_get(state8.params),
+    # one epoch (two steps) under a 4-way against an 8-way gradient
+    # reduction: conv-family dp-only fp32 re-associates into 2^6-2^8
+    # scale-aware ulps (--parity-tol's help; 221 measured here, on the
+    # BatchNorm bias), which the parity rail gates at ulp=1024
+    assert_trees_within_ulp(
+        {"loss": s4["loss"], "params": jax.device_get(state4.params)},
+        {"loss": s8["loss"], "params": jax.device_get(state8.params)},
+        ulp=1024,
     )
 
 

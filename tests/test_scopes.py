@@ -39,7 +39,6 @@ from distributed_training_comparison_tpu.train import (
     create_train_state,
     make_chunk_runner,
     make_device_chunk_runner,
-    make_epoch_runner,
     make_eval_runner,
     make_eval_step,
     make_train_step,
@@ -192,7 +191,6 @@ def _tiny_args(mesh, name):
         "chunk_runner": (state, x.reshape(4, 16, 32, 32, 3),
                          y.reshape(4, 16), key, zero),
         "device_chunk_runner": (state, x, y, key, zero, zero),
-        "epoch_runner": (state, x, y, key, zero),
     }[name]
 
 
@@ -205,8 +203,6 @@ def _make(mesh, name, monitor):
             mesh, donate=False, monitor=monitor),
         "device_chunk_runner": lambda: make_device_chunk_runner(
             mesh, 16, 2, donate=False, monitor=monitor),
-        "epoch_runner": lambda: make_epoch_runner(
-            mesh, 16, donate=False, monitor=monitor),
     }[name]()
 
 
@@ -232,7 +228,6 @@ EVENT_NAMES = {
     "train_step": "train_step", "eval_step": "eval_step",
     "eval_runner": "eval_runner", "chunk_runner": "chunk_runner",
     "device_chunk_runner": "device_chunk_runner@k2",
-    "epoch_runner": "epoch_runner",
 }
 
 

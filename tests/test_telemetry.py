@@ -834,7 +834,16 @@ def test_e2e_metrics_events_and_flight_ring(tmp_path):
     for name in ("train/grad_norm", "train/loss"):
         summ = histogram_summary(merged[name])
         assert summ is not None and summ["count"] == trained, (name, summ)
-        assert summ["p50"] <= summ["p95"] <= summ["p99"] <= summ["max"]
+        # like with like: the quantiles as computed are ordered and clamped
+        # to the recorded max; the table rounds them to six digits and
+        # leaves max as recorded, so a rounded p99 may exceed it
+        q50, q95, q99 = (
+            histogram_quantile(merged[name], q) for q in (0.50, 0.95, 0.99)
+        )
+        assert q50 <= q95 <= q99 <= merged[name]["max"] == summ["max"]
+        assert (summ["p50"], summ["p95"], summ["p99"]) == (
+            round(q50, 6), round(q95, 6), round(q99, 6)
+        )
     # the step-phase sketches ride the same stream (one sample per chunk).
     # The FIRST dispatch carried the epoch runner's jit compile, so the
     # compile monitor's taint reroutes it to step/dispatch_compile_s —
@@ -888,29 +897,6 @@ def test_e2e_no_flight_ring_flag_writes_no_ring(tmp_path):
     finally:
         trainer.close()
     assert not list((tmp_path / "version-0").glob("flight*.ring"))
-
-
-@pytest.mark.obs
-@pytest.mark.slow
-@pytest.mark.perf
-def test_bench_obs_overhead_within_budget(tmp_path, monkeypatch):
-    """The --obs-overhead leg's assertion: the per-step record path stays
-    under the stated budget relative to a telemetry-off loop, and the
-    capture's flush events pass ``run_report --check``."""
-    import bench
-
-    record = bench.bench_obs_overhead(
-        out_path=str(tmp_path / "BENCH_OBS.json"), steps=20_000
-    )
-    assert record["within_budget"], record
-    assert record["events_check_rc"] == 0
-    assert record["flushes"] > 0
-    # the compile-capture leg (PR 8): the instrumented dispatch path's
-    # per-step price holds the same budget, and its observed compile is
-    # on the stream (events_check_rc above REQUIRES a compile event)
-    leg = record["compile_capture"]
-    assert leg["within_budget"], leg
-    assert leg["observed_compiles"] >= 1
 
 
 # ------------------------------------------------------------ config flags

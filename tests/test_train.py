@@ -23,7 +23,6 @@ from distributed_training_comparison_tpu.train import (
     create_train_state,
     load_checkpoint,
     load_resume_state,
-    make_epoch_runner,
     make_eval_runner,
     make_eval_step,
     make_train_step,
@@ -34,6 +33,8 @@ from distributed_training_comparison_tpu.train.checkpoint import (
     find_best_checkpoint,
     find_version_dir,
 )
+
+from conftest import whole_epoch_runner
 
 
 class TinyNet(lnn.Module):
@@ -105,7 +106,7 @@ def test_epoch_runner_convergence_and_determinism(mesh, tiny_data):
     over epochs on learnable synthetic data (the convergence smoke test the
     reference never had, SURVEY.md §4)."""
     x, y = tiny_data
-    runner = make_epoch_runner(mesh, batch_size=64)
+    runner = whole_epoch_runner(mesh, 64, len(x))
 
     def run(n_epochs):
         state = _fresh_state(mesh)
@@ -125,7 +126,7 @@ def test_epoch_runner_convergence_and_determinism(mesh, tiny_data):
 @pytest.mark.slow
 def test_epoch_runner_epochs_differ(mesh, tiny_data):
     x, y = tiny_data
-    runner = make_epoch_runner(mesh, batch_size=64)
+    runner = whole_epoch_runner(mesh, 64, len(x))
     state = _fresh_state(mesh)
     key = jax.random.key(7)
     _, s0 = runner(_fresh_state(mesh), x, y, key, jnp.asarray(0))

@@ -222,3 +222,47 @@ def test_best_only_snapshot_copies_only_what_the_save_writes(run_dir):
     for a, b in zip(jax.tree_util.tree_leaves(whole.opt_state),
                     jax.tree_util.tree_leaves(state.opt_state)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------- the seam the benchmark's clock uses
+
+
+@pytest.mark.parametrize("data_mode", ["device", "host"])
+def test_lowering_epoch_at_an_epoch_start_makes_it_the_last(tmp_path, data_mode):
+    """``benchmark/harness/window.py`` ends a run by lowering
+    ``trainer.hparams.epoch`` from a subscriber on ``trainer.bus`` at an
+    ``epoch_start``: the epoch that has just started must then run as the
+    job's last — its ``epoch_end``, the final save, ``run_end`` and
+    ``close()`` all happen, and no further epoch starts."""
+    from flax import serialization
+
+    hp = _hparams(tmp_path, (
+        "--epoch", "1000", "--data-mode", data_mode,
+        "--save-last-min-secs", "0", "--no-progress",
+    ))
+    trainer = Trainer(hp, model=TinyNet(num_classes=100))
+    seen = []
+
+    def on_event(ev):
+        seen.append((ev["kind"], ev.get("epoch")))
+        if ev["kind"] == "epoch_start" and ev["epoch"] == 2:
+            trainer.hparams.epoch = 3
+
+    trainer.bus.subscribe(on_event)
+    try:
+        version = trainer.fit()
+    finally:
+        trainer.close()
+    assert [e for k, e in seen if k == "epoch_start"] == [0, 1, 2]
+    assert [e for k, e in seen if k == "epoch_end"] == [0, 1, 2]
+    kinds = [k for k, _ in seen]
+    assert kinds.index("run_end") > max(
+        i for i, k in enumerate(kinds) if k == "epoch_end"
+    )
+    last = serialization.msgpack_restore(
+        (tmp_path / f"version-{version}" / "last.ckpt").read_bytes()
+    )
+    assert last["epoch"] == 2  # the final save is the lowered epoch's
+    # close() joined the checkpoint writer: nothing is left in flight
+    writer = trainer.ckpt_writer
+    assert writer is None or not writer._thread.is_alive()

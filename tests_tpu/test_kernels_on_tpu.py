@@ -239,7 +239,7 @@ def test_vit_moe_train_step():
 
 def test_vit_long_train_step():
     """One vit_long train step at its design point (4096 tokens, batch 8,
-    256px) — the bench.py --smoke check as a pytest."""
+    256px); ``chip_smoke.py`` trains the same model through ``Trainer``."""
     from distributed_training_comparison_tpu import models, parallel
     from distributed_training_comparison_tpu.data import synthetic_dataset
     from distributed_training_comparison_tpu.train import (

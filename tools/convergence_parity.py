@@ -172,7 +172,7 @@ def run_flax_torch_init(args) -> dict:
     from distributed_training_comparison_tpu.train import (
         configure_optimizers,
         create_train_state,
-        make_epoch_runner,
+        make_device_chunk_runner,
         make_eval_runner,
     )
     from distributed_training_comparison_tpu.utils import (
@@ -205,11 +205,15 @@ def run_flax_torch_init(args) -> dict:
     state = jax.device_put(state, repl)
     di = jax.device_put(jnp.asarray(train.images), repl)
     dl = jax.device_put(jnp.asarray(train.labels), repl)
-    runner = make_epoch_runner(mesh, hp.batch_size, precision="fp32", augment=True)
+    # one dispatch an epoch, as Trainer's device mode runs by default
+    runner = make_device_chunk_runner(
+        mesh, hp.batch_size, len(train) // hp.batch_size,
+        precision="fp32", augment=True,
+    )
     key = jax.random.key(hp.seed)
     t0 = time.perf_counter()
     for e in range(args.epochs):
-        state, stacked = runner(state, di, dl, key, jnp.asarray(e))
+        state, stacked = runner(state, di, dl, key, jnp.asarray(e), jnp.asarray(0))
     float(stacked["loss"][-1])  # sync
 
     ev = make_eval_runner(mesh, hp.batch_size, precision="fp32")

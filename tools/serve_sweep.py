@@ -1,7 +1,6 @@
 """Bucket-ladder × arrival-rate sweep for the serving engine.
 
-The serving analogue of ``tools/mfu_sweep.py``: one-factor-at-a-time
-evidence for the README's serving analysis.  Each cell builds an engine
+One-factor-at-a-time evidence for the README's serving analysis.  Each cell builds an engine
 with one bucket ladder, drives it open-loop at one Poisson rate, and
 prints a JSON line — so the latency-vs-load curve and the effect of
 bucket granularity (fine ladders pad less but compile more programs and
