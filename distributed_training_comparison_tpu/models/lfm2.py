@@ -198,9 +198,9 @@ class GQAttention(nn.Module):
         v = _dense(self.kv_heads * hd, self.dtype, "v_proj")(h).reshape(b, s, self.kv_heads, hd)
         q = rope(RMSNorm(self.eps, self.dtype, name="q_norm")(q), self.theta)
         k = rope(RMSNorm(self.eps, self.dtype, name="k_norm")(k), self.theta)
-        # each key-value head serves heads // kv_heads consecutive queries
-        rep = self.heads // self.kv_heads
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        # each key-value head serves heads // kv_heads consecutive queries:
+        # the dispatcher takes the heads as they are (the flash kernels read
+        # head h // group by index, every other path repeats them there)
         o = attention(q, k, v, causal=True, layout="bshd", impl=self.attn_impl)
         return _dense(self.dim, self.dtype, "o_proj")(o.reshape(b, s, self.dim))
 

@@ -25,32 +25,67 @@ Kernel design (TPU-first, not a CUDA translation):
   softmax statistics (``lse``, ``delta``) are carried as (bh, S, 8) arrays
   — the row value broadcast across a stub minor dim — because TPU block
   shapes must tile to (8, 128) unless a block dim spans the whole array.
-- Backward is the standard two-kernel flash backward (one writing dq, one
-  writing dk/dv) over saved ``(out, lse)`` residuals, wired via
-  ``jax.custom_vjp``.  Both backward kernels are **fully tiled**: a 3D grid
-  (batch·heads, own block, streamed block) accumulates into the revisited
-  fp32 output block across the innermost grid dimension, so the only
-  VMEM residents are fixed-size tiles — never a whole-sequence array.
-  (Round 3 shipped a backward that kept whole-sequence Q/dO in VMEM per
-  grid instance behind a hand-written footprint formula; the formula
-  mis-predicted Mosaic's stack accounting twice and OOMed scoped VMEM at
-  S=4096, D=128, bh=32.  Tiling by grid makes the footprint small and
-  static — there is nothing left to predict.)
+- Backward, wired via ``jax.custom_vjp`` over saved ``(out, lse)``
+  residuals, in one of two forms that :func:`flash_plan` chooses from the
+  call's shapes.  **Fused** (``_bwd_fused_kernel``; calls whose q, dO and
+  dq fit VMEM whole — 4,096 tokens in bf16): one kernel takes the scores,
+  the probabilities and dO·vᵀ once a visited tile and writes dq, dk and dv
+  — five matmuls and one ``exp`` a score element.  **Two tiled kernels**
+  (``_dq_kernel``, ``_dkv_kernel``; any length): a 3D grid (batch·heads,
+  own block, streamed block) accumulates into fp32 scratch across the
+  innermost grid dimension, so the only VMEM residents are fixed-size
+  tiles — seven matmuls and two ``exp``.  (Round 3 shipped a backward
+  that kept whole-sequence Q/dO in VMEM behind a hand-written footprint
+  formula that mis-predicted Mosaic's stack accounting and OOMed scoped
+  VMEM at S=4096, D=128, bh=32; the fused kernel's residents are blocks
+  and scratch Pallas itself allocates, inside the scoped VMEM a kernel
+  gets unasked, and ``tests/test_tpu_compile.py`` compiles it at its
+  largest shapes.)
+- **The causal tile plan** (PR 30).  A causal call does the lower
+  triangle's work once: square tiles tight to the diagonal ((512, 512)
+  visits 56 % of a 4,096-key square where 128-row query tiles under
+  2,048-key blocks visited 75 %, two thirds of it through the mask), the
+  mask only on tiles that straddle the diagonal or the padding, and a tile
+  above the diagonal costs nothing — the resident forward and the fused
+  backward loop over visited tiles inside the kernel, the tiled kernels'
+  index maps name, for a skipped step, the block the neighbouring visited
+  step holds, and Pallas issues no DMA when a block index repeats.
+- **Head size and grouped heads as they are.**  A head size that divides
+  the 128 lanes goes in unpadded (a block whose minor dim spans the array),
+  and ``k`` / ``v`` may hold one head for ``group`` consecutive query
+  heads: index maps read head ``h // group`` and the fused backward sums
+  the group's dk / dv in VMEM.
 
-The *forward* has two shapes: up to ~8k keys (D=128, bf16) whole-sequence
-K/V live in VMEM per (batch, head) instance — 2·S·D·2 bytes, loaded once
+The *forward* has two shapes: up to ~8k keys (bf16) whole-sequence K/V
+live in VMEM per (batch, head) instance — 2·S·128·2 bytes, loaded once
 and reused across every query block, the bandwidth-optimal layout.  Past
 the ``_FWD_RESIDENT_KV_LIMIT`` footprint the wrapper switches to a fully
 tiled (bh, nq, nk) grid carrying the online-softmax state (acc, running
 max/sum) in fp32 VMEM scratch — K/V re-stream once per query block, and S
 is bounded by HBM, not VMEM.  Beyond one chip's HBM, shard S over the
 mesh with ring attention (``parallel/ring.py``).
+
+Measured on one v5e chip (PR 30; ``PERF.md`` §6 has the table; ms a call,
+forward + recomputed forward + backward under ``jax.checkpoint`` with the
+layout changes around the kernels).  The token cell's layer — 4 x 32 query
+heads on 8 key-value heads, 4,096 causal keys, head size 64: 46.8 -> 26.5
+(forward kernel 7.35 -> 5.99 a call, backward 14.4 + 9.3 -> 10.5, and 9.9
+with q, dO and dq double-buffered under a 32 MiB ``vmem_limit_bytes``,
+which cost the rest of the train program more than it gave:
+``_FUSED_BWD_RESIDENT_LIMIT``; upstream's splash attention with its fused
+backward 26.8 at 1,024-blocks, 29.5 at 512).  Head size 128, 8 x 4 heads,
+4,096 keys: non-causal 13.7 -> 11.6, causal 11.8 -> 8.1.  16,384 keys (streamed forward, two tiled backward
+kernels): non-causal 27.8 -> 27.7, causal 18.2 -> 16.7.  What bounds a
+512 x 512 tile at head size 64 is neither the half-filled MXU alone nor the
+VPU's multiplies (folding the scale into q moved nothing): 1.3 us in the
+forward and 2.2 in the fused backward for 262 k exponentials a tile.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -219,13 +254,16 @@ def _scores(qb, kb, scale):
     ) * scale
 
 
-def _block_mask(i, j, block_q, block_k, kv_len, causal):
-    """Validity mask for score block (i, j) from *static* true kv length."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+def _block_mask(i, j, block_q, block_k, kv_len, causal, keys_down=False):
+    """Validity mask for score block (i, j) from *static* true kv length:
+    (block_q, block_k), or its transpose with ``keys_down`` (the fused
+    backward's tiles have keys on the sublanes)."""
+    shape = (block_k, block_q) if keys_down else (block_q, block_k)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if keys_down else 1)
     cols = cols + j * block_k
     mask = cols < kv_len
     if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if keys_down else 0)
         mask = mask & (rows + i * block_q >= cols)
     return mask
 
@@ -234,6 +272,19 @@ def _causal_nk(i, block_q, block_k, nk_total):
     """Number of key blocks at/below the diagonal of query block ``i``."""
     hi = jnp.minimum((i + 1) * block_q + block_k - 1, nk_total * block_k)
     return hi // block_k
+
+
+def _causal_first_q(j, block_q, block_k):
+    """First query block with a row at/below the diagonal of key block
+    ``j``: tile (i, j) is visited iff ``i >= _causal_first_q(j)`` iff
+    ``j < _causal_nk(i)``."""
+    return (j * block_k) // block_q
+
+
+def _causal_free_q(j, block_q, block_k):
+    """First query block wholly at/below key block ``j``'s last column:
+    from here on a causal tile needs no mask."""
+    return ((j + 1) * block_k - 1 + block_q - 1) // block_q
 
 
 def _mask_split(i, j, block_q, block_k, kv_len, causal):
@@ -310,6 +361,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, 
 _FWD_RESIDENT_KV_LIMIT = 4 * 2**20
 
 
+def _kv_resident(skv, d, dtype) -> bool:
+    """Whole-sequence K/V fit the forward's VMEM budget.  In VMEM a row is
+    whole 128-lane tiles whatever the head size."""
+    footprint = 2 * skv * _ceil_to(d, 128) * jnp.dtype(dtype).itemsize
+    return footprint <= _FWD_RESIDENT_KV_LIMIT
+
+
 def _fwd_kernel_tiled(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
     *, scale, causal, kv_len,
@@ -379,7 +437,7 @@ def _fwd_kernel_tiled(
         )
 
 
-def _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret):
+def _flash_fwd_tiled(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     bh, sq, d = q3.shape
     skv = k3.shape[1]
     # Wide query tiles amortize the streamed K/V re-read (HBM traffic
@@ -387,11 +445,19 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret):
     # 2048 alone lifts the streamed forward 54 → 73 TF/s.  VMEM at
     # bq=2048: q/out blocks 0.5 MiB each + fp32 acc scratch 1 MiB —
     # comfortably inside the ~4 MiB the rest of the pipeline budgets.
-    bq = _stream_block(sq, max(block_q, 2048))
+    bq = _stream_block(sq, max(plan.block_q, 2048))
     # bk=1024 with this bq OOMs scoped VMEM (18.6 MiB vs the 16 MiB limit
     # with Mosaic's double buffering); 512 fits and the K/V re-read
     # traffic is governed by bq, not bk
     bk = _stream_block(skv, 512)
+
+    def kv_map(b, i, j):
+        if causal:
+            # a step above the diagonal names the block the last visited
+            # step held: Pallas issues no DMA when a block index repeats
+            j = jnp.minimum(j, _causal_nk(i, bq, bk, skv // bk) - 1)
+        return (b // group, j, 0)
+
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel_tiled, scale=scale, causal=causal, kv_len=kv_len
@@ -399,8 +465,8 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret):
         grid=(bh, sq // bq, skv // bk),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, bk, d), kv_map),
+            pl.BlockSpec((None, bk, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
@@ -423,22 +489,28 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret):
     return out, lse
 
 
-def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret):
+def _flash_fwd(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
+    """``q3`` is (batch · heads, S, d); ``k3``/``v3`` hold one head for
+    every ``group`` consecutive query heads, read by index map."""
     bh, sq, d = q3.shape
     skv = k3.shape[1]
-    if 2 * skv * d * q3.dtype.itemsize > _FWD_RESIDENT_KV_LIMIT:
+    if not _kv_resident(skv, d, q3.dtype):
         # resident K/V would crowd VMEM: stream tiles instead (HBM cost:
         # K/V re-read once per query block — amortized by the q tile size)
-        return _flash_fwd_tiled(q3, k3, v3, scale, causal, block_q, kv_len, interpret)
+        return _flash_fwd_tiled(
+            q3, k3, v3, scale, causal, plan, kv_len, group, interpret
+        )
+    block_q = plan.block_q
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_k=block_k, kv_len=kv_len
+            _fwd_kernel, scale=scale, causal=causal, block_k=plan.block_k,
+            kv_len=kv_len,
         ),
         grid=(bh, sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, skv, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, skv, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, skv, d), lambda b, i: (b // group, 0, 0)),
+            pl.BlockSpec((None, skv, d), lambda b, i: (b // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
@@ -596,24 +668,27 @@ def _stream_block(n: int, target: int) -> int:
     return b
 
 
-def _flash_bwd(q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpret):
-    """Two fully-tiled backward kernels.  The backward streams its own
-    (512, 512) tiles, independent of the forward's blocks — per-instance
-    VMEM is a handful of fixed-size blocks (~6 MiB at D=128) regardless of
-    sequence length, which is what fixed the round-3 scoped-VMEM OOM at
-    S=4096, bh=32.  Tile sweep on a v5e at S=4096, D=128 (fwd+bwd TF/s,
-    non-causal / causal): (256,512) 62.8/35.3, (512,512) 68.6/38.9,
-    (256,2048) 71.4/— but ~13 MiB of temps; (512,512) takes the 4%
-    haircut for VMEM headroom and is the causal optimum."""
+def _flash_bwd_split(
+    q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len, interpret
+):
+    """Two fully-tiled backward kernels, one key-value head a query head:
+    what a call gets whose q, dO and dq do not fit VMEM whole
+    (:func:`flash_plan`).  They stream their own (512, 512) tiles,
+    independent of the forward's blocks — per-instance VMEM is a handful
+    of fixed-size blocks (~6 MiB at D=128) regardless of sequence length.
+    Tile sweep on a v5e at S=4096, D=128, older than the growth PRs
+    (fwd+bwd TF/s, non-causal / causal): (256,512) 62.8/35.3, (512,512)
+    68.6/38.9, (256,2048) 71.4/— but ~13 MiB of temps.  PR 30 at head
+    size 64, 4,096 causal keys, 128 heads (ms a call with both forwards):
+    these two kernels 44.0, with skipped steps fetching nothing 38.6 —
+    44 % of the streamed K/V and Q/dO traffic was for tiles above the
+    diagonal — and the fused kernel in their place 29.9."""
     bh, sq, d = q3.shape
     skv = k3.shape[1]
-    delta = jnp.sum(
-        do3.astype(jnp.float32) * out3.astype(jnp.float32), axis=-1
-    )  # (bh, sq) → (bh, sq, 8) stub minor dim, matching lse's layout
+    # (bh, sq) → (bh, sq, 8) stub minor dim, matching lse's layout
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, 8))
 
-    bq = _stream_block(sq, 512)
-    bk = _stream_block(skv, 512)
+    bq, bk = plan.bwd_block_q, plan.bwd_block_k
     nq, nk = sq // bq, skv // bk
     # bh and the own-block grid dims are independent; only the innermost
     # (streaming, accumulating) dim must execute in order
@@ -621,13 +696,26 @@ def _flash_bwd(q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpre
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
+    # A step the causal gate skips names the block its neighbouring visited
+    # step holds, and Pallas issues no DMA when a block index repeats: the
+    # tiles above the diagonal cost a grid step and no fetch.
+    def kv_of_q(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _causal_nk(i, bq, bk, nk) - 1)
+        return (b, j, 0)
+
+    def q_of_kv(b, j, i):
+        if causal:
+            i = jnp.maximum(i, jnp.minimum(_causal_first_q(j, bq, bk), nq - 1))
+        return (b, i, 0)
+
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, kv_len=kv_len),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, bk, d), kv_of_q),
+            pl.BlockSpec((None, bk, d), kv_of_q),
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bq, 8), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bq, 8), lambda b, i, j: (b, i, 0)),
@@ -646,11 +734,11 @@ def _flash_bwd(q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpre
         in_specs=[
             pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, 8), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, 8), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, bq, 8), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((None, bq, d), q_of_kv),
+            pl.BlockSpec((None, bq, d), q_of_kv),
+            pl.BlockSpec((None, bq, 8), q_of_kv),
+            pl.BlockSpec((None, bq, 8), q_of_kv),
+            pl.BlockSpec((None, bq, 8), q_of_kv),
         ],
         out_specs=[
             pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0)),
@@ -670,35 +758,271 @@ def _flash_bwd(q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpre
     return dq, dk, dv
 
 
+def _bwd_fused_kernel(
+    q_ref, do_ref, lse_ref, adj_ref, k_ref, v_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, *kv_acc, scale, causal, block_q, kv_len, group,
+):
+    """dq, dk and dv of one key block ``j`` against one query head, the
+    scores, the probabilities and dO·vᵀ taken once a visited tile.  Grid
+    (batch · key-value heads, query heads a key-value head, key blocks):
+    the head's q and dO are whole in VMEM (fetched once a head), and the
+    query tiles at/below key block ``j``'s diagonal are a loop inside the
+    kernel — a tile above the diagonal is neither a grid step nor a fetch.
+    Tiles have keys on the sublanes (``sᵀ = k qᵀ``): dv and dk are plain
+    ``(bk, bq) x (bq, d)`` products and only dq contracts over the
+    sublanes, where the two-kernel form has two such contractions.  dq
+    accumulates over key blocks in ``dq_acc`` (float32, whole sequence),
+    dk and dv over the heads of a group in ``kv_acc`` (float32, whole
+    sequence; no scratch where a key-value head serves one query head)."""
+    block_k, d = k_ref.shape
+    nq = q_ref.shape[0] // block_q
+    g, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    kb, vb = k_ref[...], v_ref[...]
+
+    def tile(i, carry, *, masked):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        qb, dob = q_ref[rows, :], do_ref[rows, :]
+        pt = jnp.exp(_scores(kb, qb, scale) - lse_ref[pl.ds(i, 1), :])
+        if masked:
+            mask = _block_mask(
+                i, j, block_q, block_k, kv_len, causal, keys_down=True
+            )
+            pt = jnp.where(mask, pt, 0.0)
+        dv = dv + jax.lax.dot_general(
+            pt.astype(dob.dtype), dob, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            vb, dob, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        # the row terms as in ``_dq_kernel``: -delta from the out
+        # cotangent, +dlse from the lse cotangent, folded by the wrapper
+        dst = (pt * (dpt + adj_ref[pl.ds(i, 1), :]) * scale).astype(qb.dtype)
+        dk = dk + jax.lax.dot_general(
+            dst, qb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[rows, :] += jax.lax.dot_general(
+            dst, kb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dk, dv
+
+    # masked tiles first (they straddle the diagonal; with padded keys in
+    # this block, every tile), then the mask-free ones below them
+    lo = _causal_first_q(j, block_q, block_k) if causal else 0
+    free = _causal_free_q(j, block_q, block_k) if causal else 0
+    free = jnp.where((j + 1) * block_k > kv_len, nq, free)
+    free = jnp.clip(free, lo, nq)
+    zeros = jnp.zeros((block_k, d), jnp.float32)
+    carry = jax.lax.fori_loop(
+        lo, free, functools.partial(tile, masked=True), (zeros, zeros)
+    )
+    dk, dv = jax.lax.fori_loop(
+        free, nq, functools.partial(tile, masked=False), carry
+    )
+
+    if group == 1:
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+    else:
+        dk_acc, dv_acc = kv_acc
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+        @pl.when(g == 0)
+        def _first_head():
+            dk_acc[keys, :] = dk
+            dv_acc[keys, :] = dv
+
+        @pl.when(g > 0)
+        def _next_head():
+            dk_acc[keys, :] += dk
+            dv_acc[keys, :] += dv
+
+        @pl.when(g == group - 1)
+        def _write_kv():
+            dk_ref[...] = dk_acc[keys, :].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[keys, :].astype(dv_ref.dtype)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _write_q():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+# What ``flash_plan`` lets the fused backward keep whole in VMEM: q, dO and
+# dq (single-buffered: they move once a head) and the float32 accumulators.
+# With ~6 MiB of score-tile temporaries a call at this limit compiles inside
+# the 16 MiB of scoped VMEM a kernel gets unasked — and asking for more is
+# not free: under ``vmem_limit_bytes`` of 32 MiB the train program's
+# bandwidth-bound fusions *outside* the kernel ran slower (PR 30: the selects
+# around the expert layer's kernels, +2.8 ms a step in four layers that do
+# not touch attention), the compiler having less VMEM left to prefetch into.
+_FUSED_BWD_RESIDENT_LIMIT = 9 * 2**20
+
+
+def _fused_bwd_resident_bytes(sq, skv, d, group, dtype) -> int:
+    lanes, item = _ceil_to(d, 128), jnp.dtype(dtype).itemsize
+    whole = sq * lanes * (2 * item + item + 4)  # q, dO; dq; dq_acc
+    return whole + (2 * skv * lanes * 4 if group > 1 else 0)
+
+
+def _flash_bwd_fused(
+    q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len, group,
+    interpret,
+):
+    bh, sq, d = q3.shape
+    bkv, skv = k3.shape[:2]
+    bq, bk = plan.bwd_block_q, plan.bwd_block_k
+    nq, nk = sq // bq, skv // bk
+    # per-row terms with the rows on the lanes, a query tile a sublane row
+    lse_rows = lse[:, :, 0].reshape(bh, nq, bq)
+    adj_rows = (dlse[:, :, 0] - delta).reshape(bh, nq, bq)
+
+    def head(b, g, j):
+        return (b * group + g, 0, 0)
+
+    def key_block(b, g, j):
+        return (b, j, 0)
+
+    def key_block_out(b, g, j):
+        # the block leaves VMEM when its index moves: hold block 0 until
+        # the group's last head, whose steps write each block in turn
+        return (b, jnp.where(g == group - 1, j, 0), 0)
+
+    # one buffer each: a second would only hide a head's 1.5 MiB of q, dO
+    # and dq behind the eight key blocks before it, at 3 MiB of VMEM
+    whole = pl.BlockSpec((None, sq, d), head, pipeline_mode=pl.Buffered(1))
+    rows = pl.BlockSpec((None, nq, bq), head)
+    tile = pl.BlockSpec((None, bk, d), key_block)
+    tile_out = pl.BlockSpec(
+        (None, bk, d), key_block_out if group > 1 else key_block
+    )
+    kv_acc = [pltpu.VMEM((skv, d), jnp.float32)] * 2 if group > 1 else []
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq,
+            kv_len=kv_len, group=group,
+        ),
+        grid=(bkv, group, nk),
+        in_specs=[whole, whole, rows, rows, tile, tile],
+        out_specs=[whole, tile_out, tile_out],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+            jax.ShapeDtypeStruct((bkv, skv, d), k3.dtype),
+            jax.ShapeDtypeStruct((bkv, skv, d), v3.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32), *kv_acc],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
+    )(q3, do3, lse_rows, adj_rows, k3, v3)
+
+
 # ----------------------------------------------------- custom_vjp plumbing
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_core(q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret):
+def _flash_core(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     """Returns ``(out3, lse3)``; both are differentiable outputs (the lse
     cotangent folds into the backward kernels as an extra ``p·dlse`` term),
     which is what lets ring attention differentiate through its
     online-softmax combination of per-shard partials."""
-    return _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret)
+    return _flash_fwd(q3, k3, v3, scale, causal, plan, kv_len, group, interpret)
 
 
-def _flash_core_fwd(q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret):
+def _flash_core_fwd(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     out, lse = _flash_fwd(
-        q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret
+        q3, k3, v3, scale, causal, plan, kv_len, group, interpret
     )
     return (out, lse), (q3, k3, v3, out, lse)
 
 
-def _flash_core_bwd(scale, causal, block_q, block_k, kv_len, interpret, res, cots):
+def _flash_core_bwd(scale, causal, plan, kv_len, group, interpret, res, cots):
     q3, k3, v3, out3, lse = res
     do3, dlse = cots
-    dq, dk, dv = _flash_bwd(
-        q3, k3, v3, out3, lse, do3, dlse, scale, causal, kv_len, interpret
+    # softmax's row term Σ_k dp∘p = Σ_d dO∘O, (bh, sq): no pass over scores
+    delta = jnp.sum(
+        do3.astype(jnp.float32) * out3.astype(jnp.float32), axis=-1
     )
-    return dq, dk, dv
+    if plan.fused_bwd:
+        return tuple(_flash_bwd_fused(
+            q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len,
+            group, interpret,
+        ))
+    assert group == 1, "the two tiled kernels have one key-value head a query head"
+    return _flash_bwd_split(
+        q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len,
+        interpret,
+    )
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+# the smallest head size that goes in unpadded: 64 is what the chip has run
+# (32 compiles for a described v5e and has met no chip)
+_MIN_UNPADDED_HEAD = 64
+
+
+class FlashPlan(NamedTuple):
+    """What one :func:`flash_attention` call visits, at what size, and what
+    it holds in VMEM (:func:`flash_plan`)."""
+
+    block_q: int  # forward query tile; the query length is padded to it
+    block_k: int  # forward key tile; the key length is padded to it
+    head: int  # head size the kernels see: the call's own, or padded to lanes
+    fused_bwd: bool  # one backward kernel (``_bwd_fused_kernel``) or two tiled
+    bwd_block_q: int
+    bwd_block_k: int
+
+
+def flash_plan(
+    sq: int, skv: int, d: int, group: int, causal: bool, dtype,
+    block_q: int | None = None, block_k: int | None = None,
+) -> FlashPlan:
+    """The tile plan of a call, a pure function of its shapes: which score
+    tiles it visits, at what size, how often, and what it keeps in VMEM.
+    ``block_q`` / ``block_k`` are the caller's override of the forward
+    tiles.  The backward is the fused kernel wherever its whole-sequence
+    residents fit (``_FUSED_BWD_RESIDENT_LIMIT``), causal or not — at
+    4,096 keys and head size 128 it takes 0.8 x the two kernels' time
+    non-causal and 0.7 x causal (module docstring) — and the two tiled
+    kernels beyond."""
+    chosen = block_q is None and block_k is None
+    skv_128 = _ceil_to(skv, 128)
+    if causal and chosen:
+        # tiles tight to the diagonal: square, so that a query tile's sweep
+        # ends on the one tile that straddles it — (512, 512) visits 56 %
+        # of a 4,096-key square, one tile in 4.5 of them masked
+        block_q = block_k = next(c for c in (512, 256, 128) if skv_128 % c == 0)
+    if block_q is None:
+        block_q = 128
+    if block_k is None:
+        block_k = next(
+            c for c in (2048, 1024, 512, 256, 128)
+            if c <= skv_128 and skv_128 % c == 0
+        )
+    sq_p, skv_p = _ceil_to(sq, block_q), _ceil_to(skv, block_k)
+    # a head size that divides the lanes goes in as it is (the block's
+    # minor dim spans the array): the zero lanes of a padded head are HBM
+    # and VMEM traffic the MXU gains nothing from
+    head = d if 128 % d == 0 and d >= _MIN_UNPADDED_HEAD else _ceil_to(d, 128)
+    target = 512 if chosen else min(512, max(128, block_q, block_k))
+    bwd_q, bwd_k = _stream_block(sq_p, target), _stream_block(skv_p, target)
+    fused = (
+        (sq_p == skv_p or not causal)
+        and _fused_bwd_resident_bytes(sq_p, skv_p, head, group, dtype)
+        <= _FUSED_BWD_RESIDENT_LIMIT
+    )
+    return FlashPlan(block_q, block_k, head, fused, bwd_q, bwd_k)
 
 
 def flash_attention(
@@ -708,57 +1032,91 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: float | None = None,
-    block_q: int = 128,
+    block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
     return_lse: bool = False,
 ):
-    """Pallas flash attention over (B, H, S, D), differentiable.
+    """Pallas flash attention over (B, H, S, D), differentiable.  ``k`` and
+    ``v`` may hold fewer heads than ``q`` — (B, H // group, S, D), each
+    serving ``group`` consecutive query heads.
 
-    Pads S to block multiples and D up to a lane multiple (128); the true
-    key length is masked inside the kernel, so padding never changes the
-    result.  ``interpret=True`` runs the same kernels through the Pallas
-    interpreter (CI on CPU).
+    Pads S to block multiples and, where :func:`flash_plan` says so, D up
+    to a lane multiple (128); the true key length is masked inside the
+    kernel, so padding never changes the result.  ``interpret=True`` runs
+    the same kernels through the Pallas interpreter (CI on CPU).
 
-    ``block_k=None`` picks the largest of {2048, 1024, 512, 256, 128} that
-    divides the padded key length: in the resident-K/V regime the kernel
-    loop over tiny key blocks is MXU-latency-bound (measured on a v5e at
-    S=2048: 19 TF/s with 128-wide key blocks vs 85-105 TF/s with
-    1-2k-wide), and K/V are whole-sequence VMEM residents there, so wide
-    blocks cost nothing extra.  Past ``_FWD_RESIDENT_KV_LIMIT`` the
-    streamed forward takes over and ``block_q``/``block_k`` only pin the
-    padding — the streamed tiles are chosen internally: ≤2048 query rows
-    (wide q tiles amortize the K/V re-read; ~2.5 MiB of blocks + fp32
-    scratch) by ≤512 keys.  The backward always streams its own
-    (≤512, ≤512) tiles.
+    ``block_q`` / ``block_k`` override the plan's forward tiles (tests use
+    them).  Left alone, a causal call gets square tiles tight to the
+    diagonal — the largest of {512, 256, 128} that divides the padded
+    length — and a non-causal call 128 query rows under the largest of
+    {2048, ..., 128} keys that divides it: with K/V whole-sequence VMEM
+    residents the loop over tiny key blocks is MXU-latency-bound (measured
+    on a v5e at S=2048: 19 TF/s with 128-wide key blocks vs 85-105 TF/s
+    with 1-2k-wide) and wide blocks cost nothing extra — unless the call is
+    causal, where a 2,048-key block under a 128-row tile is mostly mask
+    (PR 30: the forward at 4,096 causal keys, head size 64, 7.35 ms under
+    (128, 2048), 5.99 under (512, 512) or (256, 512), 8.3 under
+    (1024, 512), 11.9 under (256, 256)).  Past ``_FWD_RESIDENT_KV_LIMIT``
+    the streamed forward takes over and the blocks only pin the padding —
+    its tiles are chosen internally: ≤2048 query rows (wide q tiles
+    amortize the K/V re-read) by ≤512 keys.
     """
     b, h, sq, d = q.shape
-    skv = k.shape[2]
+    hkv, skv = k.shape[1], k.shape[2]
     if causal and sq != skv:
         raise ValueError("causal flash attention requires q_len == kv_len")
-    scale = 1.0 / math.sqrt(d) if scale is None else scale
-
-    if block_k is None:
-        skv_128 = _ceil_to(skv, 128)
-        block_k = next(
-            c for c in (2048, 1024, 512, 256, 128)
-            if c <= skv_128 and skv_128 % c == 0
+    if h % hkv or v.shape[1] != hkv:
+        raise ValueError(
+            f"{h} query heads over {hkv} / {v.shape[1]} key / value heads"
         )
-    sq_p, skv_p = _ceil_to(sq, block_q), _ceil_to(skv, block_k)
-    d_p = _ceil_to(d, 128)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    group = h // hkv
+    plan = flash_plan(sq, skv, d, group, causal, q.dtype, block_q, block_k)
+    if group > 1 and not plan.fused_bwd:
+        # the two-kernel backward has one key-value head a query head
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        group = 1
+    sq_p, skv_p = _ceil_to(sq, plan.block_q), _ceil_to(skv, plan.block_k)
 
     def pad3(x, s_p):
-        x3 = x.reshape(b * h, x.shape[2], d)
-        return jnp.pad(x3, ((0, 0), (0, s_p - x.shape[2]), (0, d_p - d)))
+        x3 = x.reshape(-1, x.shape[2], d)
+        if s_p == x.shape[2] and plan.head == d:
+            return x3
+        return jnp.pad(x3, ((0, 0), (0, s_p - x.shape[2]), (0, plan.head - d)))
 
     out3, lse3 = _flash_core(
         pad3(q, sq_p), pad3(k, skv_p), pad3(v, skv_p),
-        scale, causal, block_q, block_k, skv, interpret,
+        scale, causal, plan, skv, group, interpret,
     )
     out = out3[:, :sq, :d].reshape(b, h, sq, d)
     if return_lse:
         return out, lse3[:, :sq, 0].reshape(b, h, sq)
     return out
+
+
+def _auto_takes_kernel(q, k, causal, seq_ax) -> bool:
+    """``impl="auto"``: the flash kernel on a TPU from the length at which
+    it beats the composed einsums."""
+    on_tpu = jax.default_backend() == "tpu"
+    # the kernel only supports square causal attention; offset-causal
+    # cross-attention stays on the reference path
+    kernel_ok = not causal or q.shape[seq_ax] == k.shape[seq_ax]
+    # Crossovers measured on a v5e against autodiff's backward of
+    # ``mha_reference``, in chip runs older than the growth PRs and than
+    # both sides' present backward (``_composed``'s own VJP, PR 25; the
+    # causal tile plan and the fused backward here, PR 30): the kernel won
+    # from S=512 at D=128 and, with half the MXU's lanes empty, from
+    # S=1024 at D=64.  PR 30 timed the kernel at 4,096 and 16,384 keys
+    # only, where the composed side cannot hold its scores; the thresholds
+    # stand until a cell sits near them (PERF.md §7).  (The short-sequence
+    # kernel in ops/attention_small.py is NOT auto-selected: standalone it
+    # wins the attention sub-graph, but at the model level XLA re-lays the
+    # custom-call boundaries and the end-to-end step loses — the winning
+    # fused form at short S is the whole-block kernel, ops/vit_block.py,
+    # which models/vit.py dispatches itself.)
+    min_seq = 512 if q.shape[-1] >= 128 else 1024
+    return on_tpu and kernel_ok and q.shape[seq_ax] >= min_seq
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, **options):
@@ -800,10 +1158,26 @@ def _attention(
     this boundary (amortized at the long lengths that select them)."""
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"unknown attention layout {layout!r}")
-    seq_ax = 1 if layout == "bshd" else 2
+    seq_ax, head_ax = (1, 2) if layout == "bshd" else (2, 1)
+    group, rest = divmod(q.shape[head_ax], k.shape[head_ax])
+    if rest or v.shape[head_ax] != k.shape[head_ax]:
+        raise ValueError(
+            f"{q.shape[head_ax]} query heads over {k.shape[head_ax]} / "
+            f"{v.shape[head_ax]} key / value heads"
+        )
 
     def to_bhsd(x):
         return x.transpose(0, 2, 1, 3) if layout == "bshd" else x
+
+    if impl == "auto":
+        impl = (
+            "pallas" if _auto_takes_kernel(q, k, causal, seq_ax)
+            else "reference"
+        )
+    if group > 1 and impl != "pallas":
+        # only the flash kernels read key-value head ``h // group`` by
+        # index; every other implementation sees one a query head
+        k, v = jnp.repeat(k, group, head_ax), jnp.repeat(v, group, head_ax)
 
     kind, _, axis = impl.partition(":")
     if kind in ("ring", "ulysses"):
@@ -818,35 +1192,6 @@ def _attention(
             axis_name=axis or "model", causal=causal, scale=scale,
         )
         return to_bhsd(out)  # transpose is its own inverse for these axes
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        # the kernel only supports square causal attention; offset-causal
-        # cross-attention stays on the reference path
-        kernel_ok = not causal or q.shape[seq_ax] == k.shape[seq_ax]
-        # A claim older than the composed branch's own backward
-        # (``_composed``): the ratios below were taken against autodiff's
-        # backward of ``mha_reference``, in chip runs older than the growth
-        # PRs.  From 256 tokens up the composed side is faster now, so the
-        # crossovers are for the kernel issue to measure again (PERF.md §7).
-        # Measured fwd+bwd crossover on a v5e chip (bf16, batched so total
-        # tokens are constant), re-validated after the round-4 tiled
-        # backward cut bwd time ~17%: at D=128 the kernel wins from S=512
-        # (0.83x at 512, 0.64x at 1024, 0.52x at 2048; 1.6x at 256); at
-        # D=64 the half-filled MXU lanes push the crossover to S=1024
-        # (1.53x at 512, 0.93x/0.88x at 1024, 0.72x at 2048).  Below that,
-        # one fused XLA softmax over big batched matmuls beats the
-        # per-(batch, head) kernel grid.  (The short-sequence kernel in
-        # ops/attention_small.py is NOT auto-selected: standalone it wins
-        # the attention sub-graph, but at the model level XLA re-lays the
-        # custom-call boundaries and the end-to-end step loses — the
-        # winning fused form at short S is the whole-block kernel,
-        # ops/vit_block.py, which models/vit.py dispatches itself.)
-        min_seq = 512 if q.shape[-1] >= 128 else 1024
-        impl = (
-            "pallas"
-            if on_tpu and kernel_ok and q.shape[seq_ax] >= min_seq
-            else "reference"
-        )
     if impl in ("pallas", "fused_small"):
         note_kernel_path(
             "attention", "pallas-interpret" if interpret else "pallas"

@@ -314,6 +314,163 @@ def test_flash_lse_and_its_cotangent(causal):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-3, f"d{name}"
 
 
+def _attention_module():
+    import importlib
+
+    return importlib.import_module(
+        "distributed_training_comparison_tpu.ops.attention"
+    )
+
+
+# The token cell's call at a reduced length: head size 64, four query heads
+# a key-value head, causal.  640 is five of the plan's 128-key tiles (512
+# and 256 do not divide it), 600 pads to them; 384 / 300 under the caller's
+# 128-blocks; ``split`` forces the two-kernel backward (what a call past
+# the fused backward's VMEM budget gets), whose skipped tiles name the
+# block a visited step holds.
+@pytest.mark.parametrize(
+    "s,blocks,lse,split",
+    [
+        (640, None, False, False),
+        (600, None, False, False),
+        (384, 128, True, False),
+        (300, 128, True, False),
+        (384, 128, False, True),
+        (300, 128, True, True),
+    ],
+    ids=["tiles5", "tiles5_padded", "lse", "lse_padded", "split", "split_lse_padded"],
+)
+def test_flash_grouped_heads_causal(monkeypatch, s, blocks, lse, split):
+    """Forward, all three gradients and the ``lse`` output with a non-zero
+    ``dlse`` cotangent against ``mha_reference`` on repeated heads."""
+    A = _attention_module()
+    if split:
+        monkeypatch.setattr(A, "_FUSED_BWD_RESIDENT_LIMIT", 0)
+    b, h, hkv, d = 2, 4, 1, 64
+    kq, kk, kv, kdo = jax.random.split(jax.random.key(s + lse), 4)
+    q = jax.random.normal(kq, (b, h, s, d))
+    k = jax.random.normal(kk, (b, hkv, s, d))
+    v = jax.random.normal(kv, (b, hkv, s, d))
+    do = jax.random.normal(kdo, (b, h, s, d))
+    plan = A.flash_plan(s, s, d, h // hkv, True, q.dtype, blocks, blocks)
+    assert plan.head == d, "a head size that divides the lanes is not padded"
+    assert plan.fused_bwd != split
+    assert -(-s // plan.bwd_block_k) >= 3, "the backward must cross tiles"
+
+    def loss(attn):
+        def f(q, k, v):
+            o, l = attn(q, k, v)
+            return (o * do).sum() + (jnp.sin(l).sum() if lse else 0.0)
+        return f
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, block_q=blocks, block_k=blocks,
+        interpret=True, return_lse=True,
+    )
+    ref = lambda q, k, v: mha_reference(  # noqa: E731
+        q, jnp.repeat(k, h // hkv, 1), jnp.repeat(v, h // hkv, 1),
+        causal=True, return_lse=True,
+    )
+    with jax.default_matmul_precision("highest"):
+        (of, lf), (orr, lr) = flash(q, k, v), ref(q, k, v)
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    assert float(jnp.max(jnp.abs(of - orr))) < 2e-5
+    assert float(jnp.max(jnp.abs(lf - lr))) < 1e-5
+    for a, b_, name in zip(gf, gr, "qkv"):
+        assert a.shape == b_.shape
+        assert float(jnp.max(jnp.abs(a - b_))) < 1e-3, f"d{name}"
+
+
+def _causal_visits(A, plan, s):
+    """Score tiles a causal call of length ``s`` visits under ``plan``:
+    ``(forward, backward)``, each a list of (first row, last row, first
+    column, tile area), from the bounds the kernels themselves loop by."""
+    s_p = -(-s // plan.block_q) * plan.block_q
+    fwd, bwd = [], []
+    bq, bk = plan.block_q, plan.block_k
+    for i in range(s_p // bq):
+        for j in range(int(A._causal_nk(i, bq, bk, s_p // bk))):
+            fwd.append((i * bq, (i + 1) * bq - 1, j * bk, bq * bk))
+    bq, bk = plan.bwd_block_q, plan.bwd_block_k
+    for j in range(s_p // bk):
+        for i in range(A._causal_first_q(j, bq, bk), s_p // bq):
+            bwd.append((i * bq, (i + 1) * bq - 1, j * bk, bq * bk))
+    return s_p, fwd, bwd
+
+
+@pytest.mark.parametrize("s", [4096, 2048, 1536, 1000])
+def test_flash_plan_causal_visits_the_lower_triangle_once(s):
+    """The plan as a pure function of the call's shapes: at the token
+    cell's call no tile strictly above the diagonal is visited, and at
+    4,096 keys the visited share of the square is at most 0.57 (it was
+    0.75 under 128-row query tiles and 2,048-key blocks)."""
+    A = _attention_module()
+    plan = A.flash_plan(s, s, 64, 4, True, jnp.bfloat16)
+    assert plan.head == 64 and plan.fused_bwd
+    s_p, fwd, bwd = _causal_visits(A, plan, s)
+    for visits in (fwd, bwd):
+        assert all(col <= last for _, last, col, _ in visits), "above the diagonal"
+        share = sum(area for *_, area in visits) / s_p**2
+        # every tile on or below the diagonal is there
+        assert share >= 0.5
+        if s == 4096:
+            assert share <= 0.57
+    # the free loop starts where the mask is not needed any more
+    bq, bk = plan.bwd_block_q, plan.bwd_block_k
+    for j in range(s_p // bk):
+        free = A._causal_free_q(j, bq, bk)
+        assert free * bq >= (j + 1) * bk - 1 > (free - 1) * bq
+
+
+@pytest.mark.parametrize(
+    "call,expected",
+    [
+        # non-causal at head size 128 (vit_long): the tiles it had before
+        # the causal plan, under the fused backward
+        ((4096, 4096, 128, 1, False, jnp.bfloat16),
+         dict(block_q=128, block_k=2048, head=128, fused_bwd=True,
+              bwd_block_q=512, bwd_block_k=512)),
+        # the streamed forward's length: no whole-sequence residents
+        ((16384, 16384, 64, 4, True, jnp.bfloat16), dict(fused_bwd=False)),
+        # float32 operands at 4,096 keys are past the VMEM budget too
+        ((4096, 4096, 64, 4, True, jnp.float32), dict(fused_bwd=False)),
+        # a head size that does not divide the lanes is padded to them
+        ((200, 200, 48, 1, True, jnp.float32), dict(head=128, block_q=256)),
+    ],
+    ids=["vit_long", "s16384", "float32", "head48"],
+)
+def test_flash_plan_other_calls(call, expected):
+    A = _attention_module()
+    plan = A.flash_plan(*call)
+    assert {k: getattr(plan, k) for k in expected} == expected
+    # the caller's blocks win over the plan's
+    assert A.flash_plan(*call, block_q=64, block_k=64)[:2] == (64, 64)
+
+
+def test_attention_dispatcher_grouped_heads():
+    """Fewer key-value heads than query heads: every implementation but
+    the flash kernels sees them repeated, in both layouts."""
+    kq, kk, kv = jax.random.split(jax.random.key(4), 3)
+    q = jax.random.normal(kq, (2, 96, 4, 32))
+    k = jax.random.normal(kk, (2, 96, 2, 32))
+    v = jax.random.normal(kv, (2, 96, 2, 32))
+    rep = lambda x: jnp.repeat(x, 2, axis=2)  # noqa: E731
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = attention(q, rep(k), rep(v), causal=True, layout="bshd")
+        got = attention(q, k, v, causal=True, layout="bshd")
+        got_bhsd = attention(t(q), t(k), t(v), causal=True)
+        got_flash = attention(
+            q, k, v, causal=True, layout="bshd", impl="pallas", interpret=True
+        )
+    assert float(jnp.max(jnp.abs(got - want))) == 0.0
+    assert float(jnp.max(jnp.abs(t(got_bhsd) - want))) < 2e-5
+    assert float(jnp.max(jnp.abs(got_flash - want))) < 2e-5
+    with pytest.raises(ValueError, match="query heads over"):
+        attention(q, k[:, :, :1].repeat(3, axis=2), v, layout="bshd")
+
+
 def test_flash_jit_and_grad_compile():
     """The custom_vjp plumbing stays jittable (static meta args hash)."""
     q, k, v, do = _rand_qkv(9, 128, 128, 64)

@@ -84,13 +84,14 @@ def _grad_of(fn, n_args):
     )
 
 
-# vit_long as chip_smoke trains it (batch 8, 4 heads, 4096 tokens, head dim
-# 128), and S=16384: past _FWD_RESIDENT_KV_LIMIT, the streamed forward
-# lfm2: the token cell's attention layer (4 sequences, 32 heads after the
-# key-value heads are repeated, 4,096 tokens, head size 64, padded to 128)
+# (batch, query heads, key-value heads, tokens, head size).  vit_long as
+# chip_smoke trains it, and S=16384: past _FWD_RESIDENT_KV_LIMIT, the
+# streamed forward.  lfm2: the token cell's attention layer — 4 sequences,
+# 32 query heads on 8 key-value heads read by index, 4,096 tokens, head size
+# 64 as it is (a block whose minor dim spans the array: no pad to 128 lanes)
 FLASH_SHAPES = {
-    "vit_long": (8, 4, 4096, 128), "s16384": (1, 4, 16384, 128),
-    "lfm2": (4, 32, 4096, 64),
+    "vit_long": (8, 4, 4, 4096, 128), "s16384": (1, 4, 4, 16384, 128),
+    "lfm2": (4, 32, 8, 4096, 64),
 }
 
 
@@ -98,10 +99,35 @@ FLASH_SHAPES = {
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("shape", FLASH_SHAPES.values(), ids=FLASH_SHAPES)
 def test_flash_attention_compiles_for_v5e(chip, shape, backward, causal):
+    b, h, hkv, s, d = shape
     attn = lambda q, k, v: flash_attention(q, k, v, causal=causal)  # noqa: E731
     fn = _grad_of(attn, 3) if backward else attn
-    text = _compiled_text(fn, chip, _s(*shape), _s(*shape), _s(*shape))
+    text = _compiled_text(
+        fn, chip, _s(b, h, s, d), _s(b, hkv, s, d), _s(b, hkv, s, d)
+    )
     assert "tpu_custom_call" in text
+    if d < 128:
+        assert " pad(" not in text, "the head dimension went in unpadded"
+    if causal and h > hkv:
+        # the plan's fused backward: one kernel, the group's dk / dv summed
+        # inside it, no repeated heads around it
+        assert text.count('custom_call_target="tpu_custom_call"') == 1 + backward
+
+
+def test_flash_attention_with_lse_compiles_for_v5e(chip):
+    """Ring attention's call at the token cell's shape: the ``lse`` output
+    and its cotangent through the fused backward."""
+    b, h, hkv, s, d = FLASH_SHAPES["lfm2"]
+
+    def both(q, k, v):
+        out, lse = flash_attention(q, k, v, causal=True, return_lse=True)
+        return out.astype(jnp.float32).sum() + jnp.sin(lse).sum()
+
+    text = _compiled_text(
+        jax.grad(both, argnums=(0, 1, 2)), chip,
+        _s(b, h, s, d), _s(b, hkv, s, d), _s(b, hkv, s, d),
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_fused_vit_block_compiles_for_v5e(chip):

@@ -40,6 +40,42 @@ def test_flash_fwd_bwd_design_point(causal):
         assert err < 0.1, f"d{name} diverged on-chip: {err}"
 
 
+def test_flash_token_cell_shape():
+    """``lfm2_ep8_seq4k_job``'s attention layer (4 sequences, 32 query
+    heads on 8 key-value heads, 4,096 causal keys, head size 64 unpadded):
+    the plan's (512, 512) forward and the fused backward with the group's
+    dk / dv summed inside the kernel, against the reference on repeated
+    heads at bf16 tolerance."""
+    kq, kk, kv = jax.random.split(jax.random.key(30), 3)
+    q = jax.random.normal(kq, (4, 32, 4096, 64), jnp.bfloat16)
+    k = jax.random.normal(kk, (4, 8, 4096, 64), jnp.bfloat16)
+    v = jax.random.normal(kv, (4, 8, 4096, 64), jnp.bfloat16)
+
+    def loss(fn):
+        return lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum()
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+    ref = lambda q, k, v: mha_reference(  # noqa: E731
+        q, jnp.repeat(k, 4, 1), jnp.repeat(v, 4, 1), causal=True
+    )
+    # the reference holds float32 scores: one sequence of the four (the
+    # loss is a sum, so a sequence's gradients are its own)
+    one = (q[:1], k[:1], v[:1])
+    out = jax.jit(flash)(q, k, v)[:1].astype(jnp.float32)
+    want = jax.jit(ref)(*one).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(out - want))) < 0.05
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(*one)
+    for a, b_, name in zip(gf, gr, "qkv"):
+        assert a[:1].shape == b_.shape
+        b32 = b_.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a[:1].astype(jnp.float32) - b32)))
+        # dk / dv of the first keys sum 4 heads x 4,096 rows: tens, where a
+        # bf16 ulp is 0.25 — the tolerance follows the tensor's magnitude
+        limit = 0.1 + 0.01 * float(jnp.max(jnp.abs(b32)))
+        assert err < limit, f"d{name} diverged on-chip: {err} (limit {limit})"
+
+
 def test_tiled_forward_engages_and_agrees():
     """S=16384 exceeds the resident-K/V limit: the streamed forward must
     compile and run (it could not before round 4); at S=4096 both paths
