@@ -97,7 +97,7 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         choices=[
             "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
             "vit_tiny", "vit_small", "vit_long", "vit_moe",
-            "lfm2_24b_a2b", "lfm2_tiny",
+            "lfm2_24b_a2b", "lfm2_tiny", "trinity_mini", "afmoe_tiny",
         ],
         help="Model zoo entry (live, unlike the reference's dead --model flag)",
     )
@@ -106,7 +106,8 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         type=str,
         default=None,
         help="What this chip holds of a published token model "
-        "(lfm2_*, models/lfm2.py), as layers=N,dense=N,experts=N,"
+        "(lfm2_*, trinity_mini, afmoe_tiny; models/token_parts.py), as "
+        "layers=N,dense=N,experts=N,"
         "first_expert=N,vocab=N: layers kept (the leading dense ones, then "
         "the layers that follow them), experts held in every expert layer "
         "and the first one's index, vocabulary rows. No width is cut; the "
@@ -371,6 +372,13 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         default=0,
         help="Truncate each split to N examples (0 = full dataset); for "
         "smoke runs and CI",
+    )
+    parser.add_argument(
+        "--valid-examples",
+        type=int,
+        default=0,
+        help="Hold out exactly N of the train split's examples for "
+        "validation (0 = the reference's tenth, rounded down)",
     )
     parser.add_argument(
         "--resume",
@@ -1115,6 +1123,8 @@ def load_config(
     args.backend = backend
     if args.limit_examples < 0:
         parser.error(f"--limit-examples must be >= 0, got {args.limit_examples}")
+    if args.valid_examples < 0:
+        parser.error(f"--valid-examples must be >= 0, got {args.valid_examples}")
     if args.max_restarts < 0:
         parser.error(f"--max-restarts must be >= 0, got {args.max_restarts}")
     if args.health_window < 4:

@@ -86,7 +86,10 @@ def get_datasets(hparams) -> tuple[DeviceDataset, DeviceDataset, DeviceDataset]:
     """Build (train, valid, test) datasets with the reference's 90/10 split."""
     images, labels = _raw_split(hparams, "train")
     full = DeviceDataset(images, labels)
-    trn_idx, val_idx = train_val_split(len(full), valid_size=0.1, seed=hparams.seed)
+    trn_idx, val_idx = train_val_split(
+        len(full), valid_size=0.1, seed=hparams.seed,
+        valid_count=getattr(hparams, "valid_examples", 0),
+    )
     test_images, test_labels = _raw_split(hparams, "test")
     return (
         full.subset(trn_idx),

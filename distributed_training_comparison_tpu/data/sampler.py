@@ -21,21 +21,26 @@ import numpy as np
 
 
 def train_val_split(
-    n: int, valid_size: float = 0.1, seed: int = 42, shuffle: bool = True
+    n: int, valid_size: float = 0.1, seed: int = 42, shuffle: bool = True,
+    valid_count: int = 0,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Disjoint (train_idx, valid_idx) covering ``range(n)``.
 
     Matches the reference's convention: shuffle indices, first
     ``floor(valid_size*n)`` are validation, rest are train
     (``src/single/dataset.py:79-87``) — but with an explicit seeded
-    Generator instead of global ``np.random`` state.
+    Generator instead of global ``np.random`` state.  A ``valid_count``
+    above 0 (``--valid-examples``) is the validation count itself, for the
+    job whose split no fraction of a tenth gives (32 and 4 of 36).
     """
     if not 0.0 <= valid_size <= 1.0:
         raise ValueError("valid_size should be in the range [0, 1].")
+    if not 0 <= valid_count < max(n, 1):
+        raise ValueError(f"valid_count {valid_count} leaves no train example of {n}")
     indices = np.arange(n)
     if shuffle:
         np.random.default_rng(seed).shuffle(indices)
-    split = int(np.floor(valid_size * n))
+    split = valid_count or int(np.floor(valid_size * n))
     return indices[split:], indices[:split]
 
 

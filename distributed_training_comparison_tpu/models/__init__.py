@@ -17,6 +17,7 @@ from .resnet import (
     ResNet101,
     ResNet152,
 )
+from .afmoe import AFMOE_TINY_MODEL, TRINITY_MINI_MODEL, Afmoe
 from .lfm2 import LFM2, LFM2_24B_A2B_MODEL, LFM2_TINY_MODEL
 from .moe import SwitchFFN, TopKMoE, resolve_dispatch
 from .vit import ViT, ViTBlock, ViTLong, ViTMoE, ViTSmall, ViTTiny
@@ -33,6 +34,8 @@ _ZOO = {
     "vit_moe": ViTMoE,
     "lfm2_24b_a2b": LFM2_24B_A2B_MODEL,
     "lfm2_tiny": LFM2_TINY_MODEL,
+    "trinity_mini": TRINITY_MINI_MODEL,
+    "afmoe_tiny": AFMOE_TINY_MODEL,
 }
 
 
@@ -81,6 +84,7 @@ __all__ = [
     "SwitchFFN",
     "TopKMoE",
     "LFM2",
+    "Afmoe",
     "get_model",
     "model_cli_options",
     "resolve_dispatch",

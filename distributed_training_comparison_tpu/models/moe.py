@@ -3,8 +3,9 @@ and drops, expert parallelism as a sharding) and ``TopKMoE`` (top-k sigmoid
 routing for a rank that is told which experts it holds, no capacity and no
 drop: its dispatch works on a bounded *held prefix* of the expert-sorted
 pairs, sized from the share of experts held, with the plain every-expert-
-on-every-token sum behind it for the call in which more arrive — see its
-docstring).  What
+on-every-token sum behind it for the call in which more arrive; optionally a
+shared expert beside the routed ones and a selection bias that the training
+call moves — see its docstring).  What
 follows is ``SwitchFFN``'s design.
 
 Switch-style mixture-of-experts FFN with expert parallelism.
@@ -66,6 +67,7 @@ import jax.numpy as jnp
 from ..obs.compilation import note_kernel_path
 from ..ops.moe_gmm import grouped_matmul, grouped_matmul_t, resolve_gmm_impl
 from ..ops.vmem import fits_weight_budget, gmm_weight_bytes
+from .token_parts import SwiGLU
 
 
 def resolve_dispatch(dispatch: str = "auto", *, expert_parallel: bool = False) -> str:
@@ -324,17 +326,20 @@ class SwitchFFN(nn.Module):
 # ------------------------------------------------------- top-k, no drops
 
 
-# The selection bias is drawn once and moved by no rule.  At 0.002 it
-# changes the top-4 of about one token in fifteen; at 0.02 it decided which
-# experts are popular, and the rows this chip's experts receive varied by a
-# tenth from seed to seed (PERF.md, Findings, PR 27).
+# Where no rule moves the selection bias (``bias_update_rate`` 0) it is drawn
+# once.  At 0.002 it changes the top-4 of about one token in fifteen; at 0.02
+# it decided which experts are popular, and the rows this chip's experts
+# receive varied by a tenth from seed to seed (PERF.md, Findings, PR 27).
 EXPERT_BIAS_STD = 0.002
 
 # The held prefix is twice the rows that even routing sends to the experts
 # held here: even routing fills half of it, and the fullest layer calls seen
 # where a rank holds an eighth of the experts (epoch 0 of lfm2_ep8_seq4k_job:
 # 10-12 k rows of an even 8 k; PERF.md, Findings, PR 27) fit with room.  More
-# than that takes the fallback, which drops nothing either.
+# than that takes the fallback, which drops nothing either: where a rank
+# holds a sixteenth (trinity_ep16_seq8k_job, top-8 of 128) up to an eighth
+# of an epoch's layer calls did in epochs 0-2 in eleven seeds of twelve, and
+# a fifth of them in every epoch in one (PERF.md, Findings, PR 31).
 SLACK = 2
 
 # Tokens a group of the grouped matmul that sums a token's rows: one lane tile
@@ -619,8 +624,22 @@ class TopKMoE(nn.Module):
     from ``held / num_experts``, which the layer is told; no flag decides it.
 
     ``expert_bias`` is a float32 buffer in the ``batch_stats`` collection,
-    not a parameter: it enters the selection only, no rule updates it (the
-    config publishes none) and the optimizer never sees it.
+    not a parameter: it enters the selection only and the optimizer never
+    sees it.  With ``bias_update_rate`` 0 no rule moves it (LFM2-MoE
+    publishes none).  With a rate ``u`` a training call (``train=True``,
+    ``batch_stats`` mutable) moves it once, after its routing, by the
+    auxiliary-loss-free balancing rule: ``c_e`` the pairs of this call that
+    selected expert ``e``, over all ``num_experts``; ``delta = u *
+    sign(mean(c) - c)``; ``b += delta - mean(delta)`` — an expert selected
+    less than its even share rises, the sum of ``b`` stays.  It is saved
+    and restored with ``batch_stats``, and an eval call leaves it alone.
+    ``moe_metrics/bias_spread`` is ``max(b) - min(b)`` after the move.
+
+    ``shared_hidden`` > 0 adds a shared expert: a SwiGLU of that width every
+    token passes through, its output added once, unweighted (module and
+    scope ``shared_expert``).  Every rank of an expert-parallel layer
+    computes it alike, so the ranks' results add up to the uncut layer's
+    with it counted once (``tests/test_moe.py``).
     """
 
     dim: int
@@ -634,22 +653,32 @@ class TopKMoE(nn.Module):
     use_bias: bool = True
     dtype: Any = jnp.float32
     gmm: str = "auto"
+    bias_update_rate: float = 0.0  # 0: a constant drawn at initialisation
+    shared_hidden: int = 0  # 0: no shared expert
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
         b, s, d = x.shape
         n, k = b * s, self.top_k
         held = self.num_experts_held or self.num_experts
         init = nn.initializers.normal(stddev=0.02)
         router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        moves = self.use_bias and self.bias_update_rate > 0
+
+        def bias_init():
+            if moves:  # a bias that a rule moves starts at zero
+                return jnp.zeros((self.num_experts,), jnp.float32)
+            return EXPERT_BIAS_STD * jax.random.normal(
+                self.make_rng("params"), (self.num_experts,), jnp.float32
+            )
+
+        bias_state = (
+            self.variable("batch_stats", "expert_bias", bias_init)
+            if self.use_bias else None
+        )
         bias = (
-            self.variable(
-                "batch_stats", "expert_bias",
-                lambda: EXPERT_BIAS_STD * jax.random.normal(
-                    self.make_rng("params"), (self.num_experts,), jnp.float32
-                ),
-            ).value
-            if self.use_bias else jnp.zeros((self.num_experts,), jnp.float32)
+            bias_state.value if self.use_bias
+            else jnp.zeros((self.num_experts,), jnp.float32)
         )
         w1 = self.param("w1", init, (held, d, self.hidden), jnp.float32)
         w3 = self.param("w3", init, (held, d, self.hidden), jnp.float32)
@@ -659,6 +688,20 @@ class TopKMoE(nn.Module):
         sel, weights = route_topk(
             xt, router, bias, k, self.scale, self.renormalise
         )
+        if moves:
+            if train and not self.is_initializing():
+                counts = jnp.sum(
+                    jax.nn.one_hot(sel, self.num_experts, dtype=jnp.float32),
+                    axis=(0, 1),
+                )
+                delta = self.bias_update_rate * jnp.sign(
+                    jnp.mean(counts) - counts
+                )
+                bias_state.value = bias + (delta - jnp.mean(delta))
+            self.sow(
+                "moe_metrics", "bias_spread",
+                jnp.max(bias_state.value) - jnp.min(bias_state.value),
+            )
         plan = _dispatch_plan(sel.reshape(n * k) - self.first_expert, held)
         rows = plan["rows"].astype(jnp.float32)
         prefix = held_prefix_rows(n * k, held, self.num_experts)
@@ -681,4 +724,9 @@ class TopKMoE(nn.Module):
             _Experts(prefix, k, impl, interpret),
             xt.astype(self.dtype), weights, w1, w3, w2, plan,
         )
-        return y.reshape(b, s, d)
+        y = y.reshape(b, s, d)
+        if self.shared_hidden:
+            y = y + SwiGLU(
+                d, self.shared_hidden, self.dtype, name="shared_expert"
+            )(x.astype(self.dtype))
+        return y

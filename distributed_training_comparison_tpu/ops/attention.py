@@ -83,6 +83,7 @@ forward and 2.2 in the fused backward for 262 k exponentials a tile.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import NamedTuple
@@ -113,9 +114,13 @@ def mha_reference(
     scale: float | None = None,
     return_lse: bool = False,
     layout: str = "bhsd",
+    window: int | None = None,
 ):
     """Plain attention; softmax in fp32.  The semantics contract the
     Pallas kernel is tested against.
+
+    ``window`` (causal calls only) is a sliding window: key ``j`` is
+    visible to query ``i`` iff ``j <= i and i - j < window``.
 
     ``layout`` is the q/k/v axis order: ``"bhsd"`` (B, H, S, D) or
     ``"bshd"`` (B, S, H, D).  The ``bshd`` path contracts directly via
@@ -134,7 +139,8 @@ def mha_reference(
     """
     d = q.shape[-1]
     scale = 1.0 / math.sqrt(d) if scale is None else scale
-    sq, skv = q.shape[-3 if layout == "bshd" else -2], k.shape[-3 if layout == "bshd" else -2]
+    if window is not None and not causal:
+        raise ValueError("an attention window needs causal=True")
     if layout == "bshd":
         # q-major scores (b, q, h, k): h stays where the inputs put it, so
         # XLA emits no relayout around either matmul — measured 1.4× faster
@@ -144,11 +150,7 @@ def mha_reference(
         score_eq, out_eq = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
     s = jnp.einsum(score_eq, q, k, preferred_element_type=jnp.float32) * scale
     if causal:
-        rows = jnp.arange(sq)[:, None] + (skv - sq)
-        mask = rows >= jnp.arange(skv)[None, :]
-        if layout == "bshd":
-            mask = mask[:, None, :]  # broadcast over the h axis of (q, h, k)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(_causal_mask(s, layout, window), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum(
         out_eq, p.astype(v.dtype), v, preferred_element_type=jnp.float32
@@ -172,32 +174,41 @@ def _composed_eqs(layout):
     return "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd", "bhqk,bhqd->bhkd"
 
 
-def _causal_mask(s, layout):
-    """:func:`mha_reference`'s causal mask for scores ``s``."""
+def _causal_mask(s, layout, window=None):
+    """:func:`mha_reference`'s causal mask for scores ``s`` (``bshd``:
+    broadcast over the h axis of (q, h, k)), with the band's lower edge
+    where the call has a ``window``."""
     sq, skv = s.shape[-3 if layout == "bshd" else -2], s.shape[-1]
-    mask = jnp.arange(sq)[:, None] + (skv - sq) >= jnp.arange(skv)[None, :]
+    rows, cols = jnp.arange(sq)[:, None] + (skv - sq), jnp.arange(skv)[None, :]
+    mask = rows >= cols
+    if window is not None:
+        mask = mask & (rows - cols < window)
     return mask[:, None, :] if layout == "bshd" else mask
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _composed(q, k, v, causal, scale, layout):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _composed(q, k, v, causal, scale, layout, window=None):
     """The dispatcher's composed branch.  Not differentiated it *is*
     :func:`mha_reference`.  Differentiated, the program and not autodiff
     chooses what crosses from forward to backward: the probabilities in the
     dtype the p·v matmul consumes them in, and no float32 score-sized
     tensor (autodiff keeps float32 ``s - max`` and every consumer in the
     backward computes ``exp`` of it again)."""
-    return mha_reference(q, k, v, causal=causal, scale=scale, layout=layout)
+    return mha_reference(
+        q, k, v, causal=causal, scale=scale, layout=layout, window=window
+    )
 
 
-def _composed_fwd(q, k, v, causal, scale, layout):
+def _composed_fwd(q, k, v, causal, scale, layout, window):
     # mha_reference's arithmetic, with p·v's operand named: the residual
     score_eq, out_eq, _ = _composed_eqs(layout)
 
     def scores(q, k):
         s = jnp.einsum(score_eq, q, k, preferred_element_type=jnp.float32)
         s = s * scale
-        return jnp.where(_causal_mask(s, layout), s, _NEG_INF) if causal else s
+        if not causal:
+            return s
+        return jnp.where(_causal_mask(s, layout, window), s, _NEG_INF)
 
     s = scores(q, k)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -221,7 +232,7 @@ def _composed_fwd(q, k, v, causal, scale, layout):
     return out, (q, k, v, p, out)
 
 
-def _composed_bwd(causal, scale, layout, res, do):
+def _composed_bwd(causal, scale, layout, window, res, do):
     q, k, v, p, out = res
     score_eq, out_eq, to_kv_eq = _composed_eqs(layout)
     f32 = jnp.float32
@@ -233,7 +244,7 @@ def _composed_bwd(causal, scale, layout, res, do):
     if causal:
         # ``where``'s rule: a masked score has no cotangent (p is 0 there
         # already, but for a row that is masked whole)
-        ds = jnp.where(_causal_mask(ds, layout), ds, 0.0)
+        ds = jnp.where(_causal_mask(ds, layout, window), ds, 0.0)
     ds = ds.astype(q.dtype)
     dq = jnp.einsum(out_eq, ds, k, preferred_element_type=f32)
     dk = jnp.einsum(to_kv_eq, ds, q, preferred_element_type=f32)
@@ -254,17 +265,23 @@ def _scores(qb, kb, scale):
     ) * scale
 
 
-def _block_mask(i, j, block_q, block_k, kv_len, causal, keys_down=False):
+def _block_mask(i, j, block_q, block_k, kv_len, causal, keys_down=False,
+                window=None):
     """Validity mask for score block (i, j) from *static* true kv length:
     (block_q, block_k), or its transpose with ``keys_down`` (the fused
-    backward's tiles have keys on the sublanes)."""
+    backward's tiles have keys on the sublanes).  With a ``window`` the
+    band's lower edge too: a key more than ``window - 1`` behind its query
+    is masked."""
     shape = (block_k, block_q) if keys_down else (block_q, block_k)
     cols = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if keys_down else 1)
     cols = cols + j * block_k
     mask = cols < kv_len
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if keys_down else 0)
-        mask = mask & (rows + i * block_q >= cols)
+        rows = rows + i * block_q
+        mask = mask & (rows >= cols)
+        if window is not None:
+            mask = mask & (rows - cols < window)
     return mask
 
 
@@ -287,10 +304,74 @@ def _causal_free_q(j, block_q, block_k):
     return ((j + 1) * block_k - 1 + block_q - 1) // block_q
 
 
-def _mask_split(i, j, block_q, block_k, kv_len, causal):
+# A window bounds a causal call's tiles from below as well: query ``r`` sees
+# keys ``(r - window, r]``, so the visited tiles are a band along the
+# diagonal.  Tile (i, j) is visited iff ``_band_first_k(i) <= j <
+# _causal_nk(i)`` iff ``_causal_first_q(j) <= i < _band_end_q(j)``, and it
+# straddles the band's lower edge (needs that mask) iff ``j <
+# _band_free_k(i)`` iff ``i >= _band_free_q(j)``.
+
+
+def _band_first_k(i, block_q, block_k, window):
+    """First key block a row of query block ``i`` sees: the one holding
+    key ``i * block_q - (window - 1)``, its first row's oldest."""
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k
+
+
+def _band_free_k(i, block_q, block_k, window):
+    """First key block wholly inside the window of every row of query
+    block ``i``: ``ceil(((i + 1) * block_q - window) / block_k)``, from 0."""
+    return jnp.maximum((i + 1) * block_q - window + block_k - 1, 0) // block_k
+
+
+def _band_end_q(j, block_q, block_k, window):
+    """One past the last query block with a row that sees a key of block
+    ``j``: key ``(j + 1) * block_k - 1`` is last seen by the row ``window -
+    1`` after it."""
+    return ((j + 1) * block_k + window - 2) // block_q + 1
+
+
+def _band_free_q(j, block_q, block_k, window):
+    """One past the last query block whose every row sees every key of
+    block ``j``: its last row is less than ``window`` after key
+    ``j * block_k``."""
+    return (j * block_k + window) // block_q
+
+
+def _band_steps(own, streamed, window, n_own, n_streamed, behind):
+    """The most grid steps the band of any one ``own``-sized block takes in
+    ``streamed``-sized blocks.  Its ``own + window - 1`` rows or columns
+    start ``behind`` before the block's first: ``window - 1`` for a query
+    block streaming keys, 0 for a key block streaming queries."""
+    most = 0
+    for b in range(n_own):
+        start = b * own - behind
+        first = max(start, 0) // streamed
+        last = min((start + own + window - 2) // streamed, n_streamed - 1)
+        most = max(most, last - first + 1)
+    return most
+
+
+def band_tiles(sq, block_q, block_k, window=None):
+    """The (query block, key block) tiles a causal call of ``sq`` padded
+    rows visits under these tiles, from the bounds the kernels loop by: 70
+    of the square's 256 at 8,192 keys, a window of 2,048 and 512-tiles (the
+    causal triangle has 136)."""
+    tiles = []
+    for i in range(sq // block_q):
+        lo = 0 if window is None else int(
+            _band_first_k(i, block_q, block_k, window)
+        )
+        hi = int(_causal_nk(i, block_q, block_k, sq // block_k))
+        tiles += [(i, j) for j in range(lo, hi)]
+    return tiles
+
+
+def _mask_split(i, j, block_q, block_k, kv_len, causal, window=None):
     """``(run, needs_mask)`` predicates for a (query block i, key block j)
     tile of any tiled kernel: ``run`` gates compute (skip tiles strictly
-    above the causal diagonal), ``needs_mask`` selects the masked path.
+    above the causal diagonal, and with a ``window`` those wholly behind
+    it), ``needs_mask`` selects the masked path.
     The per-tile iota/compare/select of ``_block_mask`` is real VPU work
     next to the MXU matmuls, so interior tiles — almost all of them at
     streaming scale — take a mask-free path: a tile needs the mask only
@@ -301,13 +382,21 @@ def _mask_split(i, j, block_q, block_k, kv_len, causal):
     needs_mask = (j + 1) * block_k > kv_len
     if causal:
         needs_mask = needs_mask | ((j + 1) * block_k - 1 > i * block_q)
+    if window is not None:
+        # the tile's first row sees its last column; its last row does not
+        # see its first column
+        run = run & (i * block_q - ((j + 1) * block_k - 1) < window)
+        needs_mask = needs_mask | ((i + 1) * block_q - 1 - j * block_k >= window)
     return run, needs_mask
 
 
 # ------------------------------------------------------------ fwd kernel
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, kv_len):
+def _fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, kv_len,
+    window=None,
+):
     block_q, d = q_ref.shape
     i = pl.program_id(1)
     qb = q_ref[...]
@@ -327,13 +416,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, 
         vb = v_ref[pl.dslice(j * block_k, block_k), :]
         s = _scores(qb, kb, scale)
         if masked:
-            s = jnp.where(
-                _block_mask(i, j, block_q, block_k, kv_len, causal), s, _NEG_INF
+            mask = _block_mask(
+                i, j, block_q, block_k, kv_len, causal, window=window
             )
+            s = jnp.where(mask, s, _NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
+        if masked and window is not None:
+            # a row whose window starts in a later block meets this one
+            # wholly masked, before it has a finite running max: exp(s -
+            # m_new) is then 1, not 0
+            p = jnp.where(mask, p, 0.0)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
@@ -344,8 +439,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, 
     acc = jnp.zeros((block_q, d), jnp.float32)
     m = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
+    carry, first = (acc, m, l), 0
+    if window is not None:
+        # the band's lower edge: start at the first block a row sees, and
+        # mask up to the first one every row sees whole
+        lo = _band_first_k(i, block_q, block_k, window)
+        first = jnp.clip(_band_free_k(i, block_q, block_k, window), lo, nk)
+        nk_free = jnp.maximum(nk_free, first)
+        carry = jax.lax.fori_loop(
+            lo, first, functools.partial(body, masked=True), carry
+        )
     carry = jax.lax.fori_loop(
-        0, nk_free, functools.partial(body, masked=False), (acc, m, l)
+        first, nk_free, functools.partial(body, masked=False), carry
     )
     acc, m, l = jax.lax.fori_loop(
         nk_free, nk, functools.partial(body, masked=True), carry
@@ -370,21 +475,24 @@ def _kv_resident(skv, d, dtype) -> bool:
 
 def _fwd_kernel_tiled(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-    *, scale, causal, kv_len,
+    *, scale, causal, kv_len, window=None,
 ):
     """One (query block, key block) tile of the forward.  Grid (bh, nq, nk):
     the innermost dim streams key/value blocks past fp32 VMEM scratch
     carrying the online-softmax state (acc, running max, running sum); the
     final key step normalizes and writes the output block.  Unlike
     ``_fwd_kernel`` nothing whole-sequence is ever VMEM-resident, so S is
-    bounded by HBM, not VMEM."""
+    bounded by HBM, not VMEM.  With a ``window`` the innermost dim counts
+    the band's key blocks only, from the first one the query block sees."""
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    j = step
+    if window is not None:
+        j = step + _band_first_k(i, block_q, block_k, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
@@ -393,17 +501,19 @@ def _fwd_kernel_tiled(
     def compute(masked):
         s = _scores(q_ref[...], k_ref[...], scale)
         if masked:
-            mask = _block_mask(i, j, block_q, block_k, kv_len, causal)
+            mask = _block_mask(
+                i, j, block_q, block_k, kv_len, causal, window=window
+            )
             s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         if masked:
-            # defensive zeroing: masked columns stay exactly 0 whatever the
-            # running max is.  In every reachable state bare exp(s - m_new)
-            # already underflows to 0 (tile j=0 always sees a valid key, so
-            # m_new is finite from then on); the where() guards the
-            # invariant against refactors, it is not load-bearing today
+            # masked columns stay exactly 0 whatever the running max is.
+            # Without a window bare exp(s - m_new) already underflows to 0
+            # (tile j=0 always sees a valid key, so m_new is finite from
+            # then on); with one a row can meet its first tile wholly
+            # masked, before a finite running max: exp(s - m_new) is then 1
             p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         else:
             p = jnp.exp(s - m_new)
@@ -416,7 +526,9 @@ def _fwd_kernel_tiled(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    run, needs_mask = _mask_split(i, j, block_q, block_k, kv_len, causal)
+    run, needs_mask = _mask_split(
+        i, j, block_q, block_k, kv_len, causal, window
+    )
 
     @pl.when(run & jnp.logical_not(needs_mask))
     def _():
@@ -428,7 +540,7 @@ def _fwd_kernel_tiled(
 
     # the last key step always runs (even when causal-skipped: the scratch
     # already holds this row block's complete softmax state)
-    @pl.when(j == nk - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_scr[:, 0:1], 1e-30)  # padded rows stay finite
         o_ref[...] = (acc[...] / l_safe).astype(o_ref.dtype)
@@ -451,7 +563,13 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     # traffic is governed by bq, not bk
     bk = _stream_block(skv, 512)
 
+    window, steps = plan.window, skv // bk
+    if window is not None:
+        steps = _band_steps(bq, bk, window, sq // bq, steps, window - 1)
+
     def kv_map(b, i, j):
+        if window is not None:
+            j = j + _band_first_k(i, bq, bk, window)
         if causal:
             # a step above the diagonal names the block the last visited
             # step held: Pallas issues no DMA when a block index repeats
@@ -460,9 +578,10 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
 
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel_tiled, scale=scale, causal=causal, kv_len=kv_len
+            _fwd_kernel_tiled, scale=scale, causal=causal, kv_len=kv_len,
+            window=window,
         ),
-        grid=(bh, sq // bq, skv // bk),
+        grid=(bh, sq // bq, steps),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bk, d), kv_map),
@@ -504,7 +623,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_k=plan.block_k,
-            kv_len=kv_len,
+            kv_len=kv_len, window=plan.window,
         ),
         grid=(bh, sq // block_q),
         in_specs=[
@@ -530,19 +649,24 @@ def _flash_fwd(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref, dq_ref, dq_acc,
-    *, scale, causal, kv_len,
+    *, scale, causal, kv_len, window=None,
 ):
     """One (query block, key block) tile of dq.  Grid (bh, nq, nk): the
     innermost grid dim streams key/value blocks past a fp32 VMEM scratch
     accumulator; the last visited step's write to ``dq_ref`` is what Mosaic
     flushes to HBM when the (``j``-independent) output block index moves —
-    one input-dtype write per element, no fp32 round trip."""
+    one input-dtype write per element, no fp32 round trip.  With a
+    ``window`` the innermost dim counts the band's key blocks only, from
+    the first one the query block sees."""
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
+    j = step
+    if window is not None:
+        j = step + _band_first_k(i, block_q, block_k, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -556,7 +680,9 @@ def _dq_kernel(
         adj_row = dlse_ref[:, 0:1] - delta_ref[:, 0:1]
         s = _scores(qb, kb, scale)
         if masked:
-            mask = _block_mask(i, j, block_q, block_k, kv_len, causal)
+            mask = _block_mask(
+                i, j, block_q, block_k, kv_len, causal, window=window
+            )
             p = jnp.where(mask, jnp.exp(s - lse_row), 0.0)
         else:
             p = jnp.exp(s - lse_row)
@@ -572,9 +698,11 @@ def _dq_kernel(
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
     # run: compute only at-or-below the causal diagonal of query block i
-    # (the BlockSpec DMAs still fetch the skipped blocks — pl.when gates
-    # compute, not prefetch)
-    run, needs_mask = _mask_split(i, j, block_q, block_k, kv_len, causal)
+    # (a skipped step fetches nothing either: its index maps name the
+    # block a visited step holds)
+    run, needs_mask = _mask_split(
+        i, j, block_q, block_k, kv_len, causal, window
+    )
 
     @pl.when(run & jnp.logical_not(needs_mask))
     def _():
@@ -587,18 +715,22 @@ def _dq_kernel(
 
 def _dkv_kernel(
     k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dlse_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, causal, kv_len,
+    dk_acc, dv_acc, *, scale, causal, kv_len, window=None, nq=None,
 ):
     """One (key block, query block) tile of dk/dv.  Grid (bh, nk, nq): the
     innermost grid dim streams query-side blocks past fp32 VMEM scratch
     accumulators; the last visited step's writes to ``dk_ref``/``dv_ref``
-    are what Mosaic flushes to HBM."""
+    are what Mosaic flushes to HBM.  With a ``window`` the innermost dim
+    counts the band's query blocks only, from the diagonal's down."""
     block_k, d = k_ref.shape
     block_q = q_ref.shape[0]
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    step = pl.program_id(2)
+    i = step
+    if window is not None:
+        i = step + _causal_first_q(j, block_q, block_k)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         # unconditional at the first inner step — AND pre-write the output
         # blocks: under caller-chosen mismatched blocks (e.g. block_q=128,
@@ -621,7 +753,9 @@ def _dkv_kernel(
         adj_row = dlse_ref[:, 0:1] - delta_ref[:, 0:1]
         s = _scores(qb, kb, scale)
         if masked:
-            mask = _block_mask(i, j, block_q, block_k, kv_len, causal)
+            mask = _block_mask(
+                i, j, block_q, block_k, kv_len, causal, window=window
+            )
             p = jnp.where(mask, jnp.exp(s - lse_row), 0.0)
         else:
             p = jnp.exp(s - lse_row)
@@ -643,7 +777,11 @@ def _dkv_kernel(
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
     # run ⟺ the old `i >= lo` visit gate: i >= (j·bk)//bq ⟺ j·bk < (i+1)·bq
-    run, needs_mask = _mask_split(i, j, block_q, block_k, kv_len, causal)
+    run, needs_mask = _mask_split(
+        i, j, block_q, block_k, kv_len, causal, window
+    )
+    if window is not None:  # the band's steps may pass the last query block
+        run = run & (i < nq)
 
     @pl.when(run & jnp.logical_not(needs_mask))
     def _():
@@ -690,6 +828,11 @@ def _flash_bwd_split(
 
     bq, bk = plan.bwd_block_q, plan.bwd_block_k
     nq, nk = sq // bq, skv // bk
+    # with a window the streamed dim counts a band's blocks, not all of them
+    window, k_steps, q_steps = plan.window, nk, nq
+    if window is not None:
+        k_steps = _band_steps(bq, bk, window, nq, nk, window - 1)
+        q_steps = _band_steps(bk, bq, window, nk, nq, 0)
     # bh and the own-block grid dims are independent; only the innermost
     # (streaming, accumulating) dim must execute in order
     params = pltpu.CompilerParams(
@@ -700,18 +843,28 @@ def _flash_bwd_split(
     # step holds, and Pallas issues no DMA when a block index repeats: the
     # tiles above the diagonal cost a grid step and no fetch.
     def kv_of_q(b, i, j):
+        if window is not None:
+            j = j + _band_first_k(i, bq, bk, window)
         if causal:
             j = jnp.minimum(j, _causal_nk(i, bq, bk, nk) - 1)
         return (b, j, 0)
 
     def q_of_kv(b, j, i):
         if causal:
-            i = jnp.maximum(i, jnp.minimum(_causal_first_q(j, bq, bk), nq - 1))
+            first = jnp.minimum(_causal_first_q(j, bq, bk), nq - 1)
+            if window is None:
+                i = jnp.maximum(i, first)
+            else:  # the band's steps count from the diagonal's block down
+                last = jnp.minimum(_band_end_q(j, bq, bk, window), nq) - 1
+                i = jnp.minimum(i + first, jnp.maximum(last, first))
         return (b, i, 0)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, kv_len=kv_len),
-        grid=(bh, nq, nk),
+        functools.partial(
+            _dq_kernel, scale=scale, causal=causal, kv_len=kv_len,
+            window=window,
+        ),
+        grid=(bh, nq, k_steps),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bk, d), kv_of_q),
@@ -729,8 +882,11 @@ def _flash_bwd_split(
     )(q3, k3, v3, do3, lse, delta, dlse)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal, kv_len=kv_len),
-        grid=(bh, nk, nq),
+        functools.partial(
+            _dkv_kernel, scale=scale, causal=causal, kv_len=kv_len,
+            window=window, nq=nq,
+        ),
+        grid=(bh, nk, q_steps),
         in_specs=[
             pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0)),
@@ -760,7 +916,7 @@ def _flash_bwd_split(
 
 def _bwd_fused_kernel(
     q_ref, do_ref, lse_ref, adj_ref, k_ref, v_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, *kv_acc, scale, causal, block_q, kv_len, group,
+    dq_acc, *kv_acc, scale, causal, block_q, kv_len, group, window=None,
 ):
     """dq, dk and dv of one key block ``j`` against one query head, the
     scores, the probabilities and dO·vᵀ taken once a visited tile.  Grid
@@ -791,7 +947,8 @@ def _bwd_fused_kernel(
         pt = jnp.exp(_scores(kb, qb, scale) - lse_ref[pl.ds(i, 1), :])
         if masked:
             mask = _block_mask(
-                i, j, block_q, block_k, kv_len, causal, keys_down=True
+                i, j, block_q, block_k, kv_len, causal, keys_down=True,
+                window=window,
             )
             pt = jnp.where(mask, pt, 0.0)
         dv = dv + jax.lax.dot_general(
@@ -816,18 +973,31 @@ def _bwd_fused_kernel(
         return dk, dv
 
     # masked tiles first (they straddle the diagonal; with padded keys in
-    # this block, every tile), then the mask-free ones below them
+    # this block, every tile), then the mask-free ones below them, then,
+    # with a window, the masked ones that straddle the band's lower edge,
+    # and none behind it
     lo = _causal_first_q(j, block_q, block_k) if causal else 0
+    hi = nq
+    if window is not None:
+        hi = jnp.minimum(_band_end_q(j, block_q, block_k, window), nq)
     free = _causal_free_q(j, block_q, block_k) if causal else 0
-    free = jnp.where((j + 1) * block_k > kv_len, nq, free)
-    free = jnp.clip(free, lo, nq)
+    free = jnp.where((j + 1) * block_k > kv_len, hi, free)
+    free = jnp.clip(free, lo, hi)
+    edge = hi
+    if window is not None:
+        edge = jnp.clip(_band_free_q(j, block_q, block_k, window), free, hi)
     zeros = jnp.zeros((block_k, d), jnp.float32)
     carry = jax.lax.fori_loop(
         lo, free, functools.partial(tile, masked=True), (zeros, zeros)
     )
-    dk, dv = jax.lax.fori_loop(
-        free, nq, functools.partial(tile, masked=False), carry
+    carry = jax.lax.fori_loop(
+        free, edge, functools.partial(tile, masked=False), carry
     )
+    if window is not None:
+        carry = jax.lax.fori_loop(
+            edge, hi, functools.partial(tile, masked=True), carry
+        )
+    dk, dv = carry
 
     if group == 1:
         dk_ref[...] = dk.astype(dk_ref.dtype)
@@ -908,7 +1078,7 @@ def _flash_bwd_fused(
     return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq,
-            kv_len=kv_len, group=group,
+            kv_len=kv_len, group=group, window=plan.window,
         ),
         grid=(bkv, group, nk),
         in_specs=[whole, whole, rows, rows, tile, tile],
@@ -982,14 +1152,20 @@ class FlashPlan(NamedTuple):
     fused_bwd: bool  # one backward kernel (``_bwd_fused_kernel``) or two tiled
     bwd_block_q: int
     bwd_block_k: int
+    # a causal call's sliding window, where it leaves a key out: every
+    # kernel bounds the key tiles it visits from below as well (``band_tiles``)
+    window: int | None = None
 
 
 def flash_plan(
     sq: int, skv: int, d: int, group: int, causal: bool, dtype,
     block_q: int | None = None, block_k: int | None = None,
+    *, window: int | None = None,
 ) -> FlashPlan:
     """The tile plan of a call, a pure function of its shapes: which score
     tiles it visits, at what size, how often, and what it keeps in VMEM.
+    A ``window`` that reaches every key is no window (the plan, and so the
+    program, is the plain causal call's).
     ``block_q`` / ``block_k`` are the caller's override of the forward
     tiles.  The backward is the fused kernel wherever its whole-sequence
     residents fit (``_FUSED_BWD_RESIDENT_LIMIT``), causal or not — at
@@ -1022,7 +1198,9 @@ def flash_plan(
         and _fused_bwd_resident_bytes(sq_p, skv_p, head, group, dtype)
         <= _FUSED_BWD_RESIDENT_LIMIT
     )
-    return FlashPlan(block_q, block_k, head, fused, bwd_q, bwd_k)
+    if window is not None and window >= skv:
+        window = None
+    return FlashPlan(block_q, block_k, head, fused, bwd_q, bwd_k, window)
 
 
 def flash_attention(
@@ -1036,10 +1214,14 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool = False,
     return_lse: bool = False,
+    window: int | None = None,
 ):
     """Pallas flash attention over (B, H, S, D), differentiable.  ``k`` and
     ``v`` may hold fewer heads than ``q`` — (B, H // group, S, D), each
-    serving ``group`` consecutive query heads.
+    serving ``group`` consecutive query heads.  ``window`` (causal calls):
+    key ``j`` is visible to query ``i`` iff ``j <= i and i - j < window``;
+    forward and backward visit, fetch and compute only the tiles that meet
+    that band (``band_tiles``).
 
     Pads S to block multiples and, where :func:`flash_plan` says so, D up
     to a lane multiple (128); the true key length is masked inside the
@@ -1066,13 +1248,17 @@ def flash_attention(
     hkv, skv = k.shape[1], k.shape[2]
     if causal and sq != skv:
         raise ValueError("causal flash attention requires q_len == kv_len")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("an attention window is positive and needs causal=True")
     if h % hkv or v.shape[1] != hkv:
         raise ValueError(
             f"{h} query heads over {hkv} / {v.shape[1]} key / value heads"
         )
     scale = 1.0 / math.sqrt(d) if scale is None else scale
     group = h // hkv
-    plan = flash_plan(sq, skv, d, group, causal, q.dtype, block_q, block_k)
+    plan = flash_plan(
+        sq, skv, d, group, causal, q.dtype, block_q, block_k, window=window
+    )
     if group > 1 and not plan.fused_bwd:
         # the two-kernel backward has one key-value head a query head
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -1125,8 +1311,23 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, **options):
     einsums, a Pallas kernel, a sequence-parallel ring — its device ops
     carry one name, so a trace times attention the same before and after a
     kernel replaces the einsums."""
-    with jax.named_scope("attention"):
-        return _attention(q, k, v, **options)
+    window = options.pop("window", None)
+    if window is not None:
+        if not options.get("causal") or window < 1:
+            raise ValueError(
+                "an attention window is positive and needs causal=True"
+            )
+        seq_ax = 1 if options.get("layout") == "bshd" else 2
+        if window >= k.shape[seq_ax]:  # it reaches every key: plain causal
+            window = None
+    # a call with a window carries a second name, so that a trace times the
+    # sliding layers apart from the full ones
+    inner = (
+        contextlib.nullcontext() if window is None
+        else jax.named_scope("attention_window")
+    )
+    with jax.named_scope("attention"), inner:
+        return _attention(q, k, v, window=window, **options)
 
 
 def _attention(
@@ -1140,6 +1341,7 @@ def _attention(
     return_lse: bool = False,
     layout: str = "bhsd",
     interpret: bool = False,
+    window: int | None = None,
 ):
     """Dispatch: Pallas kernel on TPU for non-trivial sequences, composed
     einsums elsewhere (CPU CI, tiny sequences where one fused XLA softmax
@@ -1155,7 +1357,12 @@ def _attention(
     ``layout="bshd"`` accepts (B, S, H, D) inputs: the reference path then
     runs transpose-free (the fast choice for short sequences, where
     relayouts dominate); the kernel / sequence-parallel paths transpose at
-    this boundary (amortized at the long lengths that select them)."""
+    this boundary (amortized at the long lengths that select them).
+
+    ``window`` (causal calls; the flash kernels and the composed branch):
+    key ``j`` is visible to query ``i`` iff ``j <= i and i - j < window``
+    (:func:`attention` has checked it, and dropped one that reaches every
+    key)."""
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"unknown attention layout {layout!r}")
     seq_ax, head_ax = (1, 2) if layout == "bshd" else (2, 1)
@@ -1180,6 +1387,8 @@ def _attention(
         k, v = jnp.repeat(k, group, head_ax), jnp.repeat(v, group, head_ax)
 
     kind, _, axis = impl.partition(":")
+    if window is not None and (kind in ("ring", "ulysses") or impl == "fused_small"):
+        raise ValueError(f"attention impl {impl!r} takes no window")
     if kind in ("ring", "ulysses"):
         if return_lse:
             raise ValueError("return_lse is not supported through the "
@@ -1225,7 +1434,7 @@ def _attention(
         out = flash_attention(
             to_bhsd(q), to_bhsd(k), to_bhsd(v),
             causal=causal, scale=scale, return_lse=return_lse,
-            interpret=interpret,
+            interpret=interpret, window=window,
         )
         if return_lse:
             return to_bhsd(out[0]), out[1]
@@ -1236,8 +1445,8 @@ def _attention(
             # differentiates through it): plain autodiff keeps this one
             return mha_reference(
                 q, k, v, causal=causal, scale=scale, return_lse=True,
-                layout=layout,
+                layout=layout, window=window,
             )
         scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
-        return _composed(q, k, v, causal, scale, layout)
+        return _composed(q, k, v, causal, scale, layout, window)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -169,6 +169,7 @@ def _moe_health(coll) -> Metrics:
     from jax.tree_util import tree_flatten_with_path
 
     dropped, load_max, rows, imbalance, full_buffer = [], [], [], [], []
+    bias_spread = []
     for path, leaf in tree_flatten_with_path(coll)[0]:
         keys = {getattr(p, "key", getattr(p, "name", "")) for p in path}
         if "dropped_frac" in keys:
@@ -182,11 +183,15 @@ def _moe_health(coll) -> Metrics:
             imbalance.append(jnp.mean(leaf))
         elif "full_buffer" in keys:  # 1.0 where the held prefix overflowed
             full_buffer.append(jnp.mean(leaf))
+        elif "bias_spread" in keys:  # max - min of a selection bias that moves
+            bias_spread.append(jnp.mean(leaf))
     out: Metrics = {}
     if rows:  # summed over the layers; the fullest expert's, their mean
         out["moe_rows"] = jnp.sum(jnp.stack(rows))
         out["moe_load_max_over_mean"] = jnp.mean(jnp.stack(imbalance))
         out["moe_full_buffer_share"] = jnp.mean(jnp.stack(full_buffer))
+    if bias_spread:
+        out["moe_bias_spread"] = jnp.mean(jnp.stack(bias_spread))
     if dropped:
         out["moe_dropped_frac"] = jnp.mean(jnp.stack(dropped))
     if load_max:

@@ -74,8 +74,9 @@ IMAGE_CLASSIFICATION = Task(
 
 def _token_datasets(hparams, model):
     """Train / valid / test splits of Markov walks (``data/tokens.py``),
-    cut like the image splits: ``--limit-examples`` sequences, 90/10.  The
-    vocabulary is the model's held slice."""
+    cut like the image splits: ``--limit-examples`` sequences, 90/10 or
+    ``--valid-examples`` of them for validation.  The vocabulary is the
+    model's held slice."""
     if not getattr(hparams, "synthetic_data", False):
         raise ValueError(
             "a token model trains on the seeded Markov source only: there "
@@ -90,7 +91,10 @@ def _token_datasets(hparams, model):
         return DeviceDataset(rows[:, :-1], rows[:, 1:], vocab, "markov_tokens")
 
     full = split(n, hparams.seed)
-    trn_idx, val_idx = train_val_split(len(full), valid_size=0.1, seed=hparams.seed)
+    trn_idx, val_idx = train_val_split(
+        len(full), valid_size=0.1, seed=hparams.seed,
+        valid_count=getattr(hparams, "valid_examples", 0),
+    )
     return full.subset(trn_idx), full.subset(val_idx), split(min(n, 256), hparams.seed + 1)
 
 

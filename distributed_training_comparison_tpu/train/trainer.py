@@ -3212,6 +3212,13 @@ class Trainer:
             self.metrics.gauge("moe/full_buffer_share").set(
                 self._moe_health["moe_full_buffer_share"]
             )
+        if "moe_bias_spread" in self._moe_health:
+            # a selection bias that the step moves: max - min, the mean over
+            # the expert layers and the epoch's steps (0 while it holds the
+            # rows even by itself; it widens while the rule pulls them back)
+            self.metrics.gauge("moe/bias_spread").set(
+                self._moe_health["moe_bias_spread"]
+            )
         # the per-step signals land in the metric sketches here — one
         # vectorized pass over the stacked arrays, no per-step Python loop;
         # non-finite samples count into the sketch's side counter, so a

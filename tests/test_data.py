@@ -60,6 +60,31 @@ def test_train_val_split_deterministic():
     assert not np.array_equal(a[1], c[1])
 
 
+def test_train_val_split_takes_a_validation_count():
+    """``--valid-examples``: the count itself where the tenth, rounded
+    down, cannot give the split (36 -> 33 / 3); the same shuffle, so the
+    count's first indices are the fraction's; a count that leaves no train
+    example is refused; the token task's splits follow it."""
+    from distributed_training_comparison_tpu.config import load_config
+    from distributed_training_comparison_tpu.models import get_model
+    from distributed_training_comparison_tpu.train.task import task_of
+
+    tenth = train_val_split(36, valid_size=0.1, seed=3)
+    trn, val = train_val_split(36, valid_size=0.1, seed=3, valid_count=4)
+    assert (len(tenth[0]), len(tenth[1]), len(trn), len(val)) == (33, 3, 32, 4)
+    assert np.array_equal(val[:3], tenth[1])
+    assert np.array_equal(np.sort(np.concatenate([trn, val])), np.arange(36))
+    with pytest.raises(ValueError):
+        train_val_split(36, valid_count=36)
+    argv = ["--synthetic-data", "--model", "afmoe_tiny", "--seq-len", "16",
+            "--limit-examples", "36"]
+    for extra, sizes in (([], (33, 3)), (["--valid-examples", "4"], (32, 4))):
+        hp = load_config("tpu", argv + extra)
+        model = get_model(hp.model, model_cut=hp.model_cut)
+        train, valid, _ = task_of(model).datasets(hp, model)
+        assert (len(train), len(valid)) == sizes
+
+
 def test_shard_indices_even_lockstep():
     idx = np.arange(103)
     shards = [shard_indices(idx, 8, s, even=True) for s in range(8)]

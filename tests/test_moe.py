@@ -493,23 +493,104 @@ def test_topk_layer_matches_the_plain_reference():
     np.testing.assert_allclose(got, _ref_moe(variables, x), rtol=2e-4, atol=2e-6)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("shared", [0, 40], ids=["routed_only", "shared_expert"])
+def test_the_shares_add_up_to_the_uncut_layer(shared):
     """Four ranks holding experts 0-3 ... 12-15 of the 16, summed, give
-    what the layer holding all of them gives (guide §4)."""
+    what the layer holding all of them gives (guide §4) — with what every
+    rank computes alike, the shared expert, counted once."""
+    from distributed_training_comparison_tpu.models.token_parts import SwiGLU
+
     x = jax.random.normal(jax.random.key(4), (2, 24, 32))
-    whole, variables = _topk_layer(x)
+    whole = TopKMoE(**TOPK, shared_hidden=shared)
+    variables = whole.init(jax.random.key(0), x)
     want = whole.apply(variables, x)
-    total = 0.0
+    alike = 0.0
+    if shared:
+        alike = SwiGLU(32, shared).apply(
+            {"params": variables["params"]["shared_expert"]}, x
+        )
+        assert float(jnp.abs(alike).max()) > 0
+    total = alike
     for first in range(0, 16, 4):
-        share = TopKMoE(**TOPK, num_experts_held=4, first_expert=first)
-        part = share.apply(_share(variables, first, 4), x)
+        share = TopKMoE(
+            **TOPK, num_experts_held=4, first_expert=first, shared_hidden=shared
+        )
+        part = share.apply(_share(variables, first, 4), x) - alike
         np.testing.assert_allclose(
             part, _ref_moe(_share(variables, first, 4), x, first),
             rtol=2e-4, atol=2e-6,
         )
         total = total + part
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-6)
-    np.testing.assert_allclose(want, _ref_moe(variables, x), rtol=2e-4, atol=2e-6)
+    np.testing.assert_allclose(
+        want - alike, _ref_moe(variables, x), rtol=2e-4, atol=2e-6
+    )
+
+
+def test_the_held_prefix_follows_the_share_held():
+    """``C`` is twice the even share in whole lane tiles, never more than
+    the pairs: 16,384 of 65,536 at an eighth held (LFM2's cell), 8,192 at a
+    sixteenth (the AFMoE cell, as ISSUE 31 wrote it)."""
+    assert held_prefix_rows(65536, 8, 64) == 16384
+    assert held_prefix_rows(65536, 8, 128) == 8192
+    assert held_prefix_rows(320, 4, 16) == 256 and held_prefix_rows(320, 16, 16) == 320
+    assert held_prefix_rows(300, 1, 16) == 128  # whole lane tiles
+
+
+def test_a_bias_rate_of_zero_is_the_constant_buffer():
+    """``bias_update_rate`` 0 (LFM2-MoE): the same draw of the buffer, the
+    same output bit for bit, and a training call that may mutate
+    ``batch_stats`` leaves it as it was."""
+    x = jax.random.normal(jax.random.key(7), (2, 24, 32))
+    layer, variables = _topk_layer(x)
+    same, again = _topk_layer(x, bias_update_rate=0.0)
+    assert float(jnp.abs(variables["batch_stats"]["expert_bias"]).max()) > 0
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: bool(jnp.all(a == b)), variables, again
+    ))
+    want = layer.apply(variables, x)
+    got, mutated = same.apply(
+        variables, x, train=True, mutable=["batch_stats", "moe_metrics"]
+    )
+    assert bool(jnp.all(got == want))
+    assert bool(jnp.all(
+        mutated["batch_stats"]["expert_bias"]
+        == variables["batch_stats"]["expert_bias"]
+    ))
+    assert "bias_spread" not in mutated["moe_metrics"]
+
+
+def test_the_bias_rule_moves_the_buffer_in_training_calls_only():
+    """``b`` starts at zero; a training call adds ``u * sign(mean(c) - c)``,
+    centred, after its routing (the call's own selection used the old
+    ``b``); an eval call, and ``init``, leave it alone."""
+    x = jax.random.normal(jax.random.key(8), (2, 40, 32))
+    layer, variables = _topk_layer(x, bias_update_rate=0.01)
+    bias = variables["batch_stats"]["expert_bias"]
+    assert float(jnp.abs(bias).max()) == 0.0
+    only = {k: variables[k] for k in ("params", "batch_stats")}
+    for _ in range(3):
+        sel, _ = route_topk(
+            x.reshape(-1, 32), only["params"]["router"], bias, TOPK["top_k"]
+        )
+        counts = np.bincount(np.asarray(sel).ravel(), minlength=16)
+        delta = 0.01 * np.sign(counts.mean() - counts)
+        want = np.asarray(bias) + delta - delta.mean()
+        eval_out = layer.apply(only, x)
+        out, mutated = layer.apply(
+            only, x, train=True, mutable=["batch_stats", "moe_metrics"]
+        )
+        assert bool(jnp.all(out == eval_out))  # routed with the old bias
+        bias = mutated["batch_stats"]["expert_bias"]
+        np.testing.assert_allclose(bias, want, rtol=0, atol=1e-9)
+        np.testing.assert_allclose(
+            mutated["moe_metrics"]["bias_spread"][0], want.max() - want.min(),
+            rtol=1e-6,
+        )
+        assert abs(float(bias.sum())) < 1e-7
+        only = {"params": only["params"], "batch_stats": mutated["batch_stats"]}
+    g = jax.grad(lambda v: layer.apply(v, x).sum())(only)
+    assert float(jnp.abs(g["batch_stats"]["expert_bias"]).max()) == 0.0
 
 
 # Biases that decide which of the two sizes a share holding experts 0-3 of

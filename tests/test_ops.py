@@ -448,6 +448,163 @@ def test_flash_plan_other_calls(call, expected):
     assert A.flash_plan(*call, block_q=64, block_k=64)[:2] == (64, 64)
 
 
+# ------------------------------------------------------- sliding window
+
+
+def _dense_window_attention(q, k, v, window, layout="bhsd"):
+    """Attention under a dense boolean mask ``j <= i and i - j < window``,
+    written apart from every mask helper of the op."""
+    if layout == "bshd":
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
+    s = q.shape[2]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(q.shape[-1])
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    scores = jnp.where((j <= i) & (i - j < window), scores, -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, axis=-1), v)
+    return out.transpose(0, 2, 1, 3) if layout == "bshd" else out
+
+
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+@pytest.mark.parametrize("window", [1, 7, 40])
+def test_window_in_reference_and_composed_matches_a_dense_mask(layout, window):
+    """``mha_reference`` and the dispatcher's composed branch (its own VJP)
+    against a dense mask: output and all three gradients."""
+    full, _ = _composed_case(layout, jnp.float32, sq=48, skv=48, b=2)
+    q, k, v, do = full
+    dense = lambda q, k, v: _dense_window_attention(q, k, v, window, layout)  # noqa: E731
+    options = dict(causal=True, window=window, layout=layout)
+    with jax.default_matmul_precision("highest"):
+        want, want_g = dense(q, k, v), _grads(dense, q, k, v, do)
+        for fn in (mha_reference, attention):
+            assert float(jnp.abs(fn(q, k, v, **options) - want).max()) < 2e-6
+            for g, w, name in zip(_grads(fn, q, k, v, do, **options), want_g, "qkv"):
+                assert float(jnp.abs(g - w).max()) < 1e-5, (fn.__name__, name)
+
+
+def test_a_window_that_reaches_every_key_is_the_causal_call():
+    """``window >= S``: the same program as the plain causal call (the
+    dispatcher and ``flash_plan`` drop it), so the same bits."""
+    A = _attention_module()
+    (q, k, v, do), _ = _composed_case("bshd", jnp.float32, sq=64, skv=64, b=2)
+    causal = dict(causal=True, layout="bshd")
+    want = attention(q, k, v, **causal)
+    for window in (64, 65, 1000):
+        assert bool(jnp.all(attention(q, k, v, window=window, **causal) == want))
+        assert A.flash_plan(64, 64, 64, 1, True, q.dtype, window=window).window is None
+        text = jax.jit(
+            lambda q, k, v: attention(q, k, v, window=window, **causal)
+        ).lower(q, k, v).compile().as_text()
+        assert "/attention/" in text and "attention_window" not in text
+    text = jax.jit(
+        lambda q, k, v: attention(q, k, v, window=63, **causal)
+    ).lower(q, k, v).compile().as_text()
+    assert "/attention/attention_window/" in text
+    assert A.flash_plan(64, 64, 64, 1, True, q.dtype, window=63).window == 63
+    with pytest.raises(ValueError, match="causal"):
+        attention(q, k, v, window=8, layout="bshd")
+    with pytest.raises(ValueError, match="takes no window"):
+        attention(q, k, v, causal=True, window=8, layout="bshd", impl="ring")
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=8, interpret=True)
+
+
+# The flash kernels with a window, in interpret mode, against the composed
+# branch.  640 keys are five 128-tiles: windows of one tile and a half
+# (192: not a tile multiple), of exactly two tiles (256), narrower than a
+# tile (50), padded keys (600); ``split`` forces the two tiled backward
+# kernels (the cell's own at 8,192 keys), ``streamed`` the tiled forward.
+@pytest.mark.parametrize(
+    "s,window,d,h,hkv,split,streamed",
+    [
+        (640, 192, 64, 2, 1, False, False),
+        (640, 256, 64, 2, 1, True, False),
+        (640, 50, 128, 8, 1, True, True),
+        (600, 130, 64, 4, 2, False, True),
+        (640, 300, 128, 8, 1, False, False),
+        (1024, 300, 64, 1, 1, True, False),
+    ],
+    ids=["w192_fused", "w256_split", "w50_head128_group8_split_streamed",
+         "w130_padded_fused_streamed", "w300_head128_group8_fused",
+         "w300_tile512_split"],
+)
+def test_flash_window_matches_composed(monkeypatch, s, window, d, h, hkv,
+                                       split, streamed):
+    A = _attention_module()
+    if split:
+        monkeypatch.setattr(A, "_FUSED_BWD_RESIDENT_LIMIT", 0)
+    if streamed:
+        monkeypatch.setattr(A, "_FWD_RESIDENT_KV_LIMIT", 0)
+    kq, kk, kv, kdo = jax.random.split(jax.random.key(s + window), 4)
+    q = jax.random.normal(kq, (1, h, s, d))
+    k = jax.random.normal(kk, (1, hkv, s, d))
+    v = jax.random.normal(kv, (1, hkv, s, d))
+    do = jax.random.normal(kdo, (1, h, s, d))
+    plan = A.flash_plan(s, s, d, h // hkv, True, q.dtype, window=window)
+    assert plan.window == window and plan.fused_bwd != split
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=window, interpret=True
+    )
+    composed = lambda q, k, v: attention(  # noqa: E731
+        q, k, v, causal=True, window=window, impl="reference"
+    )
+    with jax.default_matmul_precision("highest"):
+        got, want = flash(q, k, v), composed(q, k, v)
+        got_g, want_g = _grads(flash, q, k, v, do), _grads(composed, q, k, v, do)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    for g, w, name in zip(got_g, want_g, "qkv"):
+        assert g.shape == w.shape
+        assert float(jnp.abs(g - w).max()) < 1e-3, f"d{name}"
+
+
+@pytest.mark.parametrize(
+    "s,window,tile,tiles",
+    [(8192, 2048, 512, 70), (8192, None, 512, 136), (8192, 512, 512, 31),
+     (8192, 514, 512, 45), (4096, 2048, 512, 30), (640, 50, 128, 9)],
+)
+def test_flash_plan_visits_only_the_tiles_that_meet_the_band(s, window, tile, tiles):
+    """The visited-tile count as a pure function of the call: 70 of the
+    causal triangle's 136 tiles at the AFMoE cell's sliding layer; every
+    visited tile holds a visible (query, key) pair and every visible pair
+    is in a visited tile; the backward's bounds name the same tiles, its
+    grids step no further than a band's blocks, and the mask-free range
+    holds only tiles that need no mask."""
+    A = _attention_module()
+    plan = A.flash_plan(s, s, 128, 8, True, jnp.bfloat16, window=window)
+    assert (plan.block_q, plan.block_k, plan.bwd_block_q) == (tile,) * 3
+    visited = A.band_tiles(s, tile, tile, plan.window)
+    assert len(visited) == tiles
+    w = s if window is None else window
+    meets = {
+        (i, j) for i in range(s // tile) for j in range(i + 1)
+        # the tile's last column is seen by its first row, or later ones
+        if i * tile - ((j + 1) * tile - 1) < w
+    }
+    assert set(visited) == meets
+    if window is None:
+        return
+    n = s // tile
+    by_key = {
+        (i, j) for j in range(n)
+        for i in range(A._causal_first_q(j, tile, tile),
+                       min(int(A._band_end_q(j, tile, tile, w)), n))
+    }
+    assert by_key == meets
+    assert A._band_steps(tile, tile, w, n, n, w - 1) == max(
+        sum(1 for (i, j) in meets if i == row) for row in range(n)
+    )
+    assert A._band_steps(tile, tile, w, n, n, 0) == max(
+        sum(1 for (i, j) in meets if j == col) for col in range(n)
+    )
+    for i in range(n):
+        for j in range(int(A._band_free_k(i, tile, tile, w)), i):
+            assert (i + 1) * tile - 1 - j * tile < w  # no lower-edge mask
+    for j in range(n):
+        for i in range(j + 1, min(int(A._band_free_q(j, tile, tile, w)), n)):
+            assert (i + 1) * tile - 1 - j * tile < w
+
+
 def test_attention_dispatcher_grouped_heads():
     """Fewer key-value heads than query heads: every implementation but
     the flash kernels sees them repeated, in both layouts."""

@@ -114,6 +114,27 @@ def test_flash_attention_compiles_for_v5e(chip, shape, backward, causal):
         assert text.count('custom_call_target="tpu_custom_call"') == 1 + backward
 
 
+# (batch, query heads, key-value heads, tokens, head size, window).  The
+# AFMoE cell's sliding layer — the resident forward at its VMEM limit and the
+# two tiled backward kernels on a band's grid; a window under the fused
+# backward (4,096 keys); under the streamed forward; narrower than a tile
+WINDOW_SHAPES = {
+    "trinity": (1, 32, 4, 8192, 128, 2048), "fused": (4, 32, 8, 4096, 64, 2048),
+    "s16384": (1, 4, 4, 16384, 128, 2048), "narrow": (1, 8, 1, 8192, 128, 300),
+}
+
+
+@pytest.mark.parametrize("shape", WINDOW_SHAPES.values(), ids=WINDOW_SHAPES)
+def test_flash_attention_with_a_window_compiles_for_v5e(chip, shape):
+    b, h, hkv, s, d, window = shape
+    attn = lambda q, k, v: flash_attention(q, k, v, causal=True, window=window)  # noqa: E731
+    text = _compiled_text(
+        _grad_of(attn, 3), chip, _s(b, h, s, d), _s(b, hkv, s, d), _s(b, hkv, s, d)
+    )
+    fused = s <= 4096
+    assert text.count('custom_call_target="tpu_custom_call"') == (2 if fused else 3)
+
+
 def test_flash_attention_with_lse_compiles_for_v5e(chip):
     """Ring attention's call at the token cell's shape: the ``lse`` output
     and its cotangent through the fused backward."""

@@ -76,6 +76,48 @@ def test_flash_token_cell_shape():
         assert err < limit, f"d{name} diverged on-chip: {err} (limit {limit})"
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["sliding", "full"])
+def test_flash_window_cell_shape(window):
+    """``trinity_ep16_seq8k_job``'s attention layers (one sequence, 32 query
+    heads on 4 key-value heads, 8,192 keys, head size 128): the resident
+    forward at its VMEM limit and the two tiled backward kernels (past the
+    fused one's budget, key-value heads repeated), with a 2,048-key window
+    — 70 visited tiles of 512 x 512 — and without, against the reference at
+    bf16 tolerance.  The reference holds float32 scores, so it takes the
+    first key-value head and the 8 query heads it serves (the loss is a
+    sum: their gradients are their own)."""
+    kq, kk, kv = jax.random.split(jax.random.key(31), 3)
+    q = jax.random.normal(kq, (1, 32, 8192, 128), jnp.bfloat16)
+    k = jax.random.normal(kk, (1, 4, 8192, 128), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, 4, 8192, 128), jnp.bfloat16)
+
+    def loss(fn):
+        return lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum()
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=window
+    )
+    ref = lambda q, k, v: mha_reference(  # noqa: E731
+        q, jnp.repeat(k, 8, 1), jnp.repeat(v, 8, 1), causal=True, window=window
+    )
+    one = (q[:, :8], k[:, :1], v[:, :1])
+    out = jax.jit(flash)(q, k, v)[:, :8].astype(jnp.float32)
+    want = jax.jit(ref)(*one).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(out - want))) < 0.05
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(*one)
+    for a, b_, name, heads in zip(gf, gr, "qkv", (8, 1, 1)):
+        got = a[:, :heads].astype(jnp.float32)
+        b32 = b_.astype(jnp.float32)
+        assert got.shape == b32.shape
+        err = float(jnp.max(jnp.abs(got - b32)))
+        limit = 0.1 + 0.01 * float(jnp.max(jnp.abs(b32)))
+        assert err < limit, f"d{name} diverged on-chip: {err} (limit {limit})"
+    if window is not None:  # the band is there: the full call differs
+        full = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, v)
+        assert float(jnp.max(jnp.abs(full[:, :8, 4096:].astype(jnp.float32) - want[:, :, 4096:]))) > 0.05
+
+
 def test_tiled_forward_engages_and_agrees():
     """S=16384 exceeds the resident-K/V limit: the streamed forward must
     compile and run (it could not before round 4); at S=4096 both paths
