@@ -28,8 +28,9 @@ that gap:
   monitoring listener catches the cache's own hit events), the
   cost/memory analysis, the device kind/count the ``run_report
   --compute`` MFU reconstruction needs, which path each kernel gate took
-  while the program traced (``kernel_paths``, see ``note_kernel_path``)
-  and how many compiled Pallas kernels the executable carries
+  while the program traced (``kernel_paths``, see ``note_kernel_path``),
+  which backward each flash-attention call site took (``flash_backward``,
+  see ``note_flash_backward``) and how many compiled Pallas kernels the executable carries
   (``tpu_custom_calls``).
 - ``compile/*`` metrics ride the existing registry (and therefore every
   ``metrics`` flush, the OpenMetrics exporter, and ``--alert`` rules):
@@ -130,24 +131,38 @@ def note_kernel_path(kernel: str, path: str) -> None:
     built, so an observed compile collects what was noted while it lowered
     and puts it on its ``compile`` event (``kernel_paths``).  A no-op
     outside an observed compile."""
-    notes = getattr(_probe_local, "kernel_paths", None)
+    notes = getattr(_probe_local, "notes", None)
     if notes is not None:
-        notes[kernel] = path
+        notes["kernel_paths"][kernel] = path
+
+
+def note_flash_backward(form: str) -> None:
+    """Count, at TRACE time, one flash-attention call site whose backward
+    took ``form``: ``"fused"`` (one kernel) or ``"tiled"`` (two; what
+    ``ops/attention.py flash_plan`` leaves to a call whose residents do not
+    fit VMEM).  Like ``note_kernel_path`` a fact about the executable being
+    built: on its ``compile`` event as ``flash_backward: {form: call
+    sites}``, a key of its own.  A no-op outside an observed compile."""
+    notes = getattr(_probe_local, "notes", None)
+    if notes is not None:
+        counts = notes["flash_backward"]
+        counts[form] = counts.get(form, 0) + 1
 
 
 class _CacheProbe:
     """Bracket one lower+compile: classify its persistent-cache outcome and
-    collect the kernel paths its trace noted."""
+    collect what its trace noted (``notes``: a ``compile`` event's key to
+    its value, left out where nothing was noted)."""
 
     def __enter__(self) -> "_CacheProbe":
         _ensure_probe()
         self._before = getattr(_probe_local, "hits", 0)
-        self.kernel_paths: dict[str, str] = {}
-        _probe_local.kernel_paths = self.kernel_paths
+        self.notes: dict[str, dict] = {"kernel_paths": {}, "flash_backward": {}}
+        _probe_local.notes = self.notes
         return self
 
     def __exit__(self, *exc) -> None:
-        _probe_local.kernel_paths = None
+        _probe_local.notes = None
 
     def outcome(self) -> str:
         if getattr(_probe_local, "hits", 0) > self._before:
@@ -295,7 +310,7 @@ class CompileMonitor:
             compile_s = time.perf_counter() - t0
         rec = self._record_compile(
             name, fingerprint_of(name, parts), compile_s,
-            compiled, probe.outcome(), sentinel, probe.kernel_paths,
+            compiled, probe.outcome(), sentinel, probe.notes,
         )
         return compiled, rec
 
@@ -343,14 +358,14 @@ class CompileMonitor:
 
     def _record_compile(
         self, name, fingerprint, compile_s, compiled, cache, sentinel,
-        kernel_paths=None,
+        notes=None,
     ) -> ExecutableRecord:
         """Fold one observed compile into the ledger, the registry, and
         the bus.  Never raises (the caller is the training hot path)."""
         try:
             return self._record_compile_inner(
                 name, fingerprint, compile_s, compiled, cache, sentinel,
-                kernel_paths,
+                notes,
             )
         except Exception:
             rec = ExecutableRecord(name, fingerprint)
@@ -359,7 +374,7 @@ class CompileMonitor:
 
     def _record_compile_inner(
         self, name, fingerprint, compile_s, compiled, cache, sentinel,
-        kernel_paths,
+        notes,
     ) -> ExecutableRecord:
         self._taint.flag = True
         cost = executable_cost_analysis(compiled) if compiled is not None else None
@@ -432,8 +447,9 @@ class CompileMonitor:
             if rec.memory:
                 payload.update(rec.memory)
                 payload["peak_bytes"] = rec.peak_bytes
-            if kernel_paths:
-                payload["kernel_paths"] = dict(kernel_paths)
+            payload.update(
+                {key: dict(noted) for key, noted in (notes or {}).items() if noted}
+            )
             mosaic = _mosaic_kernel_count(compiled)
             if mosaic is not None:
                 payload["tpu_custom_calls"] = mosaic
@@ -583,7 +599,7 @@ class _InstrumentedFunction:
         )
         rec = self._monitor._record_compile(
             self._name, fingerprint, compile_s, compiled, cache,
-            self._sentinel, probe.kernel_paths,
+            self._sentinel, probe.notes,
         )
         entry = (compiled, rec)
         self._cache[key] = entry

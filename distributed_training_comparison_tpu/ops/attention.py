@@ -27,20 +27,27 @@ Kernel design (TPU-first, not a CUDA translation):
   shapes must tile to (8, 128) unless a block dim spans the whole array.
 - Backward, wired via ``jax.custom_vjp`` over saved ``(out, lse)``
   residuals, in one of two forms that :func:`flash_plan` chooses from the
-  call's shapes.  **Fused** (``_bwd_fused_kernel``; calls whose q, dO and
-  dq fit VMEM whole — 4,096 tokens in bf16): one kernel takes the scores,
-  the probabilities and dO·vᵀ once a visited tile and writes dq, dk and dv
-  — five matmuls and one ``exp`` a score element.  **Two tiled kernels**
-  (``_dq_kernel``, ``_dkv_kernel``; any length): a 3D grid (batch·heads,
-  own block, streamed block) accumulates into fp32 scratch across the
-  innermost grid dimension, so the only VMEM residents are fixed-size
-  tiles — seven matmuls and two ``exp``.  (Round 3 shipped a backward
-  that kept whole-sequence Q/dO in VMEM behind a hand-written footprint
-  formula that mis-predicted Mosaic's stack accounting and OOMed scoped
-  VMEM at S=4096, D=128, bh=32; the fused kernel's residents are blocks
-  and scratch Pallas itself allocates, inside the scoped VMEM a kernel
-  gets unasked, and ``tests/test_tpu_compile.py`` compiles it at its
-  largest shapes.)
+  call's shapes.  **Fused** (``_bwd_fused_kernel``): one kernel takes the
+  scores, the probabilities and dO·vᵀ once a visited tile and writes dq, dk
+  and dv — five matmuls and one ``exp`` a score element.  What it holds in
+  VMEM is the query rows a key block can reach (PR 32): key blocks run in
+  increasing order, a causal query tile's dq is finished when the key block
+  on its diagonal is done and leaves the kernel then, and with a window q,
+  dO and float32 dq are a ring of ``window + 2 tiles`` rows that the
+  kernel's own DMA fills a tile a key step — a sliding layer fits at any
+  length.  Without a window the ring is the sequence (8,192 tokens at head
+  size 128 in bf16, 8.0 MiB).  **Two tiled kernels** (``_dq_kernel``,
+  ``_dkv_kernel``; any length): a 3D grid (batch·heads, own block, streamed
+  block) accumulates into fp32 scratch across the innermost grid dimension,
+  so the only VMEM residents are fixed-size tiles — seven matmuls and two
+  ``exp``; what is left to them is a call whose sequence does not fit and
+  has no window (16,384 keys; a non-causal call, whose dq is whole, past
+  4,096).  (Round 3 shipped a backward that kept whole-sequence Q/dO in
+  VMEM behind a hand-written footprint formula that mis-predicted Mosaic's
+  stack accounting and OOMed scoped VMEM at S=4096, D=128, bh=32; the fused
+  kernel's residents are blocks and scratch Pallas itself allocates, inside
+  the scoped VMEM a kernel gets unasked, and ``tests/test_tpu_compile.py``
+  compiles it at its largest shapes.)
 - **The causal tile plan** (PR 30).  A causal call does the lower
   triangle's work once: square tiles tight to the diagonal ((512, 512)
   visits 56 % of a 4,096-key square where 128-row query tiles under
@@ -53,8 +60,13 @@ Kernel design (TPU-first, not a CUDA translation):
 - **Head size and grouped heads as they are.**  A head size that divides
   the 128 lanes goes in unpadded (a block whose minor dim spans the array),
   and ``k`` / ``v`` may hold one head for ``group`` consecutive query
-  heads: index maps read head ``h // group`` and the fused backward sums
-  the group's dk / dv in VMEM.
+  heads: index maps read head ``h // group``, forward and backward.  The
+  fused backward sums the group's dk / dv in VMEM where the two
+  whole-sequence float32 accumulators fit beside q, dO and dq (4,096 keys
+  at head size 64); where they do not (8,192 keys at head size 128: 8.4 MB
+  of accumulators) each query head writes its own dk / dv and the group's
+  sum is taken outside in float32.  Only the two tiled kernels see one
+  key-value head a query head (``jnp.repeat`` before the forward).
 
 The *forward* has two shapes: up to ~8k keys (bf16) whole-sequence K/V
 live in VMEM per (batch, head) instance — 2·S·128·2 bytes, loaded once
@@ -79,6 +91,17 @@ kernels): non-causal 27.8 -> 27.7, causal 18.2 -> 16.7.  What bounds a
 512 x 512 tile at head size 64 is neither the half-filled MXU alone nor the
 VPU's multiplies (folding the scale into q moved nothing): 1.3 us in the
 forward and 2.2 in the fused backward for 262 k exponentials a tile.
+
+PR 32, one v5e chip, one sequence of 8,192 keys, 32 query heads on 4
+key-value heads, head size 128, recomputed forward + backward through the
+dispatcher (``PERF.md`` §6).  With a window of 2,048 (70 visited tiles a
+head): 13.19 -> 9.63 ms — the two tiled backward kernels 9.2, the fused one
+on a ring of six tiles 5.8 with the group's sum outside, 2.6 us a tile; the
+forward 4.01 -> 3.81 with k and v no longer repeated.  Without one (136
+tiles): 22.78 -> 15.10 — backward 17.0 -> 9.5, 2.2 us a tile; forward 5.80
+-> 5.59.  16,384 keys under that window (4 heads): 4.14 -> 3.53.  The
+calls that were fused already did not move: 4 x 32 heads at 4,096 keys and
+head size 64 18.32 -> 18.14, 8 x 4 heads at head size 128 5.02 -> 4.96.
 """
 
 from __future__ import annotations
@@ -93,7 +116,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..obs.compilation import note_kernel_path
+from ..obs.compilation import note_flash_backward, note_kernel_path
 
 _NEG_INF = -1e30  # finite "-inf": keeps fully-masked rows NaN-free
 
@@ -810,8 +833,11 @@ def _flash_bwd_split(
     q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len, interpret
 ):
     """Two fully-tiled backward kernels, one key-value head a query head:
-    what a call gets whose q, dO and dq do not fit VMEM whole
-    (:func:`flash_plan`).  They stream their own (512, 512) tiles,
+    what a call gets whose fused residents do not fit VMEM
+    (:func:`flash_plan`) — since PR 32 a sequence without a window past
+    8,192 keys at head size 128 in bf16 (16,384 keys: 16 MiB of q, dO and
+    float32 dq), a non-causal call past 4,096, a causal call whose caller
+    chose unequal blocks.  They stream their own (512, 512) tiles,
     independent of the forward's blocks — per-instance VMEM is a handful
     of fixed-size blocks (~6 MiB at D=128) regardless of sequence length.
     Tile sweep on a v5e at S=4096, D=128, older than the growth PRs
@@ -820,7 +846,10 @@ def _flash_bwd_split(
     size 64, 4,096 causal keys, 128 heads (ms a call with both forwards):
     these two kernels 44.0, with skipped steps fetching nothing 38.6 —
     44 % of the streamed K/V and Q/dO traffic was for tiles above the
-    diagonal — and the fused kernel in their place 29.9."""
+    diagonal — and the fused kernel in their place 29.9.  PR 32 at head size
+    128, 8,192 keys, 32 heads (ms a call, backward alone): these two
+    kernels 9.2 under a window of 2,048 and 17.0 without, 4.0 us a visited
+    tile, the fused kernel 5.8 and 9.5."""
     bh, sq, d = q3.shape
     skv = k3.shape[1]
     # (bh, sq) → (bh, sq, 8) stub minor dim, matching lse's layout
@@ -916,33 +945,114 @@ def _flash_bwd_split(
 
 def _bwd_fused_kernel(
     q_ref, do_ref, lse_ref, adj_ref, k_ref, v_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, *kv_acc, scale, causal, block_q, kv_len, group, window=None,
+    dq_acc, *scratch, scale, causal, block_q, nq, ring, kv_len, group, summed,
+    window=None,
 ):
     """dq, dk and dv of one key block ``j`` against one query head, the
     scores, the probabilities and dO·vᵀ taken once a visited tile.  Grid
-    (batch · key-value heads, query heads a key-value head, key blocks):
-    the head's q and dO are whole in VMEM (fetched once a head), and the
-    query tiles at/below key block ``j``'s diagonal are a loop inside the
-    kernel — a tile above the diagonal is neither a grid step nor a fetch.
-    Tiles have keys on the sublanes (``sᵀ = k qᵀ``): dv and dk are plain
-    ``(bk, bq) x (bq, d)`` products and only dq contracts over the
-    sublanes, where the two-kernel form has two such contractions.  dq
-    accumulates over key blocks in ``dq_acc`` (float32, whole sequence),
-    dk and dv over the heads of a group in ``kv_acc`` (float32, whole
-    sequence; no scratch where a key-value head serves one query head)."""
-    block_k, d = k_ref.shape
-    nq = q_ref.shape[0] // block_q
-    g, j = pl.program_id(1), pl.program_id(2)
+    (batch · key-value heads, query heads a key-value head, key blocks),
+    key blocks in increasing order; the query tiles at/below key block
+    ``j``'s diagonal — with a ``window``, down to the band's lower edge —
+    are a loop inside the kernel, so a tile outside the band is neither a
+    grid step nor a fetch.  Tiles have keys on the sublanes (``sᵀ = k qᵀ``):
+    dv and dk are plain ``(bk, bq) x (bq, d)`` products and only dq
+    contracts over the sublanes, where the two-kernel form has two such
+    contractions.
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+    **What is held is the query rows a key block can reach**: ``ring``
+    tiles of q, dO and float32 dq (``dq_acc``), tile ``i`` in slot ``i %
+    ring``.  Where the ring is the sequence (``ring == nq``: no window)
+    ``q_ref`` and ``do_ref`` are the head's whole blocks, fetched once a
+    head.  Where it is shorter they are the arrays in HBM and the tiles
+    arrive by the kernel's own DMA into ``q_ring`` / ``do_ring``: a head's
+    first step fetches the band of key block 0; step ``j`` waits for the
+    tiles that enter the band with key block ``j`` (started a step ago),
+    zeroes their dq, and starts the next step's — so the ring is one tile
+    more than a band holds.  A causal query tile's dq is finished when the
+    key block on its diagonal is done (tiles are square): dq leaves by a
+    ``(bq, d)`` output block indexed by ``j``.  A non-causal call writes
+    dq whole at the last key block.
+
+    dk and dv: ``summed`` adds the heads of a group in ``kv_acc`` (float32,
+    whole sequence by the grid's order) and writes a key-value head's block
+    at the group's last head; otherwise each query head writes its own
+    block (the wrapper takes the group's sum in float32)."""
+    block_k, d = k_ref.shape
+    g, j = pl.program_id(1), pl.program_id(2)
+    nk = pl.num_programs(2)
+    streamed = ring < nq
+    rings, kv_acc = (scratch[:3], scratch[3:]) if streamed else ((), scratch)
+
+    def slot_of(i):
+        return jax.lax.rem(i, ring) if streamed else i
+
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(slot_of(i) * block_q, block_q), block_q)
+
+    def band_end(jj):
+        """One past the last query tile key block ``jj`` reaches."""
+        if window is None:
+            return nq
+        return jnp.minimum(_band_end_q(jj, block_q, block_k, window), nq)
+
+    hi = band_end(j)
+
+    if not streamed:
+
+        @pl.when(j == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    else:
+        q_ring, do_ring, sems = rings
+        head = pl.program_id(0) * group + g
+
+        def copies(i):
+            src = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            return [
+                pltpu.make_async_copy(
+                    hbm.at[head, src, :], held.at[rows_of(i), :],
+                    sems.at[n, slot_of(i)],
+                )
+                for n, (hbm, held) in enumerate(
+                    ((q_ref, q_ring), (do_ref, do_ring))
+                )
+            ]
+
+        def each(first, last, act):
+            def body(i, carry):
+                act(i)
+                return carry
+
+            jax.lax.fori_loop(first, last, body, 0)
+
+        def start(i):
+            for c in copies(i):
+                c.start()
+
+        def arrive(i):
+            for c in copies(i):
+                c.wait()
+            dq_acc[rows_of(i), :] = jnp.zeros((block_q, d), jnp.float32)
+
+        @pl.when(j == 0)
+        def _fill():
+            each(0, hi, start)
+
+        # the tiles that enter the band with this key block
+        each(jnp.where(j == 0, 0, band_end(j - 1)), hi, arrive)
+
+        @pl.when(j + 1 < nk)
+        def _ahead():
+            each(hi, band_end(j + 1), start)
+
+        q_ref, do_ref = q_ring, do_ring
 
     kb, vb = k_ref[...], v_ref[...]
 
     def tile(i, carry, *, masked):
         dk, dv = carry
-        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        rows = rows_of(i)
         qb, dob = q_ref[rows, :], do_ref[rows, :]
         pt = jnp.exp(_scores(kb, qb, scale) - lse_ref[pl.ds(i, 1), :])
         if masked:
@@ -977,9 +1087,6 @@ def _bwd_fused_kernel(
     # with a window, the masked ones that straddle the band's lower edge,
     # and none behind it
     lo = _causal_first_q(j, block_q, block_k) if causal else 0
-    hi = nq
-    if window is not None:
-        hi = jnp.minimum(_band_end_q(j, block_q, block_k, window), nq)
     free = _causal_free_q(j, block_q, block_k) if causal else 0
     free = jnp.where((j + 1) * block_k > kv_len, hi, free)
     free = jnp.clip(free, lo, hi)
@@ -999,7 +1106,7 @@ def _bwd_fused_kernel(
         )
     dk, dv = carry
 
-    if group == 1:
+    if not summed:
         dk_ref[...] = dk.astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
     else:
@@ -1021,26 +1128,58 @@ def _bwd_fused_kernel(
             dk_ref[...] = dk_acc[keys, :].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[keys, :].astype(dv_ref.dtype)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _write_q():
-        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+    if causal:
+        dq_ref[...] = dq_acc[rows_of(j), :].astype(dq_ref.dtype)
+    else:
+
+        @pl.when(j == nk - 1)
+        def _write_q():
+            dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-# What ``flash_plan`` lets the fused backward keep whole in VMEM: q, dO and
-# dq (single-buffered: they move once a head) and the float32 accumulators.
-# With ~6 MiB of score-tile temporaries a call at this limit compiles inside
-# the 16 MiB of scoped VMEM a kernel gets unasked — and asking for more is
-# not free: under ``vmem_limit_bytes`` of 32 MiB the train program's
-# bandwidth-bound fusions *outside* the kernel ran slower (PR 30: the selects
-# around the expert layer's kernels, +2.8 ms a step in four layers that do
-# not touch attention), the compiler having less VMEM left to prefetch into.
+# What ``flash_plan`` lets the fused backward hold in VMEM
+# (``_fused_bwd_resident_bytes``): the rings of q and dO and the float32
+# accumulators, each single-buffered.  With ~6 MiB of score-tile temporaries
+# a call at this limit compiles inside the 16 MiB of scoped VMEM a kernel
+# gets unasked — and asking for more is not free: under ``vmem_limit_bytes``
+# of 32 MiB the train program's bandwidth-bound fusions *outside* the kernel
+# ran slower (PR 30: the selects around the expert layer's kernels, +2.8 ms
+# a step in four layers that do not touch attention), the compiler having
+# less VMEM left to prefetch into.
 _FUSED_BWD_RESIDENT_LIMIT = 9 * 2**20
 
 
-def _fused_bwd_resident_bytes(sq, skv, d, group, dtype) -> int:
+def _bwd_ring_tiles(sq, skv, d, tile, window=None) -> int:
+    """Query tiles the fused backward holds for a call of padded lengths
+    under ``tile``: every tile — but with a ``window``, the most a key
+    block's band reaches and one more (the next step's arrives while this
+    one computes).  The ring's tiles are sliced out of HBM by DMA, which
+    Mosaic refuses across a minor dimension narrower than the lanes (head
+    size 64 unpadded: compiled for a described v5e), so such a call holds
+    the sequence."""
+    nq = sq // tile
+    if window is None or d % 128:
+        return nq
+    return min(nq, _band_steps(tile, tile, window, skv // tile, nq, 0) + 1)
+
+
+def _fused_bwd_resident_bytes(
+    sq, skv, d, group, dtype, window=None, *, causal=True, tile=512,
+    summed=True,
+) -> int:
+    """Bytes ``_bwd_fused_kernel`` keeps across grid steps at padded
+    lengths ``sq`` / ``skv``: q, dO and float32 dq for the rows a key
+    block's band can reach (``_bwd_ring_tiles``; the sequence without a
+    window), a non-causal call's dq output whole, and — ``summed`` — the
+    group's dk / dv accumulators, whole by the grid's order."""
     lanes, item = _ceil_to(d, 128), jnp.dtype(dtype).itemsize
-    whole = sq * lanes * (2 * item + item + 4)  # q, dO; dq; dq_acc
-    return whole + (2 * skv * lanes * 4 if group > 1 else 0)
+    rows = _bwd_ring_tiles(sq, skv, d, tile, window) * tile
+    held = rows * lanes * (2 * item + 4)  # q, dO; dq_acc
+    if not causal:
+        held += sq * lanes * item
+    if summed and group > 1:
+        held += 2 * skv * lanes * 4
+    return held
 
 
 def _flash_bwd_fused(
@@ -1051,6 +1190,8 @@ def _flash_bwd_fused(
     bkv, skv = k3.shape[:2]
     bq, bk = plan.bwd_block_q, plan.bwd_block_k
     nq, nk = sq // bq, skv // bk
+    ring, summed = plan.bwd_ring, plan.bwd_group_sum
+    assert not causal or (bq == bk and nq == nk), "a causal call's tiles are square"
     # per-row terms with the rows on the lanes, a query tile a sublane row
     lse_rows = lse[:, :, 0].reshape(bh, nq, bq)
     adj_rows = (dlse[:, :, 0] - delta).reshape(bh, nq, bq)
@@ -1058,42 +1199,67 @@ def _flash_bwd_fused(
     def head(b, g, j):
         return (b * group + g, 0, 0)
 
+    def query_tile(b, g, j):
+        return (b * group + g, j, 0)
+
     def key_block(b, g, j):
         return (b, j, 0)
 
     def key_block_out(b, g, j):
+        if not summed:  # a query head's own block
+            return (b * group + g, j, 0)
         # the block leaves VMEM when its index moves: hold block 0 until
         # the group's last head, whose steps write each block in turn
         return (b, jnp.where(g == group - 1, j, 0), 0)
 
-    # one buffer each: a second would only hide a head's 1.5 MiB of q, dO
-    # and dq behind the eight key blocks before it, at 3 MiB of VMEM
+    # one buffer for what moves once a head: a second would only hide a
+    # head's q and dO behind the key blocks before it, at twice the VMEM
     whole = pl.BlockSpec((None, sq, d), head, pipeline_mode=pl.Buffered(1))
+    held, rings = whole, []
+    if ring < nq:  # q and dO stay in HBM; the kernel fetches the band's tiles
+        held = pl.BlockSpec(memory_space=pl.ANY)
+        rings = [
+            pltpu.VMEM((ring * bq, d), q3.dtype),
+            pltpu.VMEM((ring * bq, d), do3.dtype),
+            pltpu.SemaphoreType.DMA((2, ring)),
+        ]
     rows = pl.BlockSpec((None, nq, bq), head)
     tile = pl.BlockSpec((None, bk, d), key_block)
-    tile_out = pl.BlockSpec(
-        (None, bk, d), key_block_out if group > 1 else key_block
-    )
-    kv_acc = [pltpu.VMEM((skv, d), jnp.float32)] * 2 if group > 1 else []
-    return pl.pallas_call(
+    tile_out = pl.BlockSpec((None, bk, d), key_block_out)
+    dq_out = pl.BlockSpec((None, bq, d), query_tile) if causal else whole
+    kv_heads = bkv if summed else bh
+    kv_acc = [pltpu.VMEM((skv, d), jnp.float32)] * 2 if summed else []
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq,
-            kv_len=kv_len, group=group, window=plan.window,
+            _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq, nq=nq,
+            ring=ring, kv_len=kv_len, group=group, summed=summed,
+            window=plan.window,
         ),
         grid=(bkv, group, nk),
-        in_specs=[whole, whole, rows, rows, tile, tile],
-        out_specs=[whole, tile_out, tile_out],
+        in_specs=[held, held, rows, rows, tile, tile],
+        out_specs=[dq_out, tile_out, tile_out],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-            jax.ShapeDtypeStruct((bkv, skv, d), k3.dtype),
-            jax.ShapeDtypeStruct((bkv, skv, d), v3.dtype),
+            jax.ShapeDtypeStruct((kv_heads, skv, d), k3.dtype),
+            jax.ShapeDtypeStruct((kv_heads, skv, d), v3.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32), *kv_acc],
+        scratch_shapes=[
+            pltpu.VMEM((ring * bq, d), jnp.float32), *rings, *kv_acc
+        ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
     )(q3, do3, lse_rows, adj_rows, k3, v3)
+    if kv_heads != bkv:
+        # the group's sum in float32, as the kernel's own accumulators take it
+
+        def group_sum(x):
+            x = x.reshape(bkv, group, skv, d).astype(jnp.float32)
+            return x.sum(axis=1).astype(k3.dtype)
+
+        dk, dv = group_sum(dk), group_sum(dv)
+    return dq, dk, dv
 
 
 # ----------------------------------------------------- custom_vjp plumbing
@@ -1122,6 +1288,7 @@ def _flash_core_bwd(scale, causal, plan, kv_len, group, interpret, res, cots):
     delta = jnp.sum(
         do3.astype(jnp.float32) * out3.astype(jnp.float32), axis=-1
     )
+    note_flash_backward("fused" if plan.fused_bwd else "tiled")
     if plan.fused_bwd:
         return tuple(_flash_bwd_fused(
             q3, k3, v3, lse, do3, dlse, delta, scale, causal, plan, kv_len,
@@ -1155,6 +1322,11 @@ class FlashPlan(NamedTuple):
     # a causal call's sliding window, where it leaves a key out: every
     # kernel bounds the key tiles it visits from below as well (``band_tiles``)
     window: int | None = None
+    # the fused backward's residents: query tiles held (``_bwd_ring_tiles``),
+    # and whether the group's dk / dv are summed in the kernel or written a
+    # query head and summed outside
+    bwd_ring: int = 0
+    bwd_group_sum: bool = False
 
 
 def flash_plan(
@@ -1167,11 +1339,16 @@ def flash_plan(
     A ``window`` that reaches every key is no window (the plan, and so the
     program, is the plain causal call's).
     ``block_q`` / ``block_k`` are the caller's override of the forward
-    tiles.  The backward is the fused kernel wherever its whole-sequence
-    residents fit (``_FUSED_BWD_RESIDENT_LIMIT``), causal or not — at
-    4,096 keys and head size 128 it takes 0.8 x the two kernels' time
-    non-causal and 0.7 x causal (module docstring) — and the two tiled
-    kernels beyond."""
+    tiles.  The backward is the fused kernel wherever what it holds fits
+    (``_fused_bwd_resident_bytes`` against ``_FUSED_BWD_RESIDENT_LIMIT``),
+    causal or not — at 4,096 keys and head size 128 it takes 0.8 x the two
+    kernels' time non-causal and 0.7 x causal, at 8,192 causal keys 0.56 x
+    and under a window of 2,048 0.63 x (module docstring) — and the two
+    tiled kernels beyond.  What it holds follows the band: with a window
+    ``bwd_ring`` query tiles of q, dO and dq, the most a key block reaches
+    and one (any length fits); without one the sequence.  Where the
+    group's dk / dv accumulators fit beside them the kernel sums the group
+    (``bwd_group_sum``), else each query head writes its own."""
     chosen = block_q is None and block_k is None
     skv_128 = _ceil_to(skv, 128)
     if causal and chosen:
@@ -1193,14 +1370,21 @@ def flash_plan(
     head = d if 128 % d == 0 and d >= _MIN_UNPADDED_HEAD else _ceil_to(d, 128)
     target = 512 if chosen else min(512, max(128, block_q, block_k))
     bwd_q, bwd_k = _stream_block(sq_p, target), _stream_block(skv_p, target)
-    fused = (
-        (sq_p == skv_p or not causal)
-        and _fused_bwd_resident_bytes(sq_p, skv_p, head, group, dtype)
-        <= _FUSED_BWD_RESIDENT_LIMIT
-    )
     if window is not None and window >= skv:
         window = None
-    return FlashPlan(block_q, block_k, head, fused, bwd_q, bwd_k, window)
+    if causal and sq_p != skv_p:  # the caller's unequal blocks: no square tiles
+        return FlashPlan(block_q, block_k, head, False, bwd_q, bwd_k, window)
+    held = functools.partial(
+        _fused_bwd_resident_bytes, sq_p, skv_p, head, group, dtype, window,
+        causal=causal, tile=bwd_q,
+    )
+    summed = group > 1 and held(summed=True) <= _FUSED_BWD_RESIDENT_LIMIT
+    fused = summed or held(summed=False) <= _FUSED_BWD_RESIDENT_LIMIT
+    ring = _bwd_ring_tiles(sq_p, skv_p, head, bwd_q, window)
+    return FlashPlan(
+        block_q, block_k, head, fused, bwd_q, bwd_k, window,
+        ring if fused else 0, summed,
+    )
 
 
 def flash_attention(
@@ -1260,7 +1444,11 @@ def flash_attention(
         sq, skv, d, group, causal, q.dtype, block_q, block_k, window=window
     )
     if group > 1 and not plan.fused_bwd:
-        # the two-kernel backward has one key-value head a query head
+        # the two-kernel backward has one key-value head a query head, and
+        # the forward's residuals are its inputs: 67 MB a tensor at 8,192 x
+        # 128 and 32 heads, which the AFMoE cell's layers paid until PR 32
+        # fused their backward (their forward alone 4.01 -> 3.81 ms under
+        # the window, 5.80 -> 5.59 without)
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
         group = 1
     sq_p, skv_p = _ceil_to(sq, plan.block_q), _ceil_to(skv, plan.block_k)

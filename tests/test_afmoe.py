@@ -469,3 +469,15 @@ def test_the_window_readers_share_is_the_accepted_readers_arithmetic(monkeypatch
     assert window(run) is None
     run.setup_compiles[0]["kernel_paths"]["attention"] = "pallas"
     assert roofline.share(run, "attention", "attention_window", "no_such", "nor_this") is None
+    # the counter's reader on the same stub: the parent's event has no
+    # ``flash_backward`` and reads nothing; the train program's events are
+    # summed, another program's are not its call sites
+    fused_pct = load(BENCH / "layer_metrics" / "flash_bwd_fused_pct.py").read
+    assert fused_pct(run) is None
+    run.setup_compiles[0]["flash_backward"] = {"fused": 4, "tiled": 1}
+    run.setup_compiles.append({"name": "train_step", "flash_backward": {"tiled": 5}})
+    assert fused_pct(run) == pytest.approx(80.0)
+    run.setup_compiles.append(
+        {"name": "device_chunk_runner@k4", "flash_backward": {"fused": 5}}
+    )
+    assert fused_pct(run) == pytest.approx(90.0)

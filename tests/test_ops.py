@@ -11,6 +11,7 @@ their design points in ``tests_tpu/``; the kernel's chip time is read in
 the cell ``lfm2_ep8_seq4k_job`` (``attention_roofline_pct``, ``PERF.md``).
 """
 
+import functools
 import re
 import sys
 from pathlib import Path
@@ -433,12 +434,32 @@ def test_flash_plan_causal_visits_the_lower_triangle_once(s):
               bwd_block_q=512, bwd_block_k=512)),
         # the streamed forward's length: no whole-sequence residents
         ((16384, 16384, 64, 4, True, jnp.bfloat16), dict(fused_bwd=False)),
-        # float32 operands at 4,096 keys are past the VMEM budget too
-        ((4096, 4096, 64, 4, True, jnp.float32), dict(fused_bwd=False)),
+        # float32 operands at 4,096 keys: q, dO and dq fit (6 MiB) once the
+        # group's accumulators are not beside them — each query head writes
+        # its dk / dv (PR 32; split before, when the kernel had no such form)
+        ((4096, 4096, 64, 4, True, jnp.float32),
+         dict(fused_bwd=True, bwd_group_sum=False, bwd_ring=8)),
+        # ... and at 8,192 keys they do not
+        ((8192, 8192, 64, 4, True, jnp.float32), dict(fused_bwd=False)),
         # a head size that does not divide the lanes is padded to them
         ((200, 200, 48, 1, True, jnp.float32), dict(head=128, block_q=256)),
+        # the token cell's call (LFM2): the plan PR 30 gave it, field for
+        # field — the sequence held whole, the group summed in the kernel
+        ((4096, 4096, 64, 4, True, jnp.bfloat16),
+         dict(block_q=512, block_k=512, head=64, fused_bwd=True,
+              bwd_block_q=512, bwd_block_k=512, window=None,
+              bwd_ring=8, bwd_group_sum=True)),
+        # the AFMoE cell's full layer: 8,192 keys at head size 128 whole
+        # (8.0 MiB of q, dO and float32 dq), each query head its own dk / dv
+        ((8192, 8192, 128, 8, True, jnp.bfloat16),
+         dict(fused_bwd=True, bwd_ring=16, bwd_group_sum=False)),
+        # 16,384 keys without a window stay on the two tiled kernels, and
+        # so does a non-causal call past the budget (its dq is whole)
+        ((16384, 16384, 128, 8, True, jnp.bfloat16), dict(fused_bwd=False)),
+        ((8192, 8192, 128, 1, False, jnp.bfloat16), dict(fused_bwd=False)),
     ],
-    ids=["vit_long", "s16384", "float32", "head48"],
+    ids=["vit_long", "s16384", "float32", "float32_s8192", "head48", "lfm2",
+         "trinity_full", "s16384_head128", "s8192_non_causal"],
 )
 def test_flash_plan_other_calls(call, expected):
     A = _attention_module()
@@ -446,6 +467,78 @@ def test_flash_plan_other_calls(call, expected):
     assert {k: getattr(plan, k) for k in expected} == expected
     # the caller's blocks win over the plan's
     assert A.flash_plan(*call, block_q=64, block_k=64)[:2] == (64, 64)
+
+
+@pytest.mark.parametrize(
+    "s,d,group,window,ring",
+    [
+        # the AFMoE cell's sliding layer: six of sixteen tiles held
+        (8192, 128, 8, 2048, 6),
+        # any length fits with a window: the ring follows the band
+        (65536, 128, 8, 2048, 6), (16384, 128, 1, 300, 3),
+        # head size 64 goes in unpadded, and Mosaic slices no tile out of
+        # HBM across 64 lanes: the sequence whole, as without a window
+        (4096, 64, 4, 2048, 8),
+    ],
+    ids=["trinity_sliding", "s65536", "s16384_narrow", "lfm2_with_a_window"],
+)
+def test_flash_plan_holds_the_rows_a_key_block_reaches(s, d, group, window, ring):
+    """With a window the fused backward's residents follow the band, not the
+    sequence: ``fused_bwd`` at any length, a ring of the band's tiles and
+    one, and the bytes ``_fused_bwd_resident_bytes`` counts inside the one
+    limit."""
+    A = _attention_module()
+    plan = A.flash_plan(s, s, d, group, True, jnp.bfloat16, window=window)
+    assert plan.fused_bwd and plan.window == window and plan.bwd_ring == ring
+    tile = plan.bwd_block_q
+    reached = max(
+        min(int(A._band_end_q(j, tile, tile, window)), s // tile) - j
+        for j in range(s // tile)
+    )
+    assert ring == (reached + 1 if d % 128 == 0 else s // tile)
+    held = A._fused_bwd_resident_bytes(
+        s, s, plan.head, group, jnp.bfloat16, window, tile=tile,
+        summed=plan.bwd_group_sum,
+    )
+    lanes = 128
+    assert held == ring * tile * lanes * (2 + 2 + 4) + (
+        2 * s * lanes * 4 if plan.bwd_group_sum else 0
+    )
+    assert held <= A._FUSED_BWD_RESIDENT_LIMIT
+
+
+def test_flash_plan_fused_residents_fit_the_limit():
+    """``flash_plan`` as a property over the calls a model can make: wherever
+    it says ``fused_bwd`` the bytes the kernel holds are inside the limit,
+    the group is summed in the kernel only where the accumulators fit beside
+    them, and a call it leaves to the tiled kernels would not have fitted."""
+    A = _attention_module()
+    limit = A._FUSED_BWD_RESIDENT_LIMIT
+    seen = {True: 0, False: 0}
+    for s in (512, 1000, 2048, 4096, 8192, 16384, 32768):
+        for d in (64, 128):
+            for group in (1, 4, 8):
+                for causal, window in ((False, None), (True, None), (True, 2048)):
+                    for dtype in (jnp.bfloat16, jnp.float32):
+                        plan = A.flash_plan(
+                            s, s, d, group, causal, dtype, window=window
+                        )
+                        s_p = -(-s // plan.block_q) * plan.block_q
+                        held = functools.partial(
+                            A._fused_bwd_resident_bytes, s_p, s_p, plan.head,
+                            group, dtype, plan.window, causal=causal,
+                            tile=plan.bwd_block_q,
+                        )
+                        seen[plan.fused_bwd] += 1
+                        if not plan.fused_bwd:
+                            assert held(summed=False) > limit
+                            assert plan.bwd_ring == 0 and not plan.bwd_group_sum
+                            continue
+                        assert held(summed=plan.bwd_group_sum) <= limit
+                        if group > 1 and not plan.bwd_group_sum:
+                            assert held(summed=True) > limit
+                        assert 1 <= plan.bwd_ring <= s_p // plan.bwd_block_q
+    assert min(seen.values()) > 20, seen
 
 
 # ------------------------------------------------------- sliding window
@@ -514,27 +607,76 @@ def test_a_window_that_reaches_every_key_is_the_causal_call():
 # branch.  640 keys are five 128-tiles: windows of one tile and a half
 # (192: not a tile multiple), of exactly two tiles (256), narrower than a
 # tile (50), padded keys (600); ``split`` forces the two tiled backward
-# kernels (the cell's own at 8,192 keys), ``streamed`` the tiled forward.
+# kernels (what a call past the fused backward's VMEM budget gets),
+# ``streamed`` the tiled forward.  At head size 128 the fused backward holds
+# q, dO and dq as a ring of ``ring`` query tiles it fetches itself — the
+# most a key block's band reaches and one more — and the cases below make it
+# wrap: the cell's own window of 2,048 under 512-tiles at 8,192 keys (six of
+# sixteen tiles held), a window narrower than a tile, a length that is no
+# tile multiple, and ``lse`` with a non-zero cotangent (ring attention's
+# form) against ``mha_reference``.  ``held`` is the VMEM budget in bytes:
+# 1.5 MB holds a ring and not the group's dk / dv accumulators beside it, so
+# each query head writes its own and the sum is taken outside (the cell's
+# form); left alone the group is summed in the kernel.
+def _window_case(s, window, d, h, hkv, *, split=False, streamed=False,
+                 lse=False, held=None, ring=None, summed=None):
+    return dict(s=s, window=window, d=d, h=h, hkv=hkv, split=split,
+                streamed=streamed, lse=lse, held=held, ring=ring, summed=summed)
+
+
 @pytest.mark.parametrize(
-    "s,window,d,h,hkv,split,streamed",
+    "case",
     [
-        (640, 192, 64, 2, 1, False, False),
-        (640, 256, 64, 2, 1, True, False),
-        (640, 50, 128, 8, 1, True, True),
-        (600, 130, 64, 4, 2, False, True),
-        (640, 300, 128, 8, 1, False, False),
-        (1024, 300, 64, 1, 1, True, False),
+        pytest.param(_window_case(640, 192, 64, 2, 1), id="w192_fused"),
+        pytest.param(_window_case(640, 256, 64, 2, 1, split=True), id="w256_split"),
+        pytest.param(
+            _window_case(640, 50, 128, 8, 1, split=True, streamed=True),
+            id="w50_head128_group8_split_streamed",
+        ),
+        pytest.param(
+            _window_case(600, 130, 64, 4, 2, streamed=True),
+            id="w130_padded_fused_streamed",
+        ),
+        pytest.param(
+            _window_case(640, 300, 128, 8, 1, summed=True),
+            id="w300_head128_group8_fused",
+        ),
+        pytest.param(
+            _window_case(1024, 300, 64, 1, 1, split=True), id="w300_tile512_split"
+        ),
+        pytest.param(
+            _window_case(8192, 2048, 128, 1, 1, ring=6), id="w2048_tile512_ring_wraps"
+        ),
+        pytest.param(
+            _window_case(1280, 50, 128, 2, 1, ring=3, summed=True),
+            id="w50_narrower_than_a_tile_ring",
+        ),
+        pytest.param(
+            _window_case(1100, 300, 128, 8, 1, held=1_500_000, ring=5, summed=False),
+            id="w300_padded_head128_group8_ring_per_head_sum",
+        ),
+        pytest.param(
+            _window_case(1100, 200, 128, 2, 1, lse=True, ring=4, summed=True),
+            id="w200_padded_ring_lse_cotangent",
+        ),
+        pytest.param(
+            _window_case(640, 192, 64, 4, 1, lse=True, ring=5, summed=True),
+            id="w192_head64_group4_summed_lse_cotangent",
+        ),
+        pytest.param(
+            _window_case(640, 192, 64, 4, 1, held=1_000_000, ring=5, summed=False),
+            id="w192_head64_group4_per_head_sum",
+        ),
     ],
-    ids=["w192_fused", "w256_split", "w50_head128_group8_split_streamed",
-         "w130_padded_fused_streamed", "w300_head128_group8_fused",
-         "w300_tile512_split"],
 )
-def test_flash_window_matches_composed(monkeypatch, s, window, d, h, hkv,
-                                       split, streamed):
+def test_flash_window_matches_composed(monkeypatch, case):
     A = _attention_module()
-    if split:
+    s, window, d, h, hkv = (case[n] for n in ("s", "window", "d", "h", "hkv"))
+    if case["split"]:
         monkeypatch.setattr(A, "_FUSED_BWD_RESIDENT_LIMIT", 0)
-    if streamed:
+    if case["held"] is not None:
+        monkeypatch.setattr(A, "_FUSED_BWD_RESIDENT_LIMIT", case["held"])
+    if case["streamed"]:
         monkeypatch.setattr(A, "_FWD_RESIDENT_KV_LIMIT", 0)
     kq, kk, kv, kdo = jax.random.split(jax.random.key(s + window), 4)
     q = jax.random.normal(kq, (1, h, s, d))
@@ -542,17 +684,40 @@ def test_flash_window_matches_composed(monkeypatch, s, window, d, h, hkv,
     v = jax.random.normal(kv, (1, hkv, s, d))
     do = jax.random.normal(kdo, (1, h, s, d))
     plan = A.flash_plan(s, s, d, h // hkv, True, q.dtype, window=window)
-    assert plan.window == window and plan.fused_bwd != split
+    assert plan.window == window and plan.fused_bwd != case["split"]
+    if case["ring"] is not None:
+        tiles = -(-s // plan.bwd_block_q)
+        assert plan.bwd_ring == case["ring"] <= tiles
+        # at head size 128 the ring is shorter than the sequence and wraps
+        assert (plan.bwd_ring < tiles) == (d == 128)
+    if case["summed"] is not None:
+        assert plan.bwd_group_sum == case["summed"]
+
+    def loss(attn):
+        def f(q, k, v):
+            o, l = attn(q, k, v)
+            return (o * do).sum() + (jnp.sin(l).sum() if case["lse"] else 0.0)
+        return f
+
     flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=True, window=window, interpret=True
+        q, k, v, causal=True, window=window, interpret=True, return_lse=True
     )
-    composed = lambda q, k, v: attention(  # noqa: E731
-        q, k, v, causal=True, window=window, impl="reference"
-    )
+    if case["lse"]:  # the composed branch's own VJP carries no lse cotangent
+        want_of = lambda q, k, v: mha_reference(  # noqa: E731
+            q, jnp.repeat(k, h // hkv, 1), jnp.repeat(v, h // hkv, 1),
+            causal=True, window=window, return_lse=True,
+        )
+    else:
+        want_of = lambda q, k, v: (  # noqa: E731
+            attention(q, k, v, causal=True, window=window, impl="reference"), 0.0
+        )
     with jax.default_matmul_precision("highest"):
-        got, want = flash(q, k, v), composed(q, k, v)
-        got_g, want_g = _grads(flash, q, k, v, do), _grads(composed, q, k, v, do)
+        (got, got_lse), (want, want_lse) = flash(q, k, v), want_of(q, k, v)
+        got_g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        want_g = jax.grad(loss(want_of), argnums=(0, 1, 2))(q, k, v)
     assert float(jnp.abs(got - want).max()) < 2e-5
+    if case["lse"]:
+        assert float(jnp.abs(got_lse - want_lse).max()) < 1e-5
     for g, w, name in zip(got_g, want_g, "qkv"):
         assert g.shape == w.shape
         assert float(jnp.abs(g - w).max()) < 1e-3, f"d{name}"
@@ -847,6 +1012,47 @@ def test_vit_train_step_still_reports_composed():
         obs.reset()
     assert event["payload"]["kernel_paths"] == {"attention": "composed"}
     assert event["payload"].get("tpu_custom_calls", 0) == 0
+
+
+def test_flash_backward_form_is_on_the_compile_event(monkeypatch):
+    """Which backward a flash call site took is a fact of the trace: an
+    observed compile of a gradient through two call sites — one inside the
+    fused kernel's budget, one (a non-causal call held past it) on the two
+    tiled kernels — carries ``flash_backward: {"fused": 1, "tiled": 1}``
+    under a key of its own, ``kernel_paths`` keeps its one entry, and a
+    forward-only program notes nothing."""
+    from distributed_training_comparison_tpu import obs
+
+    A = _attention_module()
+    whole = A._fused_bwd_resident_bytes(
+        256, 256, 64, 1, jnp.float32, causal=False, tile=128, summed=False
+    )
+    monkeypatch.setattr(A, "_FUSED_BWD_RESIDENT_LIMIT", whole - 1)
+    (q, k, v, _), _ = _composed_case("bhsd", jnp.float32, sq=256, skv=256, b=1, h=2)
+
+    def both(q, k, v, **options):
+        sliding = attention(
+            q, k, v, causal=True, window=100, impl="pallas", interpret=True
+        )
+        full = attention(q, k, v, impl="pallas", interpret=True)
+        return (sliding + full).sum()
+
+    assert A.flash_plan(256, 256, 64, 1, True, q.dtype, window=100).fused_bwd
+    assert not A.flash_plan(256, 256, 64, 1, False, q.dtype).fused_bwd
+    bus = obs.configure(run_id=obs.new_run_id(), persist=True)
+    try:
+        monitor = obs.CompileMonitor(bus=bus, registry=obs.MetricRegistry())
+        monitor.instrument(jax.jit(both), "forward_only")(q, k, v)
+        monitor.instrument(jax.jit(jax.grad(both, argnums=(0, 1, 2))), "both")(q, k, v)
+        forward, grad = [
+            e["payload"] for e in bus.ring_events() if e["kind"] == "compile"
+        ]
+    finally:
+        obs.reset()
+    assert grad["flash_backward"] == {"fused": 1, "tiled": 1}
+    assert grad["kernel_paths"] == {"attention": "pallas-interpret"}
+    assert "flash_backward" not in forward
+    assert forward["kernel_paths"] == grad["kernel_paths"]
 
 
 # ------------------------------------------------------- grouped MoE FFN

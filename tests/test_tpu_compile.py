@@ -116,10 +116,16 @@ def test_flash_attention_compiles_for_v5e(chip, shape, backward, causal):
 
 # (batch, query heads, key-value heads, tokens, head size, window).  The
 # AFMoE cell's sliding layer — the resident forward at its VMEM limit and the
-# two tiled backward kernels on a band's grid; a window under the fused
-# backward (4,096 keys); under the streamed forward; narrower than a tile
+# fused backward with q, dO and dq as a ring of six 512-row tiles the kernel
+# fetches itself, each query head's dk / dv summed outside — and its full
+# layer (``None``: 8,192 keys whole, 8.0 MiB held); a window under the
+# whole-sequence residents of head size 64 (4,096 keys, the group summed in
+# the kernel); under the streamed forward, where only the ring lets the
+# backward fuse; narrower than a tile
 WINDOW_SHAPES = {
-    "trinity": (1, 32, 4, 8192, 128, 2048), "fused": (4, 32, 8, 4096, 64, 2048),
+    "trinity": (1, 32, 4, 8192, 128, 2048),
+    "trinity_full": (1, 32, 4, 8192, 128, None),
+    "fused": (4, 32, 8, 4096, 64, 2048),
     "s16384": (1, 4, 4, 16384, 128, 2048), "narrow": (1, 8, 1, 8192, 128, 300),
 }
 
@@ -131,8 +137,12 @@ def test_flash_attention_with_a_window_compiles_for_v5e(chip, shape):
     text = _compiled_text(
         _grad_of(attn, 3), chip, _s(b, h, s, d), _s(b, hkv, s, d), _s(b, hkv, s, d)
     )
-    fused = s <= 4096
-    assert text.count('custom_call_target="tpu_custom_call"') == (2 if fused else 3)
+    # one forward and one backward kernel, inside the scoped VMEM a kernel
+    # gets unasked, key-value heads read by index: no head repeated
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "vmem_limit_bytes" not in text
+    repeated = rf"\[{hkv},{h // hkv},{s},{d}\]\S* broadcast\("
+    assert not re.search(repeated, text), "k / v repeated a query head"
 
 
 def test_flash_attention_with_lse_compiles_for_v5e(chip):

@@ -40,16 +40,19 @@ def test_flash_fwd_bwd_design_point(causal):
         assert err < 0.1, f"d{name} diverged on-chip: {err}"
 
 
-def test_flash_token_cell_shape():
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_token_cell_shape(dtype):
     """``lfm2_ep8_seq4k_job``'s attention layer (4 sequences, 32 query
     heads on 8 key-value heads, 4,096 causal keys, head size 64 unpadded):
     the plan's (512, 512) forward and the fused backward with the group's
     dk / dv summed inside the kernel, against the reference on repeated
-    heads at bf16 tolerance."""
+    heads at bf16 tolerance.  In float32 the accumulators do not fit beside
+    q and dO: each query head writes its dk / dv and the group's sum is
+    taken outside (fused since PR 32)."""
     kq, kk, kv = jax.random.split(jax.random.key(30), 3)
-    q = jax.random.normal(kq, (4, 32, 4096, 64), jnp.bfloat16)
-    k = jax.random.normal(kk, (4, 8, 4096, 64), jnp.bfloat16)
-    v = jax.random.normal(kv, (4, 8, 4096, 64), jnp.bfloat16)
+    q = jax.random.normal(kq, (4, 32, 4096, 64), dtype)
+    k = jax.random.normal(kk, (4, 8, 4096, 64), dtype)
+    v = jax.random.normal(kv, (4, 8, 4096, 64), dtype)
 
     def loss(fn):
         return lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum()
@@ -80,10 +83,11 @@ def test_flash_token_cell_shape():
 def test_flash_window_cell_shape(window):
     """``trinity_ep16_seq8k_job``'s attention layers (one sequence, 32 query
     heads on 4 key-value heads, 8,192 keys, head size 128): the resident
-    forward at its VMEM limit and the two tiled backward kernels (past the
-    fused one's budget, key-value heads repeated), with a 2,048-key window
-    — 70 visited tiles of 512 x 512 — and without, against the reference at
-    bf16 tolerance.  The reference holds float32 scores, so it takes the
+    forward at its VMEM limit and the fused backward — with a 2,048-key
+    window (70 visited tiles of 512 x 512) q, dO and dq as a ring of six
+    tiles the kernel fetches itself, without one the sequence whole; key-
+    value heads read by index, each query head's dk / dv summed outside —
+    against the reference at bf16 tolerance.  The reference holds float32 scores, so it takes the
     first key-value head and the 8 query heads it serves (the loss is a
     sum: their gradients are their own)."""
     kq, kk, kv = jax.random.split(jax.random.key(31), 3)
