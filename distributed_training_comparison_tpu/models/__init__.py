@@ -19,6 +19,7 @@ from .resnet import (
 )
 from .afmoe import AFMOE_TINY_MODEL, TRINITY_MINI_MODEL, Afmoe
 from .lfm2 import LFM2, LFM2_24B_A2B_MODEL, LFM2_TINY_MODEL
+from .qwen3_next import QWEN3_NEXT_MODEL, QWEN3_NEXT_TINY_MODEL, Qwen3Next
 from .moe import SwitchFFN, TopKMoE, resolve_dispatch
 from .vit import ViT, ViTBlock, ViTLong, ViTMoE, ViTSmall, ViTTiny
 
@@ -36,6 +37,8 @@ _ZOO = {
     "lfm2_tiny": LFM2_TINY_MODEL,
     "trinity_mini": TRINITY_MINI_MODEL,
     "afmoe_tiny": AFMOE_TINY_MODEL,
+    "qwen3_next": QWEN3_NEXT_MODEL,
+    "qwen3_next_tiny": QWEN3_NEXT_TINY_MODEL,
 }
 
 
@@ -85,6 +88,7 @@ __all__ = [
     "TopKMoE",
     "LFM2",
     "Afmoe",
+    "Qwen3Next",
     "get_model",
     "model_cli_options",
     "resolve_dispatch",

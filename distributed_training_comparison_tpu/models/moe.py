@@ -1,6 +1,6 @@
 """Mixture-of-experts feed-forward layers: ``SwitchFFN`` (top-1, a capacity
 and drops, expert parallelism as a sharding) and ``TopKMoE`` (top-k sigmoid
-routing for a rank that is told which experts it holds, no capacity and no
+or softmax routing for a rank told which experts it holds, no capacity, no
 drop: its dispatch works on a bounded *held prefix* of the expert-sorted
 pairs, sized from the share of experts held, with the plain every-expert-
 on-every-token sum behind it for the call in which more arrive; optionally a
@@ -569,27 +569,36 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def route_topk(x, router_kernel, bias, k: int, scale: float = 1.0,
-               renormalise: bool = True):
-    """Sigmoid routing with a selection bias (DeepSeek-V3's auxiliary-loss-
-    free form, as LFM2-MoE configures it): ``s = sigmoid(x W_r)`` in
-    float32 at ``highest`` precision (a bf16 pass flips near-ties), ``sel =
-    top_k(s + b)`` — the bias enters the selection only — and weights ``s[sel]
-    / (sum s[sel] + 1e-6) * scale`` over all ``k`` selected.  Returns ``(sel
-    (n, k) int32, weights (n, k) float32)``."""
-    scores = jax.nn.sigmoid(jnp.dot(
+               renormalise: bool = True, score: str = "sigmoid"):
+    """Top-k routing over scores in float32 at ``highest`` precision (a
+    bf16 pass flips near-ties).  ``score="sigmoid"``, with a selection bias
+    (DeepSeek-V3's auxiliary-loss-free form, as LFM2-MoE configures it):
+    ``s = sigmoid(x W_r)``, ``sel = top_k(s + b)`` — the bias enters the
+    selection only — and weights ``s[sel] / (sum s[sel] + 1e-6) * scale``
+    over all ``k`` selected.  ``score="softmax"``: ``s = softmax(x W_r)``
+    over every expert, the same selection, and weights ``s[sel] / sum
+    s[sel] * scale`` (no floor under a sum of ``k`` probabilities of the
+    largest: it is at least ``k / experts``).  Returns ``(sel (n, k) int32,
+    weights (n, k) float32)``."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown routing score {score!r}")
+    logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
-    ))
+    )
+    softmax = score == "softmax"
+    scores = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if renormalise:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + (0.0 if softmax else 1e-6))
     return sel, w * scale
 
 
 class TopKMoE(nn.Module):
-    """Top-k sigmoid-routed SwiGLU experts, no capacity and no dropped
-    pair, for a layer that is told which experts it holds.
+    """Top-k routed SwiGLU experts (sigmoid scores, or ``score="softmax"``:
+    ``route_topk``), no capacity and no dropped pair, for a layer that is
+    told which experts it holds.
 
     The router scores all ``num_experts``; this layer holds
     ``num_experts_held`` of them from index ``first_expert`` (all of them
@@ -639,7 +648,10 @@ class TopKMoE(nn.Module):
     token passes through, its output added once, unweighted (module and
     scope ``shared_expert``).  Every rank of an expert-parallel layer
     computes it alike, so the ranks' results add up to the uncut layer's
-    with it counted once (``tests/test_moe.py``).
+    with it counted once (``tests/test_moe.py``).  ``shared_gate``
+    multiplies its output by ``sigmoid(x w_g)``, ``w_g`` a ``(dim, 1)``
+    parameter (``shared_gate``); projection and multiply carry the scope
+    ``shared_expert`` too.
     """
 
     dim: int
@@ -655,6 +667,8 @@ class TopKMoE(nn.Module):
     gmm: str = "auto"
     bias_update_rate: float = 0.0  # 0: a constant drawn at initialisation
     shared_hidden: int = 0  # 0: no shared expert
+    score: str = "sigmoid"  # or "softmax" (``route_topk``)
+    shared_gate: bool = False  # the shared expert times ``sigmoid(x w_g)``
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
@@ -686,7 +700,7 @@ class TopKMoE(nn.Module):
 
         xt = x.reshape(n, d)
         sel, weights = route_topk(
-            xt, router, bias, k, self.scale, self.renormalise
+            xt, router, bias, k, self.scale, self.renormalise, self.score
         )
         if moves:
             if train and not self.is_initializing():
@@ -726,7 +740,15 @@ class TopKMoE(nn.Module):
         )
         y = y.reshape(b, s, d)
         if self.shared_hidden:
-            y = y + SwiGLU(
+            shared = SwiGLU(
                 d, self.shared_hidden, self.dtype, name="shared_expert"
             )(x.astype(self.dtype))
+            if self.shared_gate:
+                w_g = self.param("shared_gate", init, (d, 1), jnp.float32)
+                with jax.named_scope("shared_expert"):
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        x.astype(self.dtype), w_g.astype(self.dtype),
+                        preferred_element_type=jnp.float32,
+                    )).astype(self.dtype)
+            y = y + shared
         return y

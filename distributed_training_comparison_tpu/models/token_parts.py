@@ -1,7 +1,9 @@
-"""What the token decoders share (``models/lfm2.py``, ``models/afmoe.py``):
-the ``--model-cut`` that says what one chip holds of a published model, and
-the plain pieces every such decoder is made of — RMSNorm with float32
-statistics, a bias-free projection, rotate-half RoPE, the SwiGLU."""
+"""What the token decoders share (``models/lfm2.py``, ``models/afmoe.py``,
+``models/qwen3_next.py``): the ``--model-cut`` that says what one chip holds
+of a published model, and the plain pieces every such decoder is made of —
+RMSNorm with float32 statistics (scaled by ``w`` or, zero-centred, by ``1 +
+w``), a bias-free projection, rotate-half RoPE on the whole head or its
+first elements, the SwiGLU."""
 
 from __future__ import annotations
 
@@ -33,7 +35,10 @@ def cut_config(config: dict, cut: dict) -> dict:
     """The share of ``config`` one chip holds: the first ``dense`` of the
     leading dense layers, then the layers that follow the published dense
     ones, ``layers`` in all; experts ``first_expert`` .. ``+ experts``; the
-    first ``vocab`` rows of the vocabulary."""
+    first ``vocab`` rows of the vocabulary.  ``config`` carries
+    ``num_dense_layers`` and ``layer_types``: a model whose published config
+    has neither derives them in its own module (``models/qwen3_next.py``:
+    no leading dense layer, the kinds from ``full_attention_interval``)."""
     published_dense = config["num_dense_layers"]
     dense = cut.get("dense", published_dense)
     layers = cut.get("layers", config["num_hidden_layers"] - published_dense + dense)
@@ -58,12 +63,19 @@ def cut_config(config: dict, cut: dict) -> dict:
 
 
 class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * w``, ``w`` from one; ``zero_centred``:
+    ``* (1 + w)``, ``w`` from zero (the same function at the start)."""
+
     eps: float
     dtype: Any = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        init = nn.initializers.zeros if self.zero_centred else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(self.dtype)
@@ -76,9 +88,15 @@ def _dense(features, dtype, name):
     )
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, rotary: int | None = None):
     """Rotary position embedding in the rotate-half form on ``(B, S, H,
-    D)``, positions ``0 .. S-1``, angles in float32."""
+    D)``, positions ``0 .. S-1``, angles in float32.  ``rotary`` < ``D``
+    turns the first ``rotary`` elements of each head (pairs ``(i, i +
+    rotary / 2)``, frequencies over ``rotary``) and passes the rest."""
+    if rotary is not None and rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary], theta), x[..., rotary:]], axis=-1
+        )
     s, d = x.shape[1], x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]

@@ -580,7 +580,10 @@ def _flash_fwd_tiled(q3, k3, v3, scale, causal, plan, kv_len, group, interpret):
     # 2048 alone lifts the streamed forward 54 → 73 TF/s.  VMEM at
     # bq=2048: q/out blocks 0.5 MiB each + fp32 acc scratch 1 MiB —
     # comfortably inside the ~4 MiB the rest of the pipeline budgets.
-    bq = _stream_block(sq, max(plan.block_q, 2048))
+    # Those are bytes at head size 128: a wider head takes as many fewer
+    # rows (head size 256: 1,024 — at 2,048 Mosaic refused the kernel for a
+    # described v5e, 20.5 MiB of scoped VMEM against 16).
+    bq = _stream_block(sq, max(plan.block_q, 2048 * 128 // _ceil_to(d, 128)))
     # bk=1024 with this bq OOMs scoped VMEM (18.6 MiB vs the 16 MiB limit
     # with Mosaic's double buffering); 512 fits and the K/V re-read
     # traffic is governed by bq, not bk

@@ -161,6 +161,11 @@ def _cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return optax.softmax_cross_entropy_with_integer_labels(logits, labels)
 
 
+# what ``_moe_health`` names its scalars by: the expert layers' and the
+# Gated DeltaNet layers' (both sown into ``"moe_metrics"``)
+LAYER_GAUGES = ("moe_", "gdn_")
+
+
 def _moe_health(coll) -> Metrics:
     """Aggregate the routing stats MoE layers sow into ``"moe_metrics"``
     (models/moe.py) into two scalars: mean dropped-token fraction and mean
@@ -169,7 +174,7 @@ def _moe_health(coll) -> Metrics:
     from jax.tree_util import tree_flatten_with_path
 
     dropped, load_max, rows, imbalance, full_buffer = [], [], [], [], []
-    bias_spread = []
+    bias_spread, decay = [], []
     for path, leaf in tree_flatten_with_path(coll)[0]:
         keys = {getattr(p, "key", getattr(p, "name", "")) for p in path}
         if "dropped_frac" in keys:
@@ -185,6 +190,8 @@ def _moe_health(coll) -> Metrics:
             full_buffer.append(jnp.mean(leaf))
         elif "bias_spread" in keys:  # max - min of a selection bias that moves
             bias_spread.append(jnp.mean(leaf))
+        elif "gdn_decay_mean" in keys:  # models/qwen3_next.py: mean exp(g)
+            decay.append(jnp.mean(leaf))
     out: Metrics = {}
     if rows:  # summed over the layers; the fullest expert's, their mean
         out["moe_rows"] = jnp.sum(jnp.stack(rows))
@@ -192,6 +199,8 @@ def _moe_health(coll) -> Metrics:
         out["moe_full_buffer_share"] = jnp.mean(jnp.stack(full_buffer))
     if bias_spread:
         out["moe_bias_spread"] = jnp.mean(jnp.stack(bias_spread))
+    if decay:  # the mean over the Gated DeltaNet layers
+        out["gdn_decay_mean"] = jnp.mean(jnp.stack(decay))
     if dropped:
         out["moe_dropped_frac"] = jnp.mean(jnp.stack(dropped))
     if load_max:
@@ -378,7 +387,7 @@ def _make_step_core(
             top1_count = stacked["top1"].sum()
             extras = {
                 k: stacked[k].sum() if k == "moe_rows" else stacked[k].mean()
-                for k in stacked if k.startswith("moe_")
+                for k in stacked if k.startswith(LAYER_GAUGES)
             }
 
         if fault_scale is not None:

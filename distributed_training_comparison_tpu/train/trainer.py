@@ -88,6 +88,7 @@ from .optim import configure_optimizers
 from .state import create_train_state
 from .task import task_of
 from .step import (
+    LAYER_GAUGES,
     make_chunk_runner,
     make_device_chunk_runner,
     make_device_replay_step,
@@ -3172,7 +3173,7 @@ class Trainer:
                     {
                         k: v
                         for k, v in m.items()
-                        if k in keep or k.startswith("moe_")
+                        if k in keep or k.startswith(LAYER_GAUGES)
                     }
                     for m in chunk_metrics
                 ]
@@ -3191,12 +3192,15 @@ class Trainer:
             key: np.concatenate([np.asarray(m[key]) for m in fetched])
             for key in ("skipped", "grad_norm")
         }
-        self._moe_health = {
+        gauges = {  # each layer gauge's mean over the epoch's steps
             k: float(
                 np.mean(np.concatenate([np.atleast_1d(m[k]) for m in fetched]))
             )
             for k in fetched[0]
-            if k.startswith("moe_")
+            if k.startswith(LAYER_GAUGES)
+        }
+        self._moe_health = {
+            k: v for k, v in gauges.items() if k.startswith("moe_")
         }
         if "moe_rows" in fetched[0]:
             # top-k expert layers (models/moe.py TopKMoE): pairs routed to
@@ -3219,6 +3223,11 @@ class Trainer:
             self.metrics.gauge("moe/bias_spread").set(
                 self._moe_health["moe_bias_spread"]
             )
+        if "gdn_decay_mean" in gauges:
+            # Gated DeltaNet layers (models/qwen3_next.py): the mean of
+            # exp(g) over tokens, heads, layers and the epoch's steps — how
+            # fast the state forgets
+            self.metrics.gauge("gdn/decay_mean").set(gauges["gdn_decay_mean"])
         # the per-step signals land in the metric sketches here — one
         # vectorized pass over the stacked arrays, no per-step Python loop;
         # non-finite samples count into the sketch's side counter, so a

@@ -765,3 +765,120 @@ def test_grouped_matmul_matches_a_per_expert_loop(impl):
     want = jax.grad(lambda a, b: (loop(a, b) * cot).sum(), (0, 1))(xs, w)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------- softmax routing and the gated shared expert
+
+
+def _parents_route_topk(x, router_kernel, bias, k, scale=1.0, renormalise=True):
+    """``route_topk`` as it stood before it took a scoring function (PR 32),
+    word for word: what the two sigmoid decoders' calls must still give."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, sel = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if renormalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return sel, w * scale
+
+
+@pytest.mark.parametrize("renormalise", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_sigmoid_callers_get_the_parents_bits(dtype, renormalise):
+    x = jax.random.normal(jax.random.key(1), (96, 32), dtype)
+    router = 0.5 * jax.random.normal(jax.random.key(2), (32, 16))
+    bias = 0.002 * jax.random.normal(jax.random.key(3), (16,))
+    want = _parents_route_topk(x, router, bias, 4, 2.826, renormalise)
+    for got in (
+        route_topk(x, router, bias, 4, 2.826, renormalise),
+        route_topk(x, router, bias, 4, 2.826, renormalise, "sigmoid"),
+    ):
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+    with pytest.raises(ValueError, match="routing score"):
+        route_topk(x, router, bias, 4, score="tanh")
+
+
+def test_softmax_routing_is_a_plain_top_k_of_a_plain_softmax_ties_and_all():
+    x = jax.random.normal(jax.random.key(4), (64, 32))
+    router = 0.5 * jax.random.normal(jax.random.key(5), (32, 16))
+    # experts 3 and 7 score alike on every token, 0 and 1 on none but tie too
+    router = router.at[:, 7].set(router[:, 3]).at[:, 1].set(router[:, 0])
+    zero = jnp.zeros((16,))
+    sel, w = route_topk(x, router, zero, 4, score="softmax")
+    logits = np.asarray(x, np.float64) @ np.asarray(router, np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    p32 = np.asarray(jax.nn.softmax(jnp.dot(
+        x, router, precision=jax.lax.Precision.HIGHEST), axis=-1))
+    # largest first, the lower index on a tie: a stable sort of -p
+    plain = np.argsort(-p32, axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(sel, plain)
+    tied = (np.asarray(sel) == 3).any(-1) & (np.asarray(sel) == 7).any(-1)
+    assert tied.any()  # a tie inside the top four: 3 comes before 7
+    for row in np.asarray(sel)[tied]:
+        assert list(row).index(3) + 1 == list(row).index(7)
+    picked = np.take_along_axis(probs, plain, axis=-1)
+    np.testing.assert_allclose(w, picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-6)
+    # not renormalised: the probabilities themselves, over every expert
+    _, raw = route_topk(x, router, zero, 4, renormalise=False, score="softmax")
+    np.testing.assert_allclose(raw, picked, rtol=1e-5)
+    # and the plain reference of the configuration that routes so agrees
+    from lfm2_reference import BENCH, load
+
+    qwen = load(BENCH / "reference" / "qwen3_next_80b_a3b_ep32.py")
+    np.testing.assert_array_equal(qwen.top_k_by_argmax(jnp.asarray(p32), 4), sel)
+
+
+def test_gated_shared_expert_shares_add_up_to_the_uncut_reference_layer():
+    """At ``qwen3_next_tiny``'s expert layer (16 experts, top-4, softmax
+    scores, no bias, a shared expert of 40 behind a sigmoid gate): four
+    ranks holding experts 0-3 ... 12-15, summed, with what every rank
+    computes alike — the gated shared expert — counted once, give the plain
+    reference's uncut layer."""
+    from lfm2_reference import BENCH, load
+
+    from distributed_training_comparison_tpu.models.qwen3_next import QWEN3_NEXT_TINY as c
+    from distributed_training_comparison_tpu.models.token_parts import SwiGLU
+
+    qwen = load(BENCH / "reference" / "qwen3_next_80b_a3b_ep32.py")
+    layer = dict(
+        dim=c["hidden_size"], hidden=c["moe_intermediate_size"],
+        num_experts=c["num_experts"], top_k=c["num_experts_per_tok"],
+        use_bias=False, shared_hidden=c["shared_expert_intermediate_size"],
+        score="softmax", shared_gate=True,
+    )
+    x = jax.random.normal(jax.random.key(6), (2, 24, c["hidden_size"]))
+    whole = TopKMoE(**layer)
+    variables = whole.init(jax.random.key(0), x)
+    assert "batch_stats" not in variables  # no selection bias
+    p = variables["params"]
+    assert p["shared_gate"].shape == (c["hidden_size"], 1)
+    arch = {**qwen.ARCH, "num_experts_per_tok": layer["top_k"], "first_expert": 0}
+    want = qwen.moe(x, p, arch)
+    np.testing.assert_allclose(
+        whole.apply({"params": p}, x), want, rtol=2e-4, atol=2e-6
+    )
+    alike = jax.nn.sigmoid(x @ p["shared_gate"]) * SwiGLU(
+        layer["dim"], layer["shared_hidden"]
+    ).apply({"params": p["shared_expert"]}, x)
+    assert float(jnp.abs(alike).max()) > 0
+    # the gate is there: without it the shared expert adds something else
+    bare = TopKMoE(**{**layer, "shared_gate": False}).apply(
+        {"params": {k: v for k, v in p.items() if k != "shared_gate"}}, x
+    )
+    assert float(jnp.abs(bare - want).max()) > 1e-4
+    total = alike
+    for first in range(0, 16, 4):
+        held = {**p, **{k: p[k][first:first + 4] for k in ("w1", "w2", "w3")}}
+        share = TopKMoE(**layer, num_experts_held=4, first_expert=first)
+        part = share.apply({"params": held}, x)
+        np.testing.assert_allclose(
+            part, qwen.moe(x, held, {**arch, "first_expert": first}),
+            rtol=2e-4, atol=2e-6,
+        )
+        total = total + (part - alike)
+    np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-6)

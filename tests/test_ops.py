@@ -1139,3 +1139,100 @@ def test_grouped_ffn_jit_single_tile():
     ys = f(xs)
     ref = _grouped_ffn_reference(xs, w1, b1, w2, b2, starts, 16)
     assert float(jnp.max(jnp.abs(ys - ref))) < 1e-6
+
+
+# ------------------------------------------------- the gated delta rule's scan
+
+import numpy as np  # noqa: E402
+
+from distributed_training_comparison_tpu.ops.gated_delta import (  # noqa: E402
+    _unit_lower_inverse,
+    gated_delta_rule,
+    gated_delta_rule_sequential,
+)
+
+# log-decay a token by how fast a head forgets: as the model starts (``A`` up
+# to 16: a head keeps e^-20 of its state), near 0 everywhere, near 1 everywhere
+DECAYS = {"as_initialised": None, "near_0": 20.0, "near_1": 1e-3}
+
+
+def _delta_inputs(decay, b=2, s=80, hk=2, hv=4, dk=16, dv=24, alike=False):
+    keys = jax.random.split(jax.random.key(35), 6)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731
+    k = jax.random.normal(keys[1], (b, s, hk, dk))
+    if alike:  # every token the same key but for a little noise
+        k = k[:, :1] + 0.05 * k
+    q = unit(jax.random.normal(keys[0], (b, s, hk, dk))) * dk ** -0.5
+    v = jax.random.normal(keys[2], (b, s, hv, dv))
+    softplus = jax.nn.softplus(jax.random.normal(keys[3], (b, s, hv)) + 1.0)
+    rate = jnp.linspace(0.05, 16.0, hv) if DECAYS[decay] is None else DECAYS[decay]
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, s, hv)))
+    cot = jax.random.normal(keys[5], (b, s, hv, dv))
+    return (q, unit(k), v, -rate * softplus, beta), cot
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunked_gated_delta_rule_is_the_token_by_token_recurrence(chunk, decay):
+    """Output and all five gradients, in float32, 80 tokens: five chunks of
+    16, or one and a quarter of 64 (padded); two value heads a key head."""
+    x, cot = _delta_inputs(decay)
+    o = gated_delta_rule(*x, chunk=chunk)
+    want = gated_delta_rule_sequential(*x)
+    assert o.shape == want.shape == (2, 80, 4, 24)
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(o, want, rtol=1e-4, atol=2e-6 * scale)
+    grad = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * cot), argnums=(0, 1, 2, 3, 4)
+    )(*x)
+    got = grad(lambda *a: gated_delta_rule(*a, chunk=chunk))
+    for g, r, name in zip(got, grad(gated_delta_rule_sequential), ("q", "k", "v", "g", "beta")):
+        top = float(jnp.abs(r).max())
+        assert top > 0 or decay == "near_0", name
+        np.testing.assert_allclose(g, r, rtol=2e-3, atol=2e-5 * top, err_msg=name)
+    if decay == "near_0":  # where nothing survives a token, o_t = beta (k.q) v
+        q, k, v, g, beta = x
+        alone = beta[..., None] * jnp.sum(
+            jnp.repeat(q * k, 2, axis=2), -1, keepdims=True
+        ) * v
+        gone = gated_delta_rule(q, k, v, jnp.full_like(g, -100.0), beta, chunk=chunk)
+        np.testing.assert_allclose(gone, alone, rtol=1e-4, atol=1e-6)
+
+
+def test_gated_delta_rule_with_keys_alike_and_no_decay():
+    """The triangular system at its worst: every key of a chunk nearly the
+    same and nothing forgotten, where a series in powers of ``L`` cancels
+    binomially; substitution does not."""
+    x, _ = _delta_inputs("near_1", alike=True)
+    want = gated_delta_rule_sequential(*x)
+    for chunk in (16, 64):
+        np.testing.assert_allclose(
+            gated_delta_rule(*x, chunk=chunk), want, rtol=1e-3,
+            atol=1e-5 * float(jnp.abs(want).max()),
+        )
+    m = jnp.tril(jnp.ones((64, 64)), -1)  # L of identical keys, beta = 1
+    inverse = _unit_lower_inverse(m)
+    np.testing.assert_allclose(
+        inverse @ (jnp.eye(64) + m), jnp.eye(64), atol=1e-5
+    )
+    # (I + L)^-1 is then I minus the first subdiagonal: entries of size one
+    assert float(jnp.abs(inverse).max()) == pytest.approx(1.0, abs=1e-5)
+
+
+def test_gated_delta_rule_in_bf16_and_its_bad_calls():
+    """bf16 operands, float32 decays and state: close to the float32
+    recurrence, in the operands' dtype; the recurrence in blocks is the
+    recurrence; a value head count the key heads do not divide is refused."""
+    x, _ = _delta_inputs("as_initialised")
+    q, k, v, g, beta = x
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
+    o = gated_delta_rule(*low, g, beta, chunk=16)
+    want = gated_delta_rule_sequential(*x)
+    assert o.dtype == jnp.bfloat16
+    err = jnp.linalg.norm((o.astype(jnp.float32) - want).ravel())
+    assert float(err / jnp.linalg.norm(want.ravel())) < 0.02
+    np.testing.assert_array_equal(gated_delta_rule_sequential(*x, block=16), want)
+    with pytest.raises(ValueError, match="value heads"):
+        gated_delta_rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
+    with pytest.raises(ValueError, match="whole blocks"):
+        gated_delta_rule_sequential(*x, block=64)

@@ -169,6 +169,55 @@ def test_few_instructions_are_unscoped(mesh, model_name, program):
     assert phases.count("update") > 0 and phases.count("backward") > 0
 
 
+# --------------------------------------------- a token decoder's step program
+
+QWEN3_NEXT_SCOPES = (
+    "embed", "gdn", "gdn_conv", "gdn_scan", "gdn_gate_norm", "attn",
+    "attn_gate", "attention", "moe", "moe_gmm", "shared_expert", "lm_head",
+    "loss", "guards", "optimizer",
+)
+_TOKEN_OP_NAMES: list = []
+
+
+def _token_op_names(mesh) -> list:
+    """``op_name``s of ``make_train_step`` lowered (not compiled: the names
+    are the lowering's) for ``qwen3_next_tiny`` in bf16 under ``--remat``."""
+    if not _TOKEN_OP_NAMES:
+        model = get_model(
+            "qwen3_next_tiny", dtype=jnp.bfloat16, remat=True,
+            model_cut="layers=4,experts=4,first_expert=0,vocab=256",
+        )
+        state = _abstract_state(model)
+        tokens = _spec((2, 32), jnp.int32)
+        fn = make_train_step(mesh, precision="bf16", augment=False)
+        text = fn.lower(
+            state, tokens, tokens, jax.eval_shape(lambda: jax.random.key(0))
+        ).as_text(debug_info=True)
+        # the name stacks, not the call sites' function names beside them
+        _TOKEN_OP_NAMES.extend(set(re.findall(r'loc\("(jit\([^"]*)"', text)))
+    return _TOKEN_OP_NAMES
+
+
+@pytest.mark.parametrize("scope", QWEN3_NEXT_SCOPES)
+def test_qwen3_next_scopes_are_in_the_lowered_step_program(mesh, scope):
+    """``models/qwen3_next.py``'s docstring names them; the readers under
+    ``benchmark/layer_metrics`` (``gdn_ms_per_step``, ``gdn_scan_ms_per_step``,
+    ``gdn_scan_roofline_pct``) look for them as path components."""
+    names = _token_op_names(mesh)
+    mine = [n for n in names if scopes.under(n, scope)]
+    assert mine, scope
+    if scope.startswith("gdn"):
+        # the DeltaNet mixer is no attention: ``attention_ms_per_step``
+        # reads that name, and the three scopes inside sit inside ``gdn``
+        assert not any(scopes.under(n, "attention") for n in mine)
+        assert all(scopes.under(n, "gdn") for n in mine)
+        assert not any(scopes.under(n, "layers_3") for n in mine)
+    if scope in ("attn", "attention", "attn_gate"):
+        assert all(scopes.under(n, "layers_3") for n in mine)
+    if scope not in ("embed", "guards", "optimizer", "loss"):
+        assert {"forward", "backward"} <= {scopes.phase_of(n) for n in mine}
+
+
 # ------------------------------------------------------ one name a program
 
 

@@ -29,7 +29,9 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_training_comparison_tpu.models.moe import TopKMoE
 from distributed_training_comparison_tpu.ops import attention, flash_attention
+from distributed_training_comparison_tpu.ops.attention import flash_plan
 from distributed_training_comparison_tpu.ops.attention_small import small_mha
+from distributed_training_comparison_tpu.ops.gated_delta import gated_delta_rule
 from distributed_training_comparison_tpu.ops.moe_gmm import (
     grouped_ffn,
     grouped_matmul,
@@ -89,9 +91,14 @@ def _grad_of(fn, n_args):
 # streamed forward.  lfm2: the token cell's attention layer — 4 sequences,
 # 32 query heads on 8 key-value heads read by index, 4,096 tokens, head size
 # 64 as it is (a block whose minor dim spans the array: no pad to 128 lanes)
+# qwen3next: that cell's attention layer — 16 query heads on 2 key-value
+# heads, 8,192 tokens, head size 256: neither K/V nor the fused backward's q,
+# dO and dq (16 MiB) stay resident, so the streamed forward (1,024-row query
+# tiles: at 2,048 Mosaic refused it, 20.5 MiB of scoped VMEM) and the two
+# tiled backward kernels on repeated heads
 FLASH_SHAPES = {
     "vit_long": (8, 4, 4, 4096, 128), "s16384": (1, 4, 4, 16384, 128),
-    "lfm2": (4, 32, 8, 4096, 64),
+    "lfm2": (4, 32, 8, 4096, 64), "qwen3next": (1, 16, 2, 8192, 256),
 }
 
 
@@ -110,8 +117,13 @@ def test_flash_attention_compiles_for_v5e(chip, shape, backward, causal):
         assert " pad(" not in text, "the head dimension went in unpadded"
     if causal and h > hkv:
         # the plan's fused backward: one kernel, the group's dk / dv summed
-        # inside it, no repeated heads around it
-        assert text.count('custom_call_target="tpu_custom_call"') == 1 + backward
+        # inside it, no repeated heads around it — or, where its residents
+        # do not fit, the two tiled kernels
+        fused = flash_plan(s, s, d, h // hkv, True, BF16).fused_bwd
+        assert fused == (d <= 128)
+        assert text.count('custom_call_target="tpu_custom_call"') == (
+            1 + (1 if fused else 2) * backward
+        )
 
 
 # (batch, query heads, key-value heads, tokens, head size, window).  The
@@ -143,6 +155,23 @@ def test_flash_attention_with_a_window_compiles_for_v5e(chip, shape):
     assert "vmem_limit_bytes" not in text
     repeated = rf"\[{hkv},{h // hkv},{s},{d}\]\S* broadcast\("
     assert not re.search(repeated, text), "k / v repeated a query head"
+
+
+def test_gated_delta_scan_compiles_for_v5e(chip):
+    """The chunked gated delta rule at ``qwen3next_ep32_seq8k_job``'s sizes
+    (one sequence of 8,192 tokens, 32 value heads of 128 on 16 key heads,
+    chunk 64), forward and backward: composed XLA, one loop over the 128
+    chunks each way and no kernel — and no loop per token."""
+    f32 = jnp.float32
+    fn = _grad_of(lambda *a: gated_delta_rule(*a, chunk=64), 5)
+    text = _compiled_text(
+        fn, chip, _s(1, 8192, 16, 128), _s(1, 8192, 16, 128),
+        _s(1, 8192, 32, 128), _s(1, 8192, 32, dtype=f32), _s(1, 8192, 32, dtype=f32),
+    )
+    assert "tpu_custom_call" not in text
+    loops = re.findall(r" while\(.*op_name=\"([^\"]*)\"", text)
+    assert len(loops) == 2 and all("gdn_scan" in n for n in loops), loops
+    assert "transpose(" in loops[1] and "transpose(" not in loops[0]
 
 
 def test_flash_attention_with_lse_compiles_for_v5e(chip):

@@ -122,6 +122,95 @@ def test_flash_window_cell_shape(window):
         assert float(jnp.max(jnp.abs(full[:, :8, 4096:].astype(jnp.float32) - want[:, :, 4096:]))) > 0.05
 
 
+def test_flash_head_size_256_cell_shape():
+    """``qwen3next_ep32_seq8k_job``'s attention layer (one sequence, 16 query
+    heads on 2 key-value heads, 8,192 causal keys, head size 256): K/V do
+    not stay resident and the fused backward's q, dO and dq do not fit (16
+    MiB), so the plan takes the streamed forward (1,024-row query tiles)
+    and the two tiled backward kernels on keys and values repeated eight
+    times — against the composed path at bf16 tolerance, on the first
+    key-value head and the 8 query heads it serves (float32 scores)."""
+    from distributed_training_comparison_tpu.ops.attention import flash_plan
+
+    plan = flash_plan(8192, 8192, 256, 8, True, jnp.bfloat16)
+    assert (plan.head, plan.fused_bwd) == (256, False)
+    kq, kk, kv = jax.random.split(jax.random.key(35), 3)
+    q = jax.random.normal(kq, (1, 16, 8192, 256), jnp.bfloat16)
+    k = jax.random.normal(kk, (1, 2, 8192, 256), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, 2, 8192, 256), jnp.bfloat16)
+
+    def loss(fn):
+        return lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum()
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+    ref = lambda q, k, v: mha_reference(  # noqa: E731
+        q, jnp.repeat(k, 8, 1), jnp.repeat(v, 8, 1), causal=True
+    )
+    one = (q[:, :8], k[:, :1], v[:, :1])
+    out = jax.jit(flash)(q, k, v)[:, :8].astype(jnp.float32)
+    want = jax.jit(ref)(*one).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(out - want))) < 0.05
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(*one)
+    for a, b_, name, heads in zip(gf, gr, "qkv", (8, 1, 1)):
+        got = a[:, :heads].astype(jnp.float32)
+        b32 = b_.astype(jnp.float32)
+        assert got.shape == b32.shape
+        err = float(jnp.max(jnp.abs(got - b32)))
+        limit = 0.1 + 0.01 * float(jnp.max(jnp.abs(b32)))
+        assert err < limit, f"d{name} diverged on-chip: {err} (limit {limit})"
+
+
+def test_gated_delta_scan_cell_shape(capsys):
+    """``qwen3next_ep32_seq8k_job``'s scan (one sequence of 8,192 tokens, 32
+    value heads of 128 on 16 key heads, chunk 64): the chunked form on bf16
+    operands against the float32 token-by-token recurrence, output and all
+    five gradients, with decays as the model starts them (``A`` ~ U(0, 16),
+    ``softplus(alpha + 1)``).  Prints the errors: the chunked scan in bf16
+    against the float32 recurrence is the new term in the cell's
+    first-step comparison (``PERF.md`` §6)."""
+    from distributed_training_comparison_tpu.ops.gated_delta import (
+        gated_delta_rule,
+        gated_delta_rule_sequential,
+    )
+
+    keys = jax.random.split(jax.random.key(35), 7)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (1, 8192, 16, 128))) * 128 ** -0.5
+    k = unit(jax.random.normal(keys[1], (1, 8192, 16, 128)))
+    v = jax.random.normal(keys[2], (1, 8192, 32, 128))
+    a = jax.random.uniform(keys[3], (32,), minval=0.0, maxval=16.0)
+    g = -a * jax.nn.softplus(jax.random.normal(keys[4], (1, 8192, 32)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, 8192, 32)))
+    cot = jax.random.normal(keys[6], v.shape)
+    low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+
+    def program(q, k, v, g, beta):
+        o = gated_delta_rule(q, k, v, g, beta, chunk=64)
+        return jnp.sum(o.astype(jnp.float32) * cot), o
+
+    def recurrence(q, k, v, g, beta):
+        o = gated_delta_rule_sequential(q, k, v, g, beta, block=64)
+        return jnp.sum(o * cot), o
+
+    grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))  # noqa: E731
+    got, o = grad(program)(*low, g, beta)
+    want, o_ref = grad(recurrence)(q, k, v, g, beta)
+    rel = lambda x, y: float(  # noqa: E731
+        jnp.linalg.norm((x.astype(jnp.float32) - y).ravel()) / jnp.linalg.norm(y.ravel())
+    )
+    errors = {"o": rel(o, o_ref)}
+    errors.update({f"d{n}": rel(x, y) for n, x, y in zip(("q", "k", "v", "g", "beta"), got, want)})
+    with capsys.disabled():
+        print(f"\ngated_delta bf16 chunk 64 vs float32 recurrence, relative l2: {errors}")
+    assert errors["o"] < 0.02, errors
+    assert all(e < 0.05 for e in errors.values()), errors
+    # and in float32 the chunked form is the recurrence to rounding
+    with jax.default_matmul_precision("highest"):
+        o32 = jax.jit(lambda *x: gated_delta_rule(*x, chunk=64))(q, k, v, g, beta)
+    assert rel(o32, o_ref) < 1e-4
+
+
 def test_tiled_forward_engages_and_agrees():
     """S=16384 exceeds the resident-K/V limit: the streamed forward must
     compile and run (it could not before round 4); at S=4096 both paths
