@@ -40,7 +40,10 @@ the config's keys do not carry is from ``transformers``
    -exp(A_log) * softplus(alpha + dt_bias)`` (``A_log = log(u)``, ``u ~ U(0,
    16)``; ``dt_bias`` = 1), and the gated delta rule ``S <- exp(g) S``; ``d
    = beta (v - S^T k)``; ``S <- S + k d^T``; ``o = S^T q``
-   (``ops/gated_delta.py``, the chunked form).
+   (``ops/gated_delta.py``, the chunked form: on a TPU, at head sizes of
+   whole lanes and a length of whole chunks, a Pallas kernel pair that reads
+   q, k, v as the projections leave them; every other call, the CPU's and
+   ``qwen3_next_tiny``'s among them, composed XLA.  The call shows which).
 6. ``y = (w * o * rsqrt(mean(o^2) + eps)) * silu(z)`` per head (``w`` from
    one), then ``out_proj``.
 7. Full attention (``num_attention_heads`` / ``num_key_value_heads`` heads of
@@ -64,7 +67,12 @@ with ``gdn_conv``, ``gdn_scan`` and ``gdn_gate_norm`` inside it, ``attn``
 with ``attn_gate`` (the gate's sigmoid and multiply; its projection is
 ``q_proj``'s other half) and ``attention`` inside it, ``moe`` with ``moe_gmm`` and
 ``shared_expert`` inside it, ``lm_head``.  No scope of the DeltaNet mixer
-has ``attention`` as a path element.  A training call sows
+has ``attention`` as a path element.  ``gdn_scan`` holds every op of the
+scan on either path: the kernels ``gated_delta_fwd`` and ``gated_delta_bwd``
+with the running sums of ``g`` around them, or the composed form's loop.
+For its backward the kernel path keeps each chunk's start state and solved
+``T`` (268 + 34 MB a layer at 8,192 tokens), which live inside one
+rematerialised layer's backward.  A training call sows
 ``moe_metrics/gdn_decay_mean``, the mean of ``exp(g)`` over a DeltaNet
 layer's tokens and heads (the ``metrics`` event's gauge ``gdn/decay_mean``).
 """

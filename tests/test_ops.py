@@ -1145,8 +1145,10 @@ def test_grouped_ffn_jit_single_tile():
 
 import numpy as np  # noqa: E402
 
+from distributed_training_comparison_tpu.ops import gated_delta  # noqa: E402
 from distributed_training_comparison_tpu.ops.gated_delta import (  # noqa: E402
     _unit_lower_inverse,
+    gated_delta_plan,
     gated_delta_rule,
     gated_delta_rule_sequential,
 )
@@ -1154,6 +1156,17 @@ from distributed_training_comparison_tpu.ops.gated_delta import (  # noqa: E402
 # log-decay a token by how fast a head forgets: as the model starts (``A`` up
 # to 16: a head keeps e^-20 of its state), near 0 everywhere, near 1 everywhere
 DECAYS = {"as_initialised": None, "near_0": 20.0, "near_1": 1e-3}
+# the two paths of ``gated_delta_rule``: the composed form at the tiny widths
+# (80 tokens: five chunks of 16, or one and a quarter of 64, padded), and the
+# Pallas kernel pair through the interpreter at the smallest sizes it takes,
+# a head size of one lane tile and a length of whole chunks (256 tokens: two
+# grid steps of eight chunks of 16, or one of four chunks of 64)
+PATHS = {
+    "composed": dict(sizes=dict(), options={}),
+    "pallas": dict(
+        sizes=dict(b=1, s=256, dk=128, dv=128), options=dict(interpret=True)
+    ),
+}
 
 
 def _delta_inputs(decay, b=2, s=80, hk=2, hv=4, dk=16, dv=24, alike=False):
@@ -1171,22 +1184,28 @@ def _delta_inputs(decay, b=2, s=80, hk=2, hv=4, dk=16, dv=24, alike=False):
     return (q, unit(k), v, -rate * softplus, beta), cot
 
 
+def _delta_grads(f, x, cot):
+    return jax.grad(
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * cot), argnums=(0, 1, 2, 3, 4)
+    )(*x)
+
+
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_gated_delta_rule_is_the_token_by_token_recurrence(chunk, decay):
-    """Output and all five gradients, in float32, 80 tokens: five chunks of
-    16, or one and a quarter of 64 (padded); two value heads a key head."""
-    x, cot = _delta_inputs(decay)
-    o = gated_delta_rule(*x, chunk=chunk)
+def test_chunked_gated_delta_rule_is_the_token_by_token_recurrence(chunk, decay, path):
+    """Output and all five gradients, in float32; two value heads a key
+    head.  Both paths (``PATHS``) against the one recurrence."""
+    x, cot = _delta_inputs(decay, **PATHS[path]["sizes"])
+    rule = functools.partial(gated_delta_rule, chunk=chunk, **PATHS[path]["options"])
+    o = rule(*x)
     want = gated_delta_rule_sequential(*x)
-    assert o.shape == want.shape == (2, 80, 4, 24)
+    assert o.shape == want.shape == x[2].shape
     scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(o, want, rtol=1e-4, atol=2e-6 * scale)
-    grad = lambda f: jax.grad(  # noqa: E731
-        lambda *a: jnp.sum(f(*a) * cot), argnums=(0, 1, 2, 3, 4)
-    )(*x)
-    got = grad(lambda *a: gated_delta_rule(*a, chunk=chunk))
-    for g, r, name in zip(got, grad(gated_delta_rule_sequential), ("q", "k", "v", "g", "beta")):
+    got = _delta_grads(rule, x, cot)
+    refs = _delta_grads(gated_delta_rule_sequential, x, cot)
+    for g, r, name in zip(got, refs, ("q", "k", "v", "g", "beta")):
         top = float(jnp.abs(r).max())
         assert top > 0 or decay == "near_0", name
         np.testing.assert_allclose(g, r, rtol=2e-3, atol=2e-5 * top, err_msg=name)
@@ -1195,20 +1214,41 @@ def test_chunked_gated_delta_rule_is_the_token_by_token_recurrence(chunk, decay)
         alone = beta[..., None] * jnp.sum(
             jnp.repeat(q * k, 2, axis=2), -1, keepdims=True
         ) * v
-        gone = gated_delta_rule(q, k, v, jnp.full_like(g, -100.0), beta, chunk=chunk)
+        gone = rule(q, k, v, jnp.full_like(g, -100.0), beta)
         np.testing.assert_allclose(gone, alone, rtol=1e-4, atol=1e-6)
 
 
-def test_gated_delta_rule_with_keys_alike_and_no_decay():
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gated_delta_rule_with_keys_alike_and_no_decay(path, dtype):
     """The triangular system at its worst: every key of a chunk nearly the
     same and nothing forgotten, where a series in powers of ``L`` cancels
-    binomially; substitution does not."""
-    x, _ = _delta_inputs("near_1", alike=True)
+    binomially; substitution does not.  In bf16 (the cell's operands: the
+    kernels' solve is then float32 tiles made of bf16 keys) against the
+    recurrence on the same rounded operands, output and five gradients in
+    relative l2: what rounds is ``T`` on use and the products' operands
+    (measured 0.7-1.9 % in the output, up to 4.7 % in dg, either path)."""
+    x, cot = _delta_inputs("near_1", alike=True, **PATHS[path]["sizes"])
+    options = PATHS[path]["options"]
+    if dtype == "bfloat16":
+        low = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+        x = tuple(a.astype(jnp.float32) for a in low)
+        want = gated_delta_rule_sequential(*x)
+        refs = _delta_grads(gated_delta_rule_sequential, x, cot)
+        rel = lambda a, b: float(  # noqa: E731
+            jnp.linalg.norm((a.astype(jnp.float32) - b).ravel()) / jnp.linalg.norm(b.ravel())
+        )
+        for chunk in (16, 64):
+            rule = functools.partial(gated_delta_rule, chunk=chunk, **options)
+            assert rel(rule(*low), want) < 0.03, chunk
+            for a, r, name in zip(_delta_grads(rule, low, cot), refs, "qkvgb"):
+                assert rel(a, r) < 0.08, (chunk, name)
+        return
     want = gated_delta_rule_sequential(*x)
     for chunk in (16, 64):
         np.testing.assert_allclose(
-            gated_delta_rule(*x, chunk=chunk), want, rtol=1e-3,
-            atol=1e-5 * float(jnp.abs(want).max()),
+            gated_delta_rule(*x, chunk=chunk, **options), want,
+            rtol=1e-3, atol=1e-5 * float(jnp.abs(want).max()),
         )
     m = jnp.tril(jnp.ones((64, 64)), -1)  # L of identical keys, beta = 1
     inverse = _unit_lower_inverse(m)
@@ -1219,20 +1259,114 @@ def test_gated_delta_rule_with_keys_alike_and_no_decay():
     assert float(jnp.abs(inverse).max()) == pytest.approx(1.0, abs=1e-5)
 
 
-def test_gated_delta_rule_in_bf16_and_its_bad_calls():
-    """bf16 operands, float32 decays and state: close to the float32
-    recurrence, in the operands' dtype; the recurrence in blocks is the
-    recurrence; a value head count the key heads do not divide is refused."""
-    x, _ = _delta_inputs("as_initialised")
+@pytest.mark.parametrize("path", PATHS)
+def test_gated_delta_rule_in_bf16_and_its_bad_calls(path):
+    """bf16 operands, float32 decays and state: output and the five
+    gradients close to the float32 recurrence's (relative l2, the limits of
+    ``tests_tpu``'s run at the cell's shape), in the operands' dtype; the
+    recurrence in blocks is the recurrence; a value head count the key heads
+    do not divide is refused."""
+    options = PATHS[path]["options"]
+    x, cot = _delta_inputs("as_initialised", **PATHS[path]["sizes"])
     q, k, v, g, beta = x
-    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
-    o = gated_delta_rule(*low, g, beta, chunk=16)
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
+    rule = functools.partial(gated_delta_rule, chunk=16, **options)
+    o = rule(*low)
     want = gated_delta_rule_sequential(*x)
     assert o.dtype == jnp.bfloat16
-    err = jnp.linalg.norm((o.astype(jnp.float32) - want).ravel())
-    assert float(err / jnp.linalg.norm(want.ravel())) < 0.02
+    rel = lambda a, b: float(  # noqa: E731
+        jnp.linalg.norm((a.astype(jnp.float32) - b).ravel()) / jnp.linalg.norm(b.ravel())
+    )
+    assert rel(o, want) < 0.02
+    got = _delta_grads(rule, low, cot)
+    refs = _delta_grads(gated_delta_rule_sequential, x, cot)
+    for a, r, name in zip(got, refs, ("q", "k", "v", "g", "beta")):
+        assert a.dtype == (g.dtype if name in ("g", "beta") else jnp.bfloat16), name
+        assert rel(a, r) < 0.05, name
     np.testing.assert_array_equal(gated_delta_rule_sequential(*x, block=16), want)
     with pytest.raises(ValueError, match="value heads"):
-        gated_delta_rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3])
+        gated_delta_rule(q, k, v[:, :, :3], g[..., :3], beta[..., :3], **options)
     with pytest.raises(ValueError, match="whole blocks"):
-        gated_delta_rule_sequential(*x, block=64)
+        gated_delta_rule_sequential(*x, block=48)
+
+
+def _noted_path(fn, *x):
+    """``fn(*x)`` under an observed compile, and the ``gated_delta`` path its
+    trace noted on the ``compile`` event."""
+    from distributed_training_comparison_tpu import obs
+
+    bus = obs.configure(run_id=obs.new_run_id(), persist=True)
+    try:
+        monitor = obs.CompileMonitor(bus=bus, registry=obs.MetricRegistry())
+        out = monitor.instrument(jax.jit(fn), "rule")(*x)
+        (event,) = [e["payload"] for e in bus.ring_events() if e["kind"] == "compile"]
+    finally:
+        obs.reset()
+    return out, event["kernel_paths"]["gated_delta"]
+
+
+# calls the kernel pair cannot take: (sizes, chunk, interpret)
+GDN_LEFT_TO_THE_COMPOSED_FORM = {
+    "cpu_without_interpret": (dict(b=1, s=128, dk=128, dv=128), 64, False),
+    "head_size_64": (dict(b=1, s=128, dk=64, dv=128), 64, True),
+    "value_head_size_24": (dict(b=1, s=128, dk=128, dv=24), 64, True),
+    "length_of_no_whole_chunks": (dict(b=1, s=80, dk=128, dv=128), 64, True),
+    "length_of_no_whole_steps": (dict(b=1, s=144, dk=128, dv=128), 16, True),
+    "chunk_48": (dict(b=1, s=96, dk=128, dv=128), 48, True),
+}
+
+
+@pytest.mark.parametrize("case", GDN_LEFT_TO_THE_COMPOSED_FORM)
+def test_a_call_the_gated_delta_kernels_cannot_take_is_the_composed_form(case):
+    """Such a call notes ``composed`` on the compile event and is, bit for
+    bit, what ``_chunked`` gives (the only path before the kernels)."""
+    sizes, chunk, interpret = GDN_LEFT_TO_THE_COMPOSED_FORM[case]
+    x, _ = _delta_inputs("as_initialised", **sizes)
+    rule = functools.partial(gated_delta_rule, chunk=chunk, interpret=interpret)
+    o, noted = _noted_path(rule, *x)
+    assert noted == "composed"
+    composed = jax.jit(lambda *a: gated_delta._chunked(*a, chunk))(*x)
+    np.testing.assert_array_equal(o, composed)
+
+
+GDN_PLANS = {
+    # the cell's call: 128 chunks, eight a grid step; float32 operands too
+    "cell": (("tpu", jnp.bfloat16, 128, 128, 2, 8192, 64), 8),
+    "float32": (("tpu", jnp.float32, 128, 128, 2, 8192, 64), 8),
+    # up to eight chunks are one grid step
+    "one_chunk": (("tpu", jnp.bfloat16, 128, 128, 1, 64, 64), 1),
+    "five_chunks": (("tpu", jnp.bfloat16, 128, 256, 2, 80, 16), 5),
+    # what the composed form keeps: no TPU, a head size or chunk that is no
+    # whole tile, another dtype, a length that would need padding (to whole
+    # chunks, or past one grid step to whole steps), a group whose states do
+    # not fit
+    "cpu": (("cpu", jnp.bfloat16, 128, 128, 2, 8192, 64), None),
+    "head_64": (("tpu", jnp.bfloat16, 64, 128, 2, 8192, 64), None),
+    "value_head_24": (("tpu", jnp.float32, 128, 24, 2, 80, 16), None),
+    "chunk_48": (("tpu", jnp.bfloat16, 128, 128, 2, 8192, 48), None),
+    "float16": (("tpu", jnp.float16, 128, 128, 2, 8192, 64), None),
+    "padded_chunk": (("tpu", jnp.bfloat16, 128, 128, 2, 8200, 64), None),
+    "padded_step": (("tpu", jnp.bfloat16, 128, 128, 2, 9 * 64, 64), None),
+    "group_64": (("tpu", jnp.bfloat16, 128, 128, 64, 8192, 64), None),
+}
+
+
+@pytest.mark.parametrize("case", GDN_PLANS)
+def test_gated_delta_plan_takes_the_kernel_where_it_can(case):
+    """Which path a call takes is a pure function of what it shows: backend,
+    dtype, head sizes, group, length, chunk."""
+    call, step_chunks = GDN_PLANS[case]
+    assert gated_delta_plan(*call) == step_chunks
+
+
+def test_the_gated_delta_kernels_note_their_path():
+    """Through the interpreter the kernel pair notes ``pallas-interpret``
+    (on a TPU ``pallas``, which the cell's ``expect`` lists), at the
+    smallest call it takes: one chunk, one key head."""
+    x, _ = _delta_inputs("as_initialised", b=1, s=64, hk=1, hv=2, dk=128, dv=128)
+    rule = functools.partial(gated_delta_rule, chunk=64, interpret=True)
+    o, noted = _noted_path(rule, *x)
+    assert noted == "pallas-interpret"
+    np.testing.assert_allclose(
+        o, gated_delta_rule_sequential(*x), rtol=1e-4, atol=1e-5
+    )

@@ -31,7 +31,6 @@ from distributed_training_comparison_tpu.models.moe import TopKMoE
 from distributed_training_comparison_tpu.ops import attention, flash_attention
 from distributed_training_comparison_tpu.ops.attention import flash_plan
 from distributed_training_comparison_tpu.ops.attention_small import small_mha
-from distributed_training_comparison_tpu.ops.gated_delta import gated_delta_rule
 from distributed_training_comparison_tpu.ops.moe_gmm import (
     grouped_ffn,
     grouped_matmul,
@@ -157,17 +156,45 @@ def test_flash_attention_with_a_window_compiles_for_v5e(chip, shape):
     assert not re.search(repeated, text), "k / v repeated a query head"
 
 
-def test_gated_delta_scan_compiles_for_v5e(chip):
-    """The chunked gated delta rule at ``qwen3next_ep32_seq8k_job``'s sizes
-    (one sequence of 8,192 tokens, 32 value heads of 128 on 16 key heads,
-    chunk 64), forward and backward: composed XLA, one loop over the 128
-    chunks each way and no kernel — and no loop per token."""
+def _gated_delta_text(chip, tokens):
+    """``gated_delta_rule``'s forward and backward at the cell's heads (32
+    value heads of 128 on 16 key heads, chunk 64), compiled for the chip."""
+    from distributed_training_comparison_tpu.ops.gated_delta import gated_delta_rule
+
     f32 = jnp.float32
-    fn = _grad_of(lambda *a: gated_delta_rule(*a, chunk=64), 5)
-    text = _compiled_text(
-        fn, chip, _s(1, 8192, 16, 128), _s(1, 8192, 16, 128),
-        _s(1, 8192, 32, 128), _s(1, 8192, 32, dtype=f32), _s(1, 8192, 32, dtype=f32),
+    return _compiled_text(
+        _grad_of(lambda *a: gated_delta_rule(*a, chunk=64), 5), chip,
+        _s(1, tokens, 16, 128), _s(1, tokens, 16, 128), _s(1, tokens, 32, 128),
+        _s(1, tokens, 32, dtype=f32), _s(1, tokens, 32, dtype=f32),
     )
+
+
+def test_gated_delta_scan_compiles_for_v5e(chip, monkeypatch):
+    """The gated delta rule at ``qwen3next_ep32_seq8k_job``'s sizes (one
+    sequence of 8,192 tokens), through the dispatcher as a TPU shows it the
+    call (``jax.default_backend()``, which only code of this repo reads
+    under that name, says ``tpu`` for the trace): one forward and one
+    backward kernel, both under the scope ``gdn_scan`` that the cell's
+    readers match, no loop over chunks or tokens outside them, q, k, v read
+    as ``(B, S, H d)`` — no key head repeated, no float32 copy of a ``(S,
+    H, d)`` operand."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _gated_delta_text(chip, 8192)
+    kernels = re.findall(r'tpu_custom_call.*op_name="([^"]*)"', text)
+    assert len(kernels) == 2 and all("gdn_scan" in n for n in kernels), kernels
+    assert "transpose(" in kernels[1] and "transpose(" not in kernels[0]
+    assert " while(" not in text
+    assert "vmem_limit_bytes" not in text
+    assert not re.search(r"f32\[1,8192,(16|32),128\]\S* (copy|convert)\(", text)
+    assert not re.search(r"\[1,8192,16,2,128\]\S* broadcast\(", text)
+
+
+def test_composed_gated_delta_scan_compiles_for_v5e(chip, monkeypatch):
+    """A length the kernels leave to the composed form on a TPU too (8,200
+    tokens: no whole chunks, padded to 129): composed XLA, one loop over the
+    chunks each way and no kernel — and no loop per token."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _gated_delta_text(chip, 8200)
     assert "tpu_custom_call" not in text
     loops = re.findall(r" while\(.*op_name=\"([^\"]*)\"", text)
     assert len(loops) == 2 and all("gdn_scan" in n for n in loops), loops

@@ -161,19 +161,10 @@ def test_flash_head_size_256_cell_shape():
         assert err < limit, f"d{name} diverged on-chip: {err} (limit {limit})"
 
 
-def test_gated_delta_scan_cell_shape(capsys):
-    """``qwen3next_ep32_seq8k_job``'s scan (one sequence of 8,192 tokens, 32
-    value heads of 128 on 16 key heads, chunk 64): the chunked form on bf16
-    operands against the float32 token-by-token recurrence, output and all
-    five gradients, with decays as the model starts them (``A`` ~ U(0, 16),
-    ``softplus(alpha + 1)``).  Prints the errors: the chunked scan in bf16
-    against the float32 recurrence is the new term in the cell's
-    first-step comparison (``PERF.md`` §6)."""
-    from distributed_training_comparison_tpu.ops.gated_delta import (
-        gated_delta_rule,
-        gated_delta_rule_sequential,
-    )
-
+def _gated_delta_cell_inputs():
+    """``qwen3next_ep32_seq8k_job``'s scan: one sequence of 8,192 tokens, 32
+    value heads of 128 on 16 key heads, decays as the model starts them
+    (``A`` ~ U(0, 16), ``softplus(alpha + 1)``), and an output cotangent."""
     keys = jax.random.split(jax.random.key(35), 7)
     unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731
     q = unit(jax.random.normal(keys[0], (1, 8192, 16, 128))) * 128 ** -0.5
@@ -182,33 +173,103 @@ def test_gated_delta_scan_cell_shape(capsys):
     a = jax.random.uniform(keys[3], (32,), minval=0.0, maxval=16.0)
     g = -a * jax.nn.softplus(jax.random.normal(keys[4], (1, 8192, 32)) + 1.0)
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, 8192, 32)))
-    cot = jax.random.normal(keys[6], v.shape)
-    low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    return (q, k, v, g, beta), jax.random.normal(keys[6], v.shape)
 
-    def program(q, k, v, g, beta):
-        o = gated_delta_rule(q, k, v, g, beta, chunk=64)
+
+def _gated_delta_grads(rule, x, cot):
+    """``rule``'s five gradients under the cotangent ``cot`` and its output."""
+
+    def loss(*a):
+        o = rule(*a)
         return jnp.sum(o.astype(jnp.float32) * cot), o
 
-    def recurrence(q, k, v, g, beta):
-        o = gated_delta_rule_sequential(q, k, v, g, beta, block=64)
-        return jnp.sum(o * cot), o
+    grads, o = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*x)
+    return dict(zip(("o", "dq", "dk", "dv", "dg", "dbeta"), (o, *grads)))
 
-    grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4), has_aux=True))  # noqa: E731
-    got, o = grad(program)(*low, g, beta)
-    want, o_ref = grad(recurrence)(q, k, v, g, beta)
-    rel = lambda x, y: float(  # noqa: E731
-        jnp.linalg.norm((x.astype(jnp.float32) - y).ravel()) / jnp.linalg.norm(y.ravel())
+
+def _relative_l2(got, want):
+    f32 = lambda a: a.astype(jnp.float32).ravel()  # noqa: E731
+    return {
+        n: float(jnp.linalg.norm(f32(got[n]) - f32(want[n])) / jnp.linalg.norm(f32(want[n])))
+        for n in want
+    }
+
+
+def test_gated_delta_scan_cell_shape(capsys):
+    """The cell's scan as the dispatcher runs it on a TPU — the Pallas kernel
+    pair — and the composed form beside it, both on bf16 operands against
+    the float32 token-by-token recurrence, output and all five gradients.
+    Prints the errors: the chunked scan in bf16 against the float32
+    recurrence is a term of the cell's first-step comparison (``PERF.md``
+    §6); the kernels may be no worse than the composed form (0.41-0.45 %,
+    PR 35).  And in float32 the kernels are the composed form to rounding."""
+    from distributed_training_comparison_tpu.ops import gated_delta
+
+    x, cot = _gated_delta_cell_inputs()
+    low = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+    assert gated_delta.gated_delta_plan(
+        jax.default_backend(), jnp.bfloat16, 128, 128, 2, 8192, 64
+    ) is not None, "the dispatcher would take the composed form here"
+    rule = lambda *a: gated_delta.gated_delta_rule(*a, chunk=64)  # noqa: E731
+    composed = lambda *a: gated_delta._chunked(*a, 64)  # noqa: E731
+    recurrence = _gated_delta_grads(
+        lambda *a: gated_delta.gated_delta_rule_sequential(*a, block=64), x, cot
     )
-    errors = {"o": rel(o, o_ref)}
-    errors.update({f"d{n}": rel(x, y) for n, x, y in zip(("q", "k", "v", "g", "beta"), got, want)})
+    kernel = _relative_l2(_gated_delta_grads(rule, low, cot), recurrence)
+    before = _relative_l2(_gated_delta_grads(composed, low, cot), recurrence)
     with capsys.disabled():
-        print(f"\ngated_delta bf16 chunk 64 vs float32 recurrence, relative l2: {errors}")
-    assert errors["o"] < 0.02, errors
-    assert all(e < 0.05 for e in errors.values()), errors
-    # and in float32 the chunked form is the recurrence to rounding
+        print(f"\ngated_delta bf16 chunk 64 vs float32 recurrence, relative l2: "
+              f"kernel {kernel}, composed {before}")
+    assert kernel["o"] < 0.02, kernel
+    assert all(e < 0.05 for e in kernel.values()), kernel
+    assert all(kernel[n] <= 1.05 * before[n] for n in kernel), (kernel, before)
+    # and in float32 the kernels are the composed form, and the recurrence
     with jax.default_matmul_precision("highest"):
-        o32 = jax.jit(lambda *x: gated_delta_rule(*x, chunk=64))(q, k, v, g, beta)
-    assert rel(o32, o_ref) < 1e-4
+        exact_kernel = _gated_delta_grads(rule, x, cot)
+        same = _relative_l2(exact_kernel, _gated_delta_grads(composed, x, cot))
+    exact = _relative_l2(exact_kernel, recurrence)
+    with capsys.disabled():
+        print(f"gated_delta float32: kernel vs composed {same}, vs recurrence {exact}")
+    assert all(e < 1e-4 for e in same.values()), same
+    assert exact["o"] < 1e-4, exact
+
+
+def test_gated_delta_kernels_with_keys_alike_in_bf16(capsys):
+    """The solve's worst case on the path the cell takes (bf16 operands):
+    every key nearly the same and next to no decay, 1,024 tokens, 4 value
+    heads of 128 on 2 key heads.  Against the float32 recurrence on the same
+    rounded operands the kernels may be no worse than the composed form,
+    whose solve is float32 at ``highest``: the kernels' is too."""
+    from distributed_training_comparison_tpu.ops import gated_delta
+
+    keys = jax.random.split(jax.random.key(37), 6)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731
+    k = jax.random.normal(keys[1], (1, 1024, 2, 128))
+    k = unit(k[:, :1] + 0.05 * k)
+    q = unit(jax.random.normal(keys[0], (1, 1024, 2, 128))) * 128 ** -0.5
+    v = jax.random.normal(keys[2], (1, 1024, 4, 128))
+    g = -1e-3 * jax.nn.softplus(jax.random.normal(keys[3], (1, 1024, 4)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 1024, 4)))
+    cot = jax.random.normal(keys[5], v.shape)
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
+    assert gated_delta.gated_delta_plan(
+        jax.default_backend(), jnp.bfloat16, 128, 128, 2, 1024, 64
+    ) is not None, "the dispatcher would take the composed form here"
+    recurrence = _gated_delta_grads(
+        lambda *a: gated_delta.gated_delta_rule_sequential(*a, block=64),
+        tuple(a.astype(jnp.float32) for a in low), cot,
+    )
+    kernel = _relative_l2(_gated_delta_grads(
+        lambda *a: gated_delta.gated_delta_rule(*a, chunk=64), low, cot
+    ), recurrence)
+    before = _relative_l2(_gated_delta_grads(
+        lambda *a: gated_delta._chunked(*a, 64), low, cot
+    ), recurrence)
+    with capsys.disabled():
+        print(f"\ngated_delta bf16, keys alike, relative l2 vs the recurrence: "
+              f"kernel {kernel}, composed {before}")
+    assert kernel["o"] < 0.03, kernel
+    assert all(kernel[n] <= 1.05 * before[n] for n in kernel), (kernel, before)
 
 
 def test_tiled_forward_engages_and_agrees():
