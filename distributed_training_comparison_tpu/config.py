@@ -511,8 +511,11 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         "--profile-dir",
         type=str,
         default=None,
-        help="Capture a jax.profiler trace of one steady-state epoch into "
-        "this directory (view with TensorBoard's profile plugin / Perfetto)",
+        help="Capture a jax.profiler trace of one steady-state epoch, from "
+        "its epoch_start to the end of its boundary (validation, save, "
+        "bookkeeping: where the chip waits for the host), into this "
+        "directory (view with TensorBoard's profile plugin / Perfetto; "
+        "benchmark/tools/boundary_table.py prints the idle time by host span)",
     )
     # serving (serve/ subsystem: engine + micro-batcher + load generators)
     parser.add_argument(

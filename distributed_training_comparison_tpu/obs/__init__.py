@@ -15,10 +15,11 @@ of attempt 3".  ``obs`` is the one layer they all now report through:
   ``span("epoch")`` context manager recording begin/end pairs on every
   thread (trainer loop, ``DevicePrefetcher`` producer, the async
   checkpoint writer), exported as Chrome-trace/Perfetto JSON so one file
-  shows compute, staging, and checkpointing overlapping in time.  During
-  a ``--profile-dir`` capture the same spans also emit
-  ``jax.profiler.TraceAnnotation``s, so the xplane's device timeline
-  carries the host span names;
+  shows compute, staging, and checkpointing overlapping in time.  Every
+  span is a node of a tree (``id``, ``parent``, ``epoch``) and also a
+  ``jax.profiler.TraceAnnotation``, so any profiler capture carries the
+  host spans on its own clock beside the device's ops — one clock, nothing
+  to join (``benchmark/harness/host_spans.py`` reads them there);
 - ``metrics.py`` — **per-step metrics with a sampling budget**: typed
   counter/gauge/log-bucket-histogram accumulators the trainer records
   into every step, flushed as bounded periodic ``metrics`` bus events
@@ -27,10 +28,6 @@ of attempt 3".  ``obs`` is the one layer they all now report through:
   fixed-slot ring file per process mirroring every emit
   (torn-page-tolerant decode), pulled by the supervisor after every
   attempt into one cross-host ``blackbox.json`` under the ckpt root;
-- ``xplane.py`` — a dependency-free reader for the jax profiler's
-  ``*.xplane.pb`` captures, used by ``run_report --xplane`` to merge host
-  spans and the device trace into ONE Perfetto file joined on the
-  ``StepTraceAnnotation`` step ids;
 - ``heartbeat.py`` — **liveness**: bounded-cadence per-process
   ``heartbeat`` events, the supervisor-side tracker that classifies a
   lagging host as slow vs dead (``stall`` events before the collective
@@ -155,9 +152,9 @@ from .spans import (
     SpanRecorder,
     chrome_trace,
     current_recorder,
+    open_span,
     set_recorder,
     span,
-    step_annotation,
     trace_filename,
     write_chrome_trace,
 )
@@ -238,9 +235,9 @@ __all__ = [
     "SpanRecorder",
     "chrome_trace",
     "current_recorder",
+    "open_span",
     "set_recorder",
     "span",
-    "step_annotation",
     "trace_filename",
     "write_chrome_trace",
 ]

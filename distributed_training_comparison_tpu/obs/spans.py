@@ -12,18 +12,27 @@ Spans nest strictly by construction: each is a context manager pushed and
 popped on a per-thread stack, so a thread's spans at depth d always lie
 inside its enclosing depth d-1 span — the invariant the export test pins.
 
+A span is a node of a tree: besides name, interval, thread and depth it
+records an ``id``, the ``parent`` id — the span open beneath it on its
+thread, or the span another thread names as its cause (``span(...,
+parent=)``: the checkpoint writer's ``ckpt_write`` names the trainer's
+``ckpt_submit`` that queued its job) — and the ``epoch`` it belongs to,
+given as an attribute or inherited from the parent, so one epoch's spans
+share an identifier.
+
 Every span also enters a ``jax.profiler.TraceAnnotation``, which is inert
 while no profiler session runs.  So the host lines of ANY profiler trace —
 a ``--profile-dir`` capture, or one an embedder starts around ``fit()`` —
-carry these names on the trace's own clock, beside the device's ops (during
-a ``--profile-dir`` capture the trainer additionally wraps chunk dispatches
-in ``StepTraceAnnotation``).  With no session the cost of a span is two
-clock reads, one dict append and the annotation's no-op enter and exit.
+carry these names on the trace's own clock, beside the device's ops
+(``benchmark/harness/host_spans.py`` books the device's idle time to them).
+With no session the cost of a span is two clock reads, one dict append and
+the annotation's no-op enter and exit.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import threading
 import time
@@ -56,16 +65,28 @@ class SpanRecorder:
         self._spans: list[dict] = []
         self._dropped = 0
         self._local = threading.local()
+        self._ids = itertools.count(1)  # next() is atomic under the GIL
 
-    def _stack(self) -> list[str]:
+    def _stack(self) -> "list[_Span]":
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+            # looked up once a thread: a span pays no current_thread()
+            thread = threading.current_thread()
+            self._local.thread = (thread.ident, thread.name)
         return stack
 
-    def span(self, name: str, **attrs):
-        """Context manager recording one span on the calling thread."""
-        return _Span(self, name, attrs)
+    def span(self, name: str, parent: "_Span | None" = None, **attrs):
+        """Context manager recording one span on the calling thread; it
+        yields the open span.  ``parent`` names a span of ANOTHER thread as
+        this one's cause, in place of the one open beneath it here."""
+        return _Span(self, name, parent, attrs)
+
+    def open_span(self) -> "_Span | None":
+        """The innermost span open on the calling thread: what a job handed
+        to another thread names as its cause."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     def _append(self, rec: dict) -> None:
         with self._lock:
@@ -99,6 +120,9 @@ class SpanRecorder:
             "thread_id": tid,
             "thread_name": tname,
             "depth": 0,
+            "id": next(self._ids),
+            "parent": None,
+            "epoch": attrs.get("epoch"),
         }
         if attrs:
             rec["args"] = attrs
@@ -119,30 +143,47 @@ class _Span:
     host paths (every chunk dispatch) and a generator's frame costs more
     than the span's own work."""
 
-    __slots__ = ("rec", "name", "attrs", "stack", "ann", "t0")
+    __slots__ = (
+        "rec", "name", "cause", "attrs", "stack", "ann", "t0", "id", "parent",
+        "epoch",
+    )
 
-    def __init__(self, rec: SpanRecorder, name: str, attrs: dict) -> None:
-        self.rec, self.name, self.attrs = rec, name, attrs
+    def __init__(
+        self, rec: SpanRecorder, name: str, cause: "_Span | None", attrs: dict
+    ) -> None:
+        self.rec, self.name, self.cause, self.attrs = rec, name, cause, attrs
 
-    def __enter__(self) -> None:
-        self.stack = self.rec._stack()
-        self.stack.append(self.name)
+    def __enter__(self) -> "_Span":
+        rec = self.rec
+        stack = self.stack = rec._stack()
+        cause = self.cause or (stack[-1] if stack else None)
+        self.id = next(rec._ids)
+        if cause is None:
+            self.parent, self.epoch = None, self.attrs.get("epoch")
+        else:
+            self.parent = cause.id
+            self.epoch = self.attrs.get("epoch", cause.epoch)
+        stack.append(self)
         self.ann = _trace_annotation(self.name)
         self.t0 = time.monotonic()
         self.ann.__enter__()
+        return self
 
     def __exit__(self, *exc) -> None:
         self.ann.__exit__(*exc)
         t1 = time.monotonic()
         self.stack.pop()
-        thread = threading.current_thread()
+        thread_id, thread_name = self.rec._local.thread
         rec = {
             "name": str(self.name),
             "t0": self.t0,
             "t1": t1,
-            "thread_id": thread.ident,
-            "thread_name": thread.name,
+            "thread_id": thread_id,
+            "thread_name": thread_name,
             "depth": len(self.stack),
+            "id": self.id,
+            "parent": self.parent,
+            "epoch": self.epoch,
         }
         if self.attrs:
             rec["args"] = self.attrs
@@ -163,20 +204,6 @@ def _annotation_class():
 
 def _trace_annotation(name: str):
     return _annotation_class()(name)
-
-
-def step_annotation(step: int | None = None):
-    """``jax.profiler.StepTraceAnnotation("train", step_num=...)`` — the
-    marker the profile tooling joins device time to step ids with.  The
-    trainer wraps each chunk dispatch of the profiled epoch in one, so the
-    xplane capture gains step boundaries (it had none before)."""
-    try:
-        import jax.profiler
-
-        kwargs = {} if step is None else {"step_num": int(step)}
-        return jax.profiler.StepTraceAnnotation("train", **kwargs)
-    except (ImportError, AttributeError):  # pragma: no cover - exotic jax
-        return nullcontext()
 
 
 # ---------------------------------------------------------- chrome export
@@ -207,8 +234,11 @@ def chrome_trace(
             "ts": round(s["t0"] * 1e6, 3),   # microseconds, Chrome's unit
             "dur": round(max(0.0, s["t1"] - s["t0"]) * 1e6, 3),
         }
-        if s.get("args"):
-            ev["args"] = s["args"]
+        # the tree rides in args: Perfetto shows them on a click, and a
+        # reader of the file rebuilds parent -> children from them
+        tree = {k: s[k] for k in ("id", "parent", "epoch") if s.get(k) is not None}
+        if s.get("args") or tree:
+            ev["args"] = {**(s.get("args") or {}), **tree}
         events.append(ev)
     events.sort(key=lambda e: (e["tid"], e["ts"], -e["dur"]))
     name = label or f"process {process_index}"
@@ -282,3 +312,9 @@ def current_recorder() -> SpanRecorder:
 def span(name: str, **attrs):
     """Record a span on the process-current recorder."""
     return current_recorder().span(name, **attrs)
+
+
+def open_span():
+    """The innermost span open on the calling thread, on the
+    process-current recorder (``SpanRecorder.open_span``)."""
+    return current_recorder().open_span()

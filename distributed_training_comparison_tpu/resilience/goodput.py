@@ -32,7 +32,7 @@ from __future__ import annotations
 import json
 import time
 from collections import defaultdict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 
 PHASES = ("init", "step", "eval", "ckpt", "stall", "rollback")
@@ -41,7 +41,12 @@ PHASES = ("init", "step", "eval", "ckpt", "stall", "rollback")
 class GoodputMeter:
     """Accumulates per-phase wall-clock for one training attempt."""
 
-    def __init__(self) -> None:
+    def __init__(self, tracer=None) -> None:
+        # optional span recorder (obs/spans.py): ``phase(name, span=...)``
+        # then also records the interval as a host span, as
+        # ``StepTimeMeter.phase`` does — one call books the total and draws
+        # the span, so the two cannot drift apart in extent
+        self.tracer = tracer
         self.seconds: dict[str, float] = defaultdict(float)
         self._t0 = time.monotonic()
         self.written = False
@@ -60,10 +65,16 @@ class GoodputMeter:
         return moved
 
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, span: str | None = None):
+        ctx = (
+            self.tracer.span(span)
+            if span is not None and self.tracer is not None
+            else nullcontext()
+        )
         t0 = time.monotonic()
         try:
-            yield
+            with ctx:
+                yield
         finally:
             self.add(name, time.monotonic() - t0)
 

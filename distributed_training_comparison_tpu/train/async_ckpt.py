@@ -26,7 +26,7 @@ import threading
 import time
 from typing import Callable
 
-from ..obs import span as _obs_span
+from ..obs import open_span as _obs_open_span, span as _obs_span
 
 
 class AsyncCheckpointer:
@@ -41,7 +41,8 @@ class AsyncCheckpointer:
 
     def __init__(self, max_pending: int = 16, metrics=None) -> None:
         self._q: queue.Queue = queue.Queue(maxsize=max_pending)
-        self._latest: dict[str, Callable[[], object] | None] = {}
+        # key -> (job, the span open on the submitting thread), or None
+        self._latest: dict[str, tuple[Callable[[], object], object] | None] = {}
         self._lock = threading.Lock()
         self._errors: list[BaseException] = []
         self._busy_s = 0.0  # wall-clock the worker spent executing jobs
@@ -65,12 +66,15 @@ class AsyncCheckpointer:
                 return
             key = item
             with self._lock:
-                job = self._latest.get(key)
+                queued = self._latest.get(key)
                 self._latest[key] = None
+            job, cause = queued or (None, None)
             t0 = time.monotonic()
             try:
                 if job is not None:  # None => superseded, already written
-                    with _obs_span("ckpt_write", key=key):
+                    # the one cross-thread edge of the span tree: this write
+                    # is the child of the trainer's span that queued it
+                    with _obs_span("ckpt_write", parent=cause, key=key):
                         job()
             except BaseException as e:  # surfaced on wait()/close()
                 with self._lock:
@@ -108,9 +112,12 @@ class AsyncCheckpointer:
 
     def submit(self, job: Callable[[], object], key: str = "default") -> None:
         """Enqueue a checkpoint job; newer jobs with the same key supersede
-        queued-but-unstarted ones."""
+        queued-but-unstarted ones.  The span open on the calling thread
+        (the trainer's ``ckpt_submit``) rides with the job and becomes the
+        parent of the writer's ``ckpt_write``."""
+        cause = _obs_open_span()
         with self._lock:
-            self._latest[key] = job
+            self._latest[key] = (job, cause)
             self._depth += 1
             depth = self._depth
         if self._metrics is not None:

@@ -36,7 +36,6 @@ import bisect
 import os
 import time
 from collections import deque
-from contextlib import nullcontext
 from pathlib import Path
 
 import jax
@@ -1019,7 +1018,6 @@ class Trainer:
         self._step_meter = StepTimeMeter(tracer=self.tracer, metrics=self.metrics)
         self._overlap_totals = StepTimeMeter()
         self._snapshot_fn = None
-        self._profiling = False  # True only during the --profile-dir epoch
 
         # init/recovery cost: construction through restore + program builds
         # — the price every restart pays again, charged against goodput
@@ -1094,6 +1092,7 @@ class Trainer:
         )
         self.tracer = obs.SpanRecorder(process_index=jax.process_index())
         self._prev_recorder = obs.set_recorder(self.tracer)
+        self.goodput.tracer = self.tracer  # phase(..., span=) draws spans too
         self._obs_dir: Path | None = None
         # per-step metrics (obs/metrics.py): grad_norm/loss/step-phase
         # samples accumulate in typed sketches EVERY step; the bus sees one
@@ -1467,58 +1466,97 @@ class Trainer:
         while epoch < hp.epoch:
             profiling = getattr(hp, "profile_dir", None) and epoch == profile_epoch
             if profiling:
+                # the host spans are TraceAnnotations (obs/spans.py), so the
+                # capture holds them on its own clock beside the device's ops
                 jax.profiler.start_trace(hp.profile_dir)
-                # chunk dispatches gain StepTraceAnnotations for this
-                # epoch — the xplane capture joins the host spans (always
-                # TraceAnnotations, obs/spans.py) on step ids
-                self._profiling = True
-            self.bus.emit("epoch_start", epoch=epoch)
-            t0 = time.perf_counter()
             try:
-                with self.tracer.span("epoch", epoch=epoch):
-                    if self.data_mode == "device":
-                        losses, top1 = self._train_epoch_device(epoch)
-                    else:
-                        losses, top1 = self._train_epoch_host(epoch)
-            except MidEpochRollback as ctl:
-                # a chunk-boundary policy rollback unwound the epoch (the
-                # barrier already booked its step time): apply the same
-                # verified restore as the epoch-boundary path, then
-                # re-enter the loop at the restored epoch.  This partial
-                # epoch never validates, checkpoints, or blesses a best —
-                # exactly the property the boundary move must preserve.
+                self.bus.emit("epoch_start", epoch=epoch)
+                t0 = time.perf_counter()
+                try:
+                    with self.tracer.span("epoch", epoch=epoch):
+                        if self.data_mode == "device":
+                            losses, top1 = self._train_epoch_device(epoch)
+                        else:
+                            losses, top1 = self._train_epoch_host(epoch)
+                except MidEpochRollback as ctl:
+                    # a chunk-boundary policy rollback unwound the epoch (the
+                    # barrier already booked its step time): apply the same
+                    # verified restore as the epoch-boundary path, then
+                    # re-enter the loop at the restored epoch.  This partial
+                    # epoch never validates, checkpoints, or blesses a best —
+                    # exactly the property the boundary move must preserve.
+                    next_epoch = self._apply_control_rollback(
+                        epoch, time.perf_counter() - t0, ctl
+                    )
+                    if next_epoch is not None:
+                        epoch = next_epoch
+                    # an unappliable rollback re-enters the SAME epoch from
+                    # its start: the state was never touched and the per-step
+                    # key fold replays it deterministically
+                    continue
+                # the train program's results are on the host: from here to
+                # the next ``epoch_start`` is the boundary, the part of an
+                # epoch where the host paces the chip.  Each thing it does
+                # is a child span (``_boundary``), so a profiler trace books
+                # the device's idle time to the line the host was in.
+                with self.tracer.span("boundary", epoch=epoch):
+                    epoch_time = time.perf_counter() - t0
+                    self.goodput.add("step", epoch_time)
+                    redo = self._boundary(epoch, losses, top1, epoch_time)
+            finally:
                 if profiling:
+                    # where ``boundary`` closes, not where the train part
+                    # ends: an operator's capture shows the chip waiting too
                     jax.profiler.stop_trace()
-                    self._profiling = False
-                next_epoch = self._apply_control_rollback(
-                    epoch, time.perf_counter() - t0, ctl
-                )
-                if next_epoch is not None:
-                    epoch = next_epoch
-                # an unappliable rollback re-enters the SAME epoch from
-                # its start: the state was never touched and the per-step
-                # key fold replays it deterministically
+                    self.logger.info(f"profiler trace written to {hp.profile_dir}")
+            if redo is not None:  # a rollback: re-enter at the restored epoch
+                epoch = redo
                 continue
-            epoch_time = time.perf_counter() - t0
-            self.goodput.add("step", epoch_time)
-            if profiling:
-                jax.profiler.stop_trace()
-                self._profiling = False
-                self.logger.info(f"profiler trace written to {hp.profile_dir}")
-            imgs = len(losses) * hp.batch_size
-            labels_seen = imgs * self._labels_per_example
+            epoch += 1
+            if bar is not None:
+                bar.update(1)
+        if bar is not None:
+            bar.close()
+        if self.ckpt_writer is not None:
+            with self.goodput.phase("ckpt", span="ckpt_drain"):
+                self.ckpt_writer.wait()
+        self.logger.info(
+            f"[{hp.backend.upper()} Version {self.version}] done in "
+            f"{time.perf_counter() - t_start:.1f}s, best val acc {self.best_acc:.2f}%"
+        )
+        self.bus.emit(
+            "run_end",
+            epoch=hp.epoch - 1,
+            best_acc=round(self.best_acc, 4),
+            wall_s=round(time.perf_counter() - t_start, 4),
+        )
+        self._write_goodput()
+        return self.version
 
-            # failure detection + recovery, BEFORE this epoch validates or
-            # checkpoints (a bad epoch must neither save its state nor be
-            # blessed as best).  With the watchdog on, sustained badness
-            # rolls back to the last good checkpoint and replays; with
-            # --no-health, the first non-finite loss aborts (pre-PR-3
-            # behavior — the compiled guard still kept the state clean).
+    def _boundary(
+        self, epoch: int, losses, top1: float, epoch_time: float
+    ) -> int | None:
+        """What the host does between an epoch's train program and the next
+        ``epoch_start``, in order, each under a span of its own (children of
+        ``fit()``'s ``boundary``; ``benchmark/harness/host_spans.py GROUPS``
+        names the group each feeds).  A span brackets what the lines already
+        did: none adds a wait, a fetch or a reordering.  Returns the epoch
+        to re-enter after a rollback, or None to go on to the next."""
+        hp, span = self.hparams, self.tracer.span
+        imgs = len(losses) * hp.batch_size
+        labels_seen = imgs * self._labels_per_example
+
+        # failure detection + recovery, BEFORE this epoch validates or
+        # checkpoints (a bad epoch must neither save its state nor be
+        # blessed as best).  With the watchdog on, sustained badness
+        # rolls back to the last good checkpoint and replays; with
+        # --no-health, the first non-finite loss aborts (pre-PR-3
+        # behavior — the compiled guard still kept the state clean).
+        with span("health"):
             if self.watchdog is not None:
                 rollback_to = self._health_check(epoch, losses, epoch_time)
                 if rollback_to is not None:
-                    epoch = rollback_to
-                    continue
+                    return rollback_to
             elif not np.isfinite(losses).all() or (
                 np.asarray(self._epoch_health.get("skipped", ())) > 0.5
             ).any():
@@ -1527,18 +1565,19 @@ class Trainer:
                 # policy — abort exactly like the pre-guard divergence check
                 self._abort_nonfinite(epoch, losses)
 
-            # closed-loop autopilot (ops/policy.py): apply any deferred
-            # policy actions at this boundary — rollback/abort decisions
-            # queued by the in-process engine's bus tap, or requests the
-            # supervisor's engine wrote to <ckpt>/fleet/policy-*.req.
-            # After the health check (the watchdog's own verdict has
-            # priority) and BEFORE this epoch validates or checkpoints, so
-            # a policy rollback never blesses the state it is revoking.
+        # closed-loop autopilot (ops/policy.py): apply any deferred
+        # policy actions at this boundary — rollback/abort decisions
+        # queued by the in-process engine's bus tap, or requests the
+        # supervisor's engine wrote to <ckpt>/fleet/policy-*.req.
+        # After the health check (the watchdog's own verdict has
+        # priority) and BEFORE this epoch validates or checkpoints, so
+        # a policy rollback never blesses the state it is revoking.
+        with span("policy"):
             policy_next = self._apply_policy_requests(epoch, epoch_time)
-            if policy_next is not None:
-                epoch = policy_next
-                continue
+        if policy_next is not None:
+            return policy_next
 
+        with span("step_log"):
             step_base = self._epoch_step_base
             meter = AverageMeter()
             for i, loss in enumerate(losses):
@@ -1558,8 +1597,9 @@ class Trainer:
                 if getattr(hp, "log_every_step", False):
                     self._log_tb("loss/step", float(loss), gstep)
 
-            with self.goodput.phase("eval"), self.tracer.span("eval", epoch=epoch):
-                val = self.validate(epoch)
+        with self.goodput.phase("eval", span="eval"):
+            val = self.validate(epoch)
+        with span("epoch_log"):
             lr_now = float(self.lr_schedule(epoch * self.steps_per_epoch))
             self.logger.info(
                 f"[{hp.backend.upper()} Version {self.version} Epoch {epoch}] "
@@ -1578,6 +1618,7 @@ class Trainer:
                 # compute; near-zero means the chip never waited on data
                 self._log_tb(f"overlap/{phase_name}_s", secs, epoch)
             self._overlap_totals.merge(self._step_meter)
+        with span("epoch_end_emit"):
             self.bus.emit(
                 "epoch_end",
                 epoch=epoch,
@@ -1589,22 +1630,26 @@ class Trainer:
                 images_per_sec=round(imgs / epoch_time, 2),
                 step_breakdown=self._step_meter.summary(),
             )
-            # drain the sketches at every epoch boundary regardless of the
-            # step budget: per-attempt stats reconstruct exactly, and a
-            # preempted next epoch can lose at most ITS OWN steps' samples
+        # drain the sketches at every epoch boundary regardless of the
+        # step budget: per-attempt stats reconstruct exactly, and a
+        # preempted next epoch can lose at most ITS OWN steps' samples
+        with span("metrics_flush"):
             self.resources.sample(self.metrics)
             self.metrics.flush(self.bus, epoch=epoch)
+        with span("heartbeat"):
             self.heartbeat.beat(
                 epoch=epoch,
                 step=(epoch + 1) * self.steps_per_epoch,
                 flush_seq=self.metrics.flushes,
             )
-            for k, v in getattr(self, "_moe_health", {}).items():
-                # moe_dropped_frac → moe/dropped_frac, moe_load_max →
-                # moe/load_max: a collapsed router (load_max → 1.0) or
-                # capacity thrash (dropped_frac climbing) shows up per epoch
-                self._log_tb(f"moe/{k[len('moe_'):]}", v, epoch)
-            if getattr(self, "_moe_health", None):
+        if getattr(self, "_moe_health", None):
+            with span("moe_log"):
+                for k, v in self._moe_health.items():
+                    # moe_dropped_frac → moe/dropped_frac, moe_load_max →
+                    # moe/load_max: a collapsed router (load_max → 1.0) or
+                    # capacity thrash (dropped_frac climbing) shows up per
+                    # epoch
+                    self._log_tb(f"moe/{k[len('moe_'):]}", v, epoch)
                 self.logger.info(
                     f"[{hp.backend.upper()} Version {self.version} Epoch "
                     f"{epoch}] moe: "
@@ -1614,13 +1659,14 @@ class Trainer:
                     )
                 )
 
-            # Checkpoint decisions are computed on EVERY process from
-            # replicated values (val metrics are identical across hosts) so
-            # that the collective-fetch path below runs symmetrically.
-            # The comms error-feedback residual is dropped up front: no
-            # save path serializes it (checkpoint._state_dict), so fetching
-            # or snapshotting it would move a params-sized tree per save
-            # for data that is thrown away.
+        # Checkpoint decisions are computed on EVERY process from
+        # replicated values (val metrics are identical across hosts) so
+        # that the collective-fetch path below runs symmetrically.
+        # The comms error-feedback residual is dropped up front: no
+        # save path serializes it (checkpoint._state_dict), so fetching
+        # or snapshotting it would move a params-sized tree per save
+        # for data that is thrown away.
+        with span("ckpt_decide"):
             state_ref, vdir = self._ckpt_view(self.state), self.version_dir
             want_best = val["val_acc"] > self.best_acc
             if want_best:
@@ -1652,48 +1698,45 @@ class Trainer:
             want_last = getattr(hp, "save_last", True) and (
                 is_last_epoch or (due and not throttled)
             )
-            if (want_best or want_last) and sync_fetch:
-                # Cross-host-partitioned (tensor-parallel) leaves: the
-                # device→host fetch is an all-gather COLLECTIVE — run it
-                # here, on every process and on the main thread.  The
-                # process-0 writer thread then only serializes host numpy.
-                # Best-only saves need just params+batch_stats; the full
-                # state (opt_state included) is gathered only when the
-                # resumable last.ckpt is due — halves the DCN volume on
-                # best-improvement epochs.
-                with self.goodput.phase("ckpt"), self.tracer.span(
-                    "ckpt_fetch", epoch=epoch
-                ):
-                    if want_last:
-                        state_ref = fetch_to_host(state_ref)
-                    else:
-                        state_ref = state_ref.replace(
-                            params=fetch_to_host(state_ref.params),
-                            batch_stats=fetch_to_host(state_ref.batch_stats),
-                        )
-            elif want_best or want_last:
-                # The scanned runners DONATE the input state, so the next
-                # epoch's dispatch reuses these buffers — the async writer
-                # must get its own device-side snapshot (HBM→HBM copy,
-                # dispatched async; a computation, so under multi-host it
-                # runs on EVERY process), never a reference donation would
-                # invalidate mid-fetch.
-                # A best-only save writes params and statistics, so only
-                # they are copied: at a language model's size the optimizer
-                # state's copy beside the next epoch's step is what would
-                # bound the batch.
-                with self.goodput.phase("ckpt"), self.tracer.span(
-                    "ckpt_snapshot", epoch=epoch
-                ):
-                    state_ref = self._snapshot_state(
-                        state_ref, whole=want_last
+        if (want_best or want_last) and sync_fetch:
+            # Cross-host-partitioned (tensor-parallel) leaves: the
+            # device→host fetch is an all-gather COLLECTIVE — run it
+            # here, on every process and on the main thread.  The
+            # process-0 writer thread then only serializes host numpy.
+            # Best-only saves need just params+batch_stats; the full
+            # state (opt_state included) is gathered only when the
+            # resumable last.ckpt is due — halves the DCN volume on
+            # best-improvement epochs.
+            with self.goodput.phase("ckpt", span="ckpt_fetch"):
+                if want_last:
+                    state_ref = fetch_to_host(state_ref)
+                else:
+                    state_ref = state_ref.replace(
+                        params=fetch_to_host(state_ref.params),
+                        batch_stats=fetch_to_host(state_ref.batch_stats),
                     )
-            if self.is_main:
-                # write-behind: the worker thread fetches + serializes while
-                # the next epoch computes (from the snapshot/host copy above
-                # — never the live state the donated dispatch will reuse).
-                # The first job to run moves the snapshot to the host
-                # and lets the device copy go (``_WriterSnapshot``).
+        elif want_best or want_last:
+            # The scanned runners DONATE the input state, so the next
+            # epoch's dispatch reuses these buffers — the async writer
+            # must get its own device-side snapshot (HBM→HBM copy,
+            # dispatched async; a computation, so under multi-host it
+            # runs on EVERY process), never a reference donation would
+            # invalidate mid-fetch.
+            # A best-only save writes params and statistics, so only
+            # they are copied: at a language model's size the optimizer
+            # state's copy beside the next epoch's step is what would
+            # bound the batch.
+            with self.goodput.phase("ckpt", span="ckpt_snapshot"):
+                state_ref = self._snapshot_state(state_ref, whole=want_last)
+        if self.is_main:
+            # write-behind: the worker thread fetches + serializes while
+            # the next epoch computes (from the snapshot/host copy above
+            # — never the live state the donated dispatch will reuse).
+            # The first job to run moves the snapshot to the host
+            # and lets the device copy go (``_WriterSnapshot``).  The
+            # writer's ``ckpt_write`` spans name this one as their parent
+            # (``AsyncCheckpointer.submit``).
+            with span("ckpt_submit"):
                 state_ref = _WriterSnapshot(state_ref)
                 if want_best:
                     self.ckpt_writer.submit(
@@ -1723,6 +1766,7 @@ class Trainer:
                         ),
                         key="last",
                     )
+        with span("writer_stats"):
             if self.ckpt_writer is not None:
                 # periodic writer gauge: queue depth climbing epoch over
                 # epoch (or busy_frac → 1.0) means write-behind stopped
@@ -1734,8 +1778,9 @@ class Trainer:
             self._log_tb(
                 "goodput/productive_frac", self.goodput.productive_frac(), epoch
             )
-            # --- resilience hooks, at the epoch boundary (the epoch itself
-            # is one device program — the smallest interruptible unit)
+        # --- resilience hooks, at the epoch boundary (the epoch itself
+        # is one device program — the smallest interruptible unit)
+        with span("resilience"):
             if self.fault_plan is not None:
                 stall = self.fault_plan.stall_secs(epoch)
                 if stall > 0:
@@ -1753,26 +1798,7 @@ class Trainer:
                 # here on is bucket churn / an unexpected reshape, and
                 # bumps compile/recompiles_after_warmup
                 self.compile_monitor.warm()
-            epoch += 1
-            if bar is not None:
-                bar.update(1)
-        if bar is not None:
-            bar.close()
-        if self.ckpt_writer is not None:
-            with self.goodput.phase("ckpt"), self.tracer.span("ckpt_drain"):
-                self.ckpt_writer.wait()
-        self.logger.info(
-            f"[{hp.backend.upper()} Version {self.version}] done in "
-            f"{time.perf_counter() - t_start:.1f}s, best val acc {self.best_acc:.2f}%"
-        )
-        self.bus.emit(
-            "run_end",
-            epoch=hp.epoch - 1,
-            best_acc=round(self.best_acc, 4),
-            wall_s=round(time.perf_counter() - t_start, 4),
-        )
-        self._write_goodput()
-        return self.version
+        return None
 
     # -------------------------------------------------------- training health
 
@@ -3090,21 +3116,11 @@ class Trainer:
                 epoch_arr,
                 jnp.asarray(done),
             )
-            # a --profile-dir capture gets one StepTraceAnnotation per
-            # chunk dispatch: the xplane gains step boundaries, so device
-            # time joins the host spans by step id
-            ann = (
-                obs.step_annotation(epoch * steps + done)
-                if self._profiling
-                else nullcontext()
-            )
-            # the step arg on the dispatch span is the join key run_report
-            # --xplane matches against the device capture's
-            # StepTraceAnnotations (same id as the annotation above);
-            # taint= keeps a compile-bearing dispatch sample out of the
-            # straggler-scored step/dispatch_s sketch
+            # step= is the chunk's first global step, a plain attribute of
+            # the span; taint= keeps a compile-bearing dispatch sample out
+            # of the straggler-scored step/dispatch_s sketch
             t_disp = time.monotonic()
-            with ann, meter.phase(
+            with meter.phase(
                 "dispatch", taint=self.compile_monitor.take_taint,
                 step=epoch * steps + done,
             ):
@@ -3310,17 +3326,9 @@ class Trainer:
                     # host copies BEFORE the dispatch donates the buffers
                     par_x = jax.device_get(batch["x"][0])
                     par_y = jax.device_get(batch["y"][0])
-                # step boundaries for a --profile-dir capture (see the
-                # device-mode loop)
-                ann = (
-                    obs.step_annotation(epoch * steps + start)
-                    if self._profiling
-                    else nullcontext()
-                )
-                # step arg = the --xplane join key (see the device loop);
-                # taint= excludes compile-bearing samples (see there too)
+                # step= and taint=: see the device-mode loop
                 t_disp = time.monotonic()
-                with ann, meter.phase(
+                with meter.phase(
                     "dispatch", taint=self.compile_monitor.take_taint,
                     step=epoch * steps + start,
                 ):
@@ -3383,8 +3391,12 @@ class Trainer:
 
     def _run_eval(self, arrays, eval_runner):
         images, labels, weights = arrays
-        device_totals = eval_runner(self.state, images, labels, weights)
-        totals = {k: float(v) for k, v in device_totals.items()}  # one fetch
+        # the call and the fetch are two spans: the first is the host's
+        # dispatch, the second is where it blocks until the device is done
+        with self.tracer.span("eval_dispatch"):
+            device_totals = eval_runner(self.state, images, labels, weights)
+        with self.tracer.span("eval_fetch"):
+            totals = {k: float(v) for k, v in device_totals.items()}  # one fetch
         return {
             "loss": totals["loss_sum"] / totals["count"],
             "top1": 100.0 * totals["top1_count"] / totals["count"],

@@ -99,8 +99,7 @@ class StepTimeMeter:
     @contextmanager
     def phase(self, name: str, taint=None, **attrs):
         # attrs ride into the span's args — the trainer stamps the chunk's
-        # global step onto `dispatch`, the join key run_report --xplane
-        # matches against the device capture's StepTraceAnnotations.
+        # global step onto `dispatch`.
         # ``taint`` — optional zero-arg read-and-clear callable (the
         # compile monitor's take_taint): consulted once on ENTRY to drop
         # any stale flag (an eval/snapshot compile between phases must

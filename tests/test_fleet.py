@@ -1,8 +1,8 @@
 """Live fleet operations (ISSUE 7): heartbeats + stall classification,
 cross-host straggler attribution, resource telemetry, the OpenMetrics
 exporter, the alert engine, and the satellites that ride along (per-
-attempt clock-skew refit, xplane degrade-with-warning, report-tool
-forward compatibility, and the event-kind registry lint).
+attempt clock-skew refit, report-tool forward compatibility, and the
+event-kind registry lint).
 
 The load-bearing properties pinned here:
 
@@ -800,68 +800,6 @@ def test_skew_refit_per_attempt_tracks_drift():
     assert run_report.apply_clock_skew([orphan], legacy)[0][
         "t_wall"
     ] == pytest.approx(310.0 - 7.0)
-
-
-# ------------------------------------------------- satellites: xplane
-
-
-def test_xplane_unknown_planes_and_no_step_ids_degrade_with_warning(tmp_path):
-    # reuse test_telemetry's wire-format builders
-    from test_telemetry import _pb_field, _pb_msg, _pb_varint  # noqa: E402
-
-    # a plane with a RENAMED device plane name, no StepTraceAnnotations
-    # (one plain "SomeOp" event); followed by a garbage sibling plane
-    ev_meta = _pb_field(4, 2, _pb_msg(        # event_metadata map entry
-        _pb_field(1, 0, _pb_varint(1)),
-        _pb_field(2, 2, _pb_msg(
-            _pb_field(1, 0, _pb_varint(1)),
-            _pb_field(2, 2, b"SomeOp"),
-        )),
-    ))
-    line = _pb_field(3, 2, _pb_msg(           # XPlane.lines
-        _pb_field(2, 2, b"renamed-device-lane"),
-        _pb_field(3, 0, _pb_varint(1000)),    # timestamp_ns
-        _pb_field(4, 2, _pb_msg(              # XLine.events: no stats
-            _pb_field(1, 0, _pb_varint(1)),
-            _pb_field(2, 0, _pb_varint(0)),
-            _pb_field(3, 0, _pb_varint(5_000_000)),
-        )),
-    ))
-    plane = _pb_field(1, 2, _pb_msg(          # XSpace.planes
-        _pb_field(2, 2, b"/device:FUTURE_XPU:0"),
-        ev_meta, line,
-    ))
-    # siblings that must be skipped with warnings, not crash the parse:
-    # wire garbage, and a decodable plane whose name field is a varint
-    # (an int has no .decode — the AttributeError containment path)
-    int_name_plane = _pb_field(1, 2, _pb_msg(_pb_field(2, 0, _pb_varint(5))))
-    doc = plane + int_name_plane + _pb_field(1, 2, b"\xff\xff\xff\xff")
-    prof = tmp_path / "prof"
-    prof.mkdir()
-    (prof / "host.xplane.pb").write_bytes(doc)
-    host_dir = tmp_path / "run"
-    host_dir.mkdir()
-    (host_dir / "trace.json").write_text(json.dumps({
-        "traceEvents": [
-            {"ph": "X", "name": "dispatch", "pid": 0, "tid": 0,
-             "ts": 50.0, "dur": 10.0, "args": {"step": 3}},
-        ]
-    }))
-    out = tmp_path / "merged.json"
-    logs: list[str] = []
-    rc = run_report.xplane_merge(host_dir, prof, out, log=logs.append)
-    assert rc == 0
-    merged = json.loads(out.read_text())
-    names = [e.get("name") for e in merged["traceEvents"]]
-    assert "SomeOp" in names and "dispatch" in names  # both lanes survived
-    lanes = [
-        e["args"]["name"] for e in merged["traceEvents"]
-        if e.get("ph") == "M" and e.get("name") == "thread_name"
-    ]
-    assert "renamed-device-lane" in lanes  # unknown plane names pass through
-    joined = " ".join(logs)
-    assert "undecodable plane" in joined or "decode stopped early" in joined
-    assert "aligned on first-event time" in joined  # degraded, loudly
 
 
 # ------------------------- satellites: report-tool forward compatibility

@@ -16,6 +16,7 @@ import json
 import sys
 import threading
 import time
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 
 import numpy as np
@@ -372,16 +373,35 @@ def test_step_time_meter_mirrors_phases_as_spans():
     assert meter.seconds["dispatch"] >= 0.0
 
 
-def test_annotations_are_nullcontexts_outside_profiling():
-    # the step/trace annotation helpers must be inert (and cheap) when no
-    # profiler session is active — they wrap every chunk dispatch
-    with obs.step_annotation(7):
-        pass
+def test_annotations_are_inert_outside_profiling():
     rec = SpanRecorder()
     # every span enters a TraceAnnotation; no trace session is active
     with rec.span("annotated"):
         pass
     assert rec.spans()[0]["name"] == "annotated"
+
+
+@contextmanager
+def span_as_it_was(rec, name):
+    """The span before it entered a ``TraceAnnotation`` (PR 24) and became a
+    tree node (PR 38): the reference the cost tests hold a span against,
+    timed in the same rounds."""
+    stack = rec._stack()
+    depth = len(stack)
+    stack.append(name)
+    thread = threading.current_thread()
+    t0 = time.monotonic()
+    try:
+        with nullcontext():
+            yield
+    finally:
+        t1 = time.monotonic()
+        stack.pop()
+        rec._append({
+            "name": str(name), "t0": t0, "t1": t1,
+            "thread_id": thread.ident, "thread_name": thread.name,
+            "depth": depth,
+        })
 
 
 def test_span_with_no_profiler_session_is_cheap():
@@ -392,26 +412,6 @@ def test_span_with_no_profiler_session_is_cheap():
     same rounds — the machine is shared and its speed varies by session,
     so the sharp limit is the ratio (0.8 as measured) and the
     absolute one (ISSUE 24's 5 us, about 2 us as measured) is wide."""
-    from contextlib import contextmanager, nullcontext
-
-    @contextmanager
-    def span_as_it_was(rec, name):
-        stack = rec._stack()
-        depth = len(stack)
-        stack.append(name)
-        thread = threading.current_thread()
-        t0 = time.monotonic()
-        try:
-            with nullcontext():
-                yield
-        finally:
-            t1 = time.monotonic()
-            stack.pop()
-            rec._append({
-                "name": str(name), "t0": t0, "t1": t1,
-                "thread_id": thread.ident, "thread_name": thread.name,
-                "depth": depth,
-            })
 
     def mean_seconds(span):
         rec = SpanRecorder()
@@ -432,33 +432,38 @@ def test_span_with_no_profiler_session_is_cheap():
     assert now < 20e-6, said
 
 
-def test_chrome_trace_export_is_what_it_was():
-    """Always annotating changed nothing a span records or exports: the
-    same fields, nesting depth and Chrome-trace events as before."""
+def test_chrome_trace_export_carries_the_span_tree():
+    """What a span records and exports: the fields it always had, and
+    since ISSUE 38 its ``id``, its ``parent`` and the ``epoch`` it belongs
+    to, which ride in the Chrome-trace event's ``args``."""
     rec = SpanRecorder(process_index=3)
     with rec.span("epoch", epoch=2):
         with rec.span("eval"):
             pass
     rec.record("busy", 1.0, 1.5, lane="stage0", stage=0)
     inner, outer, lane = rec.spans()
-    assert set(inner) == {"name", "t0", "t1", "thread_id", "thread_name", "depth"}
-    assert set(outer) == set(inner) | {"args"}
+    assert set(inner) == {"name", "t0", "t1", "thread_id", "thread_name",
+                          "depth", "id", "parent", "epoch"}
+    assert set(outer) == set(lane) == set(inner) | {"args"}
     assert (inner["name"], inner["depth"]) == ("eval", 1)
     assert (outer["name"], outer["depth"], outer["args"]) == ("epoch", 0, {"epoch": 2})
+    assert (inner["parent"], inner["epoch"]) == (outer["id"], 2)
+    assert (outer["parent"], lane["parent"], lane["epoch"]) == (None, None, None)
     assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
     events = [e for e in obs.chrome_trace(rec.spans(), 3)["traceEvents"]
               if e["ph"] == "X"]
     by_name = {e["name"]: e for e in events}
     assert by_name["busy"] == {
         "ph": "X", "name": "busy", "pid": 3, "tid": lane["thread_id"],
-        "ts": 1e6, "dur": 5e5, "args": {"stage": 0},
+        "ts": 1e6, "dur": 5e5, "args": {"stage": 0, "id": lane["id"]},
     }
     assert by_name["eval"] == {
         "ph": "X", "name": "eval", "pid": 3, "tid": inner["thread_id"],
         "ts": round(inner["t0"] * 1e6, 3),
         "dur": round((inner["t1"] - inner["t0"]) * 1e6, 3),
+        "args": {"id": inner["id"], "parent": outer["id"], "epoch": 2},
     }
-    assert by_name["epoch"]["args"] == {"epoch": 2}
+    assert by_name["epoch"]["args"] == {"epoch": 2, "id": outer["id"]}
 
 
 # --------------------------------------------- checkpoint-writer satellite

@@ -1,6 +1,6 @@
 """Deep-telemetry tests (ISSUE 6): the typed per-step metric sketches and
 their sampling budget, the SIGKILL-surviving mmap flight ring + cross-host
-black box, the clock-skew estimator, ``run_report`` --follow/--xplane, the
+black box, the clock-skew estimator, ``run_report`` --follow, the
 serve-metrics reservoir bound, and the watchdog's per-LR-phase baselines.
 
 The load-bearing properties pinned here:
@@ -54,12 +54,6 @@ from distributed_training_comparison_tpu.obs.metrics import (
     histogram_summary,
     merge_histograms,
     merge_metric_events,
-)
-from distributed_training_comparison_tpu.obs.xplane import (
-    merge_host_and_xplane,
-    parse_xplane,
-    planes_to_chrome,
-    step_marks,
 )
 from distributed_training_comparison_tpu.serve.metrics import (
     ServeMetrics,
@@ -468,146 +462,6 @@ def test_follow_events_tails_new_lines_and_files(tmp_path):
     assert any(e.get("attempt") == 1 for e in flat)  # new file picked up
     # the torn line arrived only once, after completion
     assert sum(1 for e in flat if e.get("torn")) == 1
-
-
-# ------------------------------------------------------------------ xplane
-
-
-def _pb_varint(v):
-    out = b""
-    while True:
-        b7 = v & 0x7F
-        v >>= 7
-        if v:
-            out += bytes([b7 | 0x80])
-        else:
-            return out + bytes([b7])
-
-
-def _pb_field(fnum, wt, payload):
-    tag = _pb_varint((fnum << 3) | wt)
-    if wt == 2:
-        return tag + _pb_varint(len(payload)) + payload
-    return tag + payload
-
-
-def _pb_msg(*fields):
-    return b"".join(fields)
-
-
-def _tiny_xplane(path):
-    """Hand-encode a minimal XSpace: one device plane, one line at
-    t=1000ns with two `train` StepTraceAnnotation events carrying
-    step_num stats (ids 7 and 8)."""
-    ev_meta = _pb_field(4, 2, _pb_msg(        # event_metadata map entry
-        _pb_field(1, 0, _pb_varint(1)),       # key = 1
-        _pb_field(2, 2, _pb_msg(              # value = XEventMetadata
-            _pb_field(1, 0, _pb_varint(1)),
-            _pb_field(2, 2, b"train"),
-        )),
-    ))
-    stat_meta = _pb_field(5, 2, _pb_msg(      # stat_metadata map entry
-        _pb_field(1, 0, _pb_varint(1)),
-        _pb_field(2, 2, _pb_msg(
-            _pb_field(1, 0, _pb_varint(1)),
-            _pb_field(2, 2, b"step_num"),
-        )),
-    ))
-
-    def event(offset_ps, dur_ps, step):
-        return _pb_field(4, 2, _pb_msg(       # XLine.events
-            _pb_field(1, 0, _pb_varint(1)),   # metadata_id -> "train"
-            _pb_field(2, 0, _pb_varint(offset_ps)),
-            _pb_field(3, 0, _pb_varint(dur_ps)),
-            _pb_field(4, 2, _pb_msg(          # XEvent.stats
-                _pb_field(1, 0, _pb_varint(1)),  # -> "step_num"
-                _pb_field(4, 0, _pb_varint(step)),  # int64
-            )),
-        ))
-
-    line = _pb_field(3, 2, _pb_msg(           # XPlane.lines
-        _pb_field(2, 2, b"steps"),
-        _pb_field(3, 0, _pb_varint(1000)),    # timestamp_ns
-        event(0, 500_000_000, 7),             # 0.5 ms
-        event(1_000_000_000, 500_000_000, 8),
-    ))
-    plane = _pb_field(1, 2, _pb_msg(          # XSpace.planes
-        _pb_field(2, 2, b"/device:TPU:0"),
-        ev_meta, stat_meta, line,
-    ))
-    path.write_bytes(plane)
-
-
-def test_parse_xplane_wire_format(tmp_path):
-    pb = tmp_path / "host.xplane.pb"
-    _tiny_xplane(pb)
-    planes = parse_xplane(pb)
-    assert len(planes) == 1 and planes[0]["name"] == "/device:TPU:0"
-    (line,) = planes[0]["lines"]
-    assert line["name"] == "steps"
-    evs = line["events"]
-    assert [e["name"] for e in evs] == ["train", "train"]
-    assert evs[0]["stats"] == {"step_num": 7}
-    assert evs[0]["ts_us"] == pytest.approx(1.0)      # 1000ns base
-    assert evs[0]["dur_us"] == pytest.approx(500.0)
-    chrome = planes_to_chrome(planes)
-    marks = step_marks(chrome)
-    assert set(marks) == {7, 8}
-    assert marks[8] - marks[7] == pytest.approx(1000.0)  # 1ms apart
-
-
-def test_merge_host_and_xplane_joins_on_step_ids(tmp_path):
-    pb = tmp_path / "host.xplane.pb"
-    _tiny_xplane(pb)
-    chrome_dev = planes_to_chrome(parse_xplane(pb))
-    # host dispatch spans for the same steps, on a clock 2.5s ahead
-    host = {"traceEvents": [
-        {"ph": "X", "name": "dispatch", "pid": 0, "tid": 1,
-         "ts": 2_500_001.0, "dur": 400.0, "args": {"step": 7}},
-        {"ph": "X", "name": "dispatch", "pid": 0, "tid": 1,
-         "ts": 2_501_001.0, "dur": 400.0, "args": {"step": 8}},
-    ]}
-    doc, info = merge_host_and_xplane([host], chrome_dev)
-    assert info["aligned"] == "step_ids"
-    assert info["matched_steps"] == 2
-    assert info["offset_us"] == pytest.approx(2_500_000.0)
-    shifted = [
-        e for e in doc["traceEvents"]
-        if e.get("name") == "train" and e.get("ph") == "X"
-    ]
-    # the device events now sit on the host clock: step 7's annotation at
-    # the host's step-7 dispatch begin
-    assert min(e["ts"] for e in shifted) == pytest.approx(2_500_001.0)
-    # no shared ids → both lanes still emitted, aligned on first events
-    host_none = {"traceEvents": [
-        {"ph": "X", "name": "epoch", "pid": 0, "tid": 1,
-         "ts": 9_000_000.0, "dur": 100.0},
-    ]}
-    doc2, info2 = merge_host_and_xplane([host_none], chrome_dev)
-    assert info2["aligned"] == "first_event"
-    assert len(doc2["traceEvents"]) > 1
-
-
-def test_run_report_xplane_cli_writes_merged_file(tmp_path):
-    profile_dir = tmp_path / "profile"
-    profile_dir.mkdir()
-    _tiny_xplane(profile_dir / "host.xplane.pb")
-    root = tmp_path / "ckpt"
-    (root / "version-0").mkdir(parents=True)
-    (root / "version-0" / "trace.json").write_text(json.dumps({
-        "traceEvents": [
-            {"ph": "X", "name": "dispatch", "pid": 0, "tid": 1,
-             "ts": 100.0, "dur": 50.0, "args": {"step": 7}},
-        ]
-    }))
-    out = tmp_path / "merged.json"
-    rc = run_report.main([
-        str(root), "--xplane", str(out), "--profile-dir", str(profile_dir),
-    ])
-    assert rc == 0
-    doc = json.loads(out.read_text())
-    names = {e.get("name") for e in doc["traceEvents"]}
-    assert "dispatch" in names and "train" in names
 
 
 # --------------------------------------------------------- serve reservoir

@@ -31,8 +31,6 @@ Usage::
                                                       # kept traces
     python tools/run_report.py CKPT_ROOT --export-openmetrics [OUT]
                                                       # offline scrape render
-    python tools/run_report.py CKPT_ROOT --xplane OUT.json \\
-        --profile-dir PROFILE_DIR                     # host+device Perfetto
 
 ``CKPT_ROOT`` is a training run's checkpoint root: every ``events*.jsonl``
 under it — the supervisor's at the root, each attempt's (and, multi-host,
@@ -66,11 +64,6 @@ the live view of an in-flight run.
 by the SIGKILL-surviving recorder, torn pages dropped slot-wise) into one
 ``blackbox.json`` at the root, the same pull the supervisor does after
 every attempt.
-
-``--xplane OUT --profile-dir DIR`` merges the host span traces
-(``trace*.json``) with the jax profiler's device capture into ONE Perfetto
-file, clocks joined on the ``StepTraceAnnotation`` step ids both sides
-carry.
 """
 
 from __future__ import annotations
@@ -741,70 +734,6 @@ def blackbox_report(path: str | Path, out=print) -> int:
         out(f"{path}: black box write failed")
         return 1
     out(f"black box written: {box}")
-    return 0
-
-
-# ------------------------------------------------------------------ xplane
-
-
-def find_host_traces(path: str | Path) -> list[Path]:
-    """Every host span trace under a ckpt root (``trace*.json`` at the
-    root and in the version dirs) — the files Trainer.close exports."""
-    p = Path(path)
-    if p.is_file():
-        return [p]
-    return sorted(p.glob("trace*.json")) + sorted(
-        p.glob("version-*/trace*.json")
-    )
-
-
-def xplane_merge(
-    path: str | Path, profile_dir: str | Path, out_path: str | Path,
-    log=print,
-) -> int:
-    """ONE Perfetto file from the run's host span traces + its
-    ``--profile-dir`` capture, clocks joined on the step ids both sides
-    stamp (host ``dispatch`` spans' ``step`` args ↔ the xplane's
-    ``StepTraceAnnotation`` events)."""
-    from distributed_training_comparison_tpu.obs.xplane import (
-        load_profiler_chrome_events,
-        merge_host_and_xplane,
-    )
-
-    trace_files = find_host_traces(path)
-    host_traces = []
-    for f in trace_files:
-        try:
-            host_traces.append(json.loads(f.read_text()))
-        except (OSError, ValueError) as e:
-            log(f"skipping unreadable host trace {f}: {e}")
-    profiler_events = load_profiler_chrome_events(
-        profile_dir, warn=lambda msg: log(f"warning: {msg}")
-    )
-    if not host_traces and not profiler_events:
-        log(f"nothing to merge: no trace*.json under {path} and no "
-            f"xplane/trace artifacts under {profile_dir}")
-        return 2
-    doc, info = merge_host_and_xplane(host_traces, profiler_events)
-    if info["aligned"] == "first_event" and host_traces and profiler_events:
-        # degraded but usable: both sides render as lanes, just not
-        # step-aligned — say so instead of letting the offset pass as real
-        log(
-            "warning: no shared StepTraceAnnotation step ids between the "
-            "host spans and the device capture (an older capture, renamed "
-            "annotations, or a run without --profile-dir step marks) — "
-            "lanes are aligned on first-event time, not on steps"
-        )
-    out_path = Path(out_path)
-    out_path.parent.mkdir(parents=True, exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump(doc, f)
-    log(
-        f"merged {info['host_traces']} host trace(s) + "
-        f"{info['profiler_events']} device event(s) → {out_path} "
-        f"(aligned on {info['aligned']}, {info['matched_steps']} shared "
-        f"step id(s), offset {info['offset_us'] / 1e3:.3f} ms)"
-    )
     return 0
 
 
@@ -2249,22 +2178,7 @@ def main(argv: list[str]) -> int:
         "--metrics-port endpoint); OUT is a file path or '-'/omitted "
         "for stdout",
     )
-    ap.add_argument(
-        "--xplane", metavar="OUT.json", default=None,
-        help="write ONE Perfetto file merging the run's host span traces "
-        "with the --profile-dir device capture, joined on step ids",
-    )
-    ap.add_argument(
-        "--profile-dir", metavar="DIR", default=None,
-        help="the jax profiler capture dir --xplane merges in",
-    )
     args = ap.parse_args(argv)
-
-    if args.xplane is not None:
-        if args.profile_dir is None:
-            print("--xplane needs --profile-dir", file=sys.stderr)
-            return 2
-        return xplane_merge(args.paths[0], args.profile_dir, args.xplane)
 
     if args.blackbox:
         rc = 0
