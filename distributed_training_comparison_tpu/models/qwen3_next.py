@@ -35,7 +35,14 @@ the config's keys do not carry is from ``transformers``
    ``linear_conv_kernel_dim`` taps, no bias, then SiLU.  ``q`` and ``k`` are
    L2-normalised per head (``x * rsqrt(sum x^2 + 1e-6)``), ``q`` scaled by
    ``key head size^-1/2``; each key head serves ``value heads / key heads``
-   consecutive value heads.
+   consecutive value heads.  (``ops/gdn_pointwise.py short_conv_l2norm``: on
+   a TPU, at head sizes of whole lanes and a length of whole token tiles,
+   one fused pass over the ``(B, S, H d)`` columns of ``qkvz`` as the
+   projection wrote them — convolution, SiLU and the norms in float32 in
+   VMEM, q, k and v written flat for the scan's kernels; every other call,
+   the CPU's and ``qwen3_next_tiny``'s among them, composed XLA: the
+   convolution and SiLU in the activations' dtype, the norms in float32
+   over ``(B, S, H, d)``.  The call shows which.)
 5. Per value head and token in float32: ``beta = sigmoid(b)``, ``g =
    -exp(A_log) * softplus(alpha + dt_bias)`` (``A_log = log(u)``, ``u ~ U(0,
    16)``; ``dt_bias`` = 1), and the gated delta rule ``S <- exp(g) S``; ``d
@@ -45,7 +52,9 @@ the config's keys do not carry is from ``transformers``
    q, k, v as the projections leave them; every other call, the CPU's and
    ``qwen3_next_tiny``'s among them, composed XLA.  The call shows which).
 6. ``y = (w * o * rsqrt(mean(o^2) + eps)) * silu(z)`` per head (``w`` from
-   one), then ``out_proj``.
+   one), then ``out_proj`` (``ops/gdn_pointwise.py gated_rms_norm``: on the
+   same calls as step 4 one fused pass that reads ``o`` as the scan wrote it
+   and ``z`` in ``qkvz``'s last columns; else composed XLA in float32).
 7. Full attention (``num_attention_heads`` / ``num_key_value_heads`` heads of
    ``head_dim``): ``q_proj`` gives each head its query and its gate, side
    by side; ``k_proj``, ``v_proj``.  Zero-centred per-head RMSNorm on q and
@@ -67,7 +76,14 @@ with ``gdn_conv``, ``gdn_scan`` and ``gdn_gate_norm`` inside it, ``attn``
 with ``attn_gate`` (the gate's sigmoid and multiply; its projection is
 ``q_proj``'s other half) and ``attention`` inside it, ``moe`` with ``moe_gmm`` and
 ``shared_expert`` inside it, ``lm_head``.  No scope of the DeltaNet mixer
-has ``attention`` as a path element.  ``gdn_scan`` holds every op of the
+has ``attention`` as a path element.  On the fused path ``gdn_conv`` holds
+all of step 4 — the kernels ``gdn_conv_fwd`` and ``gdn_conv_bwd`` (q, k
+and v in one call), the l2-norms inside them — and ``gdn_gate_norm`` the
+kernels ``gdn_gate_norm_fwd`` and ``gdn_gate_norm_bwd``; on the composed
+path ``gdn_conv`` is the convolution and SiLU alone and the l2-norms are
+ops of ``gdn``.  A compile event counts a program's mixer call sites by
+form (``gdn_pointwise: {"fused": n, "composed": m}``).
+``gdn_scan`` holds every op of the
 scan on either path: the kernels ``gated_delta_fwd`` and ``gated_delta_bwd``
 with the running sums of ``g`` around them, or the composed form's loop.
 For its backward the kernel path keeps each chunk's start state and solved
@@ -88,6 +104,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention
 from ..ops.gated_delta import gated_delta_rule
+from ..ops.gdn_pointwise import gated_rms_norm, short_conv_l2norm
 from .moe import TopKMoE
 from .token_parts import RMSNorm, _dense, rope, zoo_entry
 
@@ -163,12 +180,6 @@ def derived(config: dict) -> dict:
     }
 
 
-def _l2_normalised(x):
-    """Per head over its last axis, in float32 (the source's ``l2norm``)."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
 class GatedDeltaNet(nn.Module):
     """Steps 3-6 of the module docstring."""
 
@@ -184,7 +195,6 @@ class GatedDeltaNet(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        b, s, _ = h.shape
         hk, hv, dk, dv = self.key_heads, self.value_heads, self.key_dim, self.value_dim
         keys, values = hk * dk, hv * dv
         qkvz = _dense(2 * keys + 2 * values, self.dtype, "in_proj_qkvz")(h)
@@ -197,7 +207,7 @@ class GatedDeltaNet(nn.Module):
                 key, shape, dtype, -taps ** -0.5, taps ** -0.5
             ),
             (2 * keys + values, taps), jnp.float32,
-        ).astype(self.dtype)
+        )
         a_log = self.param(
             "A_log",
             lambda key, shape, dtype: jnp.log(
@@ -206,27 +216,16 @@ class GatedDeltaNet(nn.Module):
             (hv,), jnp.float32,
         )
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), jnp.float32)
-        with jax.named_scope("gdn_conv"):
-            u = jnp.pad(qkvz[..., : 2 * keys + values], ((0, 0), (taps - 1, 0), (0, 0)))
-            mixed = nn.silu(sum(w[:, j] * u[:, j:j + s] for j in range(taps)))
-        q, k, v = jnp.split(mixed, (keys, 2 * keys), axis=-1)
-        z = qkvz[..., 2 * keys + values:].reshape(b, s, hv, dv)
-        q = (_l2_normalised(q.reshape(b, s, hk, dk)) * dk ** -0.5).astype(self.dtype)
-        k = _l2_normalised(k.reshape(b, s, hk, dk)).astype(self.dtype)
+        q, k, v = short_conv_l2norm(
+            qkvz, w, key_heads=hk, value_heads=hv, key_dim=dk, value_dim=dv
+        )
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
         self.sow("moe_metrics", "gdn_decay_mean", jnp.mean(jnp.exp(g)))
-        o = gated_delta_rule(
-            q, k, v.reshape(b, s, hv, dv), g, beta, chunk=self.chunk
-        )
+        o = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
         scale = self.param("norm_scale", nn.initializers.ones, (dv,), jnp.float32)
-        with jax.named_scope("gdn_gate_norm"):
-            o = o.astype(jnp.float32)
-            o = scale * o * jax.lax.rsqrt(
-                jnp.mean(o * o, axis=-1, keepdims=True) + self.eps
-            )
-            o = (o * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
-        return _dense(self.dim, self.dtype, "out_proj")(o.reshape(b, s, values))
+        o = gated_rms_norm(o, qkvz, scale, key_dim=dk, eps=self.eps)
+        return _dense(self.dim, self.dtype, "out_proj")(o)
 
 
 class GatedAttention(nn.Module):

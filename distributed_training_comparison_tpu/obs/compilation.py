@@ -30,7 +30,9 @@ that gap:
   --compute`` MFU reconstruction needs, which path each kernel gate took
   while the program traced (``kernel_paths``, see ``note_kernel_path``),
   which backward each flash-attention call site took (``flash_backward``,
-  see ``note_flash_backward``) and how many compiled Pallas kernels the executable carries
+  see ``note_flash_backward``), which form each Gated DeltaNet mixer's
+  pointwise stages took (``gdn_pointwise``, see ``note_gdn_pointwise``)
+  and how many compiled Pallas kernels the executable carries
   (``tpu_custom_calls``).
 - ``compile/*`` metrics ride the existing registry (and therefore every
   ``metrics`` flush, the OpenMetrics exporter, and ``--alert`` rules):
@@ -136,6 +138,12 @@ def note_kernel_path(kernel: str, path: str) -> None:
         notes["kernel_paths"][kernel] = path
 
 
+def _count_call_site(key: str, form: str) -> None:
+    notes = getattr(_probe_local, "notes", None)
+    if notes is not None:
+        notes[key][form] = notes[key].get(form, 0) + 1
+
+
 def note_flash_backward(form: str) -> None:
     """Count, at TRACE time, one flash-attention call site whose backward
     took ``form``: ``"fused"`` (one kernel) or ``"tiled"`` (two; what
@@ -143,10 +151,17 @@ def note_flash_backward(form: str) -> None:
     fit VMEM).  Like ``note_kernel_path`` a fact about the executable being
     built: on its ``compile`` event as ``flash_backward: {form: call
     sites}``, a key of its own.  A no-op outside an observed compile."""
-    notes = getattr(_probe_local, "notes", None)
-    if notes is not None:
-        counts = notes["flash_backward"]
-        counts[form] = counts.get(form, 0) + 1
+    _count_call_site("flash_backward", form)
+
+
+def note_gdn_pointwise(form: str) -> None:
+    """Count, at TRACE time, one Gated DeltaNet mixer call site whose
+    pointwise stages took ``form``: ``"fused"`` (``ops/gdn_pointwise.py``'s
+    kernels) or ``"composed"`` (what ``gdn_pointwise_plan`` leaves to XLA).
+    On the ``compile`` event as ``gdn_pointwise: {form: call sites}``, a key
+    of its own as ``flash_backward`` is: ``kernel_paths`` keeps the kernels
+    a cell's ``expect`` lists.  A no-op outside an observed compile."""
+    _count_call_site("gdn_pointwise", form)
 
 
 class _CacheProbe:
@@ -157,7 +172,9 @@ class _CacheProbe:
     def __enter__(self) -> "_CacheProbe":
         _ensure_probe()
         self._before = getattr(_probe_local, "hits", 0)
-        self.notes: dict[str, dict] = {"kernel_paths": {}, "flash_backward": {}}
+        self.notes: dict[str, dict] = {
+            "kernel_paths": {}, "flash_backward": {}, "gdn_pointwise": {},
+        }
         _probe_local.notes = self.notes
         return self
 

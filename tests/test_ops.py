@@ -1370,3 +1370,245 @@ def test_the_gated_delta_kernels_note_their_path():
     np.testing.assert_allclose(
         o, gated_delta_rule_sequential(*x), rtol=1e-4, atol=1e-5
     )
+
+
+# ------------------------------------- the DeltaNet mixer's pointwise stages
+
+from distributed_training_comparison_tpu.ops import gdn_pointwise  # noqa: E402
+from distributed_training_comparison_tpu.ops.gdn_pointwise import (  # noqa: E402
+    gated_rms_norm,
+    gdn_pointwise_plan,
+    short_conv_l2norm,
+)
+
+# the smallest mixer the fused passes take whole: one key head and two value
+# heads of one lane tile, two token tiles of 512
+MIXER = dict(key_heads=1, value_heads=2, key_dim=128, value_dim=128)
+MIXER_TOKENS = 1024
+
+
+def _mixer_inputs(dtype, s=MIXER_TOKENS):
+    """``qkvz``, the taps, ``norm_scale``, a scan's output and a cotangent
+    for each result, the activations in ``dtype``."""
+    hk, hv, d = MIXER["key_heads"], MIXER["value_heads"], MIXER["key_dim"]
+    keys = jax.random.split(jax.random.key(39), 8)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)  # noqa: E731
+    return dict(
+        qkvz=normal(keys[0], 1, s, 2 * (hk + hv) * d).astype(dtype),
+        taps=jax.random.uniform(keys[1], ((2 * hk + hv) * d, 4), jnp.float32, -0.5, 0.5),
+        scale=1.0 + 0.1 * normal(keys[2], d),
+        o=normal(keys[3], 1, s, hv, d).astype(dtype),
+        d_qkv=(normal(keys[4], 1, s, hk, d), normal(keys[5], 1, s, hk, d),
+               normal(keys[6], 1, s, hv, d)),
+        d_y=normal(keys[7], 1, s, hv * d),
+    )
+
+
+def _conv_stage(x, qkvz, taps, **options):
+    """q, k, v and the gradients of ``sum(result * cotangent)``."""
+    def loss(qkvz, taps):
+        out = short_conv_l2norm(qkvz, taps, **MIXER, **options)
+        return sum(
+            jnp.sum(o.astype(jnp.float32) * c) for o, c in zip(out, x["d_qkv"])
+        ), out
+
+    (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(qkvz, taps)
+    return dict(zip(("q", "k", "v", "d_qkvz", "d_conv_kernel"), (*out, *grads)))
+
+
+def _gate_stage(x, o, qkvz, scale, **options):
+    def loss(o, qkvz, scale):
+        y = gated_rms_norm(
+            o, qkvz, scale, key_dim=MIXER["key_dim"], eps=1e-6, **options
+        )
+        return jnp.sum(y.astype(jnp.float32) * x["d_y"]), y
+
+    (_, y), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(o, qkvz, scale)
+    return dict(zip(("y", "d_o", "d_qkvz", "d_norm_scale"), (y, *grads)))
+
+
+def _error(got, want):
+    """Relative l2 of every result against ``want``'s."""
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    return {
+        n: float(np.linalg.norm(f32(got[n]) - f32(want[n])) / np.linalg.norm(f32(want[n])))
+        for n in want
+    }
+
+
+def _stages(x, dtype, **options):
+    qkvz, o = x["qkvz"].astype(dtype), x["o"].astype(dtype)
+    return {
+        **{f"conv/{n}": v for n, v in _conv_stage(x, qkvz, x["taps"], **options).items()},
+        **{f"gate/{n}": v for n, v in _gate_stage(x, o, qkvz, x["scale"], **options).items()},
+    }
+
+
+@pytest.fixture(scope="module")
+def mixer_stages():
+    """Both kernel pairs (through the interpreter) and the composed stages on
+    the same bf16-representable inputs, in float32 and in bf16."""
+    x = _mixer_inputs(jnp.bfloat16)
+    return {
+        (form, dtype): _stages(x, dtype, **options)
+        for form, options in (("fused", dict(interpret=True)), ("composed", {}))
+        for dtype in (jnp.float32, jnp.bfloat16)
+    }
+
+
+MIXER_RESULTS = [
+    "conv/q", "conv/k", "conv/v", "conv/d_qkvz", "conv/d_conv_kernel",
+    "gate/y", "gate/d_o", "gate/d_qkvz", "gate/d_norm_scale",
+]
+
+
+@pytest.mark.parametrize("result", MIXER_RESULTS)
+def test_fused_pointwise_stages_are_the_composed_stages(mixer_stages, result):
+    """Outputs and every gradient (``qkvz``, ``conv_kernel``, ``norm_scale``,
+    the scan's ``o``) of both kernel pairs against the composed stages: in
+    float32 to 1e-5, in bf16 no further from the float32 result than the
+    composed form's own bf16 is (it rounds the convolution before the norms;
+    the kernels round once)."""
+    exact = mixer_stages["composed", jnp.float32]
+    same = _error(mixer_stages["fused", jnp.float32], exact)[result]
+    assert same < 1e-5, same
+    fused = _error(mixer_stages["fused", jnp.bfloat16], exact)[result]
+    composed = _error(mixer_stages["composed", jnp.bfloat16], exact)[result]
+    assert fused <= 1.05 * composed + 1e-6, (fused, composed)
+    assert mixer_stages["fused", jnp.bfloat16][result].dtype == (
+        jnp.float32 if result.endswith(("conv_kernel", "norm_scale")) else jnp.bfloat16
+    )
+
+
+@pytest.mark.parametrize("token", [0, 509, 511, 512, 1023])
+def test_an_impulse_meets_the_halo_and_the_zero_history(token):
+    """One token set, every other zero: its three successors see it across
+    the token tiles' edge (511 | 512) through the halo block, and nothing
+    stands before token 0; one token's cotangent reaches its three
+    predecessors the other way (the rows a tile owes the one before)."""
+    x = _mixer_inputs(jnp.float32)
+    at = jnp.arange(MIXER_TOKENS)[None, :, None] == token
+    qkvz = jnp.where(at, x["qkvz"], 0.0)
+    x["d_qkv"] = tuple(jnp.where(at[..., None], c, 0.0) for c in x["d_qkv"])
+    fused = _conv_stage(x, qkvz, x["taps"], interpret=True)
+    composed = _conv_stage(x, qkvz, x["taps"])
+    for name in composed:
+        np.testing.assert_allclose(
+            fused[name], composed[name], rtol=1e-5, atol=1e-6, err_msg=name
+        )
+    reached = np.flatnonzero(np.abs(np.asarray(fused["v"])).sum(axis=(0, 2, 3)))
+    assert reached.tolist() == list(range(token, min(token + 4, MIXER_TOKENS)))
+    owed = np.flatnonzero(np.abs(np.asarray(fused["d_qkvz"])).sum(axis=(0, 2)))
+    assert owed.tolist() == list(range(max(token - 3, 0), token + 1))
+
+
+POINTWISE_PLANS = {
+    # the cell's call, eight-thousand tokens in tiles of 512; float32 too
+    "cell": (("tpu", jnp.bfloat16, 128, 128, 8192), 512),
+    "float32": (("tpu", jnp.float32, 128, 128, 8192), 512),
+    "two_tiles": (("tpu", jnp.bfloat16, 128, 128, 1024), 512),
+    # up to 512 tokens are one tile of whole loop steps
+    "one_short_tile": (("tpu", jnp.bfloat16, 128, 128, 128), 128),
+    "head_size_256": (("tpu", jnp.bfloat16, 256, 256, 8192), 512),
+    # what the composed form keeps: no TPU, head sizes of no whole lane tile
+    # (qwen3_next_tiny's 16 and 24) or apart, another dtype, a length that
+    # would need padding
+    "cpu": (("cpu", jnp.bfloat16, 128, 128, 8192), None),
+    "tiny_heads": (("tpu", jnp.bfloat16, 16, 24, 64), None),
+    "head_size_64": (("tpu", jnp.bfloat16, 64, 64, 8192), None),
+    "head_sizes_apart": (("tpu", jnp.bfloat16, 128, 256, 8192), None),
+    "float16": (("tpu", jnp.float16, 128, 128, 8192), None),
+    "no_whole_tiles": (("tpu", jnp.bfloat16, 128, 128, 8200), None),
+    "a_tile_and_a_half": (("tpu", jnp.bfloat16, 128, 128, 768), None),
+    "no_whole_loop_steps": (("tpu", jnp.bfloat16, 128, 128, 96), None),
+}
+
+
+@pytest.mark.parametrize("case", POINTWISE_PLANS)
+def test_gdn_pointwise_plan_takes_the_kernels_where_it_can(case):
+    """Which form a mixer's pointwise stages take is a pure function of
+    what the call shows: backend, dtype, head sizes, length."""
+    call, tile = POINTWISE_PLANS[case]
+    assert gdn_pointwise_plan(*call) == tile
+
+
+def test_sections_no_one_grid_serves_take_a_call_each():
+    """Three value heads on one key head: v's range starts at column 256,
+    no multiple of its 384 lanes, so q, k and v are a kernel call each (a
+    head a grid step) — and still the composed stage."""
+    sections = gdn_pointwise._sections(1, 3, 128)
+    assert gdn_pointwise._calls(sections) == tuple((sec,) for sec in sections)
+    assert gdn_pointwise._calls(gdn_pointwise._sections(1, 2, 128)) == (
+        gdn_pointwise._sections(1, 2, 128),
+    )
+    assert gdn_pointwise._channel_tiles(gdn_pointwise._sections(16, 32, 128)) == 16
+    heads = dict(key_heads=1, value_heads=3, key_dim=128, value_dim=128)
+    keys = jax.random.split(jax.random.key(40), 5)
+    qkvz = jax.random.normal(keys[0], (1, 128, 8 * 128), jnp.float32)
+    taps = jax.random.uniform(keys[1], (5 * 128, 4), jnp.float32, -0.5, 0.5)
+    cots = [
+        jax.random.normal(k, (1, 128, h, 128), jnp.float32)
+        for k, h in zip(keys[2:], (1, 1, 3))
+    ]
+
+    def stage(**options):
+        def loss(qkvz, taps):
+            out = short_conv_l2norm(qkvz, taps, **heads, **options)
+            return sum(jnp.sum(o * c) for o, c in zip(out, cots)), out
+
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(qkvz, taps)
+        return (*out, *grads)
+
+    for got, want in zip(stage(interpret=True), stage()):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_a_cpu_call_without_interpret_is_the_composed_form_bit_for_bit():
+    x = _mixer_inputs(jnp.bfloat16, s=128)
+    q, k, v = jax.jit(functools.partial(short_conv_l2norm, **MIXER))(x["qkvz"], x["taps"])
+    want = jax.jit(lambda a, w: gdn_pointwise._composed_conv(a, w, 1, 2, 128, 128))(
+        x["qkvz"], x["taps"]
+    )
+    for got, ref in zip((q, k, v), want):
+        np.testing.assert_array_equal(got, ref)
+    y = jax.jit(functools.partial(gated_rms_norm, key_dim=128, eps=1e-6))(
+        x["o"], x["qkvz"], x["scale"]
+    )
+    np.testing.assert_array_equal(
+        y, jax.jit(functools.partial(gdn_pointwise._composed_gate_norm, eps=1e-6))(
+            x["o"], x["qkvz"], x["scale"]
+        ),
+    )
+
+
+def test_the_mixers_form_is_on_the_compile_event_under_its_own_key():
+    """An observed compile of two mixer call sites — one the kernels take
+    (through the interpreter), one left to the composed form (a CPU call
+    without it) — carries ``gdn_pointwise: {"fused": 1, "composed": 1}``
+    under a key of its own: ``kernel_paths``, which a cell's ``expect`` pins
+    key for key, gains nothing.  The gated norm counts no second site."""
+    from distributed_training_comparison_tpu import obs
+
+    x = _mixer_inputs(jnp.bfloat16, s=128)
+
+    def both(qkvz, taps, o, scale):
+        fused = short_conv_l2norm(qkvz, taps, **MIXER, interpret=True)
+        composed = short_conv_l2norm(qkvz, taps, **MIXER)
+        y = gated_rms_norm(o, qkvz, scale, key_dim=128, eps=1e-6, interpret=True)
+        return fused, composed, y
+
+    bus = obs.configure(run_id=obs.new_run_id(), persist=True)
+    try:
+        monitor = obs.CompileMonitor(bus=bus, registry=obs.MetricRegistry())
+        monitor.instrument(jax.jit(both), "mixers")(
+            x["qkvz"], x["taps"], x["o"], x["scale"]
+        )
+        monitor.instrument(jax.jit(lambda a: a + 1), "no_mixer")(x["scale"])
+        mixers, other = [
+            e["payload"] for e in bus.ring_events() if e["kind"] == "compile"
+        ]
+    finally:
+        obs.reset()
+    assert mixers["gdn_pointwise"] == {"fused": 1, "composed": 1}
+    assert "kernel_paths" not in mixers and "flash_backward" not in mixers
+    assert "gdn_pointwise" not in other

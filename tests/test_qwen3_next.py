@@ -317,6 +317,13 @@ def test_trainer_fits_tokens_saves_and_restores_the_scans_parameters(tmp_path):
     assert compiled == {
         "attention": "composed", "moe_gmm": "ragged_dot", "gated_delta": "composed",
     }
+    # the tiny head sizes (16, 24) are no lane tile: every mixer call site of
+    # the train program is counted under the composed form, a key of its own
+    sites = [
+        e["payload"]["gdn_pointwise"] for e in events if e.get("kind") == "compile"
+        and e["payload"]["name"].startswith("device_chunk_runner")
+    ]
+    assert sites and all(set(s) == {"composed"} and s["composed"] % 3 == 0 for s in sites)
     counted = [
         e["payload"]["metrics"] for e in events if e.get("kind") == "metrics"
         and "moe/rows" in e["payload"]["metrics"]
@@ -505,7 +512,7 @@ def test_cell_runs_the_recipe_its_issue_names():
     assert not {"window_attention_ms_per_step", "short_conv_ms_per_step",
                 "moe_gmm_roofline_pct"} & reported
     new = [m for m in spec["per_layer"] if m["name"].startswith("gdn_")]
-    assert [m["workloads"] for m in new] == [[CELL]] * 4
+    assert [m["workloads"] for m in new] == [[CELL]] * 5
     assert {m["layer"] for m in new} == {"Models", "Kernels"}
 
 
@@ -549,3 +556,31 @@ def test_the_scan_readers_divide_the_work_by_the_scopes_time(monkeypatch):
     assert reader("gdn_decay_mean")(run) == pytest.approx(0.2)
     run.clock = SimpleNamespace(in_window=lambda kind: [{"payload": {"metrics": {}}}])
     assert reader("gdn_decay_mean")(run) is None
+
+
+def test_the_pointwise_counters_reader_sums_the_train_programs_call_sites():
+    """``gdn_pointwise_fused_pct`` on a stub of a run's set-up: the parent's
+    event has no ``gdn_pointwise`` and reads nothing; the train program's
+    events are summed, another program's are not its call sites."""
+    read = load(BENCH / "layer_metrics" / "gdn_pointwise_fused_pct.py").read
+    run = SimpleNamespace(
+        setup_compiles=[{"name": "device_chunk_runner@k32",
+                         "kernel_paths": {"gated_delta": "pallas"}}],
+        mix={"train_program": "device_chunk_runner"},
+    )
+    assert read(run) is None
+    run.setup_compiles[0]["gdn_pointwise"] = {"fused": 3}
+    assert read(run) == 100.0
+    run.setup_compiles.append({"name": "eval_runner", "gdn_pointwise": {"composed": 3}})
+    assert read(run) == 100.0
+    run.setup_compiles.append(
+        {"name": "device_chunk_runner@k4", "gdn_pointwise": {"composed": 1}}
+    )
+    assert read(run) == pytest.approx(75.0)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (entry,) = [m for m in spec["per_layer"] if m["name"] == "gdn_pointwise_fused_pct"]
+    assert entry == {
+        "name": "gdn_pointwise_fused_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "Models",
+        "moves": "images_per_s_per_chip", "workloads": [CELL],
+    }

@@ -201,6 +201,64 @@ def test_composed_gated_delta_scan_compiles_for_v5e(chip, monkeypatch):
     assert "transpose(" in loops[1] and "transpose(" not in loops[0]
 
 
+def _mixer_pointwise_text(chip, backward):
+    """The mixer's two pointwise stages at ``qwen3next_ep32_seq8k_job``'s
+    sizes (16 key and 32 value heads of 128, one sequence of 8,192 tokens,
+    ``qkvz`` 12,288 wide), forward or the program a train step's backward
+    runs, compiled for the chip."""
+    from distributed_training_comparison_tpu.ops.gdn_pointwise import (
+        gated_rms_norm, short_conv_l2norm,
+    )
+
+    def stages(qkvz, taps, o, scale):
+        # flat at the program's edge, as the projections and the scan's
+        # kernels hold them: a (B, S, H, d) argument or result of the
+        # program itself would be tiled over (H, d) and copied
+        q, k, v = short_conv_l2norm(
+            qkvz, taps, key_heads=16, value_heads=32, key_dim=128, value_dim=128
+        )
+        y = gated_rms_norm(
+            o.reshape(1, 8192, 32, 128), qkvz, scale, key_dim=128, eps=1e-6
+        )
+        return tuple(x.reshape(1, 8192, -1) for x in (q, k, v)) + (y,)
+
+    def summed(*a):
+        return sum(x.astype(jnp.float32).sum() for x in stages(*a))
+
+    f32 = jnp.float32
+    return _compiled_text(
+        jax.grad(summed, argnums=(0, 1, 2, 3)) if backward else stages, chip,
+        _s(1, 8192, 12288), _s(8192, 4, dtype=f32), _s(1, 8192, 4096),
+        _s(128, dtype=f32),
+    )
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_gdn_pointwise_kernels_compile_for_v5e(chip, monkeypatch, backward):
+    """All four kernels of ``ops/gdn_pointwise.py`` through Mosaic at the
+    cell's shape, as a TPU shows the dispatcher the call: the convolution's
+    one call (q, k and v of a key head's group a grid step: column ranges of
+    one slab) and the gated norm's, each under the scope its stage has, and
+    around them no float32 copy of an 8,192-token activation — the ``(S, H,
+    d)`` relayouts the composed l2-norms and gated norm cost — and beside
+    the forward's no op at all."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _mixer_pointwise_text(chip, backward)
+    kernels = re.findall(r'tpu_custom_call.*op_name="([^"]*)"', text)
+    side = "bwd" if backward else "fwd"
+    assert sorted(n.split("/")[-2] for n in kernels) == (
+        [f"gdn_conv_{side}", f"gdn_gate_norm_{side}"]
+    ), kernels
+    for name in kernels:
+        scope = "gdn_conv" if "gdn_conv_" in name else "gdn_gate_norm"
+        assert f"/{scope}/" in name or f"({scope})" in name, name
+        assert ("transpose(" in name) == backward, name
+    assert "vmem_limit_bytes" not in text
+    assert not re.search(r"f32\[(1,8192|1024,8),[\d,]+\]\S* copy\(", text)
+    if not backward:
+        assert not re.search(r" (fusion|copy|concatenate)\(", text), "an op beside the kernels"
+
+
 def test_flash_attention_with_lse_compiles_for_v5e(chip):
     """Ring attention's call at the token cell's shape: the ``lse`` output
     and its cotangent through the fused backward."""

@@ -272,6 +272,71 @@ def test_gated_delta_kernels_with_keys_alike_in_bf16(capsys):
     assert all(kernel[n] <= 1.05 * before[n] for n in kernel), (kernel, before)
 
 
+def _mixer_pointwise_results(dtype, composed):
+    """The mixer's two pointwise stages at ``qwen3next_ep32_seq8k_job``'s
+    sizes (one sequence of 8,192 tokens, 16 key and 32 value heads of 128,
+    ``qkvz`` 12,288 wide, taps U(+-1/2)): outputs and every gradient, the
+    activations in ``dtype``; ``composed`` leaves both to XLA."""
+    from distributed_training_comparison_tpu.ops import gdn_pointwise as G
+
+    keys = jax.random.split(jax.random.key(39), 8)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)  # noqa: E731
+    low = lambda x: x.astype(jnp.bfloat16).astype(dtype)  # noqa: E731  (the same values)
+    qkvz, o = low(normal(keys[0], 1, 8192, 12288)), low(normal(keys[1], 1, 8192, 32, 128))
+    taps = jax.random.uniform(keys[2], (8192, 4), jnp.float32, -0.5, 0.5)
+    scale = 1.0 + 0.1 * normal(keys[3], 128)
+    cots = [normal(k, 1, 8192, h, 128) for k, h in zip(keys[4:7], (16, 16, 32))]
+    d_y = normal(keys[7], 1, 8192, 4096)
+    if composed:
+        conv = lambda a, w: G._composed_conv(a, w, 16, 32, 128, 128)  # noqa: E731
+        gate = lambda o, a, w: G._composed_gate_norm(o, a, w, 1e-6)  # noqa: E731
+    else:
+        conv = lambda a, w: G.short_conv_l2norm(  # noqa: E731
+            a, w, key_heads=16, value_heads=32, key_dim=128, value_dim=128
+        )
+        gate = lambda o, a, w: G.gated_rms_norm(o, a, w, key_dim=128, eps=1e-6)  # noqa: E731
+
+    def conv_loss(qkvz, taps):
+        out = conv(qkvz, taps)
+        return sum(jnp.sum(x.astype(jnp.float32) * c) for x, c in zip(out, cots)), out
+
+    def gate_loss(o, qkvz, scale):
+        y = gate(o, qkvz, scale)
+        return jnp.sum(y.astype(jnp.float32) * d_y), y
+
+    g1, out = jax.jit(jax.grad(conv_loss, (0, 1), has_aux=True))(qkvz, taps)
+    g2, y = jax.jit(jax.grad(gate_loss, (0, 1, 2), has_aux=True))(o, qkvz, scale)
+    names = ("q", "k", "v", "conv/d_qkvz", "d_conv_kernel", "y", "d_o",
+             "gate/d_qkvz", "d_norm_scale")
+    return dict(zip(names, (*out, *g1, y, *g2)))
+
+
+def test_gdn_pointwise_cell_shape(capsys):
+    """The mixer's pointwise stages as the dispatcher runs them on a TPU —
+    ``ops/gdn_pointwise.py``'s four kernels — and the composed stages beside
+    them, both on bf16 activations against the composed form in float32:
+    relative l2 of the outputs and of every gradient.  The kernels round
+    once, the composed form rounds the convolution before the float32
+    norms: the kernels may be no worse.  And in float32 they are the
+    composed form to rounding."""
+    from distributed_training_comparison_tpu.ops import gdn_pointwise as G
+
+    assert G.gdn_pointwise_plan(
+        jax.default_backend(), jnp.bfloat16, 128, 128, 8192
+    ) is not None, "the dispatcher would take the composed form here"
+    exact = _mixer_pointwise_results(jnp.float32, composed=True)
+    kernel = _relative_l2(_mixer_pointwise_results(jnp.bfloat16, composed=False), exact)
+    before = _relative_l2(_mixer_pointwise_results(jnp.bfloat16, composed=True), exact)
+    same = _relative_l2(_mixer_pointwise_results(jnp.float32, composed=False), exact)
+    with capsys.disabled():
+        print(f"\ngdn_pointwise bf16 vs the float32 composed form, relative l2: "
+              f"kernel {kernel}, composed {before}")
+        print(f"gdn_pointwise float32: kernel vs composed {same}")
+    assert all(e < 0.01 for e in kernel.values()), kernel
+    assert all(kernel[n] <= 1.05 * before[n] + 1e-6 for n in kernel), (kernel, before)
+    assert all(e < 1e-5 for e in same.values()), same
+
+
 def test_tiled_forward_engages_and_agrees():
     """S=16384 exceeds the resident-K/V limit: the streamed forward must
     compile and run (it could not before round 4); at S=4096 both paths
