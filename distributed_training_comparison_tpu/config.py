@@ -98,7 +98,7 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
             "resnet18", "resnet34", "resnet50", "resnet101", "resnet152",
             "vit_tiny", "vit_small", "vit_long", "vit_moe",
             "lfm2_24b_a2b", "lfm2_tiny", "trinity_mini", "afmoe_tiny",
-            "qwen3_next", "qwen3_next_tiny",
+            "qwen3_next", "qwen3_next_tiny", "nemotron_h", "nemotron_h_tiny",
         ],
         help="Model zoo entry (live, unlike the reference's dead --model flag)",
     )
@@ -107,12 +107,12 @@ def build_parser(backend: str = "single") -> argparse.ArgumentParser:
         type=str,
         default=None,
         help="What this chip holds of a published token model "
-        "(lfm2_*, trinity_mini, afmoe_tiny, qwen3_next*; "
+        "(lfm2_*, trinity_mini, afmoe_tiny, qwen3_next*, nemotron_h*; "
         "models/token_parts.py), as layers=N,dense=N,experts=N,"
         "first_expert=N,vocab=N: layers kept (the leading dense ones, then "
         "the layers that follow them), experts held in every expert layer "
         "and the first one's index, vocabulary rows. A model without "
-        "leading dense layers (qwen3_next*) takes dense=0 or no dense= at "
+        "leading dense layers (qwen3_next*, nemotron_h*) takes dense=0 or no dense= at "
         "all. No width is cut; the router keeps every output. Keys left "
         "out keep the published value",
     )

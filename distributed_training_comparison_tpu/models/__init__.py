@@ -19,6 +19,7 @@ from .resnet import (
 )
 from .afmoe import AFMOE_TINY_MODEL, TRINITY_MINI_MODEL, Afmoe
 from .lfm2 import LFM2, LFM2_24B_A2B_MODEL, LFM2_TINY_MODEL
+from .nemotron_h import NEMOTRON_H_MODEL, NEMOTRON_H_TINY_MODEL, NemotronH
 from .qwen3_next import QWEN3_NEXT_MODEL, QWEN3_NEXT_TINY_MODEL, Qwen3Next
 from .moe import SwitchFFN, TopKMoE, resolve_dispatch
 from .vit import ViT, ViTBlock, ViTLong, ViTMoE, ViTSmall, ViTTiny
@@ -39,6 +40,8 @@ _ZOO = {
     "afmoe_tiny": AFMOE_TINY_MODEL,
     "qwen3_next": QWEN3_NEXT_MODEL,
     "qwen3_next_tiny": QWEN3_NEXT_TINY_MODEL,
+    "nemotron_h": NEMOTRON_H_MODEL,
+    "nemotron_h_tiny": NEMOTRON_H_TINY_MODEL,
 }
 
 
@@ -89,6 +92,7 @@ __all__ = [
     "LFM2",
     "Afmoe",
     "Qwen3Next",
+    "NemotronH",
     "get_model",
     "model_cli_options",
     "resolve_dispatch",

@@ -65,9 +65,14 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.compilation import note_kernel_path
-from ..ops.moe_gmm import grouped_matmul, grouped_matmul_t, resolve_gmm_impl
+from ..ops.moe_gmm import (
+    grouped_matmul,
+    grouped_matmul_t,
+    lane_width,
+    resolve_gmm_impl,
+)
 from ..ops.vmem import fits_weight_budget, gmm_weight_bytes
-from .token_parts import SwiGLU
+from .token_parts import ReLU2, SwiGLU
 
 
 def resolve_dispatch(dispatch: str = "auto", *, expert_parallel: bool = False) -> str:
@@ -354,6 +359,21 @@ class _Experts(NamedTuple):
     top_k: int
     impl: str
     interpret: bool
+    mlp: str = "swiglu"  # the experts' form, one of ``MLP_FORMS``
+
+
+# An expert's MLP: the weights it takes, up-projections first and the
+# down-projection last, and what stands between them.  ``swiglu``: ``W_2
+# (silu(W_1 x) * W_3 x)``; ``relu2``: ``W_2 relu(W_1 x)^2``, no gate.  Both
+# send zero to zero, so hidden columns of zeros change nothing.
+MLP_FORMS = {"swiglu": ("w1", "w3", "w2"), "relu2": ("w1", "w2")}
+
+
+def _activation(mlp: str, hs):
+    """The hidden activation from the up-projections' outputs ``hs``."""
+    if mlp == "swiglu":
+        return nn.silu(hs[0]) * hs[1]
+    return jnp.square(nn.relu(hs[0]))
 
 
 def held_prefix_rows(pairs: int, held: int, num_experts: int) -> int:
@@ -414,25 +434,26 @@ def _gmm(st, plan):
     )
 
 
-def _pass_fwd(st, xt, weights, w1, w3, w2, plan):
-    """The expert part on the held prefix: dispatch in, the SwiGLU's three
-    grouped matmuls, combine out.  Returns ``(y, residuals)``."""
+def _pass_fwd(st, xt, weights, ws, plan):
+    """The expert part on the held prefix: dispatch in, the MLP's grouped
+    matmuls (``ws``: the up-projections, then the down-projection), combine
+    out.  Returns ``(y, residuals)``."""
     pair, gmm = plan["inv"][: st.prefix], _gmm(st, plan)
     # slots behind the live ones read some token's row: ``grouped_matmul``
     # zeroes them on both sides
     xs = xt[pair // st.top_k]
     with jax.named_scope("moe_gmm"):
-        h1, h3 = gmm(xs, w1), gmm(xs, w3)
-        ys = gmm(nn.silu(h1) * h3, w2)
+        hs = tuple(gmm(xs, w) for w in ws[:-1])
+        ys = gmm(_activation(st.mlp, hs), ws[-1])
     w_slot = weights.reshape(-1)[pair].astype(xt.dtype)
-    return _token_sums(ys, w_slot, st, plan), (xs, h1, h3, ys)
+    return _token_sums(ys, w_slot, st, plan), (xs, hs, ys)
 
 
-def _pass_bwd(st, res, g, xt, weights, w1, w3, w2, plan):
+def _pass_bwd(st, res, g, xt, weights, ws, plan):
     """The mirror image: the cotangent reaches the slots by one gather of
     their rows from its ``n``, and leaves them for ``xt`` by the same
     token sums."""
-    xs, h1, h3, ys = res
+    xs, hs, ys = res
     pair, gmm = plan["inv"][: st.prefix], _gmm(st, plan)
     g_slot = g[pair // st.top_k]
     w_slot = weights.reshape(-1)[pair].astype(xt.dtype)
@@ -450,11 +471,15 @@ def _pass_bwd(st, res, g, xt, weights, w1, w3, w2, plan):
     with jax.named_scope("moe_gmm"):
         # the forward products of these two are dead code: each grouped
         # matmul's VJP reads its operands only
-        _, down = jax.vjp(lambda a, b, w: gmm(nn.silu(a) * b, w), h1, h3, w2)
-        g_h1, g_h3, g_w2 = down(g_ys)
-        _, up = jax.vjp(lambda a, u, v: (gmm(a, u), gmm(a, v)), xs, w1, w3)
-        g_xs, g_w1, g_w3 = up((g_h1, g_h3))
-    return _token_sums(g_xs, None, st, plan), g_weights, g_w1, g_w3, g_w2
+        _, down = jax.vjp(
+            lambda hs, w: gmm(_activation(st.mlp, hs), w), hs, ws[-1]
+        )
+        g_hs, g_down = down(g_ys)
+        _, up = jax.vjp(
+            lambda a, ups: tuple(gmm(a, u) for u in ups), xs, ws[:-1]
+        )
+        g_xs, g_ups = up(g_hs)
+    return _token_sums(g_xs, None, st, plan), g_weights, (*g_ups, g_down)
 
 
 def _dispatch_plan(local, held):
@@ -481,7 +506,7 @@ def _dispatch_plan(local, held):
     }
 
 
-def _every_expert_on_every_token(xt, weights, w1, w3, w2, local):
+def _every_expert_on_every_token(mlp, xt, weights, ws, local):
     """The same sum the plain way, for the call in which more pairs arrive
     than the prefix holds: each held expert on all ``n`` tokens, its output
     weighted by the pair that selected it (no pair, for most tokens: zero)
@@ -496,14 +521,14 @@ def _every_expert_on_every_token(xt, weights, w1, w3, w2, local):
 
     @jax.checkpoint
     def add_expert(acc, expert):
-        e, u, v, w = expert
-        out = dot(nn.silu(dot(xt, u)) * dot(xt, v), w)
+        e, *ups, down = expert
+        out = dot(_activation(mlp, tuple(dot(xt, u) for u in ups)), down)
         mine = jnp.sum(jnp.where(local == e, pair_w, 0.0), axis=1)
         return acc + mine[:, None] * out.astype(jnp.float32), None
 
     acc, _ = jax.lax.scan(
         add_expert, jnp.zeros(xt.shape, jnp.float32),
-        (jnp.arange(w2.shape[0]), w1, w3, w2),
+        (jnp.arange(ws[-1].shape[0]), *ws),
     )
     return acc.astype(xt.dtype)
 
@@ -523,43 +548,44 @@ def _fits(st, plan):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(st, xt, weights, w1, w3, w2, plan):
+def _experts(st, xt, weights, ws, plan):
     """``y[t] = sum over token t's pairs held here of weight * expert(xt[t])``
     on the held prefix of the expert-sorted slots, and by
     ``_every_expert_on_every_token`` where more pairs arrive than it holds:
-    nothing is dropped, and no tensor has more rows than the prefix.  The
+    nothing is dropped, and no tensor has more rows than the prefix.  ``ws``
+    is the experts' weights in the order of ``MLP_FORMS[st.mlp]``.  The
     VJP is its own because the fallback is a loop that runs once or, in the
     common case, not at all — a ``lax.cond`` here cost the train program
     2.5 GB of temporaries (PERF.md, Findings, PR 28) — and so that the
     prefix saves prefix-sized residuals while the fallback saves nothing
     and runs its forward again inside its backward: the rare path pays."""
-    return _experts_fwd(st, xt, weights, w1, w3, w2, plan)[0]
+    return _experts_fwd(st, xt, weights, ws, plan)[0]
 
 
-def _experts_fwd(st, xt, weights, w1, w3, w2, plan):
+def _experts_fwd(st, xt, weights, ws, plan):
     plan, falls_back = _fits(st, plan)
-    y, res = _pass_fwd(st, xt, weights, w1, w3, w2, plan)
+    y, res = _pass_fwd(st, xt, weights, ws, plan)
     if falls_back is not None:
         y = jax.lax.fori_loop(
             0, falls_back,
             lambda _, y: _every_expert_on_every_token(
-                xt, weights, w1, w3, w2, plan["local"]
+                st.mlp, xt, weights, ws, plan["local"]
             ),
             y,
         )
-    return y, (res, xt, weights, w1, w3, w2, plan, falls_back)
+    return y, (res, xt, weights, ws, plan, falls_back)
 
 
 def _experts_bwd(st, saved, g):
-    res, xt, weights, w1, w3, w2, plan, falls_back = saved
-    grads = _pass_bwd(st, res, g, xt, weights, w1, w3, w2, plan)
+    res, xt, weights, ws, plan, falls_back = saved
+    grads = _pass_bwd(st, res, g, xt, weights, ws, plan)
     if falls_back is not None:
         plain = functools.partial(
-            _every_expert_on_every_token, local=plan["local"]
+            _every_expert_on_every_token, st.mlp, local=plan["local"]
         )
         grads = jax.lax.fori_loop(
             0, falls_back,
-            lambda _, grads: jax.vjp(plain, xt, weights, w1, w3, w2)[1](g),
+            lambda _, grads: jax.vjp(plain, xt, weights, ws)[1](g),
             grads,
         )
     return (*grads, None)
@@ -596,9 +622,10 @@ def route_topk(x, router_kernel, bias, k: int, scale: float = 1.0,
 
 
 class TopKMoE(nn.Module):
-    """Top-k routed SwiGLU experts (sigmoid scores, or ``score="softmax"``:
-    ``route_topk``), no capacity and no dropped pair, for a layer that is
-    told which experts it holds.
+    """Top-k routed experts (sigmoid scores, or ``score="softmax"``:
+    ``route_topk``), each a SwiGLU or, with ``mlp="relu2"``, the ungated
+    ``W_2 relu(W_1 x)^2`` of two weight tensors (``MLP_FORMS``); no capacity
+    and no dropped pair, for a layer that is told which experts it holds.
 
     The router scores all ``num_experts``; this layer holds
     ``num_experts_held`` of them from index ``first_expert`` (all of them
@@ -616,7 +643,8 @@ class TopKMoE(nn.Module):
     / num_experts`` slots (``held_prefix_rows``: twice what even routing
     sends here; ``n * k`` itself where every expert is held), not on the
     ``n * k`` that could arrive: one gather brings the prefix's token rows
-    in, three grouped matmuls (``ops/moe_gmm.py grouped_matmul``) run over
+    in, the MLP's grouped matmuls (``ops/moe_gmm.py grouped_matmul``: three
+    for a SwiGLU, two without a gate) run over
     the held groups — their work follows the rows that arrived — and the
     outputs are gathered into the order of their pairs ``(token, j)``, where
     a token's are neighbours, and summed per token by a grouped matmul with
@@ -644,8 +672,8 @@ class TopKMoE(nn.Module):
     and restored with ``batch_stats``, and an eval call leaves it alone.
     ``moe_metrics/bias_spread`` is ``max(b) - min(b)`` after the move.
 
-    ``shared_hidden`` > 0 adds a shared expert: a SwiGLU of that width every
-    token passes through, its output added once, unweighted (module and
+    ``shared_hidden`` > 0 adds a shared expert: an MLP of the experts' form
+    and that width every token passes through, its output added once, unweighted (module and
     scope ``shared_expert``).  Every rank of an expert-parallel layer
     computes it alike, so the ranks' results add up to the uncut layer's
     with it counted once (``tests/test_moe.py``).  ``shared_gate``
@@ -669,6 +697,7 @@ class TopKMoE(nn.Module):
     shared_hidden: int = 0  # 0: no shared expert
     score: str = "sigmoid"  # or "softmax" (``route_topk``)
     shared_gate: bool = False  # the shared expert times ``sigmoid(x w_g)``
+    mlp: str = "swiglu"  # or "relu2": every expert's form (``MLP_FORMS``)
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
@@ -694,9 +723,23 @@ class TopKMoE(nn.Module):
             bias_state.value if self.use_bias
             else jnp.zeros((self.num_experts,), jnp.float32)
         )
-        w1 = self.param("w1", init, (held, d, self.hidden), jnp.float32)
-        w3 = self.param("w3", init, (held, d, self.hidden), jnp.float32)
-        w2 = self.param("w2", init, (held, self.hidden, d), jnp.float32)
+        # ``relu2`` keeps its up-projection ``(held, hidden, d)``, a row a
+        # hidden unit as ``w2`` has them, where ``swiglu``'s are ``(held, d,
+        # hidden)``: its first user's hidden width, 1,856, is no whole lane
+        # tile, and the train program copies a float32 parameter whose minor
+        # axis is not whole tiles into its loop's tiled layout and out
+        # again, with both its moments — nine copies of 165 MB over three
+        # layers (PERF.md, Findings, PR 40)
+        rows_hidden = self.mlp == "relu2"
+        ws = tuple(
+            self.param(
+                name, init,
+                (held, self.hidden, d) if name == "w2" or rows_hidden
+                else (held, d, self.hidden),
+                jnp.float32,
+            )
+            for name in MLP_FORMS[self.mlp]
+        )
 
         xt = x.reshape(n, d)
         sel, weights = route_topk(
@@ -732,15 +775,28 @@ class TopKMoE(nn.Module):
         impl = resolve_gmm_impl(self.gmm)
         interpret = impl == "megablox" and jax.default_backend() != "tpu"
         note_kernel_path("moe_gmm", impl + "-interpret" * interpret)
+        # the copy the products read: in the compute dtype and, for a
+        # kernel that tiles the hidden width in whole lanes, with columns of
+        # zeros up to them (``lane_width``) — they stay zero through either
+        # activation and meet rows of zeros in ``w2``; the parameters and
+        # their gradients keep the published width
+        extra = lane_width(self.hidden, impl) - self.hidden
         with jax.named_scope("moe_gmm"):
-            w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
+            ws = tuple(w.astype(self.dtype) for w in ws)
+            if rows_hidden:
+                ws = (*(jnp.swapaxes(w, 1, 2) for w in ws[:-1]), ws[-1])
+            if extra:
+                ws = (
+                    *(jnp.pad(w, ((0, 0), (0, 0), (0, extra))) for w in ws[:-1]),
+                    jnp.pad(ws[-1], ((0, 0), (0, extra), (0, 0))),
+                )
         y = _experts(
-            _Experts(prefix, k, impl, interpret),
-            xt.astype(self.dtype), weights, w1, w3, w2, plan,
+            _Experts(prefix, k, impl, interpret, self.mlp),
+            xt.astype(self.dtype), weights, ws, plan,
         )
         y = y.reshape(b, s, d)
         if self.shared_hidden:
-            shared = SwiGLU(
+            shared = (SwiGLU if self.mlp == "swiglu" else ReLU2)(
                 d, self.shared_hidden, self.dtype, name="shared_expert"
             )(x.astype(self.dtype))
             if self.shared_gate:

@@ -1,9 +1,9 @@
 """What the token decoders share (``models/lfm2.py``, ``models/afmoe.py``,
-``models/qwen3_next.py``): the ``--model-cut`` that says what one chip holds
+``models/qwen3_next.py``, ``models/nemotron_h.py``): the ``--model-cut`` that says what one chip holds
 of a published model, and the plain pieces every such decoder is made of —
 RMSNorm with float32 statistics (scaled by ``w`` or, zero-centred, by ``1 +
 w``), a bias-free projection, rotate-half RoPE on the whole head or its
-first elements, the SwiGLU."""
+first elements, the SwiGLU and the ungated squared-ReLU MLP."""
 
 from __future__ import annotations
 
@@ -81,10 +81,10 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
-def _dense(features, dtype, name):
+def _dense(features, dtype, name, std=0.02):
     return nn.Dense(
         features, use_bias=False, dtype=dtype, param_dtype=jnp.float32,
-        kernel_init=nn.initializers.normal(stddev=0.02), name=name,
+        kernel_init=nn.initializers.normal(stddev=std), name=name,
     )
 
 
@@ -116,6 +116,19 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         gate = nn.silu(_dense(self.hidden, self.dtype, "w1")(x))
         return _dense(self.dim, self.dtype, "w2")(gate * _dense(self.hidden, self.dtype, "w3")(x))
+
+
+class ReLU2(nn.Module):
+    """``W_2 relu(W_1 x)^2``: the MLP without a gate (Nemotron-H)."""
+
+    dim: int
+    hidden: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        up = nn.relu(_dense(self.hidden, self.dtype, "w1")(x))
+        return _dense(self.dim, self.dtype, "w2")(up * up)
 
 
 def frozen_config(config: dict):

@@ -216,6 +216,25 @@ def gated_delta_rule(
     return _kernel_rule(q, k, v, g, beta, chunk, step_chunks, interpret)
 
 
+def chunk_decays(g):
+    """The decay factors of a chunked scan from ``g (..., C)``, the float32
+    log-decays (<= 0) of a chunk's tokens along the last axis, with ``gamma``
+    their running sum inside the chunk: ``(below, ratio, decay, to_end)`` —
+    the mask ``i >= j``; ``Gamma_ij = e^{gamma_i - gamma_j}`` for ``j <= i``,
+    else 0, ``(..., C, C)``; ``e^{gamma_i}``, the chunk's start to token
+    ``i``; ``e^{gamma_C - gamma_j}``, token ``j`` to the chunk's end.  Every
+    factor is a ratio of at most one: nothing is divided by a decay.  What
+    the gated delta rule here and the state-space scan (``ops/ssd.py``)
+    share."""
+    gamma = jnp.cumsum(g, axis=-1)
+    row = jnp.arange(g.shape[-1])
+    below = row[:, None] >= row[None, :]
+    ratio = jnp.exp(jnp.where(
+        below, gamma[..., :, None] - gamma[..., None, :], -jnp.inf
+    ))
+    return below, ratio, jnp.exp(gamma), jnp.exp(gamma[..., -1:] - gamma)
+
+
 def _chunked(q, k, v, g, beta, chunk):
     q, k = _heads_of(q, k, v)
     b, s, h, dv = v.shape
@@ -241,15 +260,9 @@ def _chunked(q, k, v, g, beta, chunk):
         )
 
     # ---- every chunk at once: what does not depend on the state
-    gamma = jnp.cumsum(g, axis=-1)
-    row = jnp.arange(chunk)
-    below = row[:, None] >= row[None, :]
-    ratio = jnp.exp(jnp.where(
-        below, gamma[..., :, None] - gamma[..., None, :], -jnp.inf
-    ))  # Gamma: e^{gamma_i - gamma_j} for j <= i, else 0
+    below, ratio, decay, to_end = chunk_decays(g)
     lower = beta[..., :, None] * ratio * mm("bhnid,bhnjd->bhnij", k, k)
     t = _unit_lower_inverse(jnp.where(below & ~jnp.eye(chunk, dtype=bool), lower, 0.0))
-    decay = jnp.exp(gamma)  # e^{gamma_i}, the chunk's start to token i
     # operands of the loop's products: kept in their dtype, not float32
     u = mm("bhnij,bhnjd->bhnid", t, beta[..., None] * v.astype(f32)).astype(dtype)
     w = mm(
@@ -257,7 +270,6 @@ def _chunked(q, k, v, g, beta, chunk):
     ).astype(dtype)
     scores = (ratio * mm("bhnid,bhnjd->bhnij", q, k)).astype(dtype)
     q_in = (decay[..., None] * q.astype(f32)).astype(dtype)
-    to_end = jnp.exp(gamma[..., -1:] - gamma)  # e^{gamma_C - gamma_j}
     k_out = (to_end[..., None] * k.astype(f32)).astype(dtype)
     end = decay[..., -1]  # e^{gamma_C}
 

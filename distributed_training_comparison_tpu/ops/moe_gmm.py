@@ -361,11 +361,28 @@ GMM_IMPLS = ("ragged_dot", "megablox")
 
 def _tile(dim: int, target: int) -> int:
     """The largest multiple of 128 up to ``target`` that divides ``dim``;
-    ``dim`` itself where there is none (a tiny test width)."""
+    ``dim`` itself where there is none: a test's width under one lane tile,
+    which a block may span whole.  A wider one (1,856 = 14.5 x 128) comes
+    here at its ``lane_width``."""
     for t in range(min(target, dim) // 128 * 128, 0, -128):
         if dim % t == 0:
             return t
     return dim
+
+
+def lane_width(width: int, impl: str) -> int:
+    """The width ``impl`` takes an expert's hidden axis at: ``width``
+    itself, or for ``megablox`` at a width past one lane tile that no
+    multiple of 128 divides, the next multiple of 128.  A block of the
+    whole width is no way out there: the backward's kernels take the
+    transposed tiling, whose block is neither whole lanes nor the array's
+    width (Mosaic refuses it; compiled for a described v5e at 1,856), and
+    at 1,856 a forward block would be 14 MB of the 16 MiB a kernel gets
+    unasked.  The caller pads its cast weights with zeros (``models/moe.py
+    TopKMoE``): exact for an activation that sends zero to zero."""
+    if impl != "megablox" or width <= 128 or _tile(width, 1024) % 128 == 0:
+        return width
+    return _ceil_to(width, 128)
 
 
 def resolve_gmm_impl(impl: str = "auto") -> str:

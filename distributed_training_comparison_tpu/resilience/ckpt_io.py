@@ -196,23 +196,36 @@ def atomic_write_bytes(path: str | Path, data: bytes, durable: bool = True) -> P
     return path
 
 
+# the most one write (and one hash update) takes at once: what a paced
+# writer has under way when it is asked to stop
+_PIECE_BYTES = 32 << 20
+
+
 def atomic_write_chunks(
-    path: str | Path, chunks, durable: bool = True
+    path: str | Path, chunks, durable: bool = True, pace=None
 ) -> tuple[Path, str, int]:
     """``atomic_write_bytes`` for a payload that arrives in pieces (bytes
     or C-contiguous byte buffers), hashed while it is written: returns
     ``(path, sha256 hex, size)``.  A multi-gigabyte train state is written
     from its arrays' own memory, never joined into one ``bytes``; writing
     and hashing a large buffer both release the GIL, so a writer thread
-    here does not hold up the training thread."""
+    here does not hold up the training thread.  ``pace()``, if given, is
+    called before every write of at most 32 MiB and may block: the
+    checkpoint writer stops there while the trainer's thread paces the
+    chip (``AsyncCheckpointer.pace``)."""
     path = Path(path)
     tmp = path.with_name(path.name + f".tmp.{os.getpid()}")
     digest, size = hashlib.sha256(), 0
     with open(tmp, "wb") as f:
         for chunk in chunks:
-            f.write(chunk)
-            digest.update(chunk)
-            size += len(chunk)
+            view = memoryview(chunk).cast("B")
+            for at in range(0, len(view), _PIECE_BYTES):
+                piece = view[at:at + _PIECE_BYTES]
+                if pace is not None:
+                    pace()
+                f.write(piece)
+                digest.update(piece)
+            size += len(view)
         if durable:
             f.flush()
             os.fsync(f.fileno())

@@ -16,7 +16,18 @@ Correctness notes:
 - ``wait()`` drains the queue — called before reading a checkpoint back
   (test phase, end of fit) and on ``close()``;
 - writes for the same target are serialized by the single worker, so
-  ``last.ckpt`` is always a complete, most-recent snapshot.
+  ``last.ckpt`` is always a complete, most-recent snapshot;
+- ``hold()`` / ``release()`` pace the worker: between them it STARTS no
+  job, and a job at work stops wherever it calls ``pace()`` (the saves do,
+  before every 32 MiB they write).  The Trainer holds it over the epoch
+  boundary and releases it once the next epoch's train program is
+  dispatched — a job starts with the device→host fetch of the whole
+  state, which stalls the submitting thread for as long as a large leaf's
+  transfer takes (~0.26 s of a boundary at a 6.3 GB state), and a job
+  filling the page cache at memory speed beside a boundary stretches the
+  host's small transfers there (PERF.md, Findings, PR 40); after the
+  dispatch that thread only waits for the chip.  ``wait()``, ``close()``
+  and a full queue release, so no drain can meet a held worker.
 """
 
 from __future__ import annotations
@@ -53,6 +64,8 @@ class AsyncCheckpointer:
         # `writer` gauges
         self._metrics = metrics
         self._born = time.monotonic()
+        self._go = threading.Event()  # clear between hold() and release()
+        self._go.set()
         self._thread = threading.Thread(
             target=self._worker, name="dtc-ckpt-writer", daemon=True
         )
@@ -65,6 +78,7 @@ class AsyncCheckpointer:
                 self._q.task_done()
                 return
             key = item
+            self.pace()  # held: start nothing yet (the newest job still wins)
             with self._lock:
                 queued = self._latest.get(key)
                 self._latest[key] = None
@@ -110,6 +124,17 @@ class AsyncCheckpointer:
             "queue_depth": depth,
         }
 
+    def hold(self) -> None:
+        """Start no further job until ``release()`` (or a drain)."""
+        self._go.clear()
+
+    def release(self) -> None:
+        self._go.set()
+
+    def pace(self) -> None:
+        """For a job to call where it can stop: returns once not held."""
+        self._go.wait()
+
     def submit(self, job: Callable[[], object], key: str = "default") -> None:
         """Enqueue a checkpoint job; newer jobs with the same key supersede
         queued-but-unstarted ones.  The span open on the calling thread
@@ -123,6 +148,8 @@ class AsyncCheckpointer:
         if self._metrics is not None:
             self._metrics.gauge("ckpt/queue_depth").set(depth)
             self._metrics.counter("ckpt/jobs").inc()
+        if self._q.full():  # a held worker frees no slot: pacing yields
+            self.release()
         self._q.put(key)
 
     def _raise_collected(self) -> None:
@@ -141,10 +168,12 @@ class AsyncCheckpointer:
 
     def wait(self) -> None:
         """Block until every queued job has finished; re-raise any failure."""
+        self.release()
         self._q.join()
         self._raise_collected()
 
     def close(self) -> None:
+        self.release()
         if self._thread.is_alive():
             self._q.put(None)
             self._thread.join()

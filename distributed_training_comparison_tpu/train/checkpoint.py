@@ -197,8 +197,10 @@ def save_checkpoint(
     epoch: int,
     val_acc: float,
     state_layout=None,
+    pace=None,
 ) -> Path:
     """Best-only save: drop previous best files, write the new one.
+    ``pace`` goes to ``atomic_write_chunks``.
 
     File carries params + batch_stats (what inference needs); the resumable
     full state lives in ``last.ckpt``.  On disk the trunk stack is always
@@ -219,7 +221,7 @@ def save_checkpoint(
         "val_acc": float(val_acc),
     }
     path = version_dir / f"{BEST_PREFIX}epoch_{epoch}_acc_{val_acc:.4f}.ckpt"
-    atomic_write_chunks(path, msgpack_chunks(payload))
+    atomic_write_chunks(path, msgpack_chunks(payload), pace=pace)
     # drop superseded best files only AFTER the new one is durably in place
     # — a crash mid-save (fetch can take seconds) must never leave the
     # version dir with zero best checkpoints
@@ -446,6 +448,7 @@ def save_resume_state(
     fault_hook: Callable[[str, Path], None] | None = None,
     meta: dict | None = None,
     state_layout=None,
+    pace=None,
 ) -> Path:
     """Write the fully-resumable ``last.ckpt`` (capability the reference
     lacks), crash-safely:
@@ -471,7 +474,7 @@ def save_resume_state(
     saving run's layout tag under ``state_layout`` so
     ``elastic.validate_reshard`` can report cross-layout restores.  The
     comms error-feedback residual is schedule-laid wire format, never
-    canonicalized."""
+    canonicalized.  ``pace`` goes to ``atomic_write_chunks``."""
     host_state = serialization.to_state_dict(fetch_to_host(_state_dict(state)))
     if state_layout is not None:
         host_state = tree_to_canonical(host_state, state_layout)
@@ -485,7 +488,9 @@ def save_resume_state(
     if fault_hook is not None:
         fault_hook("pre", path)
     rotate_previous(path)
-    _, digest, size = atomic_write_chunks(path, msgpack_chunks(payload))
+    _, digest, size = atomic_write_chunks(
+        path, msgpack_chunks(payload), pace=pace
+    )
     write_manifest(
         path,
         digest=digest,

@@ -161,9 +161,16 @@ def _cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return optax.softmax_cross_entropy_with_integer_labels(logits, labels)
 
 
-# what ``_moe_health`` names its scalars by: the expert layers' and the
-# Gated DeltaNet layers' (both sown into ``"moe_metrics"``)
-LAYER_GAUGES = ("moe_", "gdn_")
+# what ``_moe_health`` names its scalars by: the expert layers', the Gated
+# DeltaNet layers' and the state-space layers' (all sown into
+# ``"moe_metrics"``)
+LAYER_GAUGES = ("moe_", "gdn_", "ssm_")
+# a recurrent mixer's mean decay factor a token, by the name it is sown
+# under (models/qwen3_next.py: mean exp(g); models/nemotron_h.py: mean
+# exp(dt A)) and the gauge the trainer sets from it
+DECAY_GAUGES = {
+    "gdn_decay_mean": "gdn/decay_mean", "ssm_decay_mean": "ssm/decay_mean",
+}
 
 
 def _moe_health(coll) -> Metrics:
@@ -174,7 +181,7 @@ def _moe_health(coll) -> Metrics:
     from jax.tree_util import tree_flatten_with_path
 
     dropped, load_max, rows, imbalance, full_buffer = [], [], [], [], []
-    bias_spread, decay = [], []
+    bias_spread, decay = [], {}
     for path, leaf in tree_flatten_with_path(coll)[0]:
         keys = {getattr(p, "key", getattr(p, "name", "")) for p in path}
         if "dropped_frac" in keys:
@@ -190,8 +197,8 @@ def _moe_health(coll) -> Metrics:
             full_buffer.append(jnp.mean(leaf))
         elif "bias_spread" in keys:  # max - min of a selection bias that moves
             bias_spread.append(jnp.mean(leaf))
-        elif "gdn_decay_mean" in keys:  # models/qwen3_next.py: mean exp(g)
-            decay.append(jnp.mean(leaf))
+        elif sown := keys & DECAY_GAUGES.keys():
+            decay.setdefault(sown.pop(), []).append(jnp.mean(leaf))
     out: Metrics = {}
     if rows:  # summed over the layers; the fullest expert's, their mean
         out["moe_rows"] = jnp.sum(jnp.stack(rows))
@@ -199,8 +206,8 @@ def _moe_health(coll) -> Metrics:
         out["moe_full_buffer_share"] = jnp.mean(jnp.stack(full_buffer))
     if bias_spread:
         out["moe_bias_spread"] = jnp.mean(jnp.stack(bias_spread))
-    if decay:  # the mean over the Gated DeltaNet layers
-        out["gdn_decay_mean"] = jnp.mean(jnp.stack(decay))
+    for name, layers in decay.items():  # the mean over the mixer's layers
+        out[name] = jnp.mean(jnp.stack(layers))
     if dropped:
         out["moe_dropped_frac"] = jnp.mean(jnp.stack(dropped))
     if load_max:

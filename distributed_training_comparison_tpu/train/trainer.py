@@ -87,6 +87,7 @@ from .optim import configure_optimizers
 from .state import create_train_state
 from .task import task_of
 from .step import (
+    DECAY_GAUGES,
     LAYER_GAUGES,
     make_chunk_runner,
     make_device_chunk_runner,
@@ -120,7 +121,9 @@ class _WriterSnapshot:
     last of one epoch) read the host copy.  A job superseded before it ran
     drops its reference with it.  (Fetching at once on a thread of its own
     was tried: the transfer then runs beside the next epoch's dispatch, and
-    the boundary grew from 241 to 291 ms; PERF.md, Findings, PR 27.)"""
+    the boundary grew from 241 to 291 ms; PERF.md, Findings, PR 27.  The
+    fetch beside the rest of the boundary stalls it too, so ``fit()`` holds
+    the writer until the next train dispatch: ``AsyncCheckpointer.hold``.)"""
 
     def __init__(self, state):
         self._state, self._fetched = state, False
@@ -1502,6 +1505,11 @@ class Trainer:
                 with self.tracer.span("boundary", epoch=epoch):
                     epoch_time = time.perf_counter() - t0
                     self.goodput.add("step", epoch_time)
+                    if self.ckpt_writer is not None:
+                        # the writer starts no job, and stops one at work,
+                        # while the host paces the chip: released at the
+                        # next train dispatch
+                        self.ckpt_writer.hold()
                     redo = self._boundary(epoch, losses, top1, epoch_time)
             finally:
                 if profiling:
@@ -1744,6 +1752,7 @@ class Trainer:
                             ckpt.save_checkpoint(
                                 vdir, s.on_host(), e, b,
                                 state_layout=self._state_layout,
+                                pace=self.ckpt_writer.pace,
                             )
                         ),
                         key="best",
@@ -1762,6 +1771,7 @@ class Trainer:
                                 fault_hook=h,
                                 meta=self._ckpt_meta(),
                                 state_layout=self._state_layout,
+                                pace=self.ckpt_writer.pace,
                             )
                         ),
                         key="last",
@@ -3128,6 +3138,8 @@ class Trainer:
                     self.state, metrics = runner(*args, fault)
                 else:
                     self.state, metrics = runner(*args)
+            if self.ckpt_writer is not None:
+                self.ckpt_writer.release()  # held over the boundary (fit)
             meter.note_chunk()
             if self._pipe_meta is not None:
                 self._note_pipeline_obs(t_disp, time.monotonic())
@@ -3239,11 +3251,12 @@ class Trainer:
             self.metrics.gauge("moe/bias_spread").set(
                 self._moe_health["moe_bias_spread"]
             )
-        if "gdn_decay_mean" in gauges:
-            # Gated DeltaNet layers (models/qwen3_next.py): the mean of
-            # exp(g) over tokens, heads, layers and the epoch's steps — how
+        for sown, gauge in DECAY_GAUGES.items():
+            # recurrent mixers (Gated DeltaNet, Mamba-2): the mean decay
+            # factor over tokens, heads, layers and the epoch's steps — how
             # fast the state forgets
-            self.metrics.gauge("gdn/decay_mean").set(gauges["gdn_decay_mean"])
+            if sown in gauges:
+                self.metrics.gauge(gauge).set(gauges[sown])
         # the per-step signals land in the metric sketches here — one
         # vectorized pass over the stacked arrays, no per-step Python loop;
         # non-finite samples count into the sketch's side counter, so a
@@ -3340,6 +3353,8 @@ class Trainer:
                         self.state, metrics = self.chunk_runner(*args, fault)
                     else:
                         self.state, metrics = self.chunk_runner(*args)
+                if self.ckpt_writer is not None:
+                    self.ckpt_writer.release()  # held over the boundary (fit)
                 meter.note_chunk()
                 if self._pipe_meta is not None:
                     self._note_pipeline_obs(t_disp, time.monotonic())

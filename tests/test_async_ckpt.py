@@ -81,6 +81,46 @@ def test_async_multiple_errors_report_count():
     w.close()
 
 
+def test_held_worker_starts_nothing_until_release():
+    """Between hold() and release() the worker starts no job, and the
+    newest snapshot of a key still wins."""
+    w = AsyncCheckpointer()
+    ran = []
+    w.hold()
+    w.submit(lambda: ran.append("old"), key="last")
+    w.submit(lambda: ran.append("new"), key="last")
+    time.sleep(0.2)
+    assert ran == [] and w.stats()["queue_depth"] == 2
+    w.release()
+    w.wait()
+    assert ran == ["new"]
+    w.close()
+
+
+@pytest.mark.parametrize("drain", ["wait", "close"])
+def test_drain_releases_a_held_worker(drain):
+    w = AsyncCheckpointer()
+    ran = []
+    w.hold()
+    w.submit(lambda: ran.append(1))
+    getattr(w, drain)()  # would never return if the hold outlived it
+    assert ran == [1]
+    w.close()
+
+
+def test_full_queue_releases_a_held_worker():
+    """A held worker frees no slot, so a submit that finds the queue full
+    gives up the pacing and never blocks on it."""
+    w = AsyncCheckpointer(max_pending=2)
+    ran = []
+    w.hold()
+    for i in range(4):
+        w.submit(lambda i=i: ran.append(i), key=f"k{i}")
+    w.wait()
+    assert ran == [0, 1, 2, 3]
+    w.close()
+
+
 def test_close_idempotent():
     w = AsyncCheckpointer()
     w.close()
@@ -153,3 +193,40 @@ def test_streamed_serialisation_is_flax_msgpack_byte_for_byte(tmp_path):
     assert digest == hashlib.sha256(want).hexdigest()
     back = serialization.msgpack_restore(path.read_bytes())
     np.testing.assert_array_equal(back["state"]["params"]["half"], tree["state"]["params"]["half"])
+
+
+def test_paced_write_stops_between_pieces(tmp_path, monkeypatch):
+    """``atomic_write_chunks(..., pace=)`` calls ``pace`` before every piece
+    it writes — a large buffer goes out in pieces — and a held
+    ``AsyncCheckpointer`` stops a job there; the bytes and their hash are
+    those of the unpaced write."""
+    import hashlib
+
+    from distributed_training_comparison_tpu.resilience import (
+        atomic_write_chunks, ckpt_io,
+    )
+
+    monkeypatch.setattr(ckpt_io, "_PIECE_BYTES", 1000)
+    big = np.arange(2500, dtype=np.uint8)
+    chunks = [b"head", memoryview(big), np.arange(6, dtype=np.float32)]
+    want = b"head" + big.tobytes() + chunks[2].tobytes()
+    calls = []
+    path, digest, size = atomic_write_chunks(
+        tmp_path / "a", chunks, pace=lambda: calls.append(1)
+    )
+    assert len(calls) == 1 + 3 + 1  # 4 | 1000 + 1000 + 500 | 24 bytes
+    assert path.read_bytes() == want and size == len(want)
+    assert digest == hashlib.sha256(want).hexdigest()
+
+    w = AsyncCheckpointer()
+    w.submit(lambda: atomic_write_chunks(tmp_path / "b", chunks, pace=w.pace))
+    w.wait()
+    w.hold()
+    w.submit(lambda: atomic_write_chunks(tmp_path / "c", chunks, pace=w.pace))
+    time.sleep(0.2)
+    assert (tmp_path / "b").read_bytes() == want
+    assert not (tmp_path / "c").exists()  # held before it started
+    w.release()
+    w.wait()
+    assert (tmp_path / "c").read_bytes() == want
+    w.close()

@@ -151,6 +151,25 @@ def test_ckpt_write_names_the_ckpt_submit_that_queued_it(run):
         assert w["t0"] >= cause["t0"]
 
 
+def test_writer_starts_no_job_inside_a_boundary(run):
+    """``fit()`` holds the writer over the boundary: a save queued in epoch
+    k's boundary starts after epoch k + 1's train dispatch returned (the
+    last epoch's at the drain), so its fetch of the state never runs beside
+    the host's part of an epoch."""
+    spans, _ = run
+    dispatched = {}  # epoch -> when its first train dispatch returned
+    for s in sorted(spans, key=lambda s: s["t0"]):
+        if s["name"] == "dispatch":
+            dispatched.setdefault(s["epoch"], s["t1"])
+    (drain,) = [s for s in spans if s["name"] == "ckpt_drain"]
+    writes = [s for s in spans if s["name"] == "ckpt_write"]
+    assert {w["epoch"] for w in writes} == {0, 1}
+    for w in writes:
+        assert w["t0"] >= dispatched.get(w["epoch"] + 1, drain["t0"])
+        for b in _boundaries(spans):
+            assert not b["t0"] <= w["t0"] <= b["t1"]
+
+
 def test_eval_spans_return_what_validate_returned(tmp_path):
     """Splitting ``_run_eval``'s call from its fetch split the statement,
     not the semantics: the same floats as the one-expression form."""

@@ -436,6 +436,7 @@ def test_auto_gmm_gate_respects_vmem_budget():
 
 # ------------------------------------------------ top-k, no drops (TopKMoE)
 
+from distributed_training_comparison_tpu.models import moe  # noqa: E402
 from distributed_training_comparison_tpu.models.moe import (  # noqa: E402
     TopKMoE, held_prefix_rows, route_topk,
 )
@@ -882,3 +883,177 @@ def test_gated_shared_expert_shares_add_up_to_the_uncut_reference_layer():
         )
         total = total + (part - alike)
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-6)
+
+
+# ------------------------- the experts' MLP form: swiglu as it was, and relu2
+
+
+def _relu2_ref(variables, x, first=0):
+    """The plain sum of a ``relu2`` share: every held expert on every
+    token, weighted by the pair that selected it (LFM2's routing)."""
+    p = variables["params"]
+    sel, w = route_topk(
+        x.reshape(-1, x.shape[-1]), p["router"],
+        variables["batch_stats"]["expert_bias"], TOPK["top_k"],
+    )
+    out = jnp.zeros(x.shape, jnp.float32).reshape(-1, x.shape[-1])
+    for e in range(p["w1"].shape[0]):
+        mine = jnp.sum(jnp.where(sel == first + e, w, 0.0), axis=-1)
+        # both matrices a row a hidden unit: (hidden, d)
+        hidden = jnp.square(jax.nn.relu(x.reshape(-1, x.shape[-1]) @ p["w1"][e].T))
+        out = out + mine[:, None] * (hidden @ p["w2"][e])
+    return out.reshape(x.shape)
+
+
+def _relu2_share(x, bias):
+    whole = TopKMoE(**TOPK, mlp="relu2")
+    variables = whole.init(jax.random.key(0), x)
+    p = {**variables["params"]}
+    p["w1"], p["w2"] = p["w1"][:4], p["w2"][:4]
+    share = TopKMoE(**TOPK, num_experts_held=4, first_expert=0, mlp="relu2")
+    return share, {"params": p, "batch_stats": {"expert_bias": bias}}
+
+
+@pytest.mark.parametrize("leaf", ["x", "router", "w1", "w2"])
+@pytest.mark.parametrize("size", SIZES)
+def test_relu2_pass_and_its_hand_vjp_match_autodiff_of_the_plain_sum(size, leaf):
+    """Two weight tensors and no gate through ``_pass_fwd``, ``_pass_bwd``
+    and ``_every_expert_on_every_token``: values and gradients on the held
+    prefix and on the overflow path."""
+    bias, rows, full = SIZES[size]
+    x = jax.random.normal(jax.random.key(11), (2, 40, 32))
+    share, variables = _relu2_share(x, bias)
+    assert set(variables["params"]) == {"router", "w1", "w2"}
+    cot = jax.random.normal(jax.random.key(12), x.shape)
+    got, sown = share.apply(variables, x, mutable=["moe_metrics"])
+    assert float(sown["moe_metrics"]["rows"][0]) == rows
+    assert float(sown["moe_metrics"]["full_buffer"][0]) == full
+    np.testing.assert_allclose(got, _relu2_ref(variables, x), rtol=2e-4, atol=2e-6)
+
+    def loss(fn):
+        def of(params, x):
+            return (fn({**variables, "params": params}, x) * cot).sum()
+        return jax.grad(of, (0, 1))(variables["params"], x)
+
+    got_p, got_x = loss(lambda v, x: share.apply(v, x))
+    want_p, want_x = loss(_relu2_ref)
+    got, want = {**got_p, "x": got_x}[leaf], {**want_p, "x": want_x}[leaf]
+    assert float(jnp.abs(want).max()) > 0
+    np.testing.assert_allclose(
+        got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max())
+    )
+
+
+def _parents_pass(st, xt, weights, w1, w3, w2, plan):
+    """The SwiGLU expert pass's forward and hand VJP as they stood before
+    the pass took its MLP's form (PR 39), word for word but for the names
+    it imports: what the three accepted decoders' calls must still give,
+    bit for bit."""
+    from flax import linen as nn
+
+    pair, gmm = plan["inv"][: st.prefix], moe._gmm(st, plan)
+    xs = xt[pair // st.top_k]
+    h1, h3 = gmm(xs, w1), gmm(xs, w3)
+    ys = gmm(nn.silu(h1) * h3, w2)
+    w_slot = weights.reshape(-1)[pair].astype(xt.dtype)
+    y = moe._token_sums(ys, w_slot, st, plan)
+
+    def backward(g):
+        g_slot = g[pair // st.top_k]
+        g_w = jnp.sum(g_slot.astype(jnp.float32) * ys.astype(jnp.float32), axis=1)
+        g_weights = jnp.zeros(weights.size, weights.dtype).at[pair].set(
+            g_w.astype(weights.dtype), unique_indices=True, mode="promise_in_bounds",
+        ).reshape(weights.shape)
+        g_ys = (
+            g_slot.astype(jnp.float32) * w_slot.astype(jnp.float32)[:, None]
+        ).astype(ys.dtype)
+        _, down = jax.vjp(lambda a, b, w: gmm(nn.silu(a) * b, w), h1, h3, w2)
+        g_h1, g_h3, g_w2 = down(g_ys)
+        _, up = jax.vjp(lambda a, u, v: (gmm(a, u), gmm(a, v)), xs, w1, w3)
+        g_xs, g_w1, g_w3 = up((g_h1, g_h3))
+        return moe._token_sums(g_xs, None, st, plan), g_weights, g_w1, g_w3, g_w2
+
+    return y, backward
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_swiglu_pass_gives_the_parents_bits(dtype):
+    n, d, k, held, experts = 96, 32, 4, 4, 16
+    xt = jax.random.normal(jax.random.key(1), (n, d), dtype)
+    ws = tuple(
+        (0.2 * jax.random.normal(jax.random.key(2 + i), shape)).astype(dtype)
+        for i, shape in enumerate([(held, d, 48), (held, d, 48), (held, 48, d)])
+    )
+    sel = jax.random.randint(jax.random.key(5), (n, k), 0, experts)
+    weights = jax.random.uniform(jax.random.key(6), (n, k))
+    plan = moe._dispatch_plan(sel.reshape(-1), held)
+    st = moe._Experts(
+        moe.held_prefix_rows(n * k, held, experts), k, "ragged_dot", False
+    )
+    assert st.mlp == "swiglu" and int(plan["rows"]) <= st.prefix
+    g = jax.random.normal(jax.random.key(7), (n, d), dtype)
+    want_y, backward = _parents_pass(st, xt, weights, *ws, plan)
+    got_y, res = moe._pass_fwd(st, xt, weights, ws, plan)
+    got = moe._pass_bwd(st, res, g, xt, weights, ws, plan)
+    want = backward(g)
+    np.testing.assert_array_equal(got_y, want_y)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    # and the layer still builds the three decoders' parameter tree
+    layer, variables = _topk_layer(xt[None].astype(jnp.float32))
+    assert set(variables["params"]) == {"router", "w1", "w2", "w3"}
+
+
+@pytest.mark.parametrize("mlp", ["swiglu", "relu2"])
+def test_a_hidden_width_no_lane_tile_divides_is_padded_for_megablox_only(mlp):
+    """320 = 2.5 x 128: no multiple of 128 divides it, so the copy megablox
+    reads carries zero columns to 384 (``lane_width``) and the layer's
+    values and gradients are those of ``ragged_dot`` at 320; parameters and
+    their gradients keep 320.  A test width under one lane tile and a width
+    that lane tiles divide are left alone."""
+    from distributed_training_comparison_tpu.ops.moe_gmm import lane_width
+
+    assert lane_width(1856, "megablox") == 1920 and lane_width(320, "megablox") == 384
+    assert [lane_width(w, "megablox") for w in (48, 128, 512, 1536)] == [48, 128, 512, 1536]
+    assert lane_width(1856, "ragged_dot") == 1856
+    x = jax.random.normal(jax.random.key(3), (1, 64, 32))
+    sizes = dict(dim=32, hidden=320, num_experts=8, top_k=2, mlp=mlp)
+    plain = TopKMoE(**sizes, gmm="ragged_dot")
+    variables = plain.init(jax.random.key(4), x)
+    # relu2 keeps its up-projection a row a hidden unit, as w2 is
+    assert variables["params"]["w1"].shape == (
+        (8, 32, 320) if mlp == "swiglu" else (8, 320, 32)
+    )
+    assert variables["params"]["w2"].shape == (8, 320, 32)
+    kernel = TopKMoE(**sizes, gmm="megablox")  # interpreted off the chip
+    grad = lambda layer: jax.value_and_grad(  # noqa: E731
+        lambda p: jnp.square(layer.apply({**variables, "params": p}, x)).sum()
+    )(variables["params"])
+    (got, got_g), (want, want_g) = grad(kernel), grad(plain)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name, g in got_g.items():
+        assert g.shape == variables["params"][name].shape
+        np.testing.assert_allclose(
+            g, want_g[name], rtol=2e-3, atol=2e-5 * float(jnp.abs(want_g[name]).max())
+        )
+
+
+def test_grouped_matmul_at_a_width_no_lane_tile_divides():
+    """``grouped_matmul`` itself at 320 columns, then rows: ``ragged_dot``
+    takes any width; ``megablox`` the whole width as one block in the
+    forward (interpreted here), which the chip's compiler refuses past one
+    tile in the backward — why ``TopKMoE`` pads (the test above)."""
+    m, k, n = 128, 128, 320
+    sizes = jnp.array([50, 0, 40], jnp.int32)
+    xs = jax.random.normal(jax.random.key(7), (m, k))
+    w = jax.random.normal(jax.random.key(8), (3, k, n)) / np.sqrt(k)
+    want = jnp.concatenate(
+        [xs[:50] @ w[0], xs[50:90] @ w[2], jnp.zeros((m - 90, n))]
+    )
+    for impl in ("ragged_dot", "megablox"):
+        got = grouped_matmul(xs, w, sizes, impl=impl, interpret=impl == "megablox")
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5, err_msg=impl)
+        back = grouped_matmul(
+            got, jnp.swapaxes(w, 1, 2), sizes, impl=impl, interpret=impl == "megablox"
+        )
+        assert back.shape == (m, k) and float(jnp.abs(back[90:]).max()) == 0.0

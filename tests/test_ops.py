@@ -1612,3 +1612,76 @@ def test_the_mixers_form_is_on_the_compile_event_under_its_own_key():
     assert mixers["gdn_pointwise"] == {"fused": 1, "composed": 1}
     assert "kernel_paths" not in mixers and "flash_backward" not in mixers
     assert "gdn_pointwise" not in other
+
+
+# ------------------------------------------------- the state-space (SSD) scan
+
+from distributed_training_comparison_tpu.ops.ssd import (  # noqa: E402
+    ssd_scan,
+    ssd_scan_sequential,
+)
+
+
+def _ssd_inputs(b=2, s=80, h=6, p=8, g=2, n=16, rate=None, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.key(40), 7)
+    x = jax.random.normal(keys[0], (b, s, h, p), dtype)
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (b, s, h)) - 1.0)
+    A = -(jnp.linspace(0.05, 16.0, h) if rate is None else jnp.full((h,), rate))
+    B = jax.random.normal(keys[2], (b, s, g, n), dtype)
+    C = jax.random.normal(keys[3], (b, s, g, n), dtype)
+    D = 1.0 + 0.3 * jax.random.normal(keys[4], (h,))
+    return (x, dt, A, B, C, D), jax.random.normal(keys[5], (b, s, h, p))
+
+
+def _ssd_grads(f, x, cot):
+    return jax.grad(
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * cot), argnums=tuple(range(6))
+    )(*x)
+
+
+@pytest.mark.parametrize("rate", [None, 60.0, 1e-3], ids=["as_initialised", "near_0", "near_1"])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunked_ssd_scan_is_the_token_by_token_recurrence(chunk, rate):
+    """Output and all six gradients, in float32; three heads a group of B
+    and C; 80 tokens are five chunks of 16 or one and a quarter of 64,
+    padded.  Decays from the model's start (``A`` up to 16) to a state that
+    forgets within a token (every ratio underflows to zero, none to inf)."""
+    x, cot = _ssd_inputs(rate=rate)
+    with jax.default_matmul_precision("highest"):
+        y = ssd_scan(*x, chunk=chunk)
+        want = ssd_scan_sequential(*x, block=16)
+        assert y.shape == want.shape == x[0].shape
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(y, want, rtol=1e-4, atol=2e-6 * scale)
+        got = _ssd_grads(functools.partial(ssd_scan, chunk=chunk), x, cot)
+        refs = _ssd_grads(ssd_scan_sequential, x, cot)
+    for g, r, name in zip(got, refs, ("x", "dt", "A", "B", "C", "D")):
+        top = float(jnp.abs(r).max())
+        assert top > 0, name
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(g, r, rtol=2e-3, atol=2e-5 * top, err_msg=name)
+    if rate == 60.0:  # where a token's decay leaves nothing: y = dt (C.B) x + D x
+        xv, dt, A, B, C, D = x
+        cb = jnp.repeat(jnp.sum(B * C, -1), 3, axis=2)
+        alone = (dt * cb + D)[..., None] * xv
+        wiped = (dt * rate > 14.0)[..., None]
+        assert float(wiped.mean()) > 0.3
+        np.testing.assert_allclose(
+            jnp.where(wiped, y, 0), jnp.where(wiped, alone, 0), rtol=1e-3, atol=1e-3 * scale
+        )
+
+
+def test_ssd_scan_in_bf16_and_its_bad_calls():
+    """bf16 operands, float32 decays and state: within bf16's rounding of
+    the float32 recurrence, in the inputs' dtype; heads that the groups do
+    not divide are refused."""
+    x, _ = _ssd_inputs(dtype=jnp.bfloat16)
+    y = ssd_scan(*x, chunk=16)
+    assert y.dtype == jnp.bfloat16
+    want = ssd_scan_sequential(*(v.astype(jnp.float32) for v in x))
+    err = jnp.linalg.norm(y.astype(jnp.float32) - want) / jnp.linalg.norm(want)
+    assert 1e-4 < float(err) < 2e-2, float(err)
+    with pytest.raises(ValueError, match="groups"):
+        ssd_scan(x[0][:, :, :5], x[1][:, :, :5], x[2][:5], x[3], x[4], x[5][:5])
+    with pytest.raises(ValueError, match="whole blocks"):
+        ssd_scan_sequential(*x, block=32)

@@ -176,16 +176,24 @@ QWEN3_NEXT_SCOPES = (
     "attn_gate", "attention", "moe", "moe_gmm", "shared_expert", "lm_head",
     "loss", "guards", "optimizer",
 )
-_TOKEN_OP_NAMES: list = []
+NEMOTRON_H_SCOPES = (
+    "embed", "mamba", "ssm_conv", "ssd_scan", "ssm_gate_norm", "attn",
+    "attention", "moe", "moe_gmm", "shared_expert", "lm_head",
+    "loss", "guards", "optimizer",
+)
+TOKEN_CUTS = {
+    "qwen3_next_tiny": "layers=4,experts=4,first_expert=0,vocab=256",
+    "nemotron_h_tiny": "layers=7,experts=4,first_expert=0,vocab=256",
+}
+_TOKEN_OP_NAMES: dict = {}
 
 
-def _token_op_names(mesh) -> list:
+def _token_op_names(mesh, name="qwen3_next_tiny") -> list:
     """``op_name``s of ``make_train_step`` lowered (not compiled: the names
-    are the lowering's) for ``qwen3_next_tiny`` in bf16 under ``--remat``."""
-    if not _TOKEN_OP_NAMES:
+    are the lowering's) for a tiny token decoder in bf16 under ``--remat``."""
+    if name not in _TOKEN_OP_NAMES:
         model = get_model(
-            "qwen3_next_tiny", dtype=jnp.bfloat16, remat=True,
-            model_cut="layers=4,experts=4,first_expert=0,vocab=256",
+            name, dtype=jnp.bfloat16, remat=True, model_cut=TOKEN_CUTS[name]
         )
         state = _abstract_state(model)
         tokens = _spec((2, 32), jnp.int32)
@@ -194,8 +202,8 @@ def _token_op_names(mesh) -> list:
             state, tokens, tokens, jax.eval_shape(lambda: jax.random.key(0))
         ).as_text(debug_info=True)
         # the name stacks, not the call sites' function names beside them
-        _TOKEN_OP_NAMES.extend(set(re.findall(r'loc\("(jit\([^"]*)"', text)))
-    return _TOKEN_OP_NAMES
+        _TOKEN_OP_NAMES[name] = sorted(set(re.findall(r'loc\("(jit\([^"]*)"', text)))
+    return _TOKEN_OP_NAMES[name]
 
 
 @pytest.mark.parametrize("scope", QWEN3_NEXT_SCOPES)
@@ -214,6 +222,31 @@ def test_qwen3_next_scopes_are_in_the_lowered_step_program(mesh, scope):
         assert not any(scopes.under(n, "layers_3") for n in mine)
     if scope in ("attn", "attention", "attn_gate"):
         assert all(scopes.under(n, "layers_3") for n in mine)
+    if scope not in ("embed", "guards", "optimizer", "loss"):
+        assert {"forward", "backward"} <= {scopes.phase_of(n) for n in mine}
+
+
+@pytest.mark.parametrize("scope", NEMOTRON_H_SCOPES)
+def test_nemotron_h_scopes_are_in_the_lowered_step_program(mesh, scope):
+    """``models/nemotron_h.py``'s docstring names them; the readers under
+    ``benchmark/layer_metrics`` (``mamba_ms_per_step``,
+    ``ssd_scan_ms_per_step``, ``ssd_scan_roofline_pct``) look for them as
+    path components.  Layers 0, 2, 4 are Mamba-2, 5 attention, 1, 3, 6
+    experts: one mixer a layer."""
+    names = _token_op_names(mesh, "nemotron_h_tiny")
+    mine = [n for n in names if scopes.under(n, scope)]
+    assert mine, scope
+    layers = {i for n in mine for i in range(7) if scopes.under(n, f"layers_{i}")}
+    if scope in ("mamba", "ssm_conv", "ssd_scan", "ssm_gate_norm"):
+        # the state-space mixer is no attention (``attention_ms_per_step``
+        # reads that name) and the three scopes inside sit inside ``mamba``
+        assert not any(scopes.under(n, "attention") for n in mine)
+        assert all(scopes.under(n, "mamba") for n in mine)
+        assert layers == {0, 2, 4}
+    if scope in ("attn", "attention"):
+        assert layers == {5} and all(scopes.under(n, "attn") for n in mine)
+    if scope in ("moe", "moe_gmm", "shared_expert"):
+        assert layers == {1, 3, 6} and all(scopes.under(n, "moe") for n in mine)
     if scope not in ("embed", "guards", "optimizer", "loss"):
         assert {"forward", "backward"} <= {scopes.phase_of(n) for n in mine}
 

@@ -363,6 +363,46 @@ def test_topk_moe_moves_no_worst_case_buffer_for_v5e(chip, monkeypatch):
     assert " while(" in text and " conditional(" not in text
 
 
+def test_relu2_expert_layer_at_1856_compiles_for_v5e(chip, monkeypatch):
+    """Nemotron-3-Nano's expert layer as its chip sees it — 8,192 tokens of
+    2,688, squared-ReLU experts of 1,856 (14.5 lane tiles), 8 of 128 held,
+    top-6, a shared expert of 3,712, bf16, megablox — compiles under
+    ``jax.grad`` with the cast weights at 1,920 columns and the parameters'
+    gradients at 1,856; at the whole width Mosaic refuses the backward's
+    transposed block, which is why ``lane_width`` pads."""
+    from distributed_training_comparison_tpu.ops import moe_gmm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = TopKMoE(
+        2688, 1856, 128, 6, 8, 0, 2.5, dtype=BF16, bias_update_rate=1e-3,
+        shared_hidden=3712, mlp="relu2",
+    )
+    x = _s(1, 8192, 2688)
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros(x.shape, x.dtype))
+    )
+    assert variables["params"]["w1"].shape == (8, 1856, 2688)
+
+    def loss(params, stats, x):
+        y = layer.apply({"params": params, "batch_stats": stats}, x)
+        return jnp.square(y.astype(jnp.float32)).sum()
+
+    grad = jax.grad(loss, (0, 2))
+    text = _compiled_text(grad, chip, variables["params"], variables["batch_stats"], x)
+    assert "bf16[8,2688,1920]" in text and "bf16[8,1920,2688]" in text
+    assert "f32[8,1856,2688]" in text
+    # 2 products forward, 2 dx, 2 dW, the token sums forward and backward
+    assert text.count("tpu_custom_call") == 8
+    monkeypatch.setattr(moe_gmm, "lane_width", lambda width, impl: width)
+    monkeypatch.setattr(
+        "distributed_training_comparison_tpu.models.moe.lane_width",
+        lambda width, impl: width,
+    )
+    whole = jax.grad(lambda *a: loss(*a), (0, 2))  # a new function: traced anew
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        _compiled_text(whole, chip, variables["params"], variables["batch_stats"], x)
+
+
 def test_small_mha_compiles_for_v5e(chip):
     """The short-sequence kernel at vit_tiny's S=64 (its head_fwd/head_bwd
     helpers are what the fused block kernel is built from)."""

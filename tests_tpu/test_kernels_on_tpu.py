@@ -272,6 +272,61 @@ def test_gated_delta_kernels_with_keys_alike_in_bf16(capsys):
     assert all(kernel[n] <= 1.05 * before[n] for n in kernel), (kernel, before)
 
 
+def test_ssd_scan_cell_shape(capsys):
+    """``nemotron3nano_ep16_seq8k_job``'s scan as the model calls it — one
+    sequence of 8,192 tokens, 64 heads of 64 on a 64 x 128 state, B and C
+    shared by 8 groups, chunk 128, steps and decays as the model starts them
+    (``dt`` log-uniform in [1e-3, 0.1], ``A`` in [-16, -1]) — in bf16 against
+    the float32 token-by-token recurrence, output and all six gradients.
+    Prints the errors: the chunked scan in bf16 is a term of the cell's
+    first-step comparison (``PERF.md`` §6: 0.28 % in the output, 0.27-0.78 %
+    in the gradients).  And in float32 the chunked form is the recurrence
+    to rounding."""
+    from distributed_training_comparison_tpu.ops import ssd
+
+    keys = jax.random.split(jax.random.key(40), 8)
+    x = jax.random.normal(keys[0], (1, 8192, 64, 64))
+    step = jnp.exp(jax.random.uniform(
+        keys[1], (64,), minval=jnp.log(1e-3), maxval=jnp.log(0.1)
+    ))
+    dt = jax.nn.softplus(
+        0.1 * jax.random.normal(keys[2], (1, 8192, 64))
+        + step + jnp.log(-jnp.expm1(-step))
+    )
+    A = -jax.random.uniform(keys[3], (64,), minval=1.0, maxval=16.0)
+    B = jax.random.normal(keys[4], (1, 8192, 8, 128))
+    C = jax.random.normal(keys[5], (1, 8192, 8, 128))
+    D = jnp.ones((64,))
+    cot = jax.random.normal(keys[6], x.shape)
+    names = ("y", "dx", "ddt", "dA", "dB", "dC", "dD")
+
+    def grads(scan, args):
+        def loss(*a):
+            y = scan(*a)
+            return jnp.sum(y.astype(jnp.float32) * cot), y
+
+        g, y = jax.jit(jax.grad(loss, argnums=tuple(range(6)), has_aux=True))(*args)
+        return dict(zip(names, (y, *g)))
+
+    full = (x, dt, A, B, C, D)
+    low = (x.astype(jnp.bfloat16), dt, A, B.astype(jnp.bfloat16), C.astype(jnp.bfloat16), D)
+    chunked = lambda *a: ssd.ssd_scan(*a, chunk=128)  # noqa: E731
+    recurrence = grads(lambda *a: ssd.ssd_scan_sequential(*a, block=64), full)
+    rounded = _relative_l2(grads(chunked, low), recurrence)
+    with jax.default_matmul_precision("highest"):
+        exact = _relative_l2(grads(chunked, full), recurrence)
+    with capsys.disabled():
+        print(f"\nssd_scan chunk 128 vs float32 recurrence, relative l2: "
+              f"bf16 {rounded}, float32 {exact}")
+    assert rounded["y"] < 0.02, rounded
+    assert all(e < 0.05 for e in rounded.values()), rounded
+    # float32: y and dx to 2e-5; dA and ddt are sums over 8,192 x 64 tokens
+    # of products and read 3e-4 and 2e-4, the order of the sums (my chip
+    # run, PR 40)
+    assert exact["y"] < 1e-4 and exact["dx"] < 1e-4, exact
+    assert all(e < 1e-3 for e in exact.values()), exact
+
+
 def _mixer_pointwise_results(dtype, composed):
     """The mixer's two pointwise stages at ``qwen3next_ep32_seq8k_job``'s
     sizes (one sequence of 8,192 tokens, 16 key and 32 value heads of 128,
