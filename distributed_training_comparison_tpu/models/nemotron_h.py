@@ -33,7 +33,11 @@ written from knowledge of them):
    softplus(dt + dt_bias)``; ``A = -exp(A_log)``; per head from ``S = 0``:
    ``S <- exp(dt A) S + (dt x) B^T``; ``y = S C + D x`` (``ops/ssd.py
    ssd_scan``, the chunked form at ``chunk_size`` tokens a chunk, scope
-   ``ssd_scan``); ``y = y * silu(z)``, then RMS-normalised over each of the
+   ``ssd_scan``: a Pallas kernel pair on a TPU where ``N`` and ``(H / G) P``
+   are whole lane tiles and the length whole chunks — its backward keeps
+   each chunk's start states, nothing ``chunk x chunk`` — else composed XLA
+   with autodiff through it, the tiny models on a CPU among them;
+   ``kernel_paths`` says which as ``ssd``); ``y = y * silu(z)``, then RMS-normalised over each of the
    ``G`` groups of ``d_inner / G`` channels and scaled by ``w`` — gate
    first, norm second (scope ``ssm_gate_norm``); ``out = y W_out``.
 3. Attention (``num_attention_heads`` / ``num_key_value_heads`` heads of

@@ -1290,9 +1290,9 @@ def test_gated_delta_rule_in_bf16_and_its_bad_calls(path):
         gated_delta_rule_sequential(*x, block=48)
 
 
-def _noted_path(fn, *x):
-    """``fn(*x)`` under an observed compile, and the ``gated_delta`` path its
-    trace noted on the ``compile`` event."""
+def _noted_path(fn, *x, key="gated_delta"):
+    """``fn(*x)`` under an observed compile, and the ``key`` path its trace
+    noted on the ``compile`` event."""
     from distributed_training_comparison_tpu import obs
 
     bus = obs.configure(run_id=obs.new_run_id(), persist=True)
@@ -1302,7 +1302,7 @@ def _noted_path(fn, *x):
         (event,) = [e["payload"] for e in bus.ring_events() if e["kind"] == "compile"]
     finally:
         obs.reset()
-    return out, event["kernel_paths"]["gated_delta"]
+    return out, event["kernel_paths"][key]
 
 
 # calls the kernel pair cannot take: (sizes, chunk, interpret)
@@ -1616,10 +1616,29 @@ def test_the_mixers_form_is_on_the_compile_event_under_its_own_key():
 
 # ------------------------------------------------- the state-space (SSD) scan
 
+from distributed_training_comparison_tpu.ops import ssd  # noqa: E402
 from distributed_training_comparison_tpu.ops.ssd import (  # noqa: E402
+    ssd_plan,
     ssd_scan,
     ssd_scan_sequential,
 )
+
+# the two paths of ``ssd_scan``, as (sizes, chunk, options): the composed
+# form at the tiny widths (80 tokens: five chunks of 16, or one and a quarter
+# of 64, padded), and the Pallas kernel pair through the interpreter at the
+# smallest sizes it takes — a state of one lane tile, a group's two heads of
+# 64 one lane tile, two groups, two batch rows — at two grid steps (128
+# tokens: eight chunks of 16, four a step) and at one (two chunks of 128; one
+# batch row, one head of 128 a group)
+_SSD_KERNEL_SIZES = dict(b=2, s=128, h=4, p=64, g=2, n=128)
+SSD_PATHS = {
+    "composed-chunk_16": (dict(), 16, {}),
+    "composed-chunk_64": (dict(), 64, {}),
+    "pallas-two_steps": (_SSD_KERNEL_SIZES, 16, dict(interpret=True)),
+    "pallas-one_step": (
+        dict(b=1, s=256, h=2, p=128, g=2, n=128), 128, dict(interpret=True)
+    ),
+}
 
 
 def _ssd_inputs(b=2, s=80, h=6, p=8, g=2, n=16, rate=None, dtype=jnp.float32):
@@ -1640,20 +1659,22 @@ def _ssd_grads(f, x, cot):
 
 
 @pytest.mark.parametrize("rate", [None, 60.0, 1e-3], ids=["as_initialised", "near_0", "near_1"])
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_ssd_scan_is_the_token_by_token_recurrence(chunk, rate):
-    """Output and all six gradients, in float32; three heads a group of B
-    and C; 80 tokens are five chunks of 16 or one and a quarter of 64,
-    padded.  Decays from the model's start (``A`` up to 16) to a state that
-    forgets within a token (every ratio underflows to zero, none to inf)."""
-    x, cot = _ssd_inputs(rate=rate)
+@pytest.mark.parametrize("path", SSD_PATHS)
+def test_chunked_ssd_scan_is_the_token_by_token_recurrence(path, rate):
+    """Output and all six gradients, in float32, both paths (``SSD_PATHS``)
+    against the one recurrence; more than one head a group of B and C.
+    Decays from the model's start (``A`` up to 16) to a state that forgets
+    within a token (every ratio underflows to zero, none to inf)."""
+    sizes, chunk, options = SSD_PATHS[path]
+    x, cot = _ssd_inputs(rate=rate, **sizes)
+    scan = functools.partial(ssd_scan, chunk=chunk, **options)
     with jax.default_matmul_precision("highest"):
-        y = ssd_scan(*x, chunk=chunk)
+        y = scan(*x)
         want = ssd_scan_sequential(*x, block=16)
         assert y.shape == want.shape == x[0].shape
         scale = float(jnp.abs(want).max())
         np.testing.assert_allclose(y, want, rtol=1e-4, atol=2e-6 * scale)
-        got = _ssd_grads(functools.partial(ssd_scan, chunk=chunk), x, cot)
+        got = _ssd_grads(scan, x, cot)
         refs = _ssd_grads(ssd_scan_sequential, x, cot)
     for g, r, name in zip(got, refs, ("x", "dt", "A", "B", "C", "D")):
         top = float(jnp.abs(r).max())
@@ -1662,7 +1683,7 @@ def test_chunked_ssd_scan_is_the_token_by_token_recurrence(chunk, rate):
         np.testing.assert_allclose(g, r, rtol=2e-3, atol=2e-5 * top, err_msg=name)
     if rate == 60.0:  # where a token's decay leaves nothing: y = dt (C.B) x + D x
         xv, dt, A, B, C, D = x
-        cb = jnp.repeat(jnp.sum(B * C, -1), 3, axis=2)
+        cb = jnp.repeat(jnp.sum(B * C, -1), xv.shape[2] // B.shape[2], axis=2)
         alone = (dt * cb + D)[..., None] * xv
         wiped = (dt * rate > 14.0)[..., None]
         assert float(wiped.mean()) > 0.3
@@ -1671,17 +1692,101 @@ def test_chunked_ssd_scan_is_the_token_by_token_recurrence(chunk, rate):
         )
 
 
-def test_ssd_scan_in_bf16_and_its_bad_calls():
-    """bf16 operands, float32 decays and state: within bf16's rounding of
-    the float32 recurrence, in the inputs' dtype; heads that the groups do
-    not divide are refused."""
-    x, _ = _ssd_inputs(dtype=jnp.bfloat16)
-    y = ssd_scan(*x, chunk=16)
+@pytest.mark.parametrize("path", ["composed-chunk_16", "pallas-two_steps", "pallas-one_step"])
+def test_ssd_scan_in_bf16_and_its_bad_calls(path):
+    """bf16 operands, float32 decays and state: output and the six gradients
+    within bf16's rounding of the float32 recurrence's (relative l2, the
+    limits of ``tests_tpu``'s run at the cell's shape), in the inputs'
+    dtypes; heads that the groups do not divide are refused."""
+    sizes, chunk, options = SSD_PATHS[path]
+    x, cot = _ssd_inputs(dtype=jnp.bfloat16, **sizes)
+    scan = functools.partial(ssd_scan, chunk=chunk, **options)
+    y = scan(*x)
     assert y.dtype == jnp.bfloat16
-    want = ssd_scan_sequential(*(v.astype(jnp.float32) for v in x))
-    err = jnp.linalg.norm(y.astype(jnp.float32) - want) / jnp.linalg.norm(want)
-    assert 1e-4 < float(err) < 2e-2, float(err)
+    full = tuple(v.astype(jnp.float32) for v in x)
+    rel = lambda a, b: float(  # noqa: E731
+        jnp.linalg.norm((a.astype(jnp.float32) - b).ravel()) / jnp.linalg.norm(b.ravel())
+    )
+    assert 1e-4 < rel(y, ssd_scan_sequential(*full)) < 2e-2
+    refs = _ssd_grads(ssd_scan_sequential, full, cot)
+    for a, v, r, name in zip(_ssd_grads(scan, x, cot), x, refs, ("x", "dt", "A", "B", "C", "D")):
+        assert a.dtype == v.dtype, name
+        assert rel(a, r) < 0.05, name
+    h = x[0].shape[2] - 1
     with pytest.raises(ValueError, match="groups"):
-        ssd_scan(x[0][:, :, :5], x[1][:, :, :5], x[2][:5], x[3], x[4], x[5][:5])
+        scan(x[0][:, :, :h], x[1][:, :, :h], x[2][:h], x[3], x[4], x[5][:h])
     with pytest.raises(ValueError, match="whole blocks"):
-        ssd_scan_sequential(*x, block=32)
+        ssd_scan_sequential(*x, block=48)
+
+
+# calls the kernel pair cannot take: (sizes over the smallest it takes, chunk, interpret)
+SSD_LEFT_TO_THE_COMPOSED_FORM = {
+    "cpu_without_interpret": (dict(), 16, False),
+    "padded_length": (dict(s=120), 16, True),
+    "state_64": (dict(n=64), 16, True),
+    "group_channels_96": (dict(h=6, p=32), 16, True),
+    "head_size_8": (dict(p=8), 16, True),
+    "chunk_64": (dict(), 64, True),
+}
+
+
+@pytest.mark.parametrize("case", SSD_LEFT_TO_THE_COMPOSED_FORM)
+def test_a_call_the_ssd_kernels_cannot_take_is_the_composed_form(case):
+    """Such a call notes ``composed`` on the compile event and is, bit for
+    bit, what ``_composed`` gives (pad, ``_chunked``, cut: the only path
+    before the kernels)."""
+    sizes, chunk, interpret = SSD_LEFT_TO_THE_COMPOSED_FORM[case]
+    x, _ = _ssd_inputs(**{**_SSD_KERNEL_SIZES, "b": 1, **sizes})
+    scan = functools.partial(ssd_scan, chunk=chunk, interpret=interpret)
+    y, noted = _noted_path(scan, *x, key="ssd")
+    assert noted == "composed"
+    composed = jax.jit(functools.partial(ssd._composed, chunk=chunk))(*x)
+    np.testing.assert_array_equal(y, composed)
+
+
+SSD_PLANS = {
+    # (backend, dtype, heads, head size, groups, state, tokens, chunk): the
+    # cell's call, 64 chunks, four a grid step; float32 operands two a step
+    "cell": (("tpu", jnp.bfloat16, 64, 64, 8, 128, 8192, 128), 4),
+    "float32": (("tpu", jnp.float32, 64, 64, 8, 128, 8192, 128), 2),
+    # a length of whole chunks is whole grid steps of four, two or one
+    "one_chunk": (("tpu", jnp.bfloat16, 4, 64, 2, 128, 128, 128), 1),
+    "six_chunks": (("tpu", jnp.bfloat16, 4, 64, 2, 128, 96, 16), 2),
+    "seven_chunks": (("tpu", jnp.bfloat16, 4, 64, 2, 128, 112, 16), 1),
+    # a head of whole lane tiles, a wider state
+    "head_256": (("tpu", jnp.bfloat16, 2, 256, 1, 256, 1024, 128), 2),
+    # what the composed form keeps: no TPU, a state or a group's channels
+    # that are no whole lane tiles, a head that is neither a whole part of a
+    # tile nor whole tiles, another chunk or dtype, a length that would need
+    # padding, a group whose blocks do not fit
+    "cpu": (("cpu", jnp.bfloat16, 64, 64, 8, 128, 8192, 128), None),
+    "state_64": (("tpu", jnp.bfloat16, 64, 64, 8, 64, 8192, 128), None),
+    "group_channels_96": (("tpu", jnp.bfloat16, 6, 32, 2, 128, 8192, 128), None),
+    "head_48": (("tpu", jnp.bfloat16, 16, 48, 2, 128, 8192, 128), None),
+    "chunk_64": (("tpu", jnp.bfloat16, 64, 64, 8, 128, 8192, 64), None),
+    "float16": (("tpu", jnp.float16, 64, 64, 8, 128, 8192, 128), None),
+    "padded_chunk": (("tpu", jnp.bfloat16, 64, 64, 8, 128, 8200, 128), None),
+    "one_group_of_64_heads": (("tpu", jnp.bfloat16, 64, 64, 1, 128, 8192, 128), None),
+}
+
+
+@pytest.mark.parametrize("case", SSD_PLANS)
+def test_ssd_plan_takes_the_kernel_where_it_can(case):
+    """Which path a call takes is a pure function of what it shows: backend,
+    dtype, heads, head size, groups, state, length, chunk."""
+    call, step_chunks = SSD_PLANS[case]
+    assert ssd_plan(*call) == step_chunks
+
+
+def test_the_ssd_kernels_note_their_path():
+    """Through the interpreter the kernel pair notes ``pallas-interpret``
+    (on a TPU ``pallas``, which the cell's ``expect`` lists), at the
+    smallest call it takes: one chunk, one group of two heads."""
+    x, _ = _ssd_inputs(b=1, s=16, h=2, p=64, g=1, n=128)
+    scan = functools.partial(ssd_scan, chunk=16, interpret=True)
+    y, noted = _noted_path(scan, *x, key="ssd")
+    assert noted == "pallas-interpret"
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            y, ssd_scan_sequential(*x), rtol=1e-4, atol=1e-4
+        )

@@ -201,6 +201,110 @@ def test_composed_gated_delta_scan_compiles_for_v5e(chip, monkeypatch):
     assert "transpose(" in loops[1] and "transpose(" not in loops[0]
 
 
+# the scan call of ``nemotron3nano_ep16_seq8k_job``: one sequence, 64 heads of
+# 64 on a 64 x 128 state, B and C shared by 8 groups, chunk 128, bf16
+_SSD_CELL = dict(
+    batch=1, heads=64, head_dim=64, groups=8, state=128, chunk=128, dtype=BF16
+)
+
+
+def _ssd_scan_text(chip, tokens, *, backward=True, **sizes):
+    """``ssd_scan`` compiled for the chip, its backward with it, at the
+    cell's sizes (``_SSD_CELL``) but for ``sizes``."""
+    from distributed_training_comparison_tpu.ops.ssd import ssd_scan
+
+    z = {**_SSD_CELL, **sizes}
+    f32, heads = jnp.float32, z["heads"]
+    scan = lambda *a: ssd_scan(*a, chunk=z["chunk"])  # noqa: E731
+    bc = _s(z["batch"], tokens, z["groups"], z["state"], dtype=z["dtype"])
+    return _compiled_text(
+        _grad_of(scan, 6) if backward else scan, chip,
+        _s(z["batch"], tokens, heads, z["head_dim"], dtype=z["dtype"]),
+        _s(z["batch"], tokens, heads, dtype=f32), _s(heads, dtype=f32),
+        bc, bc, _s(heads, dtype=f32),
+    )
+
+
+def test_ssd_scan_compiles_for_v5e(chip, monkeypatch):
+    """The state-space scan at ``nemotron3nano_ep16_seq8k_job``'s sizes (one
+    sequence of 8,192 tokens), through ``ssd_scan`` as a TPU shows it the
+    call: one forward and one backward kernel, both under the scope
+    ``ssd_scan`` that the cell's readers match, no loop over chunks or
+    tokens outside them, no ``vmem_limit_bytes``; x, B and C read as ``(B,
+    S, H P)`` and ``(B, S, G N)`` — no float32 copy of an ``(S, H P)``
+    operand, no B or C repeated by head — and nothing ``L x L`` a head and
+    chunk in HBM."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ssd_scan_text(chip, 8192)
+    kernels = re.findall(r'tpu_custom_call.*op_name="([^"]*)"', text)
+    assert len(kernels) == 2 and all("ssd_scan" in n for n in kernels), kernels
+    assert "transpose(" in kernels[1] and "transpose(" not in kernels[0]
+    assert " while(" not in text
+    assert "vmem_limit_bytes" not in text
+    assert not re.search(r"f32\[[\d,]*128,128\]", text), "an (L, L) float32 tensor"
+    assert not re.search(
+        r"f32\[1,(8192,4096|8192,64,64|64,128,8,8,64)\]\S* (copy|convert)\(", text
+    )
+    assert not re.search(r"\[1,8192,8,8,128\]\S* broadcast\(", text)
+    # what the backward keeps: the 64 chunks' start states, float32
+    assert "f32[1,8,64,128,512]" in text
+
+
+def test_composed_ssd_scan_compiles_for_v5e(chip, monkeypatch):
+    """A length the kernels leave to the composed form on a TPU too (8,200
+    tokens: no whole chunks, padded to 65): composed XLA, one loop over the
+    chunks each way and no kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ssd_scan_text(chip, 8200)
+    assert "tpu_custom_call" not in text
+    loops = re.findall(r" while\(.*op_name=\"([^\"]*)\"", text)
+    assert len(loops) == 2 and all("ssd_scan" in n for n in loops), loops
+
+
+def test_ssd_scan_forward_alone_compiles_for_v5e(chip, monkeypatch):
+    """The cell's call where nothing is differentiated (``eval_runner``): the
+    forward kernel alone, and it writes no chunk's start state."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ssd_scan_text(chip, 8192, backward=False)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in text and "vmem_limit_bytes" not in text
+    assert "f32[1,8,64,128,512]" not in text
+
+
+# calls at the corners of what ``ssd_plan`` takes, as (tokens, sizes): Mosaic
+# has to take each (a head of whole lane tiles it refused in PR 41's tree,
+# "Broadcast in both sublanes and lanes", which no interpreted test could see)
+SSD_PLAN_CORNERS = {
+    "head_128": (256, dict(heads=2, head_dim=128, groups=2)),
+    "head_256_state_256": (1024, dict(heads=2, head_dim=256, groups=1, state=256)),
+    "head_16": (1024, dict(heads=8, head_dim=16, groups=1)),
+    "float32": (8192, dict(dtype=jnp.float32)),
+    "group_of_1024_channels": (8192, dict(heads=32, groups=2)),
+    "state_512": (4096, dict(heads=8, groups=1, state=512)),
+    "four_sequences": (4096, dict(batch=4)),
+    "three_chunks": (384, dict(batch=2, heads=2, groups=1)),
+    "chunk_16": (128, dict(batch=2, heads=4, groups=2, chunk=16)),
+}
+
+
+@pytest.mark.parametrize("case", SSD_PLAN_CORNERS)
+def test_what_ssd_plan_takes_compiles_for_v5e(chip, monkeypatch, case):
+    """A call the plan takes must be one the kernels run: the pair compiles
+    for the chip at each corner of the plan's conditions."""
+    from distributed_training_comparison_tpu.ops.ssd import ssd_plan
+
+    tokens, sizes = SSD_PLAN_CORNERS[case]
+    z = {**_SSD_CELL, **sizes}
+    assert ssd_plan(
+        "tpu", z["dtype"], z["heads"], z["head_dim"], z["groups"], z["state"],
+        tokens, z["chunk"],
+    ) is not None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ssd_scan_text(chip, tokens, **sizes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "vmem_limit_bytes" not in text
+
+
 def _mixer_pointwise_text(chip, backward):
     """The mixer's two pointwise stages at ``qwen3next_ep32_seq8k_job``'s
     sizes (16 key and 32 value heads of 128, one sequence of 8,192 tokens,
